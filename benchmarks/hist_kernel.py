@@ -69,6 +69,8 @@ def _timeit(fn, reps):
 
 
 def main(argv):
+    from lightgbm_tpu.utils.cache import configure_compile_cache
+    configure_compile_cache()
     json_path = None
     if "--json" in argv:
         i = argv.index("--json")

@@ -91,6 +91,8 @@ def _make_source(kind, rows, chunk_rows, workdir):
 
 
 def main(argv):
+    from lightgbm_tpu.utils.cache import configure_compile_cache
+    configure_compile_cache()
     json_path = None
     if "--json" in argv:
         i = argv.index("--json")

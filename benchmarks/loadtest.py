@@ -35,6 +35,10 @@ the availability SLO is met after the recovery window AND every client
 request reached a terminal outcome — is computed solely from the fleet
 ``/metrics`` + ``/slo`` scrapes (env knobs: FLEET_WORKERS,
 FLEET_DURATION, FLEET_QPS, FLEET_CRASH_AFTER, FLEET_RECOVERY_S).
+This rung is CPU ONLY: the launcher has touched JAX by the time it
+spawns, and a chip belongs to one process at a time, so the workers are
+pinned to ``JAX_PLATFORMS=cpu`` — it judges supervision and recovery,
+never device speed.
 
 ``--refresh`` runs the model-refresh-under-load rung: the same
 per-round updates are deployed to a live server as wire deltas
@@ -301,6 +305,7 @@ def run_fleet_chaos(workers: int = 2, duration_s: float = 8.0,
         model_file = _train_model(trees, leaves, features, tmp)
         fleet = FleetSupervisor(
             [model_file], workers=int(workers),
+            # one process per chip: spawned workers stay off the device
             worker_env={"JAX_PLATFORMS": "cpu", "PYTHONPATH": repo},
             worker_args={"warmup": "0", "max_wait_ms": "0.5"},
             first_spawn_env={0: {"LGBM_TPU_FAULTS":
@@ -1144,6 +1149,8 @@ def to_bench_matrix(report) -> dict:
 
 
 def main(argv) -> int:
+    from lightgbm_tpu.utils.cache import configure_compile_cache
+    configure_compile_cache()
     json_path = slo_path = ""
     if "--json" in argv:
         json_path = argv[argv.index("--json") + 1]

@@ -103,6 +103,8 @@ def _git_sha():
 
 
 def main(argv):
+    from lightgbm_tpu.utils.cache import configure_compile_cache
+    configure_compile_cache()
     json_path = None
     if "--json" in argv:
         i = argv.index("--json")

@@ -189,6 +189,8 @@ ALL = {
 
 
 def main():
+    from lightgbm_tpu.utils.cache import configure_compile_cache
+    configure_compile_cache()
     from lightgbm_tpu.utils.log import set_verbosity
     set_verbosity(-1)
     argv = list(sys.argv[1:])

@@ -101,6 +101,8 @@ def _measure_split(pred, Xq, reqs, bucket):
 
 
 def main(argv) -> None:
+    from lightgbm_tpu.utils.cache import configure_compile_cache
+    configure_compile_cache()
     json_path = ""
     if "--json" in argv:
         json_path = argv[argv.index("--json") + 1]
@@ -116,7 +118,7 @@ def main(argv) -> None:
 
     from lightgbm_tpu.utils.backend import default_backend
     from lightgbm_tpu.utils.log import set_verbosity
-    backend = default_backend()  # CPU fallback when the plugin is broken
+    backend = default_backend()
     set_verbosity(-1)
     rng = np.random.RandomState(1)
 

@@ -13,7 +13,7 @@ violation)."""
 
 import sys
 
-from .cli import main  # the package __init__ honors JAX_PLATFORMS
+from .cli import main
 
 if __name__ == "__main__":
     sys.exit(main())

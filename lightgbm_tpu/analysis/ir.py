@@ -192,7 +192,7 @@ def literal_operands(jaxpr_like: Any,
     """Inline Literal operands of at least ``min_elems`` elements, with
     the eqn consuming them (scalar literals are the normal case; a big
     one is a constant XLA will fold at compile time)."""
-    from jax.core import Literal
+    from jax.extend.core import Literal
     for info in iter_eqns(jaxpr_like):
         for v in info.eqn.invars:
             if isinstance(v, Literal) and aval_elems(v) >= min_elems:

@@ -92,37 +92,17 @@ MEM_GEOMETRY = Geometry(features=64, bins=255, leaves=17, wave=16,
                         rows=8192)
 
 
-def _backend_initialized() -> bool:
-    """True once a jax client exists (then the device count is fixed).
-    Must NOT itself initialize the backend — jax.devices() would."""
-    try:
-        from jax._src import xla_bridge
-        return bool(getattr(xla_bridge, "_backends", None))
-    except Exception:
-        return False
-
-
 def _ensure_devices(k: int) -> int:
     """Best-effort k virtual CPU devices.  Device count can only be set
-    before the first jax client exists; afterwards fall back to
-    whatever is visible (a larger requested W then traces over an
-    AbstractMesh — see :func:`_trace_mesh`)."""
-    import os
-
+    before the first jax client exists (jax raises RuntimeError after);
+    then fall back to whatever is visible (a larger requested W then
+    traces over an AbstractMesh — see :func:`_trace_mesh`)."""
     import jax
-    if not _backend_initialized():
-        try:
-            jax.config.update("jax_num_cpu_devices", k)
-        except (AttributeError, RuntimeError):
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    flags + f" --xla_force_host_platform_device_count={k}"
-                ).strip()
     try:
-        return min(k, len(jax.devices()))
-    except Exception:
-        return 1
+        jax.config.update("jax_num_cpu_devices", k)
+    except RuntimeError:
+        pass  # a client is live: the device count is fixed
+    return min(k, len(jax.devices()))
 
 
 def _trace_mesh(k: int, axis_name: str = "workers"):
@@ -134,13 +114,8 @@ def _trace_mesh(k: int, axis_name: str = "workers"):
     if avail >= k:
         from ..parallel.mesh import get_mesh
         return get_mesh(k, axis_name), False
-    try:
-        from jax.sharding import AbstractMesh
-    except ImportError as exc:
-        raise RuntimeError(
-            f"devices={k} exceeds the {avail} attached device(s) and this "
-            f"jax build has no AbstractMesh for trace-only meshes") from exc
-    return AbstractMesh(((axis_name, k),)), True
+    from jax.sharding import AbstractMesh
+    return AbstractMesh((k,), (axis_name,)), True
 
 
 def _mk_train_args(seed: int, n: int, geom: Geometry,
@@ -185,12 +160,11 @@ def _dp_entry(grow, mesh, ax):
     import jax
     from jax.sharding import PartitionSpec as P
     from ..parallel.data_parallel import DataParallelTreeLearner
-    from ..parallel.mesh import shard_map_compat
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         _serial_entry(grow), mesh=mesh,
         in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P(), P(), P(),
                   P(), P()),
-        out_specs=DataParallelTreeLearner._tree_specs(ax)))
+        out_specs=DataParallelTreeLearner._tree_specs(ax), check_vma=False))
 
 
 def _trace_with_tally(fn, args) -> Tuple[Any, Dict[str, Dict[str, Any]]]:

@@ -282,6 +282,8 @@ def run_profile(argv: List[str]) -> int:
 
 def main(argv: Optional[List[str]] = None) -> int:
     argv = sys.argv[1:] if argv is None else argv
+    from .utils.cache import configure_compile_cache
+    configure_compile_cache()
     if argv and argv[0] == "serve":
         # serving verb: python -m lightgbm_tpu serve model.txt [key=value]
         from .serve.server import main as serve_main

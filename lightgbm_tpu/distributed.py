@@ -58,16 +58,10 @@ def init(coordinator_address: Optional[str] = None,
         return
     import jax
     if cpu_collectives:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              cpu_collectives)
-        except AttributeError:
-            # option absent on this jax version; invalid VALUES still
-            # propagate so a typo'd backend fails loudly here rather than
-            # hanging at the first cross-process collective
-            log_warning("this jax version has no "
-                        "jax_cpu_collectives_implementation option; "
-                        "cross-process CPU collectives may be unavailable")
+        # an invalid VALUE raises here, so a typo'd backend fails loudly
+        # rather than hanging at the first cross-process collective
+        jax.config.update("jax_cpu_collectives_implementation",
+                          cpu_collectives)
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id,

@@ -470,7 +470,6 @@ def make_stacked_sharded_predict(stacked: DenseArrays, meta: DenseMeta,
     the ``serve/zoo_stack/score_psum`` collective contract (one psum
     per STACK, not one per tenant; declared in serve/zoo.py)."""
     from jax.sharding import PartitionSpec as P
-    from ..parallel.mesh import shard_map_compat
     from ..telemetry.train_record import note_collective
 
     def body(Xs, A):
@@ -478,10 +477,10 @@ def make_stacked_sharded_predict(stacked: DenseArrays, meta: DenseMeta,
         note_collective("serve/zoo_stack/score_psum", "psum", part)
         return jax.lax.psum(part, axis)
 
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), _stacked_shard_specs(stacked, axis)),
-        out_specs=P()))
+        out_specs=P(), check_vma=False))
 
 
 def _shard_specs(arrays: DenseArrays, axis: str):
@@ -510,7 +509,6 @@ def make_sharded_predict(arrays: DenseArrays, meta: DenseMeta, mesh,
     psum of the (N, K) partials — the declared
     ``serve/dense_predict/score_psum`` collective contract."""
     from jax.sharding import PartitionSpec as P
-    from ..parallel.mesh import shard_map_compat
     from ..telemetry.train_record import note_collective
 
     def body(X, A):
@@ -518,6 +516,6 @@ def make_sharded_predict(arrays: DenseArrays, meta: DenseMeta, mesh,
         note_collective("serve/dense_predict/score_psum", "psum", part)
         return jax.lax.psum(part, axis)
 
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(), _shard_specs(arrays, axis)),
-        out_specs=P()))
+        out_specs=P(), check_vma=False))

@@ -130,6 +130,20 @@ def _mappers_equal(a, b) -> bool:
     return True
 
 
+def _name_refused_kernels(exc: Exception) -> None:
+    """A kernel Mosaic refuses fails the compile of the whole grower, and
+    its message names an MLIR op, not the kernel.  Re-raise with the
+    kernels (kind + static shape) this process has traced, so the error
+    says what was being compiled; anything else passes through."""
+    if "Mosaic" not in f"{type(exc).__name__}: {exc}":
+        return
+    from ..ops.histogram_pallas import traced_kernels
+    raise RuntimeError(
+        "Mosaic refused a Pallas kernel while compiling the tree grower; "
+        f"kernels traced so far: {', '.join(traced_kernels())}. "
+        f"Compiler message: {exc}") from exc
+
+
 def _update_score_impl(score, row_leaf, leaf_value, shrinkage):
     """score += shrinkage * leaf_value[row_leaf] — training-set score update
     using the grower's final leaf assignment (replaces the reference's
@@ -465,7 +479,7 @@ class GBDT:
             self._row_valid = jax.make_array_from_process_local_data(
                 NamedSharding(_mesh, _P(_ax)), train_set._dist_valid_local)
         else:
-            self.X_dev = jnp.asarray(train_set.X_binned)
+            self.X_dev = self._put_rows(train_set.X_binned)
             self._row_valid = None
         self._is_cat_np = is_cat
         # bundle-space tree-walk decode arrays (EFB valid sets / rebuilds)
@@ -526,6 +540,7 @@ class GBDT:
             self.objective = create_objective(cfg.objective, cfg)
         if self.objective is not None:
             self.objective.init(train_set.metadata, self.num_data)
+            self.objective.place_rows(self._put_rows)
         self.num_tree_per_iteration = (
             self.objective.num_model_per_iteration if self.objective else
             max(1, cfg.num_class if cfg.num_class > 1 else 1))
@@ -549,7 +564,7 @@ class GBDT:
                 score0 = score0 + np.float32(self._pending_bias[0])
             else:
                 score0 = score0 + self._pending_bias[None, :].astype(np.float32)
-        self.score = jnp.asarray(score0)
+        self.score = self._put_rows(score0)
 
         self.train_metrics = []
         if cfg.is_provide_training_metric:
@@ -694,6 +709,24 @@ class GBDT:
             forced_splits=self._parse_forced_splits(),
             feature_contri=self._inner_contri())
 
+    def _put_rows(self, arr):
+        """Host per-row array -> device.  Under a row-sharded learner
+        (tree_learner=data/voting) the array is created on the learner's
+        mesh, sharded by rows, so the bin matrix, scores, labels and
+        masks never sit whole on device 0 to be re-scattered by every
+        grower call.  Rows that do not divide over the mesh stay on one
+        device, and so does everything in a multi-process world (each
+        process holds the full host data there and reads labels and
+        scores back, which a cross-process array does not allow); the
+        learner scatters those per call."""
+        mesh = getattr(self.learner, "mesh", None)
+        if mesh is not None and getattr(self.learner, "rows_sharded", False) \
+                and jax.process_count() == 1 \
+                and arr.shape[0] % mesh.size == 0:
+            from ..parallel.mesh import shard_rows
+            return shard_rows(mesh, arr, mesh.axis_names[0])
+        return jnp.asarray(arr)
+
     def _walk(self, bins, *tree_args):
         """Binned tree walk; routes through the bundle-space decode
         when the dataset is EFB-bundled (valid sets aligned to an EFB
@@ -788,7 +821,7 @@ class GBDT:
         if mask is not None:
             self._bag_mask = jnp.asarray(mask)
         elif not hasattr(self, "_bag_mask") or self._bag_mask.shape[0] != n:
-            self._bag_mask = jnp.ones(n, jnp.float32)
+            self._bag_mask = self._put_rows(np.ones(n, np.float32))
         return grad, hess, self._bag_mask
 
     def _feature_mask(self) -> Optional[jnp.ndarray]:
@@ -880,8 +913,13 @@ class GBDT:
                     extra["quant_key"] = jax.random.fold_in(
                         jax.random.PRNGKey(cfg.seed), it)
                 with rec.phase("grow"):
-                    grown = self.learner.train(self.X_dev, g, h, mask,
-                                               feature_mask=fmask, **extra)
+                    try:
+                        grown = self.learner.train(self.X_dev, g, h, mask,
+                                                   feature_mask=fmask,
+                                                   **extra)
+                    except Exception as exc:
+                        _name_refused_kernels(exc)
+                        raise
                 # full-data histogram passes of the last grown tree (wave
                 # grower; 0 = untracked) — a device scalar, pulled lazily
                 # by bench/diagnostic readers only

@@ -590,14 +590,14 @@ class BatchTrainer:
                        and self.L >= ndev and self.L % ndev == 0)
         if self._shard:
             from jax.sharding import PartitionSpec as P
-            from ..parallel.mesh import get_mesh, shard_map_compat
+            from ..parallel.mesh import get_mesh
             self._ndev = ndev
             mesh = get_mesh(ndev, "models")
             ax = mesh.axis_names[0]
-            self._vm_grow = jax.jit(shard_map_compat(
+            self._vm_grow = jax.jit(jax.shard_map(
                 vm_grow, mesh=mesh,
                 in_specs=(P(),) + (P(ax),) * 7,
-                out_specs=P(ax)))
+                out_specs=P(ax), check_vma=False))
         else:
             self._vm_grow = jax.jit(vm_grow)
         self._vm_walk = jax.vmap(walk_fn,

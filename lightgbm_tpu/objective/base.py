@@ -39,6 +39,13 @@ class ObjectiveFunction:
                        if metadata.weight is not None else None)
         self.num_data = num_data
 
+    def place_rows(self, put) -> None:
+        """Re-place the per-row device arrays with ``put`` (the boosting
+        driver's row-sharded placement under tree_learner=data/voting)."""
+        self.label = put(self.label)
+        if self.weight is not None:
+            self.weight = put(self.weight)
+
     def check_label(self, label: np.ndarray) -> None:
         pass
 

@@ -123,9 +123,6 @@ def _hist_packed4_chunk(bins_chunk: jnp.ndarray, w_chunk: jnp.ndarray,
 
 
 def _auto_impl() -> str:
-    # route through the probing wrapper: a broken TPU plugin raises
-    # RuntimeError from the raw jax.default_backend() before any CPU
-    # fallback can engage (utils/backend.py)
     from ..utils.backend import default_backend
     return "onehot" if default_backend() == "tpu" else "segment"
 

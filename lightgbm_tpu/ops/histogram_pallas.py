@@ -43,8 +43,8 @@ the kernel unpacks in VMEM against pre-split even/odd weight halves.
 Small-B one-hot tiles group MORE features per 128-row MXU tile instead
 of padding bins (``_tile_params``).  Contract: quantized int32 sums
 are bit-for-bit identical across every variant; f32 stays within the
-hi/lo exactness budget.  ``interpret=None`` auto-interprets off TPU,
-so all of this is testable on CPU, and the entry points batch under
+hi/lo exactness budget.  ``interpret=None`` interprets on the CPU,
+so all of this is testable there, and the entry points batch under
 ``vmap`` through jax's pallas_call batching rule (the batch axis
 becomes a leading grid dimension — what lets multitrain ride these
 kernels).
@@ -54,7 +54,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -65,9 +64,9 @@ __all__ = ["build_histogram_pallas", "build_histogram_pallas_leaves",
            "build_histogram_pallas_leaves_q8", "pack_weights8",
            "wave_trial_channels_pallas", "wave_row_update_pallas",
            "DEFAULT_ROW_BLOCK", "pad_rows", "LEAF_CHANNELS",
-           "Q_LEAF_CHANNELS", "DEFAULT_PIPELINE", "resolve_pipeline",
+           "Q_LEAF_CHANNELS", "resolve_pipeline",
            "resolve_interpret", "pack_bins4", "unpack_bins4",
-           "PACK4_MAX_BINS"]
+           "PACK4_MAX_BINS", "traced_kernels"]
 
 DEFAULT_ROW_BLOCK = 4096
 _C = 8  # weight channels (5 used), padded to a power of two for clean tiles
@@ -84,32 +83,32 @@ PACK4_MAX_BINS = 16
 # HBM->VMEM through explicitly double-buffered async copies that overlap
 # the MXU one-hot contraction; "blockspec" is the original implicit
 # per-grid-step operand fetch.  Default: dma ON TPU (where the overlap
-# is real); off-TPU the kernels run the interpreter, where the DMA
+# is real); on the CPU the kernels run the interpreter, where the DMA
 # machinery is pure emulation overhead, so unresolved calls default to
 # the cheaper-to-emulate blockspec form — explicit pipeline="dma"
-# forces the DMA form anywhere (the parity tests do).  Overridable via
-# the environment (the measured-dead-ends guard rail: re-probe with
-# LGBM_TPU_PALLAS_PIPELINE=blockspec before trusting a regression).
-DEFAULT_PIPELINE = os.environ.get("LGBM_TPU_PALLAS_PIPELINE", "")
+# forces the DMA form anywhere (the parity tests do).
 
 
 def resolve_pipeline(pipeline=None) -> str:
-    p = pipeline or DEFAULT_PIPELINE
-    if not p:
+    if not pipeline:
         from ..utils.backend import default_backend
-        p = "dma" if default_backend() == "tpu" else "blockspec"
-    if p not in ("dma", "blockspec"):
-        raise ValueError(f"pallas pipeline must be dma|blockspec, got {p!r}")
-    return p
+        pipeline = "dma" if default_backend() == "tpu" else "blockspec"
+    if pipeline not in ("dma", "blockspec"):
+        raise ValueError("pallas pipeline must be dma|blockspec, got "
+                         f"{pipeline!r}")
+    return pipeline
 
 
 def resolve_interpret(interpret=None) -> bool:
-    """None -> interpret off TPU (Mosaic cannot lower elsewhere), so the
-    kernels are runnable — and testable — on every backend."""
+    """None -> interpret exactly when the platform IS the cpu (the test
+    suite; Mosaic lowers nowhere else).  On a TPU the kernels always go
+    through Mosaic: a kernel it refuses fails the compile of the program
+    that holds it, and the boosting loop re-raises with the kernels'
+    names and shapes (:func:`traced_kernels`)."""
     if interpret is not None:
         return bool(interpret)
     from ..utils.backend import default_backend
-    return default_backend() != "tpu"
+    return default_backend() == "cpu"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -181,6 +180,25 @@ def _tile_params(num_bins: int, f: int, m_cap: int):
         b = _round_up(num_bins, 128)
         group = 1
     return b, group
+
+
+_TRACED_KERNELS: list = []
+
+
+def _kname(kind: str, **dims) -> str:
+    """``pallas_call`` name: the kernel and its static shape, so every
+    profiler event says which kernel at which size.  Mosaic's own error
+    does not carry it, so the names traced in this process are kept for
+    the boosting loop to report when a compile is refused
+    (:func:`traced_kernels`, models/gbdt.py)."""
+    name = "lgbm_" + kind + "".join(f"_{k}{v}" for k, v in dims.items())
+    if name not in _TRACED_KERNELS:
+        _TRACED_KERNELS.append(name)
+    return name
+
+
+def traced_kernels() -> tuple:
+    return tuple(_TRACED_KERNELS)
 
 
 def _note_kernel(site: str, streamed_bytes: int) -> None:
@@ -315,6 +333,8 @@ def _build_histogram_pallas_bs(bins_t: jnp.ndarray, grad: jnp.ndarray,
             bytes_accessed=f_pad * n + n * _C * 2 + f_pad * b * _C * 4,
             transcendentals=0),
         interpret=interpret,
+        name=_kname("hist_single_blockspec", f=f_pad, b=b, g=group, kr=kr,
+                    n=n),
     )(bins_t, w8)
 
     out = out.reshape(f_pad, b, _C)
@@ -353,10 +373,10 @@ def _hist_kernel_dma(bins_hbm, w_hbm, out_ref, *, num_features: int,
         def w_dma(slot, j):
             if packed:
                 return pltpu.make_async_copy(
-                    w_hbm.at[:, pl.ds(j * kb, kb), :], wbuf.at[slot],
+                    w_hbm.at[:, :, pl.ds(j * kb, kb)], wbuf.at[slot],
                     wsem.at[slot])
             return pltpu.make_async_copy(
-                w_hbm.at[pl.ds(j * kr, kr), :], wbuf.at[slot],
+                w_hbm.at[:, pl.ds(j * kr, kr)], wbuf.at[slot],
                 wsem.at[slot])
 
         bins_dma(0, 0).start()
@@ -372,16 +392,14 @@ def _hist_kernel_dma(bins_hbm, w_hbm, out_ref, *, num_features: int,
 
             bins_dma(slot, j).wait()
             w_dma(slot, j).wait()
-            blk = bbuf[slot]                         # (ft, kb) bin bytes
             if packed:
-                w_halves = (wbuf[slot, 0], wbuf[slot, 1])   # (kb, C) each
+                w_halves = (wbuf[slot, 0], wbuf[slot, 1])   # (C, kb) each
             else:
-                w_halves = (wbuf[slot],)                    # (kr, C)
+                w_halves = (wbuf[slot],)                    # (C, kr)
 
             def do(i, c):
-                fi = i * fstep
-                cols_blk = jax.lax.dynamic_slice_in_dim(
-                    blk, fi, fstep, 0).astype(jnp.int32)
+                fi = pl.multiple_of(i * fstep, fstep)
+                cols_blk = bbuf[slot, pl.ds(fi, fstep), :].astype(jnp.int32)
                 nibs = (cols_blk & 0xF, cols_blk >> 4) if packed \
                     else (cols_blk,)
                 for k in range(fstep // group):
@@ -391,7 +409,7 @@ def _hist_kernel_dma(bins_hbm, w_hbm, out_ref, *, num_features: int,
                         colrep = jnp.repeat(cols, b, axis=0)     # (g*B, kb)
                         onehot = (colrep == iota_gb).astype(jnp.bfloat16)
                         p = jax.lax.dot_general(
-                            onehot, wh, (((1,), (0,)), ((), ())),
+                            onehot, wh, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (g*B, C)
                         part = p if part is None else part + p
                     out_ref[pl.ds((fi + k * group) * b, group * b)] += part
@@ -402,7 +420,7 @@ def _hist_kernel_dma(bins_hbm, w_hbm, out_ref, *, num_features: int,
 
         jax.lax.fori_loop(0, nsteps, step, 0)
 
-    wshape = (2, 2, kb, _C) if packed else (2, kr, _C)
+    wshape = (2, 2, _C, kb) if packed else (2, _C, kr)
     pl.run_scoped(body,
                   pltpu.VMEM((2, ft, kb), bins_hbm.dtype),
                   pltpu.VMEM(wshape, jnp.bfloat16),
@@ -427,13 +445,16 @@ def _build_histogram_pallas_dma(bins_t: jnp.ndarray, grad: jnp.ndarray,
     g_hi, g_lo = _split_hi_lo(gm)
     h_hi, h_lo = _split_hi_lo(hm)
     z = jnp.zeros_like(g_hi)
+    # FEATURE-MAJOR (C, N) weights, like the leaf kernels: Mosaic refuses
+    # to DMA an 8-lane slab out of a row-major (N, C) array ("slice shape
+    # must be aligned to tiling (128)"), a (C, kr) slab is lane-dense
     w8 = jnp.stack([g_hi, g_lo, h_hi, h_lo, mask.astype(jnp.bfloat16),
-                    z, z, z], axis=-1)                     # (N, C)
+                    z, z, z], axis=0)                      # (C, N)
     if packed:
         # pre-split weight halves pair each nibble with its own rows, so
         # the kernel never lane-interleaves (Mosaic-unfriendly): half 0
         # carries even rows (low nibbles), half 1 odd rows (high nibbles)
-        w8 = jnp.stack([w8[0::2], w8[1::2]])               # (2, N/2, C)
+        w8 = jnp.stack([w8[:, 0::2], w8[:, 1::2]])         # (2, C, N/2)
 
     fstep = max(group, 8)
     ft_cap = max(fstep, 8192 // b // fstep * fstep)
@@ -448,8 +469,8 @@ def _build_histogram_pallas_dma(bins_t: jnp.ndarray, grad: jnp.ndarray,
                           group=group, fstep=fstep, kr=kr, nsteps=n // kr,
                           packed=packed),
         grid=(f_pad // ft,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((ft * b, _C), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((f_pad * b, _C), jnp.float32),
@@ -459,6 +480,8 @@ def _build_histogram_pallas_dma(bins_t: jnp.ndarray, grad: jnp.ndarray,
             n * _C * 2 + f_pad * b * _C * 4,
             transcendentals=0),
         interpret=interpret,
+        name=_kname("hist_single_dma" + ("_packed4" if packed else ""),
+                    f=f_pad, b=b, g=group, kr=kr, n=n),
     )(bins_t, w8)
 
     out = out.reshape(f_pad, b, _C)
@@ -658,6 +681,8 @@ def _build_histogram_pallas_leaves_bs(bins_t: jnp.ndarray, w8: jnp.ndarray,
             bytes_accessed=f_pad * n + n * (_C * 2 + 4) + f_pad * b * 512,
             transcendentals=0),
         interpret=interpret,
+        name=_kname("hist_leaves_blockspec", f=f_pad, b=b, g=group, kr=kr,
+                    n=n),
     )(bins_t, w8, ch2)
 
     out = out[:, :LEAF_CHANNELS * _CB].reshape(f_pad, b, LEAF_CHANNELS, _CB)
@@ -723,7 +748,6 @@ def _leaves_dma_common(bins_hbm, w_hbm, ch_hbm, out_ref, *, num_features,
             bins_dma(slot, j).wait()
             w_dma(slot, j).wait()
             ch_dma(slot, j).wait()
-            blk = bbuf[slot]
             if packed:
                 w128s = (make_w128(wbuf[slot, 0], cbuf[slot, 0]),
                          make_w128(wbuf[slot, 1], cbuf[slot, 1]))
@@ -731,9 +755,8 @@ def _leaves_dma_common(bins_hbm, w_hbm, ch_hbm, out_ref, *, num_features,
                 w128s = (make_w128(wbuf[slot], cbuf[slot]),)
 
             def do(i, c):
-                fi = i * fstep
-                cols_blk = jax.lax.dynamic_slice_in_dim(
-                    blk, fi, fstep, 0).astype(jnp.int32)
+                fi = pl.multiple_of(i * fstep, fstep)
+                cols_blk = bbuf[slot, pl.ds(fi, fstep), :].astype(jnp.int32)
                 nibs = (cols_blk & 0xF, cols_blk >> 4) if packed \
                     else (cols_blk,)
                 for k in range(fstep // group):
@@ -789,7 +812,7 @@ def _make_w128_q8(w, ch):
     return (wtile * sel).astype(jnp.int8)
 
 
-def _leaves_dma_call(bins_t, w, ch2, *, num_bins, interpret, packed,
+def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
                      m_cap, kr0, make_w128, onehot_dtype, acc_dtype,
                      out_dtype, row_block):
     """Shared wrapper plumbing of the two DMA leaf-kernel builders."""
@@ -812,9 +835,9 @@ def _leaves_dma_call(bins_t, w, ch2, *, num_bins, interpret, packed,
                           packed=packed, make_w128=make_w128,
                           onehot_dtype=onehot_dtype, acc_dtype=acc_dtype),
         grid=(f_pad // ft,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((ft * b, 128), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((f_pad * b, 128), out_dtype),
@@ -824,6 +847,8 @@ def _leaves_dma_call(bins_t, w, ch2, *, num_bins, interpret, packed,
             n * (_C * 2 + 4) + f_pad * b * 512,
             transcendentals=0),
         interpret=interpret,
+        name=_kname(kind + "_dma" + ("_packed4" if packed else ""),
+                    f=f_pad, b=b, g=group, kr=kr, n=n),
     )(bins_t, w, ch2)
     return out, f_pad
 
@@ -836,7 +861,8 @@ def _build_histogram_pallas_leaves_dma(bins_t, w8, ch, *, num_bins,
     n = w8.shape[1]
     ch2 = ch.astype(jnp.int32).reshape(1, n)
     out, f_pad = _leaves_dma_call(
-        bins_t, w8, ch2, num_bins=num_bins, interpret=interpret,
+        bins_t, w8, ch2, kind="hist_leaves", num_bins=num_bins,
+        interpret=interpret,
         packed=packed, m_cap=1024, kr0=4096, make_w128=_make_w128_bf16,
         onehot_dtype=jnp.bfloat16, acc_dtype=jnp.float32,
         out_dtype=jnp.float32, row_block=row_block)
@@ -1001,6 +1027,8 @@ def _build_histogram_pallas_leaves_q8_bs(bins_t: jnp.ndarray,
             bytes_accessed=f_pad * n + n * 9 + f_pad * b * 512,
             transcendentals=0),
         interpret=interpret,
+        name=_kname("hist_leaves_q8_blockspec", f=f_pad, b=b, g=group,
+                    kr=kr, n=n),
     )(bins_t, wch, ch.astype(jnp.int8).reshape(1, n))
 
     out = out[:, :Q_LEAF_CHANNELS * _QCB].reshape(f_pad, b,
@@ -1014,9 +1042,12 @@ def _build_histogram_pallas_leaves_q8_bs(bins_t: jnp.ndarray,
 def _build_histogram_pallas_leaves_q8_dma(bins_t, wch, ch, *, num_bins,
                                           row_block, interpret, packed):
     n = wch.shape[1]
-    ch2 = ch.astype(jnp.int8).reshape(1, n)
+    # int32 channel row: Mosaic cannot slice a (1, kr) slab out of a
+    # one-row int8 array (its tiles are 4 sublanes deep)
+    ch2 = ch.astype(jnp.int32).reshape(1, n)
     out, f_pad = _leaves_dma_call(
-        bins_t, wch, ch2, num_bins=num_bins, interpret=interpret,
+        bins_t, wch, ch2, kind="hist_leaves_q8", num_bins=num_bins,
+        interpret=interpret,
         packed=packed, m_cap=2048, kr0=4096, make_w128=_make_w128_q8,
         onehot_dtype=jnp.int8, acc_dtype=jnp.int32,
         out_dtype=jnp.int32, row_block=row_block)
@@ -1221,19 +1252,20 @@ def _wave_row_update_dma(cols_w: jnp.ndarray, rl: jnp.ndarray,
         functools.partial(_row_update_kernel_dma, w=w, krd=krd,
                           nsteps=n // kr),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((8, nd), jnp.int32),
             jax.ShapeDtypeStruct((8, nd), jnp.int8),
         ],
         interpret=interpret,
+        name=_kname("wave_row_update_dma", w=w, kr=kr, n=n),
     )(cols3, rl2, tab)
     return rl_new.reshape(n), ch.reshape(n)
 
@@ -1273,6 +1305,7 @@ def _wave_row_update_bs(cols_w: jnp.ndarray, rl: jnp.ndarray,
             jax.ShapeDtypeStruct((8, nd), jnp.int8),
         ],
         interpret=interpret,
+        name=_kname("wave_row_update_blockspec", w=w, kr=kr, n=n),
     )(cols3, rl2, tab)
     return rl_new.reshape(n), ch.reshape(n)
 

@@ -35,7 +35,7 @@ from ..learner.serial import (CommStrategy, GrownTree, local_best_candidate,
 from ..analysis.contracts import (collective_contract, memory_budget,
                                   world_size)
 from ..telemetry.train_record import note_collective
-from .mesh import get_mesh, psum_scatter_compat, shard_map_compat
+from .mesh import get_mesh, shard_rows
 
 __all__ = ["DataParallelTreeLearner", "DataParallelStrategy"]
 
@@ -142,8 +142,8 @@ class DataParallelStrategy(CommStrategy):
         # each device reduces + owns one contiguous feature block
         note_collective("data_parallel/masked/hist_reduce_scatter",
                         "psum_scatter", hist_local)
-        blk = psum_scatter_compat(hist_local, self.axis_name,
-                                  scatter_dimension=0, tiled=True)
+        blk = jax.lax.psum_scatter(hist_local, self.axis_name,
+                                   scatter_dimension=0, tiled=True)
         sl = lambda a: jax.lax.dynamic_slice(a, (start,), (fb,))
         mono = sl(self.monotone_full) if self.monotone_full is not None \
             else None
@@ -244,9 +244,8 @@ class WaveDPStrategy(CommStrategy):
         Fp/nshards block, fully reduced.  The telemetry note records the
         scattered OUTPUT (the per-device received payload — 1/k of the
         psum mode's full-batch residency)."""
-        out = psum_scatter_compat(hist, self.axis_name,
-                                  scatter_dimension=1, tiled=True,
-                                  axis_size=self.nshards)
+        out = jax.lax.psum_scatter(hist, self.axis_name,
+                                   scatter_dimension=1, tiled=True)
         note_collective("data_parallel/wave/hist_reduce_scatter",
                         "psum_scatter", out)
         return out
@@ -282,6 +281,7 @@ class DataParallelTreeLearner:
     gated off)."""
 
     name = "data"
+    rows_sharded = True  # models/gbdt.py places per-row arrays on the mesh
 
     def __init__(self, config: Config, num_features: int, max_bins: int,
                  num_bins: np.ndarray, is_cat: np.ndarray, has_nan: np.ndarray,
@@ -308,24 +308,6 @@ class DataParallelTreeLearner:
         self.wave = wave_able and (mode == "wave" or
                                    (mode == "auto" and
                                     impl_wave == "pallas"))
-        if not self.wave and not hasattr(jax, "shard_map"):
-            # jax<0.5 only ships jax.experimental.shard_map, whose legacy
-            # SPMD partitioner hits a hard CHECK (hlo_sharding_util merge
-            # of manual/tuple shardings) on the MASKED grower's program
-            # and aborts the process.  The wave grower compiles fine there
-            # — route through it when it can serve the config, otherwise
-            # fail cleanly instead of crashing the interpreter.
-            if wave_able and mode != "partition":
-                from ..utils.log import log_warning
-                log_warning("this jax version cannot compile the masked "
-                            "data-parallel grower (legacy SPMD "
-                            "partitioner); using the DP-wave grower")
-                self.wave = True
-            else:
-                raise RuntimeError(
-                    "tree_learner=data with the masked grower requires "
-                    "jax.shard_map (jax>=0.5); upgrade jax or use "
-                    "tree_grow_mode=wave")
         if self.wave:
             self._init_wave(config, num_features, num_bins, is_cat, has_nan,
                             monotone, impl_wave)
@@ -388,7 +370,7 @@ class DataParallelTreeLearner:
         def grow(X, g, h, m, nb, ic, hn, mono, fm):
             return grow_t(X, None, g, h, m, nb, ic, hn, mono, fm)
         tree_specs = self._tree_specs(self.axis)
-        self._grow = jax.jit(shard_map_compat(
+        self._grow = jax.jit(jax.shard_map(
             grow, mesh=self.mesh,
             in_specs=(P(self.axis), P(self.axis), P(self.axis), P(self.axis),
                       P(), P(), P(), P(), P()),
@@ -475,7 +457,7 @@ class DataParallelTreeLearner:
 
         tree_specs = self._tree_specs(self.axis)
         out_specs = (tree_specs, P(None, self.axis)) if nl else tree_specs
-        self._grow = jax.jit(shard_map_compat(
+        self._grow = jax.jit(jax.shard_map(
             grow, mesh=self.mesh,
             in_specs=(P(None, self.axis), P(self.axis), P(self.axis),
                       P(self.axis), P(), P(), P(), P(), P(), P()) +
@@ -506,13 +488,17 @@ class DataParallelTreeLearner:
             pad = (-n) % quantum
             if self._x_src is not X_dev:
                 Xp = jnp.pad(X_dev, ((0, pad), (0, 0))) if pad else X_dev
-                self._XpT = jnp.asarray(jnp.swapaxes(Xp, 0, 1))
+                self._XpT = shard_rows(self.mesh, jnp.swapaxes(Xp, 0, 1),
+                                       self.axis, dim=1)
                 self._x_src = X_dev
                 self._lazy_used = None  # fresh data -> fresh bitmap
             if pad:
                 grad = jnp.pad(grad, (0, pad))
                 hess = jnp.pad(hess, (0, pad))
                 sample_mask = jnp.pad(sample_mask, (0, pad))
+            grad, hess, sample_mask = (
+                shard_rows(self.mesh, v, self.axis)
+                for v in (grad, hess, sample_mask))
             if cegb_penalty is None:
                 cegb_penalty = jnp.zeros((self.num_features,), jnp.float32)
             keys = []

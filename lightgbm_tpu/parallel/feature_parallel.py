@@ -32,7 +32,7 @@ from ..learner.serial import (CommStrategy, GrownTree, local_best_candidate,
                               split_params_from_config)
 from ..analysis.contracts import collective_contract
 from ..telemetry.train_record import note_collective
-from .mesh import get_mesh, shard_map_compat
+from .mesh import get_mesh
 
 __all__ = ["FeatureParallelTreeLearner", "FeatureParallelStrategy"]
 
@@ -136,13 +136,6 @@ class FeatureParallelTreeLearner:
                  num_bins: np.ndarray, is_cat: np.ndarray, has_nan: np.ndarray,
                  monotone: Optional[np.ndarray] = None):
         self.config = config
-        if not hasattr(jax, "shard_map"):
-            # jax<0.5's legacy SPMD partitioner aborts the process (hard
-            # CHECK in hlo_sharding_util) compiling this learner's
-            # shard_map program; fail cleanly instead
-            raise RuntimeError(
-                "tree_learner=feature requires jax.shard_map (jax>=0.5); "
-                "upgrade jax, or use tree_learner=data (wave grower)")
         if config.use_quantized_grad:
             from ..utils.log import log_warning
             log_warning("use_quantized_grad is only applied by the wave "
@@ -198,7 +191,7 @@ class FeatureParallelTreeLearner:
         # descriptor args reaching the grower must be FULL arrays (global
         # feature indexing), so they ride in replicated and the strategy
         # slices per shard.
-        self._grow = jax.jit(shard_map_compat(
+        self._grow = jax.jit(jax.shard_map(
             grow, mesh=self.mesh,
             in_specs=(P(None, self.axis), P(), P(), P(), P(), P(), P(), P(),
                       P()),

@@ -4,58 +4,11 @@ is declared, XLA routes collectives over ICI/DCN)."""
 
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["get_mesh", "shard_rows", "replicate", "shard_map_compat",
-           "psum_scatter_compat"]
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at the top level with ``check_vma``; older
-    releases only have ``jax.experimental.shard_map.shard_map`` with the
-    flag spelled ``check_rep``.  Every parallel learner builds its grower
-    through this shim so the mesh path works on both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def psum_scatter_compat(x, axis_name, *, scatter_dimension=0, tiled=True,
-                        axis_size: Optional[int] = None):
-    """``jax.lax.psum_scatter`` with an emulation fallback.
-
-    The reduce-scatter collective is the backbone of the feature-sliced
-    histogram merge (the reference's ReduceScatter,
-    data_parallel_tree_learner.cpp:155-173 / network.h:164): every shard
-    receives ONE reduced block of the operand instead of the whole
-    reduced tensor.  Old jax builds that lack the primitive fall back to
-    ``psum`` + this shard's slice — functionally identical, without the
-    1/k wire saving (``axis_size`` must then be given, since the slice
-    width cannot be derived from a traced axis index)."""
-    try:
-        return jax.lax.psum_scatter(x, axis_name,
-                                    scatter_dimension=scatter_dimension,
-                                    tiled=tiled)
-    except (AttributeError, NotImplementedError):
-        if axis_size is None:
-            raise RuntimeError(
-                "this jax build lacks lax.psum_scatter and no axis_size "
-                "was provided for the psum+slice emulation")
-        full = jax.lax.psum(x, axis_name)
-        blk = x.shape[scatter_dimension] // int(axis_size)
-        idx = jax.lax.axis_index(axis_name) * blk
-        return jax.lax.dynamic_slice_in_dim(full, idx, blk,
-                                            axis=scatter_dimension)
+__all__ = ["get_mesh", "shard_rows", "replicate"]
 
 
 def get_mesh(num_devices: int = 0, axis_name: str = "workers") -> Mesh:
@@ -67,9 +20,15 @@ def get_mesh(num_devices: int = 0, axis_name: str = "workers") -> Mesh:
     return Mesh(np.array(devs), (axis_name,))
 
 
-def shard_rows(mesh: Mesh, arr, axis_name: str = "workers"):
-    """Place an array row-sharded over the mesh (data-parallel layout)."""
-    return jax.device_put(arr, NamedSharding(mesh, P(axis_name)))
+def shard_rows(mesh: Mesh, arr, axis_name: str = "workers", dim: int = 0):
+    """Place an array on the mesh with its row dimension ``dim`` sharded
+    (the data-parallel layout; ``dim=1`` for feature-major bin matrices).
+    A no-op for an array that already lives there, so the row-sharded
+    learners call it on every per-row operand: whatever the boosting
+    loop created on the mesh stays put, anything else is scattered here,
+    once, instead of silently inside each grower call."""
+    spec = P(*([None] * dim), axis_name)
+    return jax.device_put(arr, NamedSharding(mesh, spec))
 
 
 def replicate(mesh: Mesh, arr):

@@ -31,7 +31,7 @@ from ..learner.serial import (CommStrategy, GrownTree, local_best_candidate,
 from ..ops.split import NEG_INF, best_split_per_feature
 from ..analysis.contracts import collective_contract, memory_budget
 from ..telemetry.train_record import note_collective
-from .mesh import get_mesh, shard_map_compat
+from .mesh import get_mesh, shard_rows
 
 __all__ = ["VotingParallelTreeLearner", "VotingStrategy",
            "WaveVotingStrategy", "QuantizedGradUnsupportedError",
@@ -393,6 +393,7 @@ class VotingParallelTreeLearner:
     different model."""
 
     name = "voting"
+    rows_sharded = True  # models/gbdt.py places per-row arrays on the mesh
 
     def __init__(self, config: Config, num_features: int, max_bins: int,
                  num_bins: np.ndarray, is_cat: np.ndarray, has_nan: np.ndarray,
@@ -460,7 +461,7 @@ class VotingParallelTreeLearner:
         def grow(X, g, h, m, nb, ic, hn, mono, fm):
             return grow_t(X, None, g, h, m, nb, ic, hn, mono, fm)
         tree_specs = self._tree_specs(self.axis)
-        self._grow = jax.jit(shard_map_compat(
+        self._grow = jax.jit(jax.shard_map(
             grow, mesh=self.mesh,
             in_specs=(P(self.axis), P(self.axis), P(self.axis), P(self.axis),
                       P(), P(), P(), P(), P()),
@@ -530,7 +531,7 @@ class VotingParallelTreeLearner:
                           **kw)
 
         tree_specs = self._tree_specs(self.axis)
-        self._grow = jax.jit(shard_map_compat(
+        self._grow = jax.jit(jax.shard_map(
             grow, mesh=self.mesh,
             in_specs=(P(None, self.axis), P(self.axis), P(self.axis),
                       P(self.axis), P(), P(), P(), P(), P(), P()) +
@@ -555,12 +556,16 @@ class VotingParallelTreeLearner:
             pad = (-n) % quantum
             if self._x_src is not X_dev:
                 Xp = jnp.pad(X_dev, ((0, pad), (0, 0))) if pad else X_dev
-                self._XpT = jnp.asarray(jnp.swapaxes(Xp, 0, 1))
+                self._XpT = shard_rows(self.mesh, jnp.swapaxes(Xp, 0, 1),
+                                       self.axis, dim=1)
                 self._x_src = X_dev
             if pad:
                 grad = jnp.pad(grad, (0, pad))
                 hess = jnp.pad(hess, (0, pad))
                 sample_mask = jnp.pad(sample_mask, (0, pad))
+            grad, hess, sample_mask = (
+                shard_rows(self.mesh, v, self.axis)
+                for v in (grad, hess, sample_mask))
             if cegb_penalty is None:
                 cegb_penalty = jnp.zeros((self.num_features,), jnp.float32)
             keys = []

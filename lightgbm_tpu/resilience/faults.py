@@ -17,7 +17,8 @@ arm a child) or programmatically via ``faults.configure(...)``:
                      dies; the others hit a collective timeout)
   device_loss=1      make the accelerator-backend probe
                      (``utils/backend.default_backend``) report the
-                     device as lost, driving the CPU-fallback path
+                     device as lost: the probe raises, nothing falls
+                     back to the CPU
 
 Serve-side chaos (the fleet-resilience suite kills and wedges worker
 processes deterministically WHILE the load generator drives traffic;
@@ -178,7 +179,7 @@ class FaultPlan:
 
     def check_device_probe(self) -> None:
         """Called by the backend probe; an armed ``device_loss`` makes it
-        take the CPU-fallback path."""
+        raise like a lost accelerator."""
         if self.is_active("device_loss"):
             self.fire("device_loss")
             raise RuntimeError(

@@ -854,7 +854,7 @@ class FleetSupervisor:
                         w.state = "alive"
                     w.last_probe_t = now
                     # keep the REAL boot status: a worker that comes up
-                    # degraded (CPU fallback) must weigh 1x in dispatch
+                    # degraded (shedding, SLO burn) must weigh 1x in dispatch
                     # from its first request, not 4x until the next probe
                     w.last_health = boot_health
                     if self._sync_models(w):

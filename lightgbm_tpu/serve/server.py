@@ -2,10 +2,9 @@
 
 Endpoints:
   GET  /healthz  -> {"status": "ok"|"degraded", "models": [...]} —
-                    degraded (with "reasons") while serving on the CPU
-                    fallback backend, while admission control shed
-                    requests in the last minute, or while an SLO's fast
-                    burn window has run hot for several consecutive
+                    degraded (with "reasons") while admission control
+                    shed requests in the last minute, or while an SLO's
+                    fast burn window has run hot for several consecutive
                     evaluations; still 200
   GET  /models   -> per-model info (trees, classes, buckets, version)
   GET  /stats    -> per-model counters (requests/rows/batches/recompiles/
@@ -150,9 +149,9 @@ class PredictionServer:
     body) fails slow requests with 504 instead of hanging the handler
     thread.  Both ride the micro-batcher queue and are inert with
     ``batching=False`` (the direct-dispatch debug path has no queue to
-    bound or expire).  ``/healthz`` reports ``degraded`` while traffic
-    is served on the CPU fallback backend, sheds happened recently, or
-    an SLO fast-burn has been sustained (``slo_engine.sustain``
+    bound or expire).  ``/healthz`` reports ``degraded`` while sheds
+    happened recently or an SLO fast-burn has been sustained
+    (``slo_engine.sustain``
     consecutive hot evaluations)."""
 
     def __init__(self, registry: ModelRegistry, host: str = "127.0.0.1",
@@ -322,16 +321,12 @@ class PredictionServer:
 
     def health(self) -> dict:
         """``/healthz`` payload: ``ok``, or ``degraded`` with reasons
-        while traffic runs on the CPU fallback backend, admission
-        control shed requests in the last minute, or an SLO's fast burn
-        window has run hot for ``slo_engine.sustain`` consecutive
+        while admission control shed requests in the last minute, or an
+        SLO's fast burn window has run hot for ``slo_engine.sustain``
+        consecutive
         evaluations — still 200 (the tier answers), but a reason for an
         operator to look."""
-        from ..utils.backend import fallback_reason
         reasons = []
-        fb = fallback_reason()
-        if fb:
-            reasons.append(f"cpu_fallback: {fb}")
         if self._last_shed_t and \
                 time.monotonic() - self._last_shed_t < SHED_DEGRADED_WINDOW_S:
             reasons.append("shedding: request queue hit its limit in the "
@@ -822,9 +817,9 @@ def main(argv: List[str]) -> int:
     """
     from ..utils.backend import default_backend
     from ..utils.log import log_fatal
-    # resolve the backend before any model touches the device: a broken
-    # accelerator plugin downgrades the server to CPU instead of killing
-    # it during warmup
+    # resolve the backend before any model is loaded: a server that
+    # cannot reach its chip exits here instead of serving from a CPU
+    # nobody asked for
     default_backend()
     files = [a for a in argv if "=" not in a]
     kv = {k: v for k, v in

@@ -20,6 +20,7 @@ class LGBMModel:
     """Base sklearn-style estimator (reference sklearn.py LGBMModel)."""
 
     _objective_default: Optional[str] = None
+    _estimator_type: Optional[str] = None
 
     def __init__(self, boosting_type: str = "gbdt", num_leaves: int = 31,
                  max_depth: int = -1, learning_rate: float = 0.1,
@@ -58,6 +59,19 @@ class LGBMModel:
         self._classes = None
 
     # sklearn plumbing ------------------------------------------------------
+    def __sklearn_tags__(self):
+        """Estimator tags (scikit-learn >= 1.6 reads these instead of
+        ``_estimator_type``; these classes do not inherit BaseEstimator,
+        so that sklearn stays an optional dependency)."""
+        from sklearn.utils import (ClassifierTags, InputTags, RegressorTags,
+                                   Tags, TargetTags)
+        kind = self._estimator_type
+        return Tags(
+            estimator_type=kind, target_tags=TargetTags(required=True),
+            classifier_tags=ClassifierTags() if kind == "classifier" else None,
+            regressor_tags=RegressorTags() if kind == "regressor" else None,
+            input_tags=InputTags(sparse=True, allow_nan=True))
+
     def get_params(self, deep: bool = True) -> Dict[str, Any]:
         params = {k: getattr(self, k) for k in (
             "boosting_type", "num_leaves", "max_depth", "learning_rate",
@@ -228,6 +242,7 @@ class LGBMModel:
 
 class LGBMRegressor(LGBMModel):
     _objective_default = "regression"
+    _estimator_type = "regressor"
 
     def fit(self, X, y, **kwargs) -> "LGBMRegressor":
         super().fit(X, y, **kwargs)
@@ -236,6 +251,7 @@ class LGBMRegressor(LGBMModel):
 
 class LGBMClassifier(LGBMModel):
     _objective_default = "binary"
+    _estimator_type = "classifier"
 
     def _process_label(self, y, params):
         self._classes, y_enc = np.unique(y, return_inverse=True)

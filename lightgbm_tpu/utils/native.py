@@ -1,14 +1,17 @@
 """Native host-runtime loader: compiles + loads the C++ helpers in
 ``native/`` on first use (ctypes ABI; reference's ingest hot loops are C++
-too — src/io/bin.cpp / dense_bin.hpp).  Falls back to numpy silently when
-no compiler is available, so the framework stays pure-Python-runnable."""
+too — src/io/bin.cpp / dense_bin.hpp).  Built from the committed sources
+into ``<cache root>/native`` (utils/cache.py), never from or into a
+directory outside the checkout's own caches.  Falls back to numpy when no
+compiler is available — host-side binning only, results are identical —
+so the framework stays pure-Python-runnable; ``get_lib() is None`` says
+which one ran."""
 
 from __future__ import annotations
 
 import ctypes
 import os
 import subprocess
-import tempfile
 from typing import Optional
 
 import numpy as np
@@ -21,6 +24,16 @@ _NATIVE_DIR = os.path.join(
     "native")
 
 
+def _build_dir() -> str:
+    from .cache import cache_root
+    path = os.path.join(cache_root(), "native")
+    try:
+        os.makedirs(path, exist_ok=True)
+    except OSError:
+        pass  # read-only checkout: the build below fails -> numpy path
+    return path
+
+
 def _n_threads() -> int:
     return max(1, min(os.cpu_count() or 1, 32))
 
@@ -29,10 +42,7 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
     src = os.path.join(_NATIVE_DIR, "binning.cc")
     if not os.path.exists(src):
         return None
-    cache = os.path.join(tempfile.gettempdir(),
-                         f"lgbm_tpu_native_{os.getuid()}")
-    os.makedirs(cache, exist_ok=True)
-    lib_path = os.path.join(cache, "libbinning.so")
+    lib_path = os.path.join(_build_dir(), "libbinning.so")
     if (not os.path.exists(lib_path) or
             os.path.getmtime(lib_path) < os.path.getmtime(src)):
         tmp = f"{lib_path}.{os.getpid()}.tmp"  # per-pid: no build races
@@ -79,16 +89,14 @@ def build_capi_shim() -> Optional[str]:
     src = os.path.join(_NATIVE_DIR, "capi_shim.cc")
     if not os.path.exists(src):
         return None
-    cache = os.path.join(tempfile.gettempdir(),
-                         f"lgbm_tpu_native_{os.getuid()}")
-    os.makedirs(cache, exist_ok=True)
     inc = sysconfig.get_path("include")
     libdir = sysconfig.get_config_var("LIBDIR") or ""
     ver = sysconfig.get_config_var("LDVERSION") or \
         sysconfig.get_config_var("VERSION")
     # python version in the name: a shim linked against another
     # libpython must never be reused after an interpreter upgrade
-    lib_path = os.path.join(cache, f"liblightgbm_tpu_capi-py{ver}.so")
+    lib_path = os.path.join(_build_dir(),
+                            f"liblightgbm_tpu_capi-py{ver}.so")
     if (os.path.exists(lib_path) and
             os.path.getmtime(lib_path) >= os.path.getmtime(src)):
         return lib_path

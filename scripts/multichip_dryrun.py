@@ -34,7 +34,7 @@ def collective_bytes_snapshot(n_devices: int) -> dict:
     from lightgbm_tpu.ops.split import SplitParams
     from lightgbm_tpu.parallel.data_parallel import (
         DataParallelTreeLearner, WaveDPStrategy)
-    from lightgbm_tpu.parallel.mesh import get_mesh, shard_map_compat
+    from lightgbm_tpu.parallel.mesh import get_mesh
     from lightgbm_tpu.parallel.voting_parallel import WaveVotingStrategy
     from lightgbm_tpu.telemetry.train_record import (collectives_reset,
                                                      collectives_snapshot)
@@ -65,13 +65,13 @@ def collective_bytes_snapshot(n_devices: int) -> dict:
             split_params=sp, hist_impl="pallas", any_cat=False,
             interpret=True, jit=False, wave_size=4, stochastic=False,
             quantized=True, strategy=strategy)
-        wrapped = shard_map_compat(
+        wrapped = jax.shard_map(
             lambda X_T, g, h, m, nb, ic, hn, mono, cp, fm: grow(
                 X_T, g, h, m, nb, ic, hn, mono, cp, (), fm),
             mesh=mesh,
             in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P(), P(),
                       P(), P(), P()),
-            out_specs=DataParallelTreeLearner._tree_specs(ax))
+            out_specs=DataParallelTreeLearner._tree_specs(ax), check_vma=False)
         collectives_reset()
         jax.make_jaxpr(lambda *a: wrapped(*a))(*args)
         out[mode] = collectives_snapshot()

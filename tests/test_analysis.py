@@ -47,7 +47,7 @@ def _nested_program(x):
 def test_ir_walks_nested_subjaxprs():
     jx = ir.trace(_nested_program, jnp.ones((4,)))
     prims = [info.prim for info in ir.iter_eqns(jx)]
-    assert "scan" in prims and "cond" in prims and "pjit" in prims
+    assert "scan" in prims and "cond" in prims and "jit" in prims
     # eqns INSIDE the scan body were visited and carry the loop path
     in_scan = [info for info in ir.iter_eqns(jx) if "scan" in info.path]
     assert in_scan and all(info.in_loop for info in in_scan)
@@ -142,7 +142,6 @@ def _mesh8():
 def _shard_psum(fn_site, payload_shape):
     """shard_map program psum-ing one payload, tallied at ``fn_site``."""
     from jax.sharding import PartitionSpec as P
-    from lightgbm_tpu.parallel.mesh import shard_map_compat
     mesh = _mesh8()
     ax = mesh.axis_names[0]
 
@@ -150,8 +149,8 @@ def _shard_psum(fn_site, payload_shape):
         note_collective(fn_site, "psum", x)
         return jax.lax.psum(x, ax)
 
-    return shard_map_compat(f, mesh=mesh, in_specs=(P(ax),),
-                            out_specs=P(ax)), \
+    return jax.shard_map(f, mesh=mesh, in_specs=(P(ax),),
+                         out_specs=P(ax), check_vma=False), \
         jnp.ones((8,) + payload_shape, jnp.float32)
 
 
@@ -195,7 +194,6 @@ def test_budget_rule_flags_count_overrun_and_undeclared_site():
     contracts.collective_contract(site, "psum", max_count=1)
     try:
         from jax.sharding import PartitionSpec as P
-        from lightgbm_tpu.parallel.mesh import shard_map_compat
         mesh = _mesh8()
         ax = mesh.axis_names[0]
 
@@ -208,8 +206,8 @@ def test_budget_rule_flags_count_overrun_and_undeclared_site():
             c = jax.lax.pmax(x, ax)
             return a + b + c
 
-        fn = shard_map_compat(f, mesh=mesh, in_specs=(P(ax),),
-                              out_specs=P(ax))
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P(ax),),
+                           out_specs=P(ax), check_vma=False)
         unit = _unit_for(fn, (jnp.ones((16,)),), site_filter="test/")
         vs = CollectiveBudgetRule().check(unit)
         msgs = "\n".join(v.message for v in vs)
@@ -226,11 +224,10 @@ def test_budget_rule_flags_untallied_collective_drift():
     """A collective op in the program with NO note_collective tally:
     the contract/tally drift class."""
     from jax.sharding import PartitionSpec as P
-    from lightgbm_tpu.parallel.mesh import shard_map_compat
     mesh = _mesh8()
     ax = mesh.axis_names[0]
-    fn = shard_map_compat(lambda x: jax.lax.psum(x, ax), mesh=mesh,
-                          in_specs=(P(ax),), out_specs=P(ax))
+    fn = jax.shard_map(lambda x: jax.lax.psum(x, ax), mesh=mesh,
+                       in_specs=(P(ax),), out_specs=P(ax), check_vma=False)
     unit = _unit_for(fn, (jnp.ones((16,)),), site_filter="test/")
     vs = CollectiveBudgetRule().check(unit)
     assert any("drifted" in v.message and v.site == "<program>"

@@ -6,7 +6,6 @@ while other features vary)."""
 import numpy as np
 import pytest
 
-from conftest import FP_SKIP
 
 import lightgbm_tpu as lgb
 
@@ -76,7 +75,6 @@ def test_monotone_data_parallel():
     assert _is_monotone(bst, 1, -1)
 
 
-@FP_SKIP
 def test_monotone_feature_parallel():
     X, y = _gen()
     bst = lgb.train({**PARAMS, "tree_learner": "feature", "num_devices": 4},

@@ -6,7 +6,6 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import FP_SKIP
 
 import lightgbm_tpu as lgb
 
@@ -27,7 +26,7 @@ def test_mesh_available():
 
 
 @pytest.mark.parametrize("tree_learner", [
-    "data", pytest.param("feature", marks=FP_SKIP), "voting"])
+    "data", "feature", "voting"])
 def test_parallel_matches_serial(tree_learner, data):
     X, y = data
     p = {}
@@ -49,7 +48,7 @@ def test_data_parallel_regression(data):
 
 
 @pytest.mark.parametrize("tree_learner", [
-    "data", pytest.param("feature", marks=FP_SKIP)])
+    "data", "feature"])
 def test_parallel_bagging_goss_matches_serial(tree_learner, data):
     """Sampling paths under shard_map: bagging masks and GOSS gradient
     amplification must reproduce the serial learner exactly (the mask is

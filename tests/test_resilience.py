@@ -455,17 +455,34 @@ def test_kill_at_iter_subprocess_leaves_resumable_ring(tmp_path):
 
 
 # -- device-loss fault -------------------------------------------------------
-def test_device_loss_fault_drives_cpu_fallback():
+def test_device_loss_is_an_error_not_a_cpu_run(monkeypatch):
+    """An accelerator that cannot be initialised raises out of the
+    backend probe; nothing re-pins the process to the CPU."""
+    import jax
     from lightgbm_tpu.utils import backend
-    saved = backend._resolved, backend._fallback_reason
+    monkeypatch.setattr(backend, "_resolved", None)
+    before = jax.config.jax_platforms
+    faults.configure("device_loss=1")
     try:
-        backend._reset_probe_for_tests()
-        faults.configure("device_loss=1")
-        assert backend.default_backend() == "cpu"
-        assert "device lost" in (backend.fallback_reason() or "")
+        with pytest.raises(RuntimeError, match="device lost"):
+            backend.default_backend()
     finally:
         faults.clear()
-        backend._resolved, backend._fallback_reason = saved
+    assert backend._resolved is None
+    assert jax.config.jax_platforms == before
+
+
+def test_cpu_nobody_asked_for_is_an_error(monkeypatch):
+    """JAX settling on the CPU by itself (no JAX_PLATFORMS=cpu) must not
+    pass for a run on the chip."""
+    import types
+    from lightgbm_tpu.utils import backend
+    monkeypatch.setattr(backend, "_resolved", None)
+    monkeypatch.setattr(backend, "jax", types.SimpleNamespace(
+        default_backend=lambda: "cpu",
+        config=types.SimpleNamespace(jax_platforms=None)))
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        backend.default_backend()
 
 
 def test_fault_plan_env_parse():

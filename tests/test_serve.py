@@ -647,23 +647,3 @@ def test_serve_sigterm_drains_and_exits_128_plus_signum(tmp_path,
         if proc.poll() is None:
             proc.kill()
             proc.wait(30)
-
-
-def test_healthz_degraded_on_cpu_fallback(tmp_path, booster, monkeypatch):
-    """/healthz reports degraded (with the probe's reason) while the
-    process serves on the CPU fallback backend."""
-    from lightgbm_tpu.utils import backend
-    model_file = str(tmp_path / "model.txt")
-    booster.save_model(model_file)
-    reg = ModelRegistry()
-    reg.load("model", model_file, warmup=False)
-    srv = PredictionServer(reg, port=0)
-    try:
-        assert srv.health()["status"] == "ok"
-        monkeypatch.setattr(backend, "_fallback_reason",
-                            "plugin UNAVAILABLE (injected)")
-        health = srv.health()
-        assert health["status"] == "degraded"
-        assert any("cpu_fallback" in r for r in health["reasons"])
-    finally:
-        srv._httpd.server_close()

@@ -136,14 +136,13 @@ def _wrap_dp(grow, mesh, ax):
     import jax
     from jax.sharding import PartitionSpec as P
     from lightgbm_tpu.parallel.data_parallel import DataParallelTreeLearner
-    from lightgbm_tpu.parallel.mesh import shard_map_compat
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         lambda X_T, g, h, m, nb, ic, hn, mono, cp, fm: grow(
             X_T, g, h, m, nb, ic, hn, mono, cp, (), fm),
         mesh=mesh,
         in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P(), P(), P(),
                   P(), P()),
-        out_specs=DataParallelTreeLearner._tree_specs(ax)))
+        out_specs=DataParallelTreeLearner._tree_specs(ax), check_vma=False))
 
 
 def test_spec_dp_matches_serial_on_mesh():

@@ -23,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 from lightgbm_tpu.analysis import ir, lint, spmd
 from lightgbm_tpu.analysis.lint import ALL_RULES
 from lightgbm_tpu.analysis.rules import TraceUnit, run_rules
-from lightgbm_tpu.parallel.mesh import get_mesh, shard_map_compat
+from lightgbm_tpu.parallel.mesh import get_mesh
 from lightgbm_tpu.telemetry import _config as tele_config
 
 
@@ -44,7 +44,8 @@ def test_collective_trace_orders_ops():
         b = jax.lax.pmax(a, ax)
         return jax.lax.psum(b * 2, ax)
 
-    fn = shard_map_compat(f, mesh=mesh, in_specs=(P(ax),), out_specs=P(ax))
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(P(ax),), out_specs=P(ax),
+                       check_vma=False)
     seq = spmd.collective_trace(ir.trace(fn, jnp.ones((16, 4))))
     assert [op[0] for op in seq] == ["psum", "pmax", "psum"]
     assert all("workers" in op[1] for op in seq)
@@ -70,8 +71,9 @@ def _cond_program(divergent: bool):
         other = arm_identity if divergent else arm_with_psum
         return jax.lax.cond(pred, arm_with_psum, other, x)
 
-    return shard_map_compat(f, mesh=mesh, in_specs=(P(ax),),
-                            out_specs=P(ax) if divergent else P())
+    return jax.shard_map(f, mesh=mesh, in_specs=(P(ax),),
+                         out_specs=P(ax) if divergent else P(),
+                         check_vma=False)
 
 
 def test_divergent_cond_arm_fires():
@@ -98,8 +100,8 @@ def test_shard_map_mesh_mismatch_fires():
     """A program sharded over axis 'model' while the config declares a
     ('workers',) mesh — the launcher would never build it."""
     mesh = get_mesh(4, axis_name="model")
-    fn = shard_map_compat(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
-                          in_specs=(P("model"),), out_specs=P())
+    fn = jax.shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
+                       in_specs=(P("model"),), out_specs=P(), check_vma=False)
     unit = TraceUnit(name="planted",
                      jaxpr=ir.trace(fn, jnp.ones((8, 2))),
                      ctx={"mesh_axes": ("workers",)})
@@ -112,8 +114,8 @@ def test_shard_map_mesh_mismatch_fires():
 def test_shard_map_matching_mesh_quiet():
     mesh = _mesh8()
     ax = mesh.axis_names[0]
-    fn = shard_map_compat(lambda x: jax.lax.psum(x, ax), mesh=mesh,
-                          in_specs=(P(ax),), out_specs=P())
+    fn = jax.shard_map(lambda x: jax.lax.psum(x, ax), mesh=mesh,
+                       in_specs=(P(ax),), out_specs=P(), check_vma=False)
     unit = TraceUnit(name="ok", jaxpr=ir.trace(fn, jnp.ones((16,))),
                      ctx={"mesh_axes": ("workers",)})
     assert spmd.ShardingConsistencyRule().check(unit) == []
