@@ -221,14 +221,14 @@ def run_profile(argv: List[str]) -> int:
 
     Wraps a train or predict run (``config.task``, default train) in a
     ``jax.profiler.trace`` capture plus a telemetry dump: enables the
-    span tracer and timetag timer, runs the task, then writes
+    span tracer's event list, runs the task, then writes
 
       * ``<profile_dir>/``            — jax profiler capture
         (TensorBoard / xprof readable), unless ``jax_trace=0``
       * ``<profile_dir>/host_spans.json`` — host span chrome trace
       * ``<profile_dir>/telemetry.json``  — metrics registry + the run's
-        TrainRecord (per-phase seconds, hist passes, collective tallies,
-        compile events, memory watermark)
+        TrainRecord (per-phase host seconds, set-up seconds, hist passes
+        by kind, collective tallies, compile events, memory watermark)
 
     Keys consumed here: ``profile_dir`` (default ``lgbm_tpu_profile``),
     ``telemetry_out``, ``host_trace_out``, ``jax_trace`` (1).
@@ -246,11 +246,9 @@ def run_profile(argv: List[str]) -> int:
     os.makedirs(prof_dir, exist_ok=True)
     from .telemetry import enable as telemetry_enable
     from .telemetry import global_tracer, write_snapshot
-    from .utils.timer import global_timer
     telemetry_enable()
     global_tracer.enable()
     global_tracer.clear()
-    global_timer.enable()
     cfg = Config(params)
     task = cfg.task or "train"
     if task not in ("train", "predict", "refit"):

@@ -28,6 +28,7 @@ import numpy as np
 from .binning import (BinMapper, bin_matrix, find_bin,
                       find_bin_from_summary)
 from .config import Config
+from .telemetry.trace import timed_span
 from .utils.log import log_info
 
 __all__ = ["Dataset", "Metadata", "DatasetCorruptError"]
@@ -128,13 +129,19 @@ class Dataset:
         self.num_total_features = 0
         self.efb = None  # BundleInfo when EFB-bundled (efb.py)
         self._device_cache: Dict[Any, Any] = {}
+        # host seconds of construct()'s phases (all host-synchronous):
+        # "to_float64", "bin_find", "bin_matrix"; GBDT._init_train copies
+        # them into the run's TrainRecord
+        self.setup_seconds: Dict[str, float] = {}
 
     # -- construction --------------------------------------------------------
     def construct(self, config: Optional[Config] = None) -> "Dataset":
         if self.constructed:
             return self
         cfg = config or Config(self.params)
-        raw, feature_names = self._materialize_raw()
+        secs = self.setup_seconds
+        with timed_span(secs, "to_float64", "dataset/construct/to_float64"):
+            raw, feature_names = self._materialize_raw()
         sparse = hasattr(raw, "tocsc")
         if sparse:
             raw = raw.tocsc()
@@ -179,8 +186,9 @@ class Dataset:
         # root); the shared generator keeps the sparse path's remaining
         # stream identical
         from .ingest.sketch import sample_row_indices
-        sample_idx = sample_row_indices(n, sample_cnt,
-                                        cfg.data_random_seed, rng=rng)
+        with timed_span(secs, "bin_find", "dataset/construct/sample"):
+            sample_idx = sample_row_indices(n, sample_cnt,
+                                            cfg.data_random_seed, rng=rng)
         dist_sketch = None
         dist_sparse_cols = None
         n_total = n
@@ -256,58 +264,59 @@ class Dataset:
             # SampleTextDataFromFile — here rows are already in memory)
             forced_bins = self._load_forced_bins(cfg)
             self.bin_mappers = []
-            for j in range(f):
-                if dist_sketch is not None:
-                    # distributed dense: finalize the merged summaries
-                    # through the shared sketch machinery
-                    summary = dist_sketch.summary(j)
-                    filt = max(1, int(cfg.min_data_in_leaf *
-                                      summary.total_cnt /
+            with timed_span(secs, "bin_find", "dataset/construct/find_bins"):
+                for j in range(f):
+                    if dist_sketch is not None:
+                        # distributed dense: finalize the merged summaries
+                        # through the shared sketch machinery
+                        summary = dist_sketch.summary(j)
+                        filt = max(1, int(cfg.min_data_in_leaf *
+                                          summary.total_cnt /
+                                          max(1, n_total))) \
+                            if cfg.feature_pre_filter else 0
+                        self.bin_mappers.append(find_bin_from_summary(
+                            summary, cfg.max_bin,
+                            min_data_in_bin=cfg.min_data_in_bin,
+                            use_missing=cfg.use_missing,
+                            zero_as_missing=cfg.zero_as_missing,
+                            forced_bounds=forced_bins.get(j),
+                            pre_filter_cnt=filt))
+                        continue
+                    if dist_sparse_cols is not None:
+                        col_sample = dist_sparse_cols[j]
+                    elif sparse:
+                        # sparse column: sampled nonzeros + proportional
+                        # implied zeros (no densification)
+                        lo, hi = raw.indptr[j], raw.indptr[j + 1]
+                        vals = np.asarray(raw.data[lo:hi], np.float64)
+                        if len(vals) > sample_cnt:
+                            vals = vals[np.sort(rng.choice(len(vals),
+                                                           sample_cnt, False))]
+                        zfrac = 1.0 - (hi - lo) / max(n, 1)
+                        nz = int(round(len(vals) * zfrac / max(1e-9, 1 - zfrac))) \
+                            if zfrac < 1.0 else sample_cnt
+                        nz = min(nz, sample_cnt)
+                        col_sample = np.concatenate([vals, np.zeros(nz)])
+                    else:
+                        col_sample = raw[sample_idx, j]
+                    # the reference's pre-filter threshold scales
+                    # min_data_in_leaf by the sample fraction
+                    # (dataset_loader.cpp filter_cnt)
+                    # 0 disables the pre-filter (feature_pre_filter=false
+                    # keeps even never-splittable features, like the reference)
+                    filt = max(1, int(cfg.min_data_in_leaf * len(col_sample) /
                                       max(1, n_total))) \
                         if cfg.feature_pre_filter else 0
-                    self.bin_mappers.append(find_bin_from_summary(
-                        summary, cfg.max_bin,
+                    self.bin_mappers.append(find_bin(
+                        col_sample, max_bin=cfg.max_bin,
                         min_data_in_bin=cfg.min_data_in_bin,
+                        total_cnt=len(col_sample),
+                        is_categorical=(j in cat_indices),
                         use_missing=cfg.use_missing,
                         zero_as_missing=cfg.zero_as_missing,
                         forced_bounds=forced_bins.get(j),
                         pre_filter_cnt=filt))
-                    continue
-                if dist_sparse_cols is not None:
-                    col_sample = dist_sparse_cols[j]
-                elif sparse:
-                    # sparse column: sampled nonzeros + proportional
-                    # implied zeros (no densification)
-                    lo, hi = raw.indptr[j], raw.indptr[j + 1]
-                    vals = np.asarray(raw.data[lo:hi], np.float64)
-                    if len(vals) > sample_cnt:
-                        vals = vals[np.sort(rng.choice(len(vals),
-                                                       sample_cnt, False))]
-                    zfrac = 1.0 - (hi - lo) / max(n, 1)
-                    nz = int(round(len(vals) * zfrac / max(1e-9, 1 - zfrac))) \
-                        if zfrac < 1.0 else sample_cnt
-                    nz = min(nz, sample_cnt)
-                    col_sample = np.concatenate([vals, np.zeros(nz)])
-                else:
-                    col_sample = raw[sample_idx, j]
-                # the reference's pre-filter threshold scales
-                # min_data_in_leaf by the sample fraction
-                # (dataset_loader.cpp filter_cnt)
-                # 0 disables the pre-filter (feature_pre_filter=false
-                # keeps even never-splittable features, like the reference)
-                filt = max(1, int(cfg.min_data_in_leaf * len(col_sample) /
-                                  max(1, n_total))) \
-                    if cfg.feature_pre_filter else 0
-                self.bin_mappers.append(find_bin(
-                    col_sample, max_bin=cfg.max_bin,
-                    min_data_in_bin=cfg.min_data_in_bin,
-                    total_cnt=len(col_sample),
-                    is_categorical=(j in cat_indices),
-                    use_missing=cfg.use_missing,
-                    zero_as_missing=cfg.zero_as_missing,
-                    forced_bounds=forced_bins.get(j),
-                    pre_filter_cnt=filt))
-            self._finalize_used_features(f)
+                self._finalize_used_features(f)
 
         used = self.used_feature_map
         mappers = [self.bin_mappers[j] for j in used]
@@ -322,7 +331,7 @@ class Dataset:
                                                   mappers, self.efb)
             else:
                 self.X_binned = bundle_binned_matrix(
-                    bin_matrix(raw[:, used], mappers), self.efb)
+                    self._bin_dense(raw, used, mappers), self.efb)
             log_info(f"EFB: bundled {len(used)} features into "
                      f"{self.efb.n_bundles} device columns "
                      f"({self.efb.bundle_bins} bundle bins)")
@@ -339,7 +348,7 @@ class Dataset:
                 cols.append(col)
             self.X_binned = np.stack(cols, axis=1)
         else:
-            self.X_binned = bin_matrix(raw[:, used], mappers)
+            self.X_binned = self._bin_dense(raw, used, mappers)
         if cfg.linear_tree and not sparse:
             # linear trees fit on RAW feature values (reference
             # linear_tree_learner.cpp raw_index); keep the used columns
@@ -356,6 +365,14 @@ class Dataset:
         if self.free_raw_data:
             self.data = None
         return self
+
+    def _bin_dense(self, raw, used, mappers) -> np.ndarray:
+        """Bin codes of the used columns of a dense float64 matrix."""
+        secs = self.setup_seconds
+        with timed_span(secs, "bin_matrix", "dataset/construct/select_columns"):
+            cols = raw[:, used]
+        with timed_span(secs, "bin_matrix", "dataset/construct/bin_matrix"):
+            return bin_matrix(cols, mappers)
 
     @staticmethod
     def _load_forced_bins(cfg) -> Dict[int, list]:

@@ -537,5 +537,9 @@ class ChunkedWaveGrower:
             leaf_count=host["leaf_count"],
             num_leaves=host["num_leaves"],
             row_leaf=np.zeros((0,), np.int32),
-            hist_passes=host["hist_passes"])
+            hist_passes=host["hist_passes"],
+            # every streamed pass after the root's is a wave: this grower
+            # has neither a ramp nor an endgame
+            wave_passes=host["hist_passes"] - 1,
+            endgame_passes=np.int32(0), ramp_committed=np.int32(0))
         return grown, rl_chunks

@@ -612,7 +612,7 @@ def train_streamed(params: Dict[str, Any], train_set: StreamedDataset,
             if abs(bias) > EPSILON:
                 tree.add_bias(bias)
             trees.append(tree)
-            # score update: the in-core _update_score_impl's
+            # score update: the in-core _score_update_impl's
             # score + lv[row_leaf], per chunk, host f32 (same IEEE ops)
             for i, rl_c in enumerate(rl_chunks):
                 lo, hi = train_set.chunk_bounds(i)

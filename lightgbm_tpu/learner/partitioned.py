@@ -47,7 +47,7 @@ from ..ops.histogram import build_histogram
 from ..ops.split import (BIG, NEG_INF, _leaf_gain, leaf_output,
                          leaf_output_smoothed)
 from .endgame import patch_child_pointers, write_split_records
-from .serial import CommStrategy, GrownTree
+from .serial import CommStrategy, GrownTree, untracked_passes
 
 __all__ = ["make_partitioned_grow_fn", "PART_ROW_BLOCK"]
 
@@ -647,6 +647,6 @@ def make_partitioned_grow_fn(*, num_leaves: int, num_features: int,
             internal_count=s["internal_count"], leaf_value=s["leaf_value"],
             leaf_weight=s["leaf_weight"], leaf_count=s["leaf_count"],
             num_leaves=s["num_leaves"], row_leaf=row_leaf,
-            hist_passes=jnp.asarray(0, jnp.int32))
+            **untracked_passes())
 
     return jax.jit(grow) if jit else grow

@@ -45,9 +45,10 @@ from ..ops.histogram import build_histogram
 from ..ops.split import (BIG, NEG_INF, FeatureSplits, SplitParams,
                          best_split_per_feature, leaf_output)
 from ..models.tree import CAT_MASK, DEFAULT_LEFT_MASK, MISSING_NAN
+from ..telemetry.trace import timed_span
 
 __all__ = ["SerialTreeLearner", "GrownTree", "make_grow_fn", "CommStrategy",
-           "local_best_candidate"]
+           "local_best_candidate", "untracked_passes"]
 
 
 class GrownTree(NamedTuple):
@@ -73,6 +74,20 @@ class GrownTree(NamedTuple):
     #                                0 = untracked: the partitioned/masked
     #                                growers' per-split builds scale with
     #                                the split leaf's size, not with N)
+    # the kinds of those passes (wave grower; 0 elsewhere):
+    # 1 + wave_passes + endgame_passes == hist_passes
+    wave_passes: jnp.ndarray       # () int32 — committed waves
+    endgame_passes: jnp.ndarray    # () int32 — exact-endgame bank passes
+    ramp_committed: jnp.ndarray    # () int32 — splits the speculative
+    #                                ramp's verifying pass committed, of
+    #                                W-1 provisional (0 with the ramp off)
+
+
+def untracked_passes() -> dict:
+    """The pass-count fields of a grower that does not count passes."""
+    z = jnp.asarray(0, jnp.int32)
+    return dict(hist_passes=z, wave_passes=z, endgame_passes=z,
+                ramp_committed=z)
 
 
 def local_best_candidate(hist, leaf_sum, num_bins, is_cat, has_nan,
@@ -531,7 +546,7 @@ def make_grow_fn(*, num_leaves: int, max_bins: int, max_depth: int,
             internal_count=s["internal_count"], leaf_value=s["leaf_value"],
             leaf_weight=s["leaf_weight"], leaf_count=s["leaf_count"],
             num_leaves=s["num_leaves"], row_leaf=s["row_leaf"],
-            hist_passes=jnp.asarray(0, jnp.int32))
+            **untracked_passes())
 
     return jax.jit(grow) if jit else grow
 
@@ -736,6 +751,7 @@ class SerialTreeLearner:
             impl = "onehot"
         self.pallas = impl == "pallas"
         self._x_src = None
+        self.setup_seconds = {}   # "layout": see train()
         # The partition-ordered grower (learner/partitioned.py) is the
         # exact sequential serial path — no full-N work per split.  The
         # wave grower (learner/wave.py) trades row movement for MXU
@@ -905,23 +921,27 @@ class SerialTreeLearner:
         else:
             n_pad = n
         if self._x_src is not X_dev:  # strong ref: ids can be recycled
-            self._lazy_used = None  # fresh data -> fresh used bitmap
-            Xp = jnp.pad(X_dev, ((0, n_pad - n), (0, 0))) \
-                if n_pad != n else X_dev
-            if self.grow_mode == "wave":
-                # only the feature-major copy is consumed; do not keep the
-                # padded row-major matrix alive next to it in HBM — and
-                # under pack4 only the nibble-packed HALF-width matrix
-                # (two 4-bit codes per int8 lane) lives on device
-                xpt = jnp.asarray(jnp.swapaxes(Xp, 0, 1))
-                if self.pack4:
-                    from ..ops.histogram_pallas import pack_bins4
-                    xpt = pack_bins4(xpt.astype(jnp.uint8))
-                self._XpT = xpt
-                self._Xp = None
-            else:
-                self._Xp = Xp
-            self._x_src = X_dev
+            # once per matrix: pad + transpose (+ pack4).  Host seconds of
+            # ENQUEUEING them; the eager ops compile one module each, which
+            # takes no named scope, so the device side has no `lgbm.` name
+            with timed_span(self.setup_seconds, "layout", "train/layout"):
+                self._lazy_used = None  # fresh data -> fresh used bitmap
+                Xp = jnp.pad(X_dev, ((0, n_pad - n), (0, 0))) \
+                    if n_pad != n else X_dev
+                if self.grow_mode == "wave":
+                    # only the feature-major copy is consumed; do not keep the
+                    # padded row-major matrix alive next to it in HBM — and
+                    # under pack4 only the nibble-packed HALF-width matrix
+                    # (two 4-bit codes per int8 lane) lives on device
+                    xpt = jnp.asarray(jnp.swapaxes(Xp, 0, 1))
+                    if self.pack4:
+                        from ..ops.histogram_pallas import pack_bins4
+                        xpt = pack_bins4(xpt.astype(jnp.uint8))
+                    self._XpT = xpt
+                    self._Xp = None
+                else:
+                    self._Xp = Xp
+                self._x_src = X_dev
         pad = n_pad - n
         if pad:
             grad = jnp.pad(grad, (0, pad))

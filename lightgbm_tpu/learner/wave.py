@@ -560,9 +560,10 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
         bundle_decode = make_bundle_decode(efb_arrays if use_efb else ())
         f_bundle = efb_arrays[1] if use_efb else None
 
-        gm = (grad * bag_mask).astype(jnp.float32)
-        hm = (hess * bag_mask).astype(jnp.float32)
-        cnt_mask = (bag_mask > 0).astype(jnp.float32)
+        with jax.named_scope("lgbm.quantize"):
+            gm = (grad * bag_mask).astype(jnp.float32)
+            hm = (hess * bag_mask).astype(jnp.float32)
+            cnt_mask = (bag_mask > 0).astype(jnp.float32)
         if use_lazy:
             # packed vs bool layout of the persistent `used` bitmap: follow
             # whatever the learner threads in (its dtype is static at trace
@@ -571,7 +572,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 else (lazy_bitpack and n % LAZY_PACK == 0)
         if pallas:
             if not quantized:
-                w8 = pack_weights8(grad, hess, bag_mask)
+                with jax.named_scope("lgbm.quantize"):
+                    w8 = pack_weights8(grad, hess, bag_mask)
             bins_rows = None
         else:
             # row-major copy made ONCE per grow call (outside the wave
@@ -583,16 +585,17 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             # (gradient_discretizer.cpp DiscretizeGradients); every DP
             # shard derives the same scales, so integer histograms psum
             # exactly.
-            gmax = strat.reduce_max(jnp.max(jnp.abs(gm)))
-            hmax = strat.reduce_max(jnp.max(hm))
-            g_scale = jnp.maximum(gmax, jnp.float32(1e-30)) / gq_max
-            h_scale = jnp.maximum(hmax, jnp.float32(1e-30)) / hq_max
-            qscales = dequant_scales(g_scale, h_scale)
-            qk = quant_key if quant_key is not None else \
-                jax.random.PRNGKey(0)
-            wch0 = quantize_wch(grad, hess, bag_mask, g_scale, h_scale,
-                                strat.shard_key(qk), gq_max=gq_max,
-                                hq_max=hq_max, stochastic=stochastic)
+            with jax.named_scope("lgbm.quantize"):
+                gmax = strat.reduce_max(jnp.max(jnp.abs(gm)))
+                hmax = strat.reduce_max(jnp.max(hm))
+                g_scale = jnp.maximum(gmax, jnp.float32(1e-30)) / gq_max
+                h_scale = jnp.maximum(hmax, jnp.float32(1e-30)) / hq_max
+                qscales = dequant_scales(g_scale, h_scale)
+                qk = quant_key if quant_key is not None else \
+                    jax.random.PRNGKey(0)
+                wch0 = quantize_wch(grad, hess, bag_mask, g_scale, h_scale,
+                                    strat.shard_key(qk), gq_max=gq_max,
+                                    hq_max=hq_max, stochastic=stochastic)
 
             def dq(h):
                 """int32 channel sums -> f32 (sum_grad, sum_hess, count)."""
@@ -1139,575 +1142,584 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             }
 
         if use_spec:
-            state = _spec_state()
+            with jax.named_scope("lgbm.ramp"):
+                state = _spec_state()
         else:
             # ---- root ----
-            if quantized:
-                # derive the root totals from the quantized histogram
-                # itself (any bundle's bins sum to the total, and the
-                # integer sum is exact BEFORE dequantization — identical
-                # for every feature, shard and merge mode) so candidate
-                # left+right sums stay consistent with the totals
-                # downstream
-                rh, rtot = hist_waves(jnp.zeros((n,), jnp.int8), k=1,
-                                      with_totals=True)
-                root_hist = rh[0]
-                root_sum = rtot[0]
-            else:
-                root_hist = hist_waves(jnp.zeros((n,), jnp.int8), k=1)[0]
-                root_sum = strat.reduce_sum(jnp.stack([
-                    jnp.sum(gm), jnp.sum(hm), jnp.sum(cnt_mask)]))
-            root_hist_f = dq(root_hist) if quantized else root_hist
-            root_bound = jnp.asarray([-BIG, BIG], jnp.float32)
-            root_out = _child_out(root_sum[0], root_sum[1], root_sum[2],
-                                  jnp.asarray(0.0, jnp.float32))
-            rid = jnp.asarray([2 * L], jnp.int32)
-            fm_root = feature_mask
-            if use_ic:
-                fm_root = fm_root & allowed_features(
-                    jnp.zeros((F,), jnp.bool_))
-            if use_bynode:
-                fm_root = fm_root & node_mask_many(rid)[0]
-            rb_root = node_rand_many(rid)[0] if use_et else None
-            if use_lazy:
-                # Charge only rows whose feature bit is still unset in the
-                # PERSISTENT used bitmap (cost_effective_gradient_boosting.hpp
-                # CalculateOndemandCosts): from the second tree on, features
-                # already materialized by earlier trees' splits cost nothing
-                # for those rows.  used_root[f] = in-bag rows with bit set.
-                # Like cnt_group below, the f32-accumulated 0/1 dot is exact
-                # to 2^24 counted rows per shard; beyond that the lazy cost
-                # degrades gracefully (it only biases split selection).
-                base = strat.cegb_full if strat.cegb_full is not None else 0.0
-                used0 = lazy_used if lazy_used is not None \
-                    else lazy_bitmap_init(F, n, lp)
-                used_root = strat.reduce_sum(jax.lax.dot_general(
-                    (_unpack_bits(used0) if lp
-                     else used0).astype(jnp.bfloat16),
-                    (bag_mask > 0).astype(jnp.bfloat16)[None, :],
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)[:, 0])       # (F,)
-                strat.cegb_full = base + lazy_pen * jnp.maximum(
-                    root_sum[2] - used_root, 0.0)
-            if use_scatter or use_voting:
-                # the root scan rides the sliced/voted many_candidates
-                # path (a 1-channel batch) so it too scans only this
-                # shard's block (scatter) or merges only the voted
-                # feature slices (voting)
-                c1 = many_candidates(
-                    _scan_hists(root_hist[None], root_sum[None]),
-                    root_sum[None], root_bound[None],
-                    jnp.zeros((1,), jnp.int32), root_out[None],
-                    fm_root[None],
-                    rb_root[None] if rb_root is not None else None)
-                cand = tuple(a[0] for a in c1)
-            else:
-                cand = strat.leaf_candidates(
-                    expand_hist(root_hist_f, root_sum), root_sum, fm_root,
-                    sp, root_bound, jnp.asarray(0, jnp.int32), root_out,
-                    rb_root)
+            with jax.named_scope("lgbm.root"):
+                if quantized:
+                    # derive the root totals from the quantized histogram
+                    # itself (any bundle's bins sum to the total, and the
+                    # integer sum is exact BEFORE dequantization — identical
+                    # for every feature, shard and merge mode) so candidate
+                    # left+right sums stay consistent with the totals
+                    # downstream
+                    rh, rtot = hist_waves(jnp.zeros((n,), jnp.int8), k=1,
+                                          with_totals=True)
+                    root_hist = rh[0]
+                    root_sum = rtot[0]
+                else:
+                    root_hist = hist_waves(jnp.zeros((n,), jnp.int8), k=1)[0]
+                    root_sum = strat.reduce_sum(jnp.stack([
+                        jnp.sum(gm), jnp.sum(hm), jnp.sum(cnt_mask)]))
+                root_hist_f = dq(root_hist) if quantized else root_hist
+                root_bound = jnp.asarray([-BIG, BIG], jnp.float32)
+                root_out = _child_out(root_sum[0], root_sum[1], root_sum[2],
+                                      jnp.asarray(0.0, jnp.float32))
+                rid = jnp.asarray([2 * L], jnp.int32)
+                fm_root = feature_mask
+                if use_ic:
+                    fm_root = fm_root & allowed_features(
+                        jnp.zeros((F,), jnp.bool_))
+                if use_bynode:
+                    fm_root = fm_root & node_mask_many(rid)[0]
+                rb_root = node_rand_many(rid)[0] if use_et else None
+                if use_lazy:
+                    # Charge only rows whose feature bit is still unset in the
+                    # PERSISTENT used bitmap (cost_effective_gradient_boosting.hpp
+                    # CalculateOndemandCosts): from the second tree on, features
+                    # already materialized by earlier trees' splits cost nothing
+                    # for those rows.  used_root[f] = in-bag rows with bit set.
+                    # Like cnt_group below, the f32-accumulated 0/1 dot is exact
+                    # to 2^24 counted rows per shard; beyond that the lazy cost
+                    # degrades gracefully (it only biases split selection).
+                    base = strat.cegb_full if strat.cegb_full is not None else 0.0
+                    used0 = lazy_used if lazy_used is not None \
+                        else lazy_bitmap_init(F, n, lp)
+                    used_root = strat.reduce_sum(jax.lax.dot_general(
+                        (_unpack_bits(used0) if lp
+                         else used0).astype(jnp.bfloat16),
+                        (bag_mask > 0).astype(jnp.bfloat16)[None, :],
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)[:, 0])       # (F,)
+                    strat.cegb_full = base + lazy_pen * jnp.maximum(
+                        root_sum[2] - used_root, 0.0)
+                if use_scatter or use_voting:
+                    # the root scan rides the sliced/voted many_candidates
+                    # path (a 1-channel batch) so it too scans only this
+                    # shard's block (scatter) or merges only the voted
+                    # feature slices (voting)
+                    c1 = many_candidates(
+                        _scan_hists(root_hist[None], root_sum[None]),
+                        root_sum[None], root_bound[None],
+                        jnp.zeros((1,), jnp.int32), root_out[None],
+                        fm_root[None],
+                        rb_root[None] if rb_root is not None else None)
+                    cand = tuple(a[0] for a in c1)
+                else:
+                    cand = strat.leaf_candidates(
+                        expand_hist(root_hist_f, root_sum), root_sum, fm_root,
+                        sp, root_bound, jnp.asarray(0, jnp.int32), root_out,
+                        rb_root)
 
-            state = {
-                "row_leaf": jnp.zeros((n,), rl_dtype),
-                "leaf_sum": jnp.zeros((L, 3), jnp.float32).at[0].set(root_sum),
-                "leaf_depth": jnp.zeros((L,), jnp.int32),
-                "cand_gain": jnp.full((L,), NEG_INF, jnp.float32).at[0].set(cand[0]),
-                "cand_feat": jnp.zeros((L,), jnp.int32).at[0].set(cand[1]),
-                "cand_bin": jnp.zeros((L,), jnp.int32).at[0].set(cand[2]),
-                "cand_dleft": jnp.zeros((L,), jnp.bool_).at[0].set(cand[3]),
-                "cand_lsum": jnp.zeros((L, 3), jnp.float32).at[0].set(cand[4]),
-                "cand_rsum": jnp.zeros((L, 3), jnp.float32).at[0].set(cand[5]),
-                "cand_member": jnp.zeros((L, max_bins), jnp.bool_).at[0].set(
-                    cand[6]),
-                "hists": jnp.zeros(
-                    (L, G_loc, Bb, 3),
-                    jnp.int32 if quantized else jnp.float32).at[0].set(
-                        root_hist),
-                "split_feature": jnp.full((L - 1,), -1, jnp.int32),
-                "threshold_bin": jnp.zeros((L - 1,), jnp.int32),
-                "nan_bin": jnp.full((L - 1,), -1, jnp.int32),
-                "cat_member": jnp.zeros((L - 1, max_bins), jnp.bool_),
-                "decision_type": jnp.zeros((L - 1,), jnp.int32),
-                "left_child": jnp.zeros((L - 1,), jnp.int32),
-                "right_child": jnp.zeros((L - 1,), jnp.int32),
-                "split_gain": jnp.zeros((L - 1,), jnp.float32),
-                "internal_value": jnp.zeros((L - 1,), jnp.float32),
-                "internal_weight": jnp.zeros((L - 1,), jnp.float32),
-                "internal_count": jnp.zeros((L - 1,), jnp.float32),
-                "leaf_value": jnp.zeros((L,), jnp.float32).at[0].set(root_out),
-                "leaf_weight": jnp.zeros((L,), jnp.float32).at[0].set(root_sum[1]),
-                "leaf_count": jnp.zeros((L,), jnp.float32).at[0].set(root_sum[2]),
-                "num_leaves": jnp.asarray(1, jnp.int32),
-                "done": jnp.asarray(False),
-                "hist_passes": jnp.asarray(1, jnp.int32),  # the root pass
-            }
-            if use_mc:
-                state["leaf_mn"] = jnp.full((L,), -BIG, jnp.float32)
-                state["leaf_mx"] = jnp.full((L,), BIG, jnp.float32)
-                if mc_inter:
-                    # per-leaf bin-space region boxes for the geometric
-                    # contiguity test of the intermediate constraints
-                    state["leaf_lo"] = jnp.zeros((L, F), jnp.int32)
-                    state["leaf_hi"] = jnp.broadcast_to(
-                        (nb_full - 1).astype(jnp.int32)[None, :],
-                        (L, F)).copy()
-            if use_ic:
-                # features used on the path to each leaf (interaction
-                # constraints restrict children to compatible groups)
-                state["leaf_path"] = jnp.zeros((L, F), jnp.bool_)
-            if use_lazy:
-                # per-(feature, row) "already computed" bitmap — PERSISTENT
-                # across trees like the reference's feature_used_in_data_
-                # bitset (it is allocated once per training run and never
-                # cleared); the learner threads it through every grow call.
-                # Packed to uint8 bitfields (lazy_bitmap_init) — 8x less
-                # HBM than the former bool layout; lazy_bitpack=False
-                # keeps the bool path (tests cross-check equality).
-                state["used"] = lazy_used if lazy_used is not None \
-                    else lazy_bitmap_init(F, n, lp)
+                state = {
+                    "row_leaf": jnp.zeros((n,), rl_dtype),
+                    "leaf_sum": jnp.zeros((L, 3), jnp.float32).at[0].set(root_sum),
+                    "leaf_depth": jnp.zeros((L,), jnp.int32),
+                    "cand_gain": jnp.full((L,), NEG_INF, jnp.float32).at[0].set(cand[0]),
+                    "cand_feat": jnp.zeros((L,), jnp.int32).at[0].set(cand[1]),
+                    "cand_bin": jnp.zeros((L,), jnp.int32).at[0].set(cand[2]),
+                    "cand_dleft": jnp.zeros((L,), jnp.bool_).at[0].set(cand[3]),
+                    "cand_lsum": jnp.zeros((L, 3), jnp.float32).at[0].set(cand[4]),
+                    "cand_rsum": jnp.zeros((L, 3), jnp.float32).at[0].set(cand[5]),
+                    "cand_member": jnp.zeros((L, max_bins), jnp.bool_).at[0].set(
+                        cand[6]),
+                    "hists": jnp.zeros(
+                        (L, G_loc, Bb, 3),
+                        jnp.int32 if quantized else jnp.float32).at[0].set(
+                            root_hist),
+                    "split_feature": jnp.full((L - 1,), -1, jnp.int32),
+                    "threshold_bin": jnp.zeros((L - 1,), jnp.int32),
+                    "nan_bin": jnp.full((L - 1,), -1, jnp.int32),
+                    "cat_member": jnp.zeros((L - 1, max_bins), jnp.bool_),
+                    "decision_type": jnp.zeros((L - 1,), jnp.int32),
+                    "left_child": jnp.zeros((L - 1,), jnp.int32),
+                    "right_child": jnp.zeros((L - 1,), jnp.int32),
+                    "split_gain": jnp.zeros((L - 1,), jnp.float32),
+                    "internal_value": jnp.zeros((L - 1,), jnp.float32),
+                    "internal_weight": jnp.zeros((L - 1,), jnp.float32),
+                    "internal_count": jnp.zeros((L - 1,), jnp.float32),
+                    "leaf_value": jnp.zeros((L,), jnp.float32).at[0].set(root_out),
+                    "leaf_weight": jnp.zeros((L,), jnp.float32).at[0].set(root_sum[1]),
+                    "leaf_count": jnp.zeros((L,), jnp.float32).at[0].set(root_sum[2]),
+                    "num_leaves": jnp.asarray(1, jnp.int32),
+                    "done": jnp.asarray(False),
+                    "hist_passes": jnp.asarray(1, jnp.int32),  # the root pass
+                }
+                if use_mc:
+                    state["leaf_mn"] = jnp.full((L,), -BIG, jnp.float32)
+                    state["leaf_mx"] = jnp.full((L,), BIG, jnp.float32)
+                    if mc_inter:
+                        # per-leaf bin-space region boxes for the geometric
+                        # contiguity test of the intermediate constraints
+                        state["leaf_lo"] = jnp.zeros((L, F), jnp.int32)
+                        state["leaf_hi"] = jnp.broadcast_to(
+                            (nb_full - 1).astype(jnp.int32)[None, :],
+                            (L, F)).copy()
+                if use_ic:
+                    # features used on the path to each leaf (interaction
+                    # constraints restrict children to compatible groups)
+                    state["leaf_path"] = jnp.zeros((L, F), jnp.bool_)
+                if use_lazy:
+                    # per-(feature, row) "already computed" bitmap — PERSISTENT
+                    # across trees like the reference's feature_used_in_data_
+                    # bitset (it is allocated once per training run and never
+                    # cleared); the learner threads it through every grow call.
+                    # Packed to uint8 bitfields (lazy_bitmap_init) — 8x less
+                    # HBM than the former bool layout; lazy_bitpack=False
+                    # keeps the bool path (tests cross-check equality).
+                    state["used"] = lazy_used if lazy_used is not None \
+                        else lazy_bitmap_init(F, n, lp)
 
         jarange = jnp.arange(W, dtype=jnp.int32)
 
         def body(s, forced=None):
-            nl0 = s["num_leaves"]
-            if forced is None:
-                budget = L - nl0
-                # Endgame taper: committing a full wave close to the leaf
-                # budget would lock in splits that freshly-created children
-                # (whose gains are not yet known) should have outcompeted —
-                # the sequential best-first order lets them.  Halving the
-                # wave once budget < 2W closes most of the quality gap to
-                # the exact order; the W//4 floor caps the halving cascade
-                # at ~2-3 extra waves (each wave is a full-data histogram
-                # pass — a log2(W)-deep taper costs more wall time than
-                # its last few splits are worth).
-                k_eff = wave_taper_k(budget, W)
-                vals, sel_leaves = jax.lax.top_k(s["cand_gain"], W)
-                sel = (vals > 0) & (jarange < k_eff)
-                feat = s["cand_feat"][sel_leaves]          # (W,)
-                thr = s["cand_bin"][sel_leaves]
-                dleft = s["cand_dleft"][sel_leaves]
-                lsum = s["cand_lsum"][sel_leaves]          # (W, 3)
-                rsum = s["cand_rsum"][sel_leaves]
-                member = s["cand_member"][sel_leaves]      # (W, B)
-                psum_ = s["leaf_sum"][sel_leaves]
-            else:
-                # forced wave: fixed (leaf, feature, bin) applied
-                # regardless of gain; child sums read from the parent's
-                # pooled histogram (the partitioned grower's ForceSplits
-                # override, learner/partitioned.py:440, batched)
-                import numpy as _np
-                k = len(forced)
-                pad = [(0, 0, 0)] * (W - k)
-                trip = _np.asarray(list(forced) + pad, _np.int32)
-                sel_leaves = jnp.asarray(trip[:, 0])
-                feat = jnp.asarray(trip[:, 1])
-                thr = jnp.asarray(trip[:, 2])
-                psum_ = s["leaf_sum"][jnp.asarray(trip[:, 0])]
-                # empty forced leaves are skipped like the partitioned
-                # grower's `do = leaf_seg > 0` gate (degenerate forcing
-                # files route all rows one way; the reference stops
-                # forcing such subtrees too)
-                sel = jnp.asarray(_np.arange(W) < k) & (psum_[:, 2] > 0)
-                dleft = jnp.zeros((W,), jnp.bool_)
-                member = jnp.zeros((W, max_bins), jnp.bool_)
-                ph = s["hists"][sel_leaves]
-                phf = dq(ph) if quantized else ph
-                exh = jax.vmap(expand_hist)(phf, psum_)    # (W, F, B, 3)
-                fh = exh[jnp.arange(W), feat]              # (W, B, 3)
-                csum = jnp.cumsum(fh, axis=1)
-                lsum = csum[jnp.arange(W),
-                            jnp.clip(thr, 0, max_bins - 1)]
-                rsum = psum_ - lsum
-                # record the forced split's REAL gain (the reference's
-                # ForceSplits computes a full SplitInfo for the forced
-                # threshold), on the scan's shifted-gain scale
-                vals = (_leaf_gain(lsum[:, 0], lsum[:, 1],
-                                   sp.lambda_l1, sp.lambda_l2) +
-                        _leaf_gain(rsum[:, 0], rsum[:, 1],
-                                   sp.lambda_l1, sp.lambda_l2) -
-                        _leaf_gain(psum_[:, 0], psum_[:, 1],
-                                   sp.lambda_l1, sp.lambda_l2) -
-                        sp.min_gain_to_split)
-            prefix = jnp.cumsum(sel.astype(jnp.int32))
-            total_new = prefix[-1]
-            new_ids = nl0 + prefix - 1                     # valid where sel
-            node_ids = (nl0 - 1) + prefix - 1              # node index
-            left_smaller = lsum[:, 2] <= rsum[:, 2]        # (W,)
-            fcat = ic_full[feat]
-            fnan = hn_full[feat]
-            f_nan_bin = jnp.where(fnan, nb_full[feat] - 1, -1)
+            # ---- the wave's leaves: top-W by gain (or the forced ones) ----
+            with jax.named_scope("lgbm.wave.commit"):
+                nl0 = s["num_leaves"]
+                if forced is None:
+                    budget = L - nl0
+                    # Endgame taper: committing a full wave close to the leaf
+                    # budget would lock in splits that freshly-created children
+                    # (whose gains are not yet known) should have outcompeted —
+                    # the sequential best-first order lets them.  Halving the
+                    # wave once budget < 2W closes most of the quality gap to
+                    # the exact order; the W//4 floor caps the halving cascade
+                    # at ~2-3 extra waves (each wave is a full-data histogram
+                    # pass — a log2(W)-deep taper costs more wall time than
+                    # its last few splits are worth).
+                    k_eff = wave_taper_k(budget, W)
+                    vals, sel_leaves = jax.lax.top_k(s["cand_gain"], W)
+                    sel = (vals > 0) & (jarange < k_eff)
+                    feat = s["cand_feat"][sel_leaves]          # (W,)
+                    thr = s["cand_bin"][sel_leaves]
+                    dleft = s["cand_dleft"][sel_leaves]
+                    lsum = s["cand_lsum"][sel_leaves]          # (W, 3)
+                    rsum = s["cand_rsum"][sel_leaves]
+                    member = s["cand_member"][sel_leaves]      # (W, B)
+                    psum_ = s["leaf_sum"][sel_leaves]
+                else:
+                    # forced wave: fixed (leaf, feature, bin) applied
+                    # regardless of gain; child sums read from the parent's
+                    # pooled histogram (the partitioned grower's ForceSplits
+                    # override, learner/partitioned.py:440, batched)
+                    import numpy as _np
+                    k = len(forced)
+                    pad = [(0, 0, 0)] * (W - k)
+                    trip = _np.asarray(list(forced) + pad, _np.int32)
+                    sel_leaves = jnp.asarray(trip[:, 0])
+                    feat = jnp.asarray(trip[:, 1])
+                    thr = jnp.asarray(trip[:, 2])
+                    psum_ = s["leaf_sum"][jnp.asarray(trip[:, 0])]
+                    # empty forced leaves are skipped like the partitioned
+                    # grower's `do = leaf_seg > 0` gate (degenerate forcing
+                    # files route all rows one way; the reference stops
+                    # forcing such subtrees too)
+                    sel = jnp.asarray(_np.arange(W) < k) & (psum_[:, 2] > 0)
+                    dleft = jnp.zeros((W,), jnp.bool_)
+                    member = jnp.zeros((W, max_bins), jnp.bool_)
+                    ph = s["hists"][sel_leaves]
+                    phf = dq(ph) if quantized else ph
+                    exh = jax.vmap(expand_hist)(phf, psum_)    # (W, F, B, 3)
+                    fh = exh[jnp.arange(W), feat]              # (W, B, 3)
+                    csum = jnp.cumsum(fh, axis=1)
+                    lsum = csum[jnp.arange(W),
+                                jnp.clip(thr, 0, max_bins - 1)]
+                    rsum = psum_ - lsum
+                    # record the forced split's REAL gain (the reference's
+                    # ForceSplits computes a full SplitInfo for the forced
+                    # threshold), on the scan's shifted-gain scale
+                    vals = (_leaf_gain(lsum[:, 0], lsum[:, 1],
+                                       sp.lambda_l1, sp.lambda_l2) +
+                            _leaf_gain(rsum[:, 0], rsum[:, 1],
+                                       sp.lambda_l1, sp.lambda_l2) -
+                            _leaf_gain(psum_[:, 0], psum_[:, 1],
+                                       sp.lambda_l1, sp.lambda_l2) -
+                            sp.min_gain_to_split)
+                prefix = jnp.cumsum(sel.astype(jnp.int32))
+                total_new = prefix[-1]
+                new_ids = nl0 + prefix - 1                     # valid where sel
+                node_ids = (nl0 - 1) + prefix - 1              # node index
+                left_smaller = lsum[:, 2] <= rsum[:, 2]        # (W,)
+                fcat = ic_full[feat]
+                fnan = hn_full[feat]
+                f_nan_bin = jnp.where(fnan, nb_full[feat] - 1, -1)
 
             # ---- row_leaf + wave-channel update ----
-            rl = s["row_leaf"]
-            rl_old = rl
-            if pallas and small_bins and not any_cat:
-                # one fused kernel pass instead of W masked XLA sweeps
-                # (each sweep's fused-loop launch overhead alone costs
-                # ~0.7 ms at 10.5M rows)
-                cols_w = take_cols(feat)                      # (W, N) u8
-                tab = jnp.stack([
-                    thr, f_nan_bin, dleft.astype(jnp.int32),
-                    left_smaller.astype(jnp.int32), sel_leaves, new_ids,
-                    sel.astype(jnp.int32), jnp.zeros_like(thr)])
-                rl_new, ch = wave_row_update_pallas(
-                    cols_w, rl, tab, interpret=interpret,
-                    pipeline=pipeline)
-                rl = rl_new.astype(rl.dtype)
-            else:
-                # Vectorized XLA fallback (categorical / EFB / wide-bin
-                # shapes the fused kernel cannot take).  The former W
-                # SEQUENTIAL masked sweeps cost ~0.7-2 ms of fused-loop
-                # launch overhead EACH (~50 ms/wave at small N — the
-                # dominant cost of the whole benchmark-matrix shapes);
-                # one batched (W, N) formulation replaces them: every
-                # row belongs to at most one split leaf, so an argmax
-                # over the match matrix picks its slot and a single
-                # take_along_axis resolves the decision.
-                if small_bins:
-                    thr_c = thr.astype(jnp.uint8)[:, None]
-                    nan_c = jnp.where(f_nan_bin < 0, 255,
-                                      f_nan_bin).astype(jnp.uint8)[:, None]
+            with jax.named_scope("lgbm.wave.row_update"):
+                rl = s["row_leaf"]
+                rl_old = rl
+                if pallas and small_bins and not any_cat:
+                    # one fused kernel pass instead of W masked XLA sweeps
+                    # (each sweep's fused-loop launch overhead alone costs
+                    # ~0.7 ms at 10.5M rows)
+                    cols_w = take_cols(feat)                      # (W, N) u8
+                    tab = jnp.stack([
+                        thr, f_nan_bin, dleft.astype(jnp.int32),
+                        left_smaller.astype(jnp.int32), sel_leaves, new_ids,
+                        sel.astype(jnp.int32), jnp.zeros_like(thr)])
+                    rl_new, ch = wave_row_update_pallas(
+                        cols_w, rl, tab, interpret=interpret,
+                        pipeline=pipeline)
+                    rl = rl_new.astype(rl.dtype)
                 else:
-                    thr_c = thr[:, None]
-                    nan_c = f_nan_bin[:, None]
-                sel_c = sel_leaves.astype(rl.dtype)
-                mi8 = member.astype(jnp.int8).T                # (B, W)
-                cat_static = sp.cat_idx if any_cat else ()
-
-                def _upd_block(Xb, rlb):
-                    """One row block of the batched update — (W, m)
-                    intermediates stay bounded for very large N."""
-                    m = Xb.shape[1]
-
-                    def fcol(ff):
-                        g = f_bundle[ff] if use_efb else ff
-                        v = jax.lax.dynamic_slice(Xb, (g, 0), (1, m))[0]
-                        if small_bins:
-                            return v
-                        return bundle_decode(v.astype(jnp.int32), ff)
-
-                    cols_w = jax.vmap(fcol)(feat)              # (W, m)
-                    num_go = jnp.where(cols_w == nan_c, dleft[:, None],
-                                       cols_w <= thr_c)
-                    if not any_cat:
-                        go_w = num_go
-                    elif 0 < len(cat_static) <= 8:
-                        # per-slot bitset lookup as FEW-INDICES x
-                        # WIDE-ROW embedding takes: a (W, N)-indexed
-                        # gather from the (W, B) membership table costs
-                        # ~45 ms at 145K rows on TPU for every dtype,
-                        # while N row-takes from the transposed (B, W)
-                        # table cost ~6 ms — loop the STATIC cat
-                        # features, combine by split-feature match
-                        acc = jnp.zeros((m, W), jnp.int8)
-                        for cf in cat_static:
-                            colv = fcol(jnp.asarray(cf, jnp.int32))
-                            look = jnp.take(mi8, colv.astype(jnp.int32),
-                                            axis=0)            # (m, W)
-                            acc = acc + look * (feat == cf).astype(
-                                jnp.int8)[None, :]
-                        go_w = jnp.where(fcat[:, None], acc.T > 0, num_go)
+                    # Vectorized XLA fallback (categorical / EFB / wide-bin
+                    # shapes the fused kernel cannot take).  The former W
+                    # SEQUENTIAL masked sweeps cost ~0.7-2 ms of fused-loop
+                    # launch overhead EACH (~50 ms/wave at small N — the
+                    # dominant cost of the whole benchmark-matrix shapes);
+                    # one batched (W, N) formulation replaces them: every
+                    # row belongs to at most one split leaf, so an argmax
+                    # over the match matrix picks its slot and a single
+                    # take_along_axis resolves the decision.
+                    if small_bins:
+                        thr_c = thr.astype(jnp.uint8)[:, None]
+                        nan_c = jnp.where(f_nan_bin < 0, 255,
+                                          f_nan_bin).astype(jnp.uint8)[:, None]
                     else:
-                        go_w = jnp.where(
-                            fcat[:, None],
-                            jnp.take_along_axis(
-                                member, cols_w.astype(jnp.int32), axis=1),
-                            num_go)
-                    match = sel[:, None] & (rlb[None, :] == sel_c[:, None])
-                    has = jnp.any(match, axis=0)               # (m,)
-                    jhit = jnp.argmax(match, axis=0)
-                    go = jnp.take_along_axis(go_w, jhit[None, :],
-                                             axis=0)[0]
-                    chb = jnp.where(
-                        has & (go == left_smaller[jhit]),
-                        jhit.astype(jnp.int8), jnp.int8(-1))
-                    rlb = jnp.where(has & jnp.logical_not(go),
-                                    new_ids[jhit].astype(rlb.dtype), rlb)
-                    return rlb, chb
+                        thr_c = thr[:, None]
+                        nan_c = f_nan_bin[:, None]
+                    sel_c = sel_leaves.astype(rl.dtype)
+                    mi8 = member.astype(jnp.int8).T                # (B, W)
+                    cat_static = sp.cat_idx if any_cat else ()
 
-                blk = max(4096, ((1 << 26) // max(W, 1)) // 4096 * 4096)
-                if n <= blk:
-                    rl, ch = _upd_block(X_T, rl)
-                else:
-                    parts = [_upd_block(X_T[:, lo:lo + blk],
-                                        rl[lo:lo + blk])
-                             for lo in range(0, n, blk)]
-                    rl = jnp.concatenate([p_[0] for p_ in parts])
-                    ch = jnp.concatenate([p_[1] for p_ in parts])
+                    def _upd_block(Xb, rlb):
+                        """One row block of the batched update — (W, m)
+                        intermediates stay bounded for very large N."""
+                        m = Xb.shape[1]
+
+                        def fcol(ff):
+                            g = f_bundle[ff] if use_efb else ff
+                            v = jax.lax.dynamic_slice(Xb, (g, 0), (1, m))[0]
+                            if small_bins:
+                                return v
+                            return bundle_decode(v.astype(jnp.int32), ff)
+
+                        cols_w = jax.vmap(fcol)(feat)              # (W, m)
+                        num_go = jnp.where(cols_w == nan_c, dleft[:, None],
+                                           cols_w <= thr_c)
+                        if not any_cat:
+                            go_w = num_go
+                        elif 0 < len(cat_static) <= 8:
+                            # per-slot bitset lookup as FEW-INDICES x
+                            # WIDE-ROW embedding takes: a (W, N)-indexed
+                            # gather from the (W, B) membership table costs
+                            # ~45 ms at 145K rows on TPU for every dtype,
+                            # while N row-takes from the transposed (B, W)
+                            # table cost ~6 ms — loop the STATIC cat
+                            # features, combine by split-feature match
+                            acc = jnp.zeros((m, W), jnp.int8)
+                            for cf in cat_static:
+                                colv = fcol(jnp.asarray(cf, jnp.int32))
+                                look = jnp.take(mi8, colv.astype(jnp.int32),
+                                                axis=0)            # (m, W)
+                                acc = acc + look * (feat == cf).astype(
+                                    jnp.int8)[None, :]
+                            go_w = jnp.where(fcat[:, None], acc.T > 0, num_go)
+                        else:
+                            go_w = jnp.where(
+                                fcat[:, None],
+                                jnp.take_along_axis(
+                                    member, cols_w.astype(jnp.int32), axis=1),
+                                num_go)
+                        match = sel[:, None] & (rlb[None, :] == sel_c[:, None])
+                        has = jnp.any(match, axis=0)               # (m,)
+                        jhit = jnp.argmax(match, axis=0)
+                        go = jnp.take_along_axis(go_w, jhit[None, :],
+                                                 axis=0)[0]
+                        chb = jnp.where(
+                            has & (go == left_smaller[jhit]),
+                            jhit.astype(jnp.int8), jnp.int8(-1))
+                        rlb = jnp.where(has & jnp.logical_not(go),
+                                        new_ids[jhit].astype(rlb.dtype), rlb)
+                        return rlb, chb
+
+                    blk = max(4096, ((1 << 26) // max(W, 1)) // 4096 * 4096)
+                    if n <= blk:
+                        rl, ch = _upd_block(X_T, rl)
+                    else:
+                        parts = [_upd_block(X_T[:, lo:lo + blk],
+                                            rl[lo:lo + blk])
+                                 for lo in range(0, n, blk)]
+                        rl = jnp.concatenate([p_[0] for p_ in parts])
+                        ch = jnp.concatenate([p_[1] for p_ in parts])
 
             # ---- one kernel pass: all W smaller-child histograms ----
-            hist_small = hist_waves(ch)                    # (W, G, Bb, 3)
-            parents = s["hists"][sel_leaves]
-            hist_big = parents - hist_small
-            ls4 = left_smaller[:, None, None, None]
-            hist_l = jnp.where(ls4, hist_small, hist_big)
-            hist_r = jnp.where(ls4, hist_big, hist_small)
+            with jax.named_scope("lgbm.wave.hist"):
+                hist_small = hist_waves(ch)                    # (W, G, Bb, 3)
+                parents = s["hists"][sel_leaves]
+                hist_big = parents - hist_small
+                ls4 = left_smaller[:, None, None, None]
+                hist_l = jnp.where(ls4, hist_small, hist_big)
+                hist_r = jnp.where(ls4, hist_big, hist_small)
 
             # ---- children outputs (smoothed toward the split leaf's own
             # value under path_smooth) + monotone bounds
             # (BasicLeafConstraints::Update) ----
-            parent_lv = s["leaf_value"][sel_leaves]
-            out_l = _child_out(lsum[:, 0], lsum[:, 1], lsum[:, 2], parent_lv)
-            out_r = _child_out(rsum[:, 0], rsum[:, 1], rsum[:, 2], parent_lv)
-            if use_mc and mc_inter:
-                # Intermediate constraints (monotone_constraints.hpp:514
-                # IntermediateLeafConstraints): children are bounded by
-                # the SIBLING'S OUTPUT instead of the midpoint, and the
-                # new outputs propagate to every geometrically contiguous
-                # leaf.  The reference finds contiguous leaves by walking
-                # up the tree and filtering thresholds
-                # (GoUpToFindLeavesToUpdate / GoDownToFindLeavesToUpdate);
-                # here each leaf carries its bin-space region box
-                # (leaf_lo/leaf_hi), and contiguity is the EXACT geometric
-                # test — regions overlapping in every feature except one
-                # monotone feature where they are disjoint and ordered.
-                # The wave's W splits are refined sequentially over the
-                # SMALL (L,)-sized arrays (one histogram pass still serves
-                # the whole wave), so later slots see earlier slots'
-                # tightened bounds — within-wave batching stays safe.
-                mn_all, mx_all = s["leaf_mn"], s["leaf_mx"]
-                lo_all, hi_all = s["leaf_lo"], s["leaf_hi"]
-                out_l2 = jnp.zeros((W,), jnp.float32)
-                out_r2 = jnp.zeros((W,), jnp.float32)
-                bnd_l = jnp.zeros((W, 2), jnp.float32)
-                bnd_r = jnp.zeros((W, 2), jnp.float32)
-                inc_row = (monotone > 0)[None, :]
-                dec_row = (monotone < 0)[None, :]
-                for j in range(W):
-                    act = sel[j]
-                    p = sel_leaves[j]
-                    fj = feat[j]
-                    mj = jnp.where(fcat[j], 0, monotone[fj])
-                    pmn, pmx = mn_all[p], mx_all[p]
-                    ol = jnp.clip(out_l[j], pmn, pmx)
-                    orr = jnp.clip(out_r[j], pmn, pmx)
-                    # bounds tightened by earlier slots can cross a stale
-                    # candidate's outputs; collapse to the shared boundary
-                    # (monotone-safe, zero-gain degenerate split)
-                    cross = ((mj > 0) & (ol > orr)) | ((mj < 0) & (ol < orr))
-                    midj = (ol + orr) / 2.0
-                    ol = jnp.where(cross, jnp.clip(midj, pmn, pmx), ol)
-                    orr = jnp.where(cross, jnp.clip(midj, pmn, pmx), orr)
-                    # child entries (UpdateConstraintsWithOutputs)
-                    mn_lj = jnp.where(mj < 0, jnp.maximum(pmn, orr), pmn)
-                    mx_lj = jnp.where(mj > 0, jnp.minimum(pmx, orr), pmx)
-                    mn_rj = jnp.where(mj > 0, jnp.maximum(pmn, ol), pmn)
-                    mx_rj = jnp.where(mj < 0, jnp.minimum(pmx, ol), pmx)
-                    # child regions (categorical splits keep the parent box
-                    # — no feature-order relation between cat children)
-                    lo_p, hi_p = lo_all[p], hi_all[p]
-                    num_j = jnp.logical_not(fcat[j])
-                    hi_l = jnp.where(num_j, hi_p.at[fj].set(thr[j]), hi_p)
-                    lo_r = jnp.where(num_j,
-                                     lo_p.at[fj].set(thr[j] + 1), lo_p)
-                    for c_lo, c_hi, c_out in ((lo_p, hi_l, ol),
-                                              (lo_r, hi_p, orr)):
-                        inter = (lo_all <= c_hi[None, :]) & \
-                            (hi_all >= c_lo[None, :])          # (L, F)
-                        nfail = jnp.sum(jnp.logical_not(inter), axis=1)
-                        onlyf = (nfail == 1)[:, None] & \
-                            jnp.logical_not(inter)
-                        below = onlyf & (hi_all < c_lo[None, :])
-                        above = onlyf & (lo_all > c_hi[None, :])
-                        capmax = jnp.any((below & inc_row) |
-                                         (above & dec_row), axis=1)
-                        capmin = jnp.any((above & inc_row) |
-                                         (below & dec_row), axis=1)
-                        mx_all = jnp.where(act & capmax,
-                                           jnp.minimum(mx_all, c_out),
-                                           mx_all)
-                        mn_all = jnp.where(act & capmin,
-                                           jnp.maximum(mn_all, c_out),
-                                           mn_all)
-                    pj = jnp.where(act, p, L)
-                    rj = jnp.where(act, new_ids[j], L)
-                    mn_all = mn_all.at[pj].set(mn_lj, mode="drop") \
-                                   .at[rj].set(mn_rj, mode="drop")
-                    mx_all = mx_all.at[pj].set(mx_lj, mode="drop") \
-                                   .at[rj].set(mx_rj, mode="drop")
-                    hi_all = hi_all.at[pj].set(hi_l, mode="drop") \
-                                   .at[rj].set(hi_p, mode="drop")
-                    lo_all = lo_all.at[rj].set(lo_r, mode="drop")
-                    out_l2 = out_l2.at[j].set(ol)
-                    out_r2 = out_r2.at[j].set(orr)
-                    bnd_l = bnd_l.at[j].set(jnp.stack([mn_lj, mx_lj]))
-                    bnd_r = bnd_r.at[j].set(jnp.stack([mn_rj, mx_rj]))
-                out_l, out_r = out_l2, out_r2
-                mn_l, mx_l = bnd_l[:, 0], bnd_l[:, 1]
-                mn_r, mx_r = bnd_r[:, 0], bnd_r[:, 1]
-                bounds2 = jnp.concatenate([bnd_l, bnd_r])   # (2W, 2)
-            elif use_mc:
-                p_mn = s["leaf_mn"][sel_leaves]
-                p_mx = s["leaf_mx"][sel_leaves]
-                out_l = jnp.clip(out_l, p_mn, p_mx)
-                out_r = jnp.clip(out_r, p_mn, p_mx)
-                m = jnp.where(fcat, 0, monotone[feat])
-                mid = (out_l + out_r) / 2.0
-                mn_l = jnp.where(m < 0, jnp.maximum(p_mn, mid), p_mn)
-                mx_l = jnp.where(m > 0, jnp.minimum(p_mx, mid), p_mx)
-                mn_r = jnp.where(m > 0, jnp.maximum(p_mn, mid), p_mn)
-                mx_r = jnp.where(m < 0, jnp.minimum(p_mx, mid), p_mx)
-                bounds2 = jnp.concatenate([
-                    jnp.stack([mn_l, mx_l], axis=1),
-                    jnp.stack([mn_r, mx_r], axis=1)])       # (2W, 2)
-            else:
-                bounds2 = jnp.zeros((2 * W, 2), jnp.float32)
+            with jax.named_scope("lgbm.wave.child_out"):
+                parent_lv = s["leaf_value"][sel_leaves]
+                out_l = _child_out(lsum[:, 0], lsum[:, 1], lsum[:, 2], parent_lv)
+                out_r = _child_out(rsum[:, 0], rsum[:, 1], rsum[:, 2], parent_lv)
+                if use_mc and mc_inter:
+                    # Intermediate constraints (monotone_constraints.hpp:514
+                    # IntermediateLeafConstraints): children are bounded by
+                    # the SIBLING'S OUTPUT instead of the midpoint, and the
+                    # new outputs propagate to every geometrically contiguous
+                    # leaf.  The reference finds contiguous leaves by walking
+                    # up the tree and filtering thresholds
+                    # (GoUpToFindLeavesToUpdate / GoDownToFindLeavesToUpdate);
+                    # here each leaf carries its bin-space region box
+                    # (leaf_lo/leaf_hi), and contiguity is the EXACT geometric
+                    # test — regions overlapping in every feature except one
+                    # monotone feature where they are disjoint and ordered.
+                    # The wave's W splits are refined sequentially over the
+                    # SMALL (L,)-sized arrays (one histogram pass still serves
+                    # the whole wave), so later slots see earlier slots'
+                    # tightened bounds — within-wave batching stays safe.
+                    mn_all, mx_all = s["leaf_mn"], s["leaf_mx"]
+                    lo_all, hi_all = s["leaf_lo"], s["leaf_hi"]
+                    out_l2 = jnp.zeros((W,), jnp.float32)
+                    out_r2 = jnp.zeros((W,), jnp.float32)
+                    bnd_l = jnp.zeros((W, 2), jnp.float32)
+                    bnd_r = jnp.zeros((W, 2), jnp.float32)
+                    inc_row = (monotone > 0)[None, :]
+                    dec_row = (monotone < 0)[None, :]
+                    for j in range(W):
+                        act = sel[j]
+                        p = sel_leaves[j]
+                        fj = feat[j]
+                        mj = jnp.where(fcat[j], 0, monotone[fj])
+                        pmn, pmx = mn_all[p], mx_all[p]
+                        ol = jnp.clip(out_l[j], pmn, pmx)
+                        orr = jnp.clip(out_r[j], pmn, pmx)
+                        # bounds tightened by earlier slots can cross a stale
+                        # candidate's outputs; collapse to the shared boundary
+                        # (monotone-safe, zero-gain degenerate split)
+                        cross = ((mj > 0) & (ol > orr)) | ((mj < 0) & (ol < orr))
+                        midj = (ol + orr) / 2.0
+                        ol = jnp.where(cross, jnp.clip(midj, pmn, pmx), ol)
+                        orr = jnp.where(cross, jnp.clip(midj, pmn, pmx), orr)
+                        # child entries (UpdateConstraintsWithOutputs)
+                        mn_lj = jnp.where(mj < 0, jnp.maximum(pmn, orr), pmn)
+                        mx_lj = jnp.where(mj > 0, jnp.minimum(pmx, orr), pmx)
+                        mn_rj = jnp.where(mj > 0, jnp.maximum(pmn, ol), pmn)
+                        mx_rj = jnp.where(mj < 0, jnp.minimum(pmx, ol), pmx)
+                        # child regions (categorical splits keep the parent box
+                        # — no feature-order relation between cat children)
+                        lo_p, hi_p = lo_all[p], hi_all[p]
+                        num_j = jnp.logical_not(fcat[j])
+                        hi_l = jnp.where(num_j, hi_p.at[fj].set(thr[j]), hi_p)
+                        lo_r = jnp.where(num_j,
+                                         lo_p.at[fj].set(thr[j] + 1), lo_p)
+                        for c_lo, c_hi, c_out in ((lo_p, hi_l, ol),
+                                                  (lo_r, hi_p, orr)):
+                            inter = (lo_all <= c_hi[None, :]) & \
+                                (hi_all >= c_lo[None, :])          # (L, F)
+                            nfail = jnp.sum(jnp.logical_not(inter), axis=1)
+                            onlyf = (nfail == 1)[:, None] & \
+                                jnp.logical_not(inter)
+                            below = onlyf & (hi_all < c_lo[None, :])
+                            above = onlyf & (lo_all > c_hi[None, :])
+                            capmax = jnp.any((below & inc_row) |
+                                             (above & dec_row), axis=1)
+                            capmin = jnp.any((above & inc_row) |
+                                             (below & dec_row), axis=1)
+                            mx_all = jnp.where(act & capmax,
+                                               jnp.minimum(mx_all, c_out),
+                                               mx_all)
+                            mn_all = jnp.where(act & capmin,
+                                               jnp.maximum(mn_all, c_out),
+                                               mn_all)
+                        pj = jnp.where(act, p, L)
+                        rj = jnp.where(act, new_ids[j], L)
+                        mn_all = mn_all.at[pj].set(mn_lj, mode="drop") \
+                                       .at[rj].set(mn_rj, mode="drop")
+                        mx_all = mx_all.at[pj].set(mx_lj, mode="drop") \
+                                       .at[rj].set(mx_rj, mode="drop")
+                        hi_all = hi_all.at[pj].set(hi_l, mode="drop") \
+                                       .at[rj].set(hi_p, mode="drop")
+                        lo_all = lo_all.at[rj].set(lo_r, mode="drop")
+                        out_l2 = out_l2.at[j].set(ol)
+                        out_r2 = out_r2.at[j].set(orr)
+                        bnd_l = bnd_l.at[j].set(jnp.stack([mn_lj, mx_lj]))
+                        bnd_r = bnd_r.at[j].set(jnp.stack([mn_rj, mx_rj]))
+                    out_l, out_r = out_l2, out_r2
+                    mn_l, mx_l = bnd_l[:, 0], bnd_l[:, 1]
+                    mn_r, mx_r = bnd_r[:, 0], bnd_r[:, 1]
+                    bounds2 = jnp.concatenate([bnd_l, bnd_r])   # (2W, 2)
+                elif use_mc:
+                    p_mn = s["leaf_mn"][sel_leaves]
+                    p_mx = s["leaf_mx"][sel_leaves]
+                    out_l = jnp.clip(out_l, p_mn, p_mx)
+                    out_r = jnp.clip(out_r, p_mn, p_mx)
+                    m = jnp.where(fcat, 0, monotone[feat])
+                    mid = (out_l + out_r) / 2.0
+                    mn_l = jnp.where(m < 0, jnp.maximum(p_mn, mid), p_mn)
+                    mx_l = jnp.where(m > 0, jnp.minimum(p_mx, mid), p_mx)
+                    mn_r = jnp.where(m > 0, jnp.maximum(p_mn, mid), p_mn)
+                    mx_r = jnp.where(m < 0, jnp.minimum(p_mx, mid), p_mx)
+                    bounds2 = jnp.concatenate([
+                        jnp.stack([mn_l, mx_l], axis=1),
+                        jnp.stack([mn_r, mx_r], axis=1)])       # (2W, 2)
+                else:
+                    bounds2 = jnp.zeros((2 * W, 2), jnp.float32)
 
             # ---- children candidates: one vmapped scan over 2W ----
-            child_depth = s["leaf_depth"][sel_leaves] + 1
-            hists2 = jnp.concatenate([hist_l, hist_r])      # (2W, G, Bb, 3)
-            sums2 = jnp.concatenate([lsum, rsum])
-            totals2 = sums2
-            ex2 = _scan_hists(hists2, totals2)
-            depth2 = jnp.concatenate([child_depth, child_depth])
-            lv2 = jnp.concatenate([out_l, out_r])
-            fm2 = jnp.broadcast_to(feature_mask, (2 * W, F))
-            if use_ic:
-                child_path = s["leaf_path"][sel_leaves] | \
-                    (jnp.arange(F, dtype=jnp.int32)[None, :] ==
-                     feat[:, None])                          # (W, F)
-                path2 = jnp.concatenate([child_path, child_path])
-                fm2 = fm2 & jax.vmap(allowed_features)(path2)
-            ids2 = jnp.concatenate([2 * node_ids, 2 * node_ids + 1])
-            if use_bynode:
-                fm2 = fm2 & node_mask_many(ids2)
-            rb2 = node_rand_many(ids2) if use_et else None
-            cegb2 = None
-            if use_lazy:
-                # 1) mark the wave's split features as computed for every
-                # parent row (the reference marks the split leaf's rows,
-                # cost_effective_gradient_boosting.hpp:111-121) BEFORE the
-                # children scans, which must see the updated bitmap
-                used_b = s["used"]
-                slz = sel_leaves.astype(rl_old.dtype)
-                in_bag = bag_mask > 0
-                for j in range(W):
-                    # only in-bag rows: the reference marks via the
-                    # bagged DataPartition's GetIndexOnLeaf
-                    m = sel[j] & (rl_old == slz[j]) & in_bag
-                    used_b = used_b.at[feat[j]].set(
-                        used_b[feat[j]] | (_pack_bits(m) if lp
-                                           else m))
-                # 2) per-(feature, child) unused counts: grouped matvecs
-                # against the bitmap (0/1 bf16 products, f32 accumulation
-                # — exact to 2^24 counted rows per shard)
-                live2 = jnp.concatenate([sel, sel])
-                cid2 = jnp.where(live2, jnp.concatenate(
-                    [sel_leaves, new_ids]), -2)
-                pad_c = (-cid2.shape[0]) % 7
-                if pad_c:
-                    cid2 = jnp.concatenate(
-                        [cid2, jnp.full((pad_c,), -2, cid2.dtype)])
-                used_f = (_unpack_bits(used_b) if lp
-                          else used_b).astype(jnp.bfloat16)
-                # out-of-bag rows are invisible to the counts (sums2
-                # totals are bagged counts too)
-                rl32 = jnp.where(in_bag, rl.astype(jnp.int32), -9)
+            with jax.named_scope("lgbm.wave.scan"):
+                child_depth = s["leaf_depth"][sel_leaves] + 1
+                hists2 = jnp.concatenate([hist_l, hist_r])      # (2W, G, Bb, 3)
+                sums2 = jnp.concatenate([lsum, rsum])
+                totals2 = sums2
+                ex2 = _scan_hists(hists2, totals2)
+                depth2 = jnp.concatenate([child_depth, child_depth])
+                lv2 = jnp.concatenate([out_l, out_r])
+                fm2 = jnp.broadcast_to(feature_mask, (2 * W, F))
+                if use_ic:
+                    child_path = s["leaf_path"][sel_leaves] | \
+                        (jnp.arange(F, dtype=jnp.int32)[None, :] ==
+                         feat[:, None])                          # (W, F)
+                    path2 = jnp.concatenate([child_path, child_path])
+                    fm2 = fm2 & jax.vmap(allowed_features)(path2)
+                ids2 = jnp.concatenate([2 * node_ids, 2 * node_ids + 1])
+                if use_bynode:
+                    fm2 = fm2 & node_mask_many(ids2)
+                rb2 = node_rand_many(ids2) if use_et else None
+                cegb2 = None
+                if use_lazy:
+                    # 1) mark the wave's split features as computed for every
+                    # parent row (the reference marks the split leaf's rows,
+                    # cost_effective_gradient_boosting.hpp:111-121) BEFORE the
+                    # children scans, which must see the updated bitmap
+                    used_b = s["used"]
+                    slz = sel_leaves.astype(rl_old.dtype)
+                    in_bag = bag_mask > 0
+                    for j in range(W):
+                        # only in-bag rows: the reference marks via the
+                        # bagged DataPartition's GetIndexOnLeaf
+                        m = sel[j] & (rl_old == slz[j]) & in_bag
+                        used_b = used_b.at[feat[j]].set(
+                            used_b[feat[j]] | (_pack_bits(m) if lp
+                                               else m))
+                    # 2) per-(feature, child) unused counts: grouped matvecs
+                    # against the bitmap (0/1 bf16 products, f32 accumulation
+                    # — exact to 2^24 counted rows per shard)
+                    live2 = jnp.concatenate([sel, sel])
+                    cid2 = jnp.where(live2, jnp.concatenate(
+                        [sel_leaves, new_ids]), -2)
+                    pad_c = (-cid2.shape[0]) % 7
+                    if pad_c:
+                        cid2 = jnp.concatenate(
+                            [cid2, jnp.full((pad_c,), -2, cid2.dtype)])
+                    used_f = (_unpack_bits(used_b) if lp
+                              else used_b).astype(jnp.bfloat16)
+                    # out-of-bag rows are invisible to the counts (sums2
+                    # totals are bagged counts too)
+                    rl32 = jnp.where(in_bag, rl.astype(jnp.int32), -9)
 
-                def cnt_group(cids):
-                    m = (rl32[None, :] == cids[:, None]).astype(
-                        jnp.bfloat16)                         # (7, N)
-                    return jax.lax.dot_general(
-                        used_f, m, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)   # (F, 7)
+                    def cnt_group(cids):
+                        m = (rl32[None, :] == cids[:, None]).astype(
+                            jnp.bfloat16)                         # (7, N)
+                        return jax.lax.dot_general(
+                            used_f, m, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)   # (F, 7)
 
-                used_cnt = jax.lax.map(cnt_group, cid2.reshape(-1, 7))
-                used_cnt = jnp.moveaxis(used_cnt, 0, 1).reshape(
-                    F, -1)[:, :2 * W]                         # (F, 2W)
-                used_cnt = strat.reduce_sum(used_cnt)
-                unused = jnp.maximum(sums2[:, 2][None, :] - used_cnt, 0.0)
-                base = cegb_penalty if sp.use_cegb else \
-                    jnp.zeros((F,), jnp.float32)
-                cegb2 = base[None, :] + (lazy_pen[:, None] * unused).T
-            cands = many_candidates(ex2, sums2, bounds2, depth2, lv2, fm2,
-                                    rb2, cegb2)
-            depth_ok = jnp.logical_or(max_depth <= 0, child_depth < max_depth)
-            dok2 = jnp.concatenate([depth_ok, depth_ok])
-            cg = jnp.where(dok2 & jnp.concatenate([sel, sel]), cands[0],
-                           NEG_INF)
+                    used_cnt = jax.lax.map(cnt_group, cid2.reshape(-1, 7))
+                    used_cnt = jnp.moveaxis(used_cnt, 0, 1).reshape(
+                        F, -1)[:, :2 * W]                         # (F, 2W)
+                    used_cnt = strat.reduce_sum(used_cnt)
+                    unused = jnp.maximum(sums2[:, 2][None, :] - used_cnt, 0.0)
+                    base = cegb_penalty if sp.use_cegb else \
+                        jnp.zeros((F,), jnp.float32)
+                    cegb2 = base[None, :] + (lazy_pen[:, None] * unused).T
+                cands = many_candidates(ex2, sums2, bounds2, depth2, lv2, fm2,
+                                        rb2, cegb2)
+                depth_ok = jnp.logical_or(max_depth <= 0, child_depth < max_depth)
+                dok2 = jnp.concatenate([depth_ok, depth_ok])
+                cg = jnp.where(dok2 & jnp.concatenate([sel, sel]), cands[0],
+                               NEG_INF)
 
             # ---- scatter state updates (invalid lanes -> dropped) ----
-            idx_l = jnp.where(sel, sel_leaves, L)
-            idx_r = jnp.where(sel, new_ids, L)
-            idx2 = jnp.concatenate([idx_l, idx_r])
+            with jax.named_scope("lgbm.wave.commit"):
+                idx_l = jnp.where(sel, sel_leaves, L)
+                idx_r = jnp.where(sel, new_ids, L)
+                idx2 = jnp.concatenate([idx_l, idx_r])
 
-            def sc2(arr, val2):
-                return arr.at[idx2].set(val2, mode="drop")
+                def sc2(arr, val2):
+                    return arr.at[idx2].set(val2, mode="drop")
 
-            out = dict(s)
-            out["row_leaf"] = rl
-            out["hists"] = s["hists"].at[idx_l].set(
-                hist_l, mode="drop").at[idx_r].set(hist_r, mode="drop")
-            out["leaf_sum"] = sc2(s["leaf_sum"], sums2)
-            out["leaf_depth"] = sc2(s["leaf_depth"], depth2)
-            out["cand_gain"] = sc2(s["cand_gain"], cg)
-            out["cand_feat"] = sc2(s["cand_feat"], cands[1])
-            out["cand_bin"] = sc2(s["cand_bin"], cands[2])
-            out["cand_dleft"] = sc2(s["cand_dleft"], cands[3])
-            out["cand_lsum"] = sc2(s["cand_lsum"], cands[4])
-            out["cand_rsum"] = sc2(s["cand_rsum"], cands[5])
-            out["cand_member"] = sc2(s["cand_member"], cands[6])
-            if use_mc and mc_inter:
-                # the sequential refinement already wrote child entries
-                # AND propagated caps to contiguous leaves
-                out["leaf_mn"] = mn_all
-                out["leaf_mx"] = mx_all
-                out["leaf_lo"] = lo_all
-                out["leaf_hi"] = hi_all
-            elif use_mc:
-                out["leaf_mn"] = sc2(s["leaf_mn"],
-                                     jnp.concatenate([mn_l, mn_r]))
-                out["leaf_mx"] = sc2(s["leaf_mx"],
-                                     jnp.concatenate([mx_l, mx_r]))
-            if use_ic:
-                out["leaf_path"] = sc2(s["leaf_path"], path2)
-            if use_lazy:
-                out["used"] = used_b
-            out["leaf_value"] = sc2(s["leaf_value"], lv2)
-            out["leaf_weight"] = sc2(s["leaf_weight"], sums2[:, 1])
-            out["leaf_count"] = sc2(s["leaf_count"], sums2[:, 2])
+                out = dict(s)
+                out["row_leaf"] = rl
+                out["hists"] = s["hists"].at[idx_l].set(
+                    hist_l, mode="drop").at[idx_r].set(hist_r, mode="drop")
+                out["leaf_sum"] = sc2(s["leaf_sum"], sums2)
+                out["leaf_depth"] = sc2(s["leaf_depth"], depth2)
+                out["cand_gain"] = sc2(s["cand_gain"], cg)
+                out["cand_feat"] = sc2(s["cand_feat"], cands[1])
+                out["cand_bin"] = sc2(s["cand_bin"], cands[2])
+                out["cand_dleft"] = sc2(s["cand_dleft"], cands[3])
+                out["cand_lsum"] = sc2(s["cand_lsum"], cands[4])
+                out["cand_rsum"] = sc2(s["cand_rsum"], cands[5])
+                out["cand_member"] = sc2(s["cand_member"], cands[6])
+                if use_mc and mc_inter:
+                    # the sequential refinement already wrote child entries
+                    # AND propagated caps to contiguous leaves
+                    out["leaf_mn"] = mn_all
+                    out["leaf_mx"] = mx_all
+                    out["leaf_lo"] = lo_all
+                    out["leaf_hi"] = hi_all
+                elif use_mc:
+                    out["leaf_mn"] = sc2(s["leaf_mn"],
+                                         jnp.concatenate([mn_l, mn_r]))
+                    out["leaf_mx"] = sc2(s["leaf_mx"],
+                                         jnp.concatenate([mx_l, mx_r]))
+                if use_ic:
+                    out["leaf_path"] = sc2(s["leaf_path"], path2)
+                if use_lazy:
+                    out["used"] = used_b
+                out["leaf_value"] = sc2(s["leaf_value"], lv2)
+                out["leaf_weight"] = sc2(s["leaf_weight"], sums2[:, 1])
+                out["leaf_count"] = sc2(s["leaf_count"], sums2[:, 2])
 
-            # ---- tree node records ----
-            nidx = jnp.where(sel, node_ids, L - 1)
-            dleft_rec = jnp.where(fcat, member[:, 0], dleft)
-            dt_bits = (jnp.where(fcat, CAT_MASK, 0) |
-                       jnp.where(dleft_rec, DEFAULT_LEFT_MASK, 0) |
-                       jnp.where(fnan & jnp.logical_not(fcat), MISSING_NAN, 0)
-                       ).astype(jnp.int32)
+                # ---- tree node records ----
+                nidx = jnp.where(sel, node_ids, L - 1)
+                dleft_rec = jnp.where(fcat, member[:, 0], dleft)
+                dt_bits = (jnp.where(fcat, CAT_MASK, 0) |
+                           jnp.where(dleft_rec, DEFAULT_LEFT_MASK, 0) |
+                           jnp.where(fnan & jnp.logical_not(fcat), MISSING_NAN, 0)
+                           ).astype(jnp.int32)
 
-            def scn(arr, val):
-                return arr.at[nidx].set(val, mode="drop")
+                def scn(arr, val):
+                    return arr.at[nidx].set(val, mode="drop")
 
-            out["split_feature"] = scn(s["split_feature"], feat)
-            out["threshold_bin"] = scn(s["threshold_bin"], thr)
-            out["nan_bin"] = scn(s["nan_bin"], f_nan_bin)
-            out["cat_member"] = scn(s["cat_member"], member)
-            out["decision_type"] = scn(s["decision_type"], dt_bits)
-            out["split_gain"] = scn(s["split_gain"], vals)
-            out["internal_value"] = scn(
-                s["internal_value"], leaf_output(psum_[:, 0], psum_[:, 1], sp))
-            out["internal_weight"] = scn(s["internal_weight"], psum_[:, 1])
-            out["internal_count"] = scn(s["internal_count"], psum_[:, 2])
+                out["split_feature"] = scn(s["split_feature"], feat)
+                out["threshold_bin"] = scn(s["threshold_bin"], thr)
+                out["nan_bin"] = scn(s["nan_bin"], f_nan_bin)
+                out["cat_member"] = scn(s["cat_member"], member)
+                out["decision_type"] = scn(s["decision_type"], dt_bits)
+                out["split_gain"] = scn(s["split_gain"], vals)
+                out["internal_value"] = scn(
+                    s["internal_value"], leaf_output(psum_[:, 0], psum_[:, 1], sp))
+                out["internal_weight"] = scn(s["internal_weight"], psum_[:, 1])
+                out["internal_count"] = scn(s["internal_count"], psum_[:, 2])
 
-            # patch parent nodes' child slots pointing at the split leaves
-            # (encoded as -(leaf+1)), then write the new nodes' own slots
-            enc = -(sel_leaves + 1)
-            for name in ("left_child", "right_child"):
-                arr = s[name]
-                match = (arr[:, None] == enc[None, :]) & sel[None, :]
-                has = jnp.any(match, axis=1)
-                pick = jnp.argmax(match, axis=1)
-                arr = jnp.where(has, node_ids[pick], arr)
-                if name == "left_child":
-                    arr = arr.at[nidx].set(enc, mode="drop")
-                else:
-                    arr = arr.at[nidx].set(-(new_ids + 1), mode="drop")
-                out[name] = arr
+                # patch parent nodes' child slots pointing at the split leaves
+                # (encoded as -(leaf+1)), then write the new nodes' own slots
+                enc = -(sel_leaves + 1)
+                for name in ("left_child", "right_child"):
+                    arr = s[name]
+                    match = (arr[:, None] == enc[None, :]) & sel[None, :]
+                    has = jnp.any(match, axis=1)
+                    pick = jnp.argmax(match, axis=1)
+                    arr = jnp.where(has, node_ids[pick], arr)
+                    if name == "left_child":
+                        arr = arr.at[nidx].set(enc, mode="drop")
+                    else:
+                        arr = arr.at[nidx].set(-(new_ids + 1), mode="drop")
+                    out[name] = arr
 
-            out["num_leaves"] = nl0 + total_new
-            out["done"] = total_new == 0
-            out["hist_passes"] = s["hist_passes"] + 1
+                out["num_leaves"] = nl0 + total_new
+                out["done"] = total_new == 0
+                out["hist_passes"] = s["hist_passes"] + 1
             return out
 
         if use_endgame:
@@ -1772,7 +1784,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                         return jnp.where(
                             move, pend["newid"][k].astype(rl_.dtype), rl_)
                     return jax.lax.fori_loop(0, EG, one, rl)
-                return jax.lax.cond(pcnt > 0, flush, lambda r: r, rl)
+                with jax.named_scope("lgbm.endgame.row_update"):
+                    return jax.lax.cond(pcnt > 0, flush, lambda r: r, rl)
 
             def _trial_channels(rl, sel, sel_leaves, feat, thr, fnanb,
                                 dleft, small):
@@ -1905,15 +1918,18 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 rsum = s["cand_rsum"][sel_leaves]
                 fnanb = jnp.where(hn_full[feat], nb_full[feat] - 1, -1)
                 small = lsum[:, 2] <= rsum[:, 2]
-                ch = _trial_channels(rl, sel, sel_leaves, feat, thr,
-                                     fnanb, dleft, small)
-                bank = hist_waves(ch)       # (W, G, Bb, 3); DP: one psum
+                with jax.named_scope("lgbm.endgame.row_update"):
+                    ch = _trial_channels(rl, sel, sel_leaves, feat, thr,
+                                         fnanb, dleft, small)
+                with jax.named_scope("lgbm.endgame.hist"):
+                    bank = hist_waves(ch)       # (W, G, Bb, 3); DP: one psum
                 slot = jnp.full((L,), -1, jnp.int32).at[
                     jnp.where(sel, sel_leaves, L)].set(
                         jnp.arange(W, dtype=jnp.int32), mode="drop")
-                s, slot, pend, pcnt = jax.lax.while_loop(
-                    _commit_cond, _make_commit(bank),
-                    (s, slot, pend, pcnt))
+                with jax.named_scope("lgbm.endgame.select"):
+                    s, slot, pend, pcnt = jax.lax.while_loop(
+                        _commit_cond, _make_commit(bank),
+                        (s, slot, pend, pcnt))
                 s = dict(s)
                 s["hist_passes"] = s["hist_passes"] + 1
                 return (s, pend, pcnt)
@@ -1925,15 +1941,21 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 go = go & (s["num_leaves"] + 2 * W <= L)
             return go
 
+        # pass kinds, read off the counters the loops already carry (no
+        # new loop state): 1 + wave_passes + endgame_passes == hist_passes
+        ramp_committed = state["num_leaves"] - 1 if use_spec \
+            else jnp.asarray(0, jnp.int32)
         for fw in forced_waves:   # pre-committed ForceSplits prefix
             state = body(state, forced=fw)
         s = jax.lax.while_loop(cond, body, state)
+        wave_passes = s["hist_passes"] - 1
         if use_endgame:
-            s, pend, pcnt = jax.lax.while_loop(
-                _eg_cond, _eg_body,
-                (s, _pend0(), jnp.asarray(0, jnp.int32)))
-            s = dict(s)
-            s["row_leaf"] = _apply_pending(s["row_leaf"], pend, pcnt)
+            with jax.named_scope("lgbm.endgame"):
+                s, pend, pcnt = jax.lax.while_loop(
+                    _eg_cond, _eg_body,
+                    (s, _pend0(), jnp.asarray(0, jnp.int32)))
+                s = dict(s)
+                s["row_leaf"] = _apply_pending(s["row_leaf"], pend, pcnt)
             s["done"] = jnp.asarray(True)
 
         if quantized and renew_leaf:
@@ -1944,35 +1966,36 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             # On the Pallas path this reuses the single-leaf histogram
             # kernel with row_leaf as a one-feature bin column (cost
             # ~1/F of a wave pass); off-TPU it is a segment-sum.
-            rl = s["row_leaf"].astype(jnp.int32)
-            if pallas:
-                parts = []
-                for c in range((L + 255) // 256):
-                    m = bag_mask * (rl // 256 == c).astype(bag_mask.dtype)
-                    bins1 = (rl % 256).astype(jnp.uint8)[None, :]
-                    parts.append(build_histogram_pallas(
-                        bins1, grad, hess, m, num_bins=256,
-                        interpret=interpret, kr=4096,
-                        pipeline=pipeline)[0])
-                gh = jnp.concatenate(parts, axis=0)[:L, :2]       # (L, 2)
-            else:
-                gh = jax.ops.segment_sum(
-                    jnp.stack([gm, hm], axis=-1), rl, num_segments=L)
-            gh = strat.reduce_sum(gh)
-            vals = leaf_output(gh[:, 0], gh[:, 1], sp)
-            if use_sm:
-                # path-smoothed outputs blend with the parent chain; renew
-                # against the recorded (pre-renew) value as the parent
-                # proxy — matches the reference's renew-in-place behavior
-                vals = leaf_output_smoothed(gh[:, 0], gh[:, 1],
-                                            s["leaf_count"],
-                                            s["leaf_value"], sp)
-            if use_mc:
-                vals = jnp.clip(vals, s["leaf_mn"], s["leaf_mx"])
-            live = jnp.arange(L, dtype=jnp.int32) < s["num_leaves"]
-            ok = live & (s["leaf_count"] > 0)
-            s["leaf_value"] = jnp.where(ok, vals, s["leaf_value"])
-            s["leaf_weight"] = jnp.where(ok, gh[:, 1], s["leaf_weight"])
+            with jax.named_scope("lgbm.renew"):
+                rl = s["row_leaf"].astype(jnp.int32)
+                if pallas:
+                    parts = []
+                    for c in range((L + 255) // 256):
+                        m = bag_mask * (rl // 256 == c).astype(bag_mask.dtype)
+                        bins1 = (rl % 256).astype(jnp.uint8)[None, :]
+                        parts.append(build_histogram_pallas(
+                            bins1, grad, hess, m, num_bins=256,
+                            interpret=interpret, kr=4096,
+                            pipeline=pipeline)[0])
+                    gh = jnp.concatenate(parts, axis=0)[:L, :2]       # (L, 2)
+                else:
+                    gh = jax.ops.segment_sum(
+                        jnp.stack([gm, hm], axis=-1), rl, num_segments=L)
+                gh = strat.reduce_sum(gh)
+                vals = leaf_output(gh[:, 0], gh[:, 1], sp)
+                if use_sm:
+                    # path-smoothed outputs blend with the parent chain; renew
+                    # against the recorded (pre-renew) value as the parent
+                    # proxy — matches the reference's renew-in-place behavior
+                    vals = leaf_output_smoothed(gh[:, 0], gh[:, 1],
+                                                s["leaf_count"],
+                                                s["leaf_value"], sp)
+                if use_mc:
+                    vals = jnp.clip(vals, s["leaf_mn"], s["leaf_mx"])
+                live = jnp.arange(L, dtype=jnp.int32) < s["num_leaves"]
+                ok = live & (s["leaf_count"] > 0)
+                s["leaf_value"] = jnp.where(ok, vals, s["leaf_value"])
+                s["leaf_weight"] = jnp.where(ok, gh[:, 1], s["leaf_weight"])
 
         tree_out = GrownTree(
             split_feature=s["split_feature"],
@@ -1986,7 +2009,9 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             leaf_weight=s["leaf_weight"], leaf_count=s["leaf_count"],
             num_leaves=s["num_leaves"],
             row_leaf=s["row_leaf"].astype(jnp.int32),
-            hist_passes=s["hist_passes"])
+            hist_passes=s["hist_passes"], wave_passes=wave_passes,
+            endgame_passes=s["hist_passes"] - 1 - wave_passes,
+            ramp_committed=ramp_committed)
         if use_lazy:
             return tree_out, s["used"]
         return tree_out

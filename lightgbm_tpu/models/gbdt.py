@@ -15,6 +15,7 @@ model, plus metric scalars when evaluation runs.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -27,10 +28,10 @@ from ..dataset import Dataset
 from ..learner.serial import GrownTree, SerialTreeLearner
 from ..metric import Metric, create_metrics
 from ..objective import ObjectiveFunction, create_objective
+from ..telemetry.trace import span, timed_span
 from ..telemetry.train_record import TrainRecord, set_last_train_record
 from ..utils.log import log_info, log_warning
 from ..utils.random import host_rng
-from ..utils.timer import FunctionTimer
 from .tree import Tree, TreeBatch, pad_rows, predict_raw
 from ..ops.split import leaf_output as _leaf_output_fn
 
@@ -144,16 +145,21 @@ def _name_refused_kernels(exc: Exception) -> None:
         f"Compiler message: {exc}") from exc
 
 
-def _update_score_impl(score, row_leaf, leaf_value, shrinkage):
+def _score_update_impl(score, row_leaf, leaf_value, shrinkage):
     """score += shrinkage * leaf_value[row_leaf] — training-set score update
     using the grower's final leaf assignment (replaces the reference's
-    ScoreUpdater::AddScore tree walk for train data, score_updater.hpp:54)."""
-    return score + shrinkage * leaf_value[row_leaf]
+    ScoreUpdater::AddScore tree walk for train data, score_updater.hpp:54).
+
+    Named ``_update_score_impl`` until it got its scope: the persistent
+    compile cache's key leaves metadata out, so a cache that holds the
+    scope-less executable would go on serving it under the old module name."""
+    with jax.named_scope("lgbm.score_update"):
+        return score + shrinkage * leaf_value[row_leaf]
 
 
 # Undonated entry: the multitrain driver vmaps this over the model axis
 # (donation annotations do not survive inner-jit batching).
-_update_score_by_leaf = jax.jit(_update_score_impl)
+_update_score_by_leaf = jax.jit(_score_update_impl)
 
 # Standalone boosting path: the incoming (N,)/(N,) column score buffer is
 # dead after the call (``self.score`` is rebound to the result; the
@@ -169,7 +175,7 @@ _update_score_by_leaf = jax.jit(_update_score_impl)
 # N-row HBM buffer per tree.
 SCORE_DONATE_ARGNUMS = (0,)
 _update_score_by_leaf_donated = jax.jit(
-    _update_score_impl, donate_argnums=SCORE_DONATE_ARGNUMS)
+    _score_update_impl, donate_argnums=SCORE_DONATE_ARGNUMS)
 
 
 def _score_update_entry():
@@ -355,6 +361,7 @@ class GBDT:
         # replaces it with the published per-run record (same deal for
         # the flight recorder: inert/disabled until a training run)
         self.train_record = TrainRecord(meta={"boosting": self.name})
+        self._first_update = False
         from ..telemetry.flight import FlightRecorder
         self.flight = FlightRecorder(capacity=1, enabled=False)
         if train_set is not None:
@@ -363,6 +370,8 @@ class GBDT:
     # -- setup ---------------------------------------------------------------
     def _init_train(self, train_set: Dataset) -> None:
         cfg = self.config
+        t_init = time.perf_counter()
+        setup_s: Dict[str, float] = {}
         # params verbosity drives the global log level (reference: the C++
         # global Log level is set from config at Booster creation)
         from ..utils.log import set_verbosity
@@ -463,108 +472,111 @@ class GBDT:
                     f"(2^31/{_gq} rows per shard at num_grad_quant_bins="
                     f"{cfg.num_grad_quant_bins}); lower num_grad_quant_bins "
                     "or shard rows across more devices")
-        if getattr(train_set, "distributed_rows", False):
-            # pre-partitioned ingest: assemble the global row-sharded
-            # matrix from each process's local shard (features never
-            # replicate across hosts)
-            if cfg.tree_learner not in ("data", "voting"):
-                raise ValueError("pre_partition-ed training requires "
-                                 "tree_learner=data or voting")
-            from jax.sharding import NamedSharding, PartitionSpec as _P
-            from ..parallel.mesh import get_mesh as _get_mesh
-            _mesh = _get_mesh(int(cfg.num_devices))
-            _ax = _mesh.axis_names[0]
-            self.X_dev = jax.make_array_from_process_local_data(
-                NamedSharding(_mesh, _P(_ax)), train_set.X_binned)
-            self._row_valid = jax.make_array_from_process_local_data(
-                NamedSharding(_mesh, _P(_ax)), train_set._dist_valid_local)
-        else:
-            self.X_dev = self._put_rows(train_set.X_binned)
-            self._row_valid = None
-        self._is_cat_np = is_cat
-        # bundle-space tree-walk decode arrays (EFB valid sets / rebuilds)
-        # — the standard efb_arrays layout minus exp_map (unused by the
-        # walk's decode)
-        efb = getattr(train_set, "efb", None)
-        self._efb_walk = None if efb is None else (
-            None, jnp.asarray(efb.f_bundle), jnp.asarray(efb.f_offset),
-            jnp.asarray(efb.f_default), jnp.asarray(efb.f_nbins),
-            jnp.asarray(efb.f_single))
-        # CEGB (cost_effective_gradient_boosting.hpp): coupled per-feature
-        # penalties charge once until the feature is first used; tracked
-        # host-side across trees (per-tree granularity)
-        self._cegb_coupled = None
-        serial = isinstance(self.learner, SerialTreeLearner)
-        supports_extras = serial or getattr(self.learner,
-                                            "supports_extras", False)
-        if cfg.cegb_penalty_feature_coupled or cfg.cegb_penalty_split > 0:
-            if not supports_extras:
-                log_warning("CEGB penalties are applied by the serial and "
-                            "data-parallel(wave) learners only; this "
-                            "learner ignores them")
-            elif cfg.cegb_penalty_feature_coupled:
-                full = np.zeros(train_set.num_total_features, np.float64)
-                cpl = cfg.cegb_penalty_feature_coupled
-                full[:len(cpl)] = [float(v) for v in cpl]
-                self._cegb_coupled = (full[train_set.used_feature_map] *
-                                      float(cfg.cegb_tradeoff))
-                self._cegb_used = np.zeros(self.num_features, bool)
-                self._defer_trees = False  # used-set updates per tree
-        if cfg.feature_fraction_bynode < 1.0 and not supports_extras:
-            log_warning("feature_fraction_bynode is applied by the serial "
-                        "and data-parallel(wave) learners only; this "
-                        "learner ignores it")
-        self._linear = bool(cfg.linear_tree)
-        if self._linear and self.name != "gbdt":
-            log_warning(f"linear_tree is not supported with "
-                        f"boosting={self.name}; training plain trees")
-            self._linear = False
-        if self._linear:
-            # linear leaves re-fit on raw values each iteration; tree
-            # deferral buys nothing here
-            self._defer_trees = False
+        # bins, labels, weights and scores go to the device: host seconds of
+        # ENQUEUEING the copies (device_put returns before they land)
+        with timed_span(setup_s, "upload", "train/init/upload"):
             if getattr(train_set, "distributed_rows", False):
-                # pre-partitioned: assemble the row-sharded global raw
-                # matrix like X_dev (local shards never replicate)
-                from jax.sharding import NamedSharding
-                from jax.sharding import PartitionSpec as _P2
-                from ..parallel.mesh import get_mesh as _get_mesh2
-                _mesh2 = _get_mesh2(int(cfg.num_devices))
-                self.X_raw_dev = jax.make_array_from_process_local_data(
-                    NamedSharding(_mesh2, _P2(_mesh2.axis_names[0])),
-                    train_set.raw_used)
+                # pre-partitioned ingest: assemble the global row-sharded
+                # matrix from each process's local shard (features never
+                # replicate across hosts)
+                if cfg.tree_learner not in ("data", "voting"):
+                    raise ValueError("pre_partition-ed training requires "
+                                     "tree_learner=data or voting")
+                from jax.sharding import NamedSharding, PartitionSpec as _P
+                from ..parallel.mesh import get_mesh as _get_mesh
+                _mesh = _get_mesh(int(cfg.num_devices))
+                _ax = _mesh.axis_names[0]
+                self.X_dev = jax.make_array_from_process_local_data(
+                    NamedSharding(_mesh, _P(_ax)), train_set.X_binned)
+                self._row_valid = jax.make_array_from_process_local_data(
+                    NamedSharding(_mesh, _P(_ax)), train_set._dist_valid_local)
             else:
-                self.X_raw_dev = jnp.asarray(train_set.raw_used)
+                self.X_dev = self._put_rows(train_set.X_binned)
+                self._row_valid = None
+            self._is_cat_np = is_cat
+            # bundle-space tree-walk decode arrays (EFB valid sets / rebuilds)
+            # — the standard efb_arrays layout minus exp_map (unused by the
+            # walk's decode)
+            efb = getattr(train_set, "efb", None)
+            self._efb_walk = None if efb is None else (
+                None, jnp.asarray(efb.f_bundle), jnp.asarray(efb.f_offset),
+                jnp.asarray(efb.f_default), jnp.asarray(efb.f_nbins),
+                jnp.asarray(efb.f_single))
+            # CEGB (cost_effective_gradient_boosting.hpp): coupled per-feature
+            # penalties charge once until the feature is first used; tracked
+            # host-side across trees (per-tree granularity)
+            self._cegb_coupled = None
+            serial = isinstance(self.learner, SerialTreeLearner)
+            supports_extras = serial or getattr(self.learner,
+                                                "supports_extras", False)
+            if cfg.cegb_penalty_feature_coupled or cfg.cegb_penalty_split > 0:
+                if not supports_extras:
+                    log_warning("CEGB penalties are applied by the serial and "
+                                "data-parallel(wave) learners only; this "
+                                "learner ignores them")
+                elif cfg.cegb_penalty_feature_coupled:
+                    full = np.zeros(train_set.num_total_features, np.float64)
+                    cpl = cfg.cegb_penalty_feature_coupled
+                    full[:len(cpl)] = [float(v) for v in cpl]
+                    self._cegb_coupled = (full[train_set.used_feature_map] *
+                                          float(cfg.cegb_tradeoff))
+                    self._cegb_used = np.zeros(self.num_features, bool)
+                    self._defer_trees = False  # used-set updates per tree
+            if cfg.feature_fraction_bynode < 1.0 and not supports_extras:
+                log_warning("feature_fraction_bynode is applied by the serial "
+                            "and data-parallel(wave) learners only; this "
+                            "learner ignores it")
+            self._linear = bool(cfg.linear_tree)
+            if self._linear and self.name != "gbdt":
+                log_warning(f"linear_tree is not supported with "
+                            f"boosting={self.name}; training plain trees")
+                self._linear = False
+            if self._linear:
+                # linear leaves re-fit on raw values each iteration; tree
+                # deferral buys nothing here
+                self._defer_trees = False
+                if getattr(train_set, "distributed_rows", False):
+                    # pre-partitioned: assemble the row-sharded global raw
+                    # matrix like X_dev (local shards never replicate)
+                    from jax.sharding import NamedSharding
+                    from jax.sharding import PartitionSpec as _P2
+                    from ..parallel.mesh import get_mesh as _get_mesh2
+                    _mesh2 = _get_mesh2(int(cfg.num_devices))
+                    self.X_raw_dev = jax.make_array_from_process_local_data(
+                        NamedSharding(_mesh2, _P2(_mesh2.axis_names[0])),
+                        train_set.raw_used)
+                else:
+                    self.X_raw_dev = jnp.asarray(train_set.raw_used)
 
-        if self.objective is None and cfg.objective != "none":
-            self.objective = create_objective(cfg.objective, cfg)
-        if self.objective is not None:
-            self.objective.init(train_set.metadata, self.num_data)
-            self.objective.place_rows(self._put_rows)
-        self.num_tree_per_iteration = (
-            self.objective.num_model_per_iteration if self.objective else
-            max(1, cfg.num_class if cfg.num_class > 1 else 1))
-        k = self.num_tree_per_iteration
-        shape = (self.num_data,) if k == 1 else (self.num_data, k)
+            if self.objective is None and cfg.objective != "none":
+                self.objective = create_objective(cfg.objective, cfg)
+            if self.objective is not None:
+                self.objective.init(train_set.metadata, self.num_data)
+                self.objective.place_rows(self._put_rows)
+            self.num_tree_per_iteration = (
+                self.objective.num_model_per_iteration if self.objective else
+                max(1, cfg.num_class if cfg.num_class > 1 else 1))
+            k = self.num_tree_per_iteration
+            shape = (self.num_data,) if k == 1 else (self.num_data, k)
 
-        # initial scores: user init_score > boost_from_average > zero
-        self._pending_bias = np.zeros(k)
-        score0 = np.zeros(shape, np.float32)
-        md = train_set.metadata
-        if md.init_score is not None:
-            init = md.init_score.reshape(shape)
-            score0 = score0 + init.astype(np.float32)
-        elif cfg.boost_from_average and self.objective is not None:
-            for cid in range(k):
-                s = self.objective.boost_from_score(cid)
-                self._pending_bias[cid] = s
-                if abs(s) > EPSILON:
-                    log_info(f"Start training from score {s:.6f}")
-            if k == 1:
-                score0 = score0 + np.float32(self._pending_bias[0])
-            else:
-                score0 = score0 + self._pending_bias[None, :].astype(np.float32)
-        self.score = self._put_rows(score0)
+            # initial scores: user init_score > boost_from_average > zero
+            self._pending_bias = np.zeros(k)
+            score0 = np.zeros(shape, np.float32)
+            md = train_set.metadata
+            if md.init_score is not None:
+                init = md.init_score.reshape(shape)
+                score0 = score0 + init.astype(np.float32)
+            elif cfg.boost_from_average and self.objective is not None:
+                for cid in range(k):
+                    s = self.objective.boost_from_score(cid)
+                    self._pending_bias[cid] = s
+                    if abs(s) > EPSILON:
+                        log_info(f"Start training from score {s:.6f}")
+                if k == 1:
+                    score0 = score0 + np.float32(self._pending_bias[0])
+                else:
+                    score0 = score0 + self._pending_bias[None, :].astype(np.float32)
+            self.score = self._put_rows(score0)
 
         self.train_metrics = []
         if cfg.is_provide_training_metric:
@@ -584,8 +596,12 @@ class GBDT:
             "num_leaves": int(cfg.num_leaves),
             "num_data": int(self.num_data),
             "num_features": int(self.num_features),
-        })
+        }, compile_since=t_init)
+        self.train_record.add_setup_seconds(
+            getattr(train_set, "setup_seconds", {}))
+        self.train_record.add_setup_seconds(setup_s)
         set_last_train_record(self.train_record)
+        self._first_update = True
         # flight recorder: bounded per-iteration event ring for crash/
         # preemption post-mortems (telemetry/flight.py).  Observation
         # only — recorder-on training is bit-identical to recorder-off.
@@ -831,141 +847,159 @@ class GBDT:
     # -- one boosting iteration (gbdt.cpp:369 TrainOneIter) ------------------
     def train_one_iter(self, grad: Optional[jnp.ndarray] = None,
                        hess: Optional[jnp.ndarray] = None) -> bool:
+        """One boosting iteration under a ``train/iter`` span.  The run's
+        first is ``train/first_update`` instead and its host seconds go to
+        ``setup_seconds["first_update"]``: it traces, lowers and compiles
+        (or loads) every program of a tree and lays out the bin matrix,
+        then, like every later one, only ENQUEUES the tree."""
+        rec = self.train_record
+        if self._first_update:
+            self._first_update = False
+            with rec.setup("first_update", "train/first_update"):
+                finished = self._train_one_iter(grad, hess)
+            rec.add_setup_seconds(getattr(self.learner, "setup_seconds", {}))
+        else:
+            with span("train/iter"):
+                finished = self._train_one_iter(grad, hess)
+        rec.end_of_update()
+        return finished
+
+    def _train_one_iter(self, grad, hess) -> bool:
         cfg = self.config
         k = self.num_tree_per_iteration
         rec = self.train_record
-        with FunctionTimer("GBDT::train_one_iter"):
-            if grad is None or hess is None:
-                if self.objective is None:
-                    raise ValueError("no objective: pass gradients explicitly "
-                                     "(custom objective path, boosting.h:85)")
-                with rec.phase("gradients"):
-                    grad, hess = self.objective.get_gradients(self.score)
-            else:
-                def _coerce(a):
-                    a = jnp.asarray(a, jnp.float32)
-                    if k == 1:
-                        return a.reshape((self.num_data,))
-                    if a.ndim == 2:
-                        if a.shape == (self.num_data, k):
-                            return a
-                        if a.shape == (k, self.num_data):
-                            return a.T
-                        raise ValueError(
-                            f"custom objective gradients have shape {a.shape}; "
-                            f"expected ({self.num_data}, {k}) or flat "
-                            f"class-major length {self.num_data * k}")
-                    # flat custom-fobj output is CLASS-MAJOR in the reference
-                    # API (grouped by class_id then row_id, c_api.cpp
-                    # UpdateOneIterCustom convention)
-                    return a.reshape((k, self.num_data)).T
-                grad = _coerce(grad)
-                hess = _coerce(hess)
+        if grad is None or hess is None:
+            if self.objective is None:
+                raise ValueError("no objective: pass gradients explicitly "
+                                 "(custom objective path, boosting.h:85)")
+            with rec.phase("gradients"):
+                grad, hess = self.objective.get_gradients(self.score)
+        else:
+            def _coerce(a):
+                a = jnp.asarray(a, jnp.float32)
+                if k == 1:
+                    return a.reshape((self.num_data,))
+                if a.ndim == 2:
+                    if a.shape == (self.num_data, k):
+                        return a
+                    if a.shape == (k, self.num_data):
+                        return a.T
+                    raise ValueError(
+                        f"custom objective gradients have shape {a.shape}; "
+                        f"expected ({self.num_data}, {k}) or flat "
+                        f"class-major length {self.num_data * k}")
+                # flat custom-fobj output is CLASS-MAJOR in the reference
+                # API (grouped by class_id then row_id, c_api.cpp
+                # UpdateOneIterCustom convention)
+                return a.reshape((k, self.num_data)).T
+            grad = _coerce(grad)
+            hess = _coerce(hess)
 
-            # Lagged no-split stop for the deferred-tree path: the previous
-            # iteration's tree sizes are device-computed by now, so this host
-            # pull is a bare RTT and doesn't stall the dispatch pipeline.
-            # When the previous iteration grew only stumps, pop them (the
-            # reference pops non-splitting trees, gbdt.cpp:430-450) and stop.
-            prev = getattr(self, "_prev_iter_leaves", None)
-            if prev is not None and \
-                    all(int(x) <= 1 for x in jax.device_get(prev)):
-                self._prev_iter_leaves = None
-                self._pop_stump_iteration()
-                log_warning("Stopped training because there are no more "
-                            "leaves that meet the split requirements")
-                return True
+        # Lagged no-split stop for the deferred-tree path: the previous
+        # iteration's tree sizes are device-computed by now, so this host
+        # pull is a bare RTT and doesn't stall the dispatch pipeline.
+        # When the previous iteration grew only stumps, pop them (the
+        # reference pops non-splitting trees, gbdt.cpp:430-450) and stop.
+        prev = getattr(self, "_prev_iter_leaves", None)
+        if prev is not None and \
+                all(int(x) <= 1 for x in jax.device_get(prev)):
+            self._prev_iter_leaves = None
+            self._pop_stump_iteration()
+            log_warning("Stopped training because there are no more "
+                        "leaves that meet the split requirements")
+            return True
 
-            finished = True
-            fl_leaves = fl_gain = None  # flight-event fields (last class)
-            fmask = self._feature_mask()
-            grad, hess, mask = self._prepare_iter_sampling(grad, hess)
-            if getattr(self, "_row_valid", None) is not None:
-                # pre_partition padding rows never enter a tree (applied
-                # centrally so GOSS's override is covered too)
-                mask = mask * self._row_valid
-            self._last_sample_mask = mask
-            leaves_this_iter = []
-            for cid in range(k):
-                g = grad if k == 1 else grad[:, cid]
-                h = hess if k == 1 else hess[:, cid]
-                self._cur_gh = (g, h)
-                extra = {}
-                it = self.iter_ * k + cid
-                if getattr(self.learner, "supports_extras", False):
-                    if self._cegb_coupled is not None:
-                        extra["cegb_penalty"] = jnp.asarray(
-                            np.where(self._cegb_used, 0.0,
-                                     self._cegb_coupled), jnp.float32)
-                    if cfg.feature_fraction_bynode < 1.0 or cfg.extra_trees:
-                        # independent streams, like the reference's separate
-                        # ColSampler and ExtraTrees RNGs: row 0 = bynode
-                        # sampling (feature_fraction_seed), row 1 =
-                        # ExtraTrees thresholds (extra_seed)
-                        extra["node_key"] = jnp.stack([
-                            jax.random.fold_in(jax.random.PRNGKey(
-                                cfg.feature_fraction_seed), it),
-                            jax.random.fold_in(jax.random.PRNGKey(
-                                cfg.extra_seed), it)])
-                if getattr(self.learner, "quantized", False):
-                    # per-tree stochastic-rounding stream
-                    # (gradient_discretizer.cpp seeds from config seed)
-                    extra["quant_key"] = jax.random.fold_in(
-                        jax.random.PRNGKey(cfg.seed), it)
-                with rec.phase("grow"):
-                    try:
-                        grown = self.learner.train(self.X_dev, g, h, mask,
-                                                   feature_mask=fmask,
-                                                   **extra)
-                    except Exception as exc:
-                        _name_refused_kernels(exc)
-                        raise
-                # full-data histogram passes of the last grown tree (wave
-                # grower; 0 = untracked) — a device scalar, pulled lazily
-                # by bench/diagnostic readers only
-                self.last_hist_passes = grown.hist_passes
-                rec.add_tree(self.iter_, cid, grown.hist_passes,
-                             grown.num_leaves)
-                if self.flight.enabled:
-                    # last grown tree's fields for this iteration's
-                    # flight event (device scalars, pulled lazily on
-                    # dump; the max over split gains is one tiny
-                    # device reduce)
-                    fl_leaves = grown.num_leaves
-                    fl_gain = jnp.max(grown.split_gain)
-                with rec.phase("record"):
-                    tree = self._record_tree(grown, cid)
-                if tree is not None and self._cegb_coupled is not None:
-                    sf = tree.split_feature[:tree.num_leaves - 1]
-                    self._cegb_used[sf[sf >= 0]] = True
-                if tree is None:
-                    # deferred: the lagged check above decides next iteration
-                    finished = False
-                    leaves_this_iter.append(grown.num_leaves)
-                elif tree.num_leaves > 1:
-                    finished = False
-            self._prev_iter_leaves = leaves_this_iter or None
-            for x in leaves_this_iter:
-                # start the device->host copy NOW so next iteration's
-                # lagged stump check reads a landed value instead of
-                # paying a blocking ~100 ms round trip per iteration
-                # (small-shape configs spend more time in that RTT than
-                # in their kernels)
-                if hasattr(x, "copy_to_host_async"):
-                    x.copy_to_host_async()
-            self.iter_ += 1
+        finished = True
+        fl_leaves = fl_gain = None  # flight-event fields (last class)
+        fmask = self._feature_mask()
+        grad, hess, mask = self._prepare_iter_sampling(grad, hess)
+        if getattr(self, "_row_valid", None) is not None:
+            # pre_partition padding rows never enter a tree (applied
+            # centrally so GOSS's override is covered too)
+            mask = mask * self._row_valid
+        self._last_sample_mask = mask
+        leaves_this_iter = []
+        for cid in range(k):
+            g = grad if k == 1 else grad[:, cid]
+            h = hess if k == 1 else hess[:, cid]
+            self._cur_gh = (g, h)
+            extra = {}
+            it = self.iter_ * k + cid
+            if getattr(self.learner, "supports_extras", False):
+                if self._cegb_coupled is not None:
+                    extra["cegb_penalty"] = jnp.asarray(
+                        np.where(self._cegb_used, 0.0,
+                                 self._cegb_coupled), jnp.float32)
+                if cfg.feature_fraction_bynode < 1.0 or cfg.extra_trees:
+                    # independent streams, like the reference's separate
+                    # ColSampler and ExtraTrees RNGs: row 0 = bynode
+                    # sampling (feature_fraction_seed), row 1 =
+                    # ExtraTrees thresholds (extra_seed)
+                    extra["node_key"] = jnp.stack([
+                        jax.random.fold_in(jax.random.PRNGKey(
+                            cfg.feature_fraction_seed), it),
+                        jax.random.fold_in(jax.random.PRNGKey(
+                            cfg.extra_seed), it)])
+            if getattr(self.learner, "quantized", False):
+                # per-tree stochastic-rounding stream
+                # (gradient_discretizer.cpp seeds from config seed)
+                extra["quant_key"] = jax.random.fold_in(
+                    jax.random.PRNGKey(cfg.seed), it)
+            with rec.phase("grow"):
+                try:
+                    grown = self.learner.train(self.X_dev, g, h, mask,
+                                               feature_mask=fmask,
+                                               **extra)
+                except Exception as exc:
+                    _name_refused_kernels(exc)
+                    raise
+            # full-data histogram passes of the last grown tree (wave
+            # grower; 0 = untracked) — a device scalar, pulled lazily
+            # by bench/diagnostic readers only
+            self.last_hist_passes = grown.hist_passes
+            rec.add_tree(self.iter_, cid, grown.hist_passes,
+                         grown.num_leaves, grown.wave_passes,
+                         grown.endgame_passes, grown.ramp_committed)
             if self.flight.enabled:
-                self.flight.note_iter(
-                    self.iter_, hist_passes=self.last_hist_passes,
-                    num_leaves=fl_leaves, best_gain=fl_gain)
-            if self.iter_ % 16 == 1:
-                # periodic device-memory watermark sample (cheap local
-                # PJRT query; None on backends without memory_stats)
-                rec.note_memory()
-            if finished:
-                log_warning("Stopped training because there are no more leaves "
-                            "that meet the split requirements")
-            return finished
+                # last grown tree's fields for this iteration's
+                # flight event (device scalars, pulled lazily on
+                # dump; the max over split gains is one tiny
+                # device reduce)
+                fl_leaves = grown.num_leaves
+                fl_gain = jnp.max(grown.split_gain)
+            with rec.phase("record"):
+                tree = self._record_tree(grown, cid)
+            if tree is not None and self._cegb_coupled is not None:
+                sf = tree.split_feature[:tree.num_leaves - 1]
+                self._cegb_used[sf[sf >= 0]] = True
+            if tree is None:
+                # deferred: the lagged check above decides next iteration
+                finished = False
+                leaves_this_iter.append(grown.num_leaves)
+            elif tree.num_leaves > 1:
+                finished = False
+        self._prev_iter_leaves = leaves_this_iter or None
+        for x in leaves_this_iter:
+            # start the device->host copy NOW so next iteration's
+            # lagged stump check reads a landed value instead of
+            # paying a blocking ~100 ms round trip per iteration
+            # (small-shape configs spend more time in that RTT than
+            # in their kernels)
+            if hasattr(x, "copy_to_host_async"):
+                x.copy_to_host_async()
+        self.iter_ += 1
+        if self.flight.enabled:
+            self.flight.note_iter(
+                self.iter_, hist_passes=self.last_hist_passes,
+                num_leaves=fl_leaves, best_gain=fl_gain)
+        if self.iter_ % 16 == 1:
+            # periodic device-memory watermark sample (cheap local
+            # PJRT query; None on backends without memory_stats)
+            rec.note_memory()
+        if finished:
+            log_warning("Stopped training because there are no more leaves "
+                        "that meet the split requirements")
+        return finished
 
     def _pop_stump_iteration(self) -> None:
         """Drop the previous iteration's no-split stump trees (they carry a

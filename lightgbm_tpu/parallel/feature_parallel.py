@@ -186,7 +186,8 @@ class FeatureParallelTreeLearner:
             split_gain=P(), internal_value=P(), internal_weight=P(),
             internal_count=P(), leaf_value=P(), leaf_weight=P(),
             leaf_count=P(), num_leaves=P(), row_leaf=P(),
-            hist_passes=P())
+            hist_passes=P(), wave_passes=P(),
+            endgame_passes=P(), ramp_committed=P())
         # X is feature-sharded; rows + every descriptor replicated.  The
         # descriptor args reaching the grower must be FULL arrays (global
         # feature indexing), so they ride in replicated and the strategy

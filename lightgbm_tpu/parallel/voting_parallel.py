@@ -476,7 +476,8 @@ class VotingParallelTreeLearner:
             right_child=P(), split_gain=P(), internal_value=P(),
             internal_weight=P(), internal_count=P(), leaf_value=P(),
             leaf_weight=P(), leaf_count=P(), num_leaves=P(),
-            row_leaf=P(axis), hist_passes=P())
+            row_leaf=P(axis), hist_passes=P(), wave_passes=P(),
+            endgame_passes=P(), ramp_committed=P())
 
     def _init_wave(self, config, num_features, num_bins, is_cat, has_nan,
                    monotone, impl, sp, local_sp):

@@ -4,14 +4,15 @@ Four pieces (see the module docstrings for depth):
 
   * :mod:`.metrics` — the process-wide :class:`MetricsRegistry` of
     counters / gauges / windowed histograms with labeled series;
-    ``serve/stats.ModelStats`` and ``utils/timer.global_timer`` report
-    into it.
+    ``serve/stats.ModelStats`` reports into it.
   * :mod:`.trace` — hierarchical ``span("tree/wave/psum")`` host spans
-    paired with ``jax.profiler.TraceAnnotation``, chrome-trace export;
-    near-zero overhead when disabled.
+    that always open a ``jax.profiler.TraceAnnotation`` (the profiler's
+    session is the switch) and feed the chrome-trace export when the
+    tracer is enabled.
   * :mod:`.train_record` — the per-run :class:`TrainRecord` (histogram
-    passes per tree, trace-time collective counts/bytes, XLA compile
-    events, device-memory watermark, per-phase wall time), accumulated
+    passes per tree by kind, trace-time collective counts/bytes, XLA
+    compile events, device-memory watermark, per-phase host dispatch time,
+    set-up seconds), accumulated
     by ``models/gbdt.py`` and surfaced as ``Booster.train_record``.
   * :mod:`.export` — Prometheus text / JSON renderers; the serve HTTP
     server mounts ``GET /metrics``; ``python -m lightgbm_tpu profile``
@@ -30,7 +31,7 @@ training produce bit-identical models — accumulation only observes.
 from ._config import enable, disable, enabled
 from .metrics import (Counter, Gauge, MetricsRegistry, SlidingWindow,
                       WindowedHistogram, default_registry, percentile)
-from .trace import Tracer, global_tracer, span
+from .trace import Tracer, global_tracer, span, timed_span
 from .train_record import (TrainRecord, collectives_reset,
                            collectives_snapshot, device_memory_peak,
                            last_train_record, note_collective,
@@ -44,7 +45,7 @@ __all__ = [
     "enable", "disable", "enabled",
     "Counter", "Gauge", "MetricsRegistry", "SlidingWindow",
     "WindowedHistogram", "default_registry", "percentile",
-    "Tracer", "global_tracer", "span",
+    "Tracer", "global_tracer", "span", "timed_span",
     "TrainRecord", "collectives_reset", "collectives_snapshot",
     "device_memory_peak", "last_train_record", "note_collective",
     "set_last_train_record",

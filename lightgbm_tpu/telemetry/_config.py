@@ -4,7 +4,7 @@ trace/train_record can read it without import cycles).
 Default ON — accumulation is cheap host-side bookkeeping and purely
 observational (bit-identical training is a tested contract).  Disable
 with ``LGBM_TPU_TELEMETRY=0`` or ``lightgbm_tpu.telemetry.disable()``;
-the span TRACER and the timetag timer stay separately opt-in."""
+the span tracer's Python-side event list stays separately opt-in."""
 
 from __future__ import annotations
 

@@ -93,7 +93,14 @@ def _render_train_record(snap: Dict, out: List[str]) -> None:
     first = True
     for ph, secs in sorted(snap["phase_seconds"].items()):
         line("phase_seconds_total", secs, {"phase": ph}, "counter",
-             "wall seconds per boosting phase" if first else "")
+             "host seconds per boosting phase; grow and gradients are "
+             "asynchronous dispatch time, not device time"
+             if first else "")
+        first = False
+    first = True
+    for ph, secs in sorted(snap.get("setup_seconds", {}).items()):
+        line("setup_seconds", secs, {"phase": ph}, "gauge",
+             "host seconds of the run's set-up, by phase" if first else "")
         first = False
     first = True
     for site, rec in sorted(snap["collectives_traced"].items()):

@@ -4,14 +4,10 @@ One registry of named, labeled series — counters (monotone), gauges
 (last value / watermark), and windowed histograms (percentiles over a
 bounded ring of recent observations, because an operator wants the
 CURRENT tail, not the all-time one).  Everything that used to count
-things privately — ``serve/stats.ModelStats``, ``utils/timer``'s time
-tags, the per-tree training records — now lands in one place with one
+things privately — ``serve/stats.ModelStats``, the per-tree training
+records — now lands in one place with one
 export surface (``telemetry/export.py`` renders Prometheus text and
 JSON; the serve HTTP server mounts it at ``/metrics``).
-
-The reference ships ``Common::Timer`` timetags compiled into every layer
-(include/LightGBM/utils/common.h:931); this module is the registry those
-fragments report into here.
 
 Design constraints:
   * thread-safe — serving bumps counters from request threads while

@@ -1,21 +1,26 @@
 """Hierarchical host-side span tracing with chrome-trace export.
 
 ``span("tree/wave/psum")`` contexts nest through a thread-local stack,
-producing events whose names are full slash paths; each host span also
-opens a ``jax.profiler.TraceAnnotation`` so the same names line up with
-device rows when a ``jax.profiler.trace`` capture is running (the
-``profile`` CLI verb wires both together).
+producing events whose names are full slash paths.  A span has two
+halves:
 
-Cost model: when the tracer is disabled (the default) ``span()`` returns
-a shared no-op context manager — the entire overhead is one function
-call and two attribute reads, so spans can stay compiled into the
-boosting loop the way the reference leaves ``FunctionTimer`` timetags
-compiled in (common.h:995).  When only ``utils/timer.global_timer`` is
-enabled (the ``LGBM_TPU_TIMETAG=1`` compat shim), spans feed the timer's
-per-tag accumulators without recording trace events.
+* a ``jax.profiler.TraceAnnotation`` of the same path, ALWAYS entered.
+  A ``TraceMe`` is inert unless a profiler session is running, so the
+  profiler's session is the switch: whoever starts ``jax.profiler.trace``
+  (the ``profile`` CLI verb, a benchmark's traced run) finds every span of
+  the program on the device trace's own clock, with no environment
+  variable and no argument.  With no session a span costs about a
+  microsecond (``tests/test_telemetry.py::test_span_cost_without_session``
+  holds it under 50);
+* a Python-side event, recorded only while ``global_tracer.enabled``
+  (``LGBM_TPU_TRACE=1`` or ``global_tracer.enable()``): the list that
+  ``global_tracer.export_chrome_trace(path)`` writes in the
+  ``chrome://tracing`` / Perfetto JSON array format.
 
-Export: ``global_tracer.export_chrome_trace(path)`` writes the
-``chrome://tracing`` / Perfetto JSON array format.
+``timed_span(store, key, name)`` is a span that also adds its host seconds
+to ``store[key]`` (the set-up phases of ``Dataset.construct`` and
+``GBDT``).  Host seconds of a span around code that only ENQUEUES device
+work are dispatch time, not the work's time.
 """
 
 from __future__ import annotations
@@ -26,24 +31,10 @@ import threading
 import time
 from typing import Dict, List
 
-from ..utils.timer import global_timer
+from jax.profiler import TraceAnnotation
 
-__all__ = ["Tracer", "global_tracer", "span"]
+__all__ = ["Tracer", "global_tracer", "span", "timed_span", "in_span"]
 
-
-class _NoopSpan:
-    """Shared do-nothing context manager for the disabled fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP = _NoopSpan()
 _tls = threading.local()
 
 
@@ -105,57 +96,66 @@ global_tracer = Tracer()
 
 
 class _Span:
-    """Live span: pushes its name on the thread-local path stack, times
-    the region, and mirrors it to the jax profiler + global_timer."""
+    """Live span: pushes its name on the thread-local path stack, opens
+    the profiler annotation, and records a Python-side event when the
+    tracer is enabled."""
 
-    __slots__ = ("name", "path", "_trace", "_timer", "_t0", "_jax_scope")
+    __slots__ = ("name", "path", "_t0", "_ann")
 
-    def __init__(self, name: str, trace_on: bool, timer_on: bool) -> None:
+    def __init__(self, name: str) -> None:
         self.name = name
-        self._trace = trace_on
-        self._timer = timer_on
-        self._jax_scope = None
 
     def __enter__(self):
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
-        self.path = "/".join(stack + [self.name]) if stack else self.name
         stack.append(self.name)
-        if self._timer:
-            global_timer.start(self.path)
-        if self._trace:
-            try:
-                import jax.profiler
-                self._jax_scope = jax.profiler.TraceAnnotation(self.path)
-                self._jax_scope.__enter__()
-            except Exception:
-                self._jax_scope = None
+        self.path = "/".join(stack)
+        self._ann = TraceAnnotation(self.path)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        if self._jax_scope is not None:
-            self._jax_scope.__exit__(*exc)
-        if self._timer:
-            global_timer.stop(self.path)
-        if self._trace:
+        self._ann.__exit__(*exc)
+        if global_tracer.enabled:
             global_tracer._record(self.path, self._t0, t1 - self._t0,
                                   threading.get_ident())
-        stack = getattr(_tls, "stack", None)
-        if stack:
-            stack.pop()
+        _tls.stack.pop()
         return False
 
 
 def span(name: str):
-    """``with span("tree/grow"):`` — nested scope timer/tracer.
+    """``with span("tree/grow"):`` — nested scope on the profiler's clock
+    (always) and in the tracer's event list (when it is enabled)."""
+    return _Span(name)
 
-    Near-zero overhead when both the tracer and the timetag timer are
-    disabled (returns a shared no-op context manager)."""
-    trace_on = global_tracer.enabled
-    timer_on = global_timer.enabled
-    if not (trace_on or timer_on):
-        return _NOOP
-    return _Span(name, trace_on, timer_on)
+
+def in_span() -> bool:
+    """Whether this thread is inside a span (a nested one then names
+    itself relative to it)."""
+    return bool(getattr(_tls, "stack", None))
+
+
+class timed_span:
+    """``with timed_span(store, "bin_find", "dataset/construct/sample"):``
+    — a span whose host seconds are also added to ``store[key]``."""
+
+    __slots__ = ("_store", "_key", "_span", "_t0")
+
+    def __init__(self, store: Dict[str, float], key: str, name: str) -> None:
+        self._store = store
+        self._key = key
+        self._span = _Span(name)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        self._store[self._key] = self._store.get(self._key, 0.0) + dt
+        return False

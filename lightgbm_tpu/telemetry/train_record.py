@@ -7,9 +7,12 @@ numbers by hand in PERF.md.  A ``TrainRecord`` accumulates them as the
 boosting loop runs:
 
   * per-tree full-data histogram passes (``GrownTree.hist_passes``, the
-    counter already asserted by tests/test_endgame.py) and leaf counts —
-    kept as device scalars and pulled in batched, lazy fetches so the
-    async dispatch pipeline never stalls;
+    counter already asserted by tests/test_endgame.py), their kinds
+    (``wave_passes``, ``endgame_passes``; the wave grower's
+    ``1 + wave_passes + endgame_passes == hist_passes``), the splits the
+    speculative ramp's verifying pass committed (``ramp_committed``) and
+    leaf counts — kept as device scalars and pulled in batched, lazy
+    fetches so the async dispatch pipeline never stalls;
   * collective count and reduced bytes, tallied at the
     ``parallel/*.py`` collective call sites.  Those sites execute at
     TRACE time (the growers are jit/shard_map programs), so the tally
@@ -25,7 +28,12 @@ boosting loop runs:
   * XLA compile/retrace events via a ``jax.monitoring`` listener;
   * device-memory watermark via ``device.memory_stats()`` where the
     backend provides it (TPU does; CPU returns None);
-  * per-phase wall time (gradients / grow / record / eval).
+  * per-phase HOST time (gradients / grow / record / eval): the boosting
+    loop dispatches asynchronously, so ``phase_seconds["grow"]`` is the
+    host's time to enqueue a tree (plus, on the first tree, to trace and
+    compile the grower), not the tree's time on the device;
+  * ``setup_seconds``: what the run spent before its first timed tree,
+    by phase (see :meth:`TrainRecord.snapshot`).
 
 Accumulation is gated by ``telemetry.enabled()`` and purely
 observational: it reads values training already computed, so
@@ -35,12 +43,13 @@ telemetry-on and telemetry-off training produce bit-identical models
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
 from . import _config
-from .trace import span
+from .trace import in_span, timed_span
 
 __all__ = ["TrainRecord", "note_collective", "collectives_snapshot",
            "collectives_reset", "last_train_record",
@@ -145,12 +154,56 @@ def _on_event(event: str, **kwargs) -> None:
         _mon_counts[event] = _mon_counts.get(event, 0) + 1
 
 
+# The duration events JAX 0.9 emits around a jitted function's first call
+# (jax/_src/dispatch.py), by the kind ``setup_seconds`` files them under.
+# ``backend_compile_duration`` encloses ``compiler.compile_or_get_cached``,
+# so a persistent-cache hit's ``/jax/compilation_cache/
+# cache_retrieval_time_sec`` lies inside it and is not added again.
+_SETUP_KIND_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace_lower",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_trace_lower",
+    "/jax/core/compile/backend_compile_duration": "compile_or_load",
+}
+# (kind, start, end) on the perf_counter clock.  An inner jit's trace event
+# ends inside its caller's, so the sums are taken over the UNION of a kind's
+# intervals, not over the durations.
+_mon_intervals: collections.deque = collections.deque(maxlen=1 << 16)
+
+
 def _on_event_duration(event: str, duration: float, **kwargs) -> None:
     if not _config.enabled():
         return
+    end = time.perf_counter()
     with _mon_lock:
         _mon_counts[event] = _mon_counts.get(event, 0) + 1
         _mon_secs[event] = _mon_secs.get(event, 0.0) + float(duration)
+        kind = _SETUP_KIND_OF_EVENT.get(event)
+        if kind is not None:
+            _mon_intervals.append((kind, end - float(duration), end))
+
+
+def _union_seconds(intervals) -> float:
+    total, cur_lo, cur_hi = 0.0, None, None
+    for lo, hi in sorted(intervals):
+        if cur_hi is None or lo > cur_hi:
+            if cur_hi is not None:
+                total += cur_hi - cur_lo
+            cur_lo, cur_hi = lo, hi
+        elif hi > cur_hi:
+            cur_hi = hi
+    if cur_hi is not None:
+        total += cur_hi - cur_lo
+    return total
+
+
+def _compile_seconds_by_kind(since: float, until: float) -> Dict[str, float]:
+    """{"jax_trace_lower": s, "compile_or_load": s} of the events that
+    ended between ``since`` and ``until`` (perf_counter clock)."""
+    with _mon_lock:
+        events = [e for e in _mon_intervals if since <= e[2] <= until]
+    return {kind: _union_seconds([(lo, hi) for k, lo, hi in events
+                                  if k == kind])
+            for kind in ("jax_trace_lower", "compile_or_load")}
 
 
 def _ensure_monitoring() -> None:
@@ -212,27 +265,20 @@ def device_memory_peak() -> Optional[int]:
 # TrainRecord
 # ---------------------------------------------------------------------------
 
-class _Phase:
-    __slots__ = ("_rec", "_name", "_span", "_t0")
+class _Phase(timed_span):
+    """A timed span into ``phase_seconds`` that also counts its calls.
+    Inside ``train/iter`` the phase names itself relative to it."""
+
+    __slots__ = ("_calls",)
 
     def __init__(self, rec: "TrainRecord", name: str) -> None:
-        self._rec = rec
-        self._name = name
-
-    def __enter__(self):
-        self._span = span("train/" + self._name)
-        self._span.__enter__()
-        self._t0 = time.perf_counter()
-        return self
+        super().__init__(rec._phase_s, name,
+                         name if in_span() else "train/" + name)
+        self._calls = rec._phase_n
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        self._span.__exit__(*exc)
-        with self._rec._lock:
-            ph = self._rec._phase_s
-            ph[self._name] = ph.get(self._name, 0.0) + dt
-            cn = self._rec._phase_n
-            cn[self._name] = cn.get(self._name, 0) + 1
+        super().__exit__(*exc)
+        self._calls[self._key] = self._calls.get(self._key, 0) + 1
         return False
 
 
@@ -258,15 +304,23 @@ class TrainRecord:
     ``Booster.train_record`` (a dict snapshot); the freshest record is
     also published process-wide for the ``/metrics`` exporter."""
 
-    def __init__(self, meta: Optional[Dict[str, Any]] = None) -> None:
+    def __init__(self, meta: Optional[Dict[str, Any]] = None,
+                 compile_since: Optional[float] = None) -> None:
         self._lock = threading.Lock()
         self.meta = dict(meta or {})
         self._t_created = time.perf_counter()
+        # JAX's trace/lower/compile events count from here (perf_counter):
+        # the start of the set-up the record belongs to, if it began earlier
+        self._compile_since = self._t_created if compile_since is None \
+            else compile_since
+        self._compile_until: Optional[float] = None   # see end_of_update()
         self._phase_s: Dict[str, float] = {}
         self._phase_n: Dict[str, int] = {}
         # per-tree device scalars pending a batched host pull
-        self._pending: List[tuple] = []   # (iteration, class_id, hp, nl)
+        # (iteration, class_id, (hp, nl, wave, endgame, ramp_committed))
+        self._pending: List[tuple] = []
         self._trees: List[Dict[str, int]] = []
+        self._setup_s: Dict[str, float] = {}
         self._mem_peak: Optional[int] = None
         self._coll_base = collectives_snapshot()
         self._hist_base = hist_kernel_snapshot()
@@ -275,22 +329,49 @@ class TrainRecord:
 
     # -- accumulation (boosting loop) ------------------------------------
     def phase(self, name: str):
-        """``with record.phase("grow"):`` — adds wall time to the named
-        phase and opens a ``train/<name>`` telemetry span."""
+        """``with record.phase("grow"):`` — adds HOST time to the named
+        phase and opens a ``train/<name>`` telemetry span.  Around
+        asynchronous dispatch (``grow``, ``gradients``) that is the time
+        to enqueue the work, not the work's time on the device."""
         if not _config.enabled():
             return _NOOP_PHASE
         return _Phase(self, name)
 
+    def setup(self, key: str, name: str):
+        """``with record.setup("upload", "train/init/upload"):`` — a span
+        whose host seconds are added to ``setup_seconds[key]``."""
+        if not _config.enabled():
+            return _NOOP_PHASE
+        return timed_span(self._setup_s, key, name)
+
+    def end_of_update(self) -> None:
+        """A boosting iteration has returned.  ``setup_seconds`` counts
+        JAX's trace/lower/compile events up to the latest one, so what the
+        caller compiles after training (a predictor) is not filed under
+        the training run's set-up."""
+        self._compile_until = time.perf_counter()
+
+    def add_setup_seconds(self, seconds: Dict[str, float]) -> None:
+        """Copy in set-up seconds kept elsewhere (the ``Dataset`` is built,
+        and the learner lays out its matrix, outside this record)."""
+        if not _config.enabled():
+            return
+        with self._lock:
+            for k, v in seconds.items():
+                self._setup_s[k] = self._setup_s.get(k, 0.0) + float(v)
+
     def add_tree(self, iteration: int, class_id: int, hist_passes,
-                 num_leaves) -> None:
-        """Record one grown tree.  ``hist_passes``/``num_leaves`` may be
-        device scalars; they are NOT synced here — batches are pulled
-        lazily so the async dispatch pipeline keeps flowing."""
+                 num_leaves, wave_passes=0, endgame_passes=0,
+                 ramp_committed=0) -> None:
+        """Record one grown tree.  The counts may be device scalars; they
+        are NOT synced here — batches are pulled lazily so the async
+        dispatch pipeline keeps flowing."""
         if not _config.enabled():
             return
         with self._lock:
             self._pending.append((int(iteration), int(class_id),
-                                  hist_passes, num_leaves))
+                                  (hist_passes, num_leaves, wave_passes,
+                                   endgame_passes, ramp_committed)))
             flush = len(self._pending) >= _FLUSH_EVERY
         if flush:
             self._flush()
@@ -310,12 +391,14 @@ class TrainRecord:
             return
         try:
             import jax
-            vals = jax.device_get([(p[2], p[3]) for p in pending])
+            vals = jax.device_get([p[2] for p in pending])
         except Exception:
-            vals = [(p[2], p[3]) for p in pending]
+            vals = [p[2] for p in pending]
         rows = [{"iteration": it, "class_id": cid,
-                 "hist_passes": int(hp), "num_leaves": int(nl)}
-                for (it, cid, _, _), (hp, nl) in zip(pending, vals)]
+                 "hist_passes": int(hp), "num_leaves": int(nl),
+                 "wave_passes": int(wp), "endgame_passes": int(ep),
+                 "ramp_committed": int(rc)}
+                for (it, cid, _), (hp, nl, wp, ep, rc) in zip(pending, vals)]
         with self._lock:
             self._trees.extend(rows)
 
@@ -323,13 +406,31 @@ class TrainRecord:
     def snapshot(self) -> Dict[str, Any]:
         """JSON-ready record; pulls any pending device scalars (one
         batched fetch) and diffs the process-wide compile/collective
-        tallies against this record's baseline."""
+        tallies against this record's baseline.
+
+        ``setup_seconds`` (host seconds; a phase that only enqueues device
+        work reads its dispatch time): ``to_float64``, ``bin_find``,
+        ``bin_matrix`` from ``Dataset.construct``; ``upload``
+        (``GBDT._init_train`` placing bins, labels and scores: enqueues
+        the copies), ``layout`` (the learner's one-time pad + transpose:
+        enqueues), ``first_update`` (the first ``train_one_iter``: traces,
+        lowers, compiles or loads every program of a tree, then enqueues
+        it); and, of JAX's own ``jax.monitoring`` duration events since
+        the record was created, ``jax_trace_lower``
+        (``/jax/core/compile/jaxpr_trace_duration`` +
+        ``/jax/core/compile/jaxpr_to_mlir_module_duration``) and
+        ``compile_or_load`` (``/jax/core/compile/backend_compile_duration``,
+        which encloses the persistent cache's
+        ``/jax/compilation_cache/cache_retrieval_time_sec``), each the
+        union of its events' intervals, up to the end of the latest
+        boosting iteration."""
         self._flush()
         self.note_memory()  # final watermark: periodic samples miss the tail
         with self._lock:
             trees = list(self._trees)
             phase_s = dict(self._phase_s)
             phase_n = dict(self._phase_n)
+            setup_s = dict(self._setup_s)
             mem_peak = self._mem_peak
             elapsed = time.perf_counter() - self._t_created
         trees.sort(key=lambda r: (r["iteration"], r["class_id"]))
@@ -361,6 +462,8 @@ class TrainRecord:
             if d > 1e-9 and any(m in k.lower() for m in _COMPILE_MARKERS):
                 secs[k] = round(d, 6)
         hp = [r["hist_passes"] for r in trees]
+        setup_s.update(_compile_seconds_by_kind(
+            self._compile_since, self._compile_until or time.perf_counter()))
         return {
             "schema": "train-record-v1",
             "meta": dict(self.meta),
@@ -370,6 +473,7 @@ class TrainRecord:
             "hist_passes_last": hp[-1] if hp else 0,
             "phase_seconds": {k: round(v, 6) for k, v in phase_s.items()},
             "phase_calls": phase_n,
+            "setup_seconds": {k: round(v, 6) for k, v in setup_s.items()},
             "collectives_traced": coll,
             "hist_kernel": hist_kernels,
             "compile_events": events,

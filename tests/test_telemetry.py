@@ -2,9 +2,9 @@
 
 Covers: the metrics registry primitives and their thread-safety, the
 shared percentile/sliding-window implementation serve/stats now rides
-on, span tracing + chrome export, the timer satellites (log routing,
-registry publish, debug-strict stop), TrainRecord accumulation through
-real training, the bit-identical-training contract, the trace-time
+on, span tracing + chrome export (spans always annotate; only the event
+list waits on the tracer), TrainRecord accumulation through real training
+(set-up seconds, pass kinds), the bit-identical-training contract, the trace-time
 collective tally against the jaxpr psum count (the same quantity
 tests/test_specramp.py asserts), Prometheus rendering, the /metrics
 endpoint end-to-end, the profile CLI verb, and the enabled-vs-disabled
@@ -145,12 +145,57 @@ def test_model_stats_schema_unchanged():
 
 # -- spans ------------------------------------------------------------------
 
-def test_span_disabled_is_shared_noop():
+def test_span_annotates_without_tracer(monkeypatch):
+    """The profiler's session is the switch: with the tracer disabled a
+    span still enters a TraceAnnotation carrying its slash path, and the
+    Python-side event list stays empty."""
     from lightgbm_tpu.telemetry import trace as ttrace
     assert not ttrace.global_tracer.enabled
-    a = telemetry.span("x")
-    b = telemetry.span("y")
-    assert a is b  # the shared no-op instance
+    ttrace.global_tracer.clear()
+    entered = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            entered.append(("exit", self.name))
+
+    monkeypatch.setattr(ttrace, "TraceAnnotation", FakeAnnotation)
+    with telemetry.span("train/iter"):
+        assert ttrace.in_span()
+        with telemetry.span("grow"):
+            pass
+    assert not ttrace.in_span()
+    assert entered == [("enter", "train/iter"), ("enter", "train/iter/grow"),
+                       ("exit", "train/iter/grow"), ("exit", "train/iter")]
+    assert ttrace.global_tracer.events() == []
+
+
+def test_span_cost_without_session():
+    """No profiler session, tracer off: a span is a TraceMe that does
+    nothing.  Expect about a microsecond; the ceiling is generous."""
+    from lightgbm_tpu.telemetry import trace as ttrace
+    assert not ttrace.global_tracer.enabled
+    n = 10_000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with telemetry.span("probe"):
+            pass
+    per_span_us = (time.perf_counter() - t0) / n * 1e6
+    assert per_span_us < 50, per_span_us
+
+
+def test_timed_span_adds_host_seconds():
+    store = {}
+    with telemetry.timed_span(store, "k", "probe/a"):
+        time.sleep(0.002)
+    with telemetry.timed_span(store, "k", "probe/b"):
+        pass
+    assert store["k"] >= 0.002 and list(store) == ["k"]
 
 
 def test_span_nesting_and_chrome_export(tmp_path):
@@ -173,60 +218,6 @@ def test_span_nesting_and_chrome_export(tmp_path):
     finally:
         tr.disable()
         tr.clear()
-
-
-# -- timer satellites -------------------------------------------------------
-
-def test_timer_stop_without_start_raises_in_debug():
-    from lightgbm_tpu.utils.log import (LEVEL_DEBUG, get_verbosity,
-                                        set_verbosity)
-    from lightgbm_tpu.utils.timer import Timer
-    t = Timer()
-    t.enable()
-    old = get_verbosity()
-    try:
-        set_verbosity(0)
-        t.stop("never-started")  # silent below debug
-        set_verbosity(LEVEL_DEBUG)
-        with pytest.raises(RuntimeError, match="without a matching start"):
-            t.stop("never-started")
-    finally:
-        set_verbosity(old)
-
-
-def test_timer_exit_report_routes_through_log():
-    """The exit report goes through the log sink (callbacks capture it)
-    but is NOT verbosity-filtered — training configs routinely set
-    verbosity=-1 and an explicitly enabled timetag must still report."""
-    from lightgbm_tpu.utils import log
-    from lightgbm_tpu.utils.timer import Timer
-    t = Timer()
-    t.enable()
-    t.start("phase")
-    t.stop("phase")
-    lines = []
-    old_v = log.get_verbosity()
-    log.register_log_callback(lines.append)
-    try:
-        log.set_verbosity(-1)
-        t.print_at_exit()
-    finally:
-        log.set_verbosity(old_v)
-        log.register_log_callback(None)
-    assert any("time tags" in l and "phase" in l for l in lines)
-
-
-def test_timer_publishes_to_registry():
-    from lightgbm_tpu.utils.timer import Timer
-    t = Timer()
-    t.enable()
-    t.start("probe_tag")
-    t.stop("probe_tag")
-    reg = telemetry.default_registry()
-    assert reg.counter("timetag_calls_total",
-                       labels=("tag",)).value(tag="probe_tag") >= 1
-    assert reg.counter("timetag_seconds_total",
-                       labels=("tag",)).value(tag="probe_tag") >= 0
 
 
 # -- TrainRecord through real training --------------------------------------
@@ -261,6 +252,84 @@ def test_train_record_wave_hist_passes():
     assert hp[-1] == int(bst._gbdt.last_hist_passes)
     assert snap["hist_passes_total"] == sum(hp)
     assert snap["hist_passes_last"] == hp[-1]
+
+
+def test_setup_seconds_in_train_record():
+    """The set-up's host seconds by phase, the dataset's copied in; the
+    two binning halves fit inside the wall time around construct()."""
+    rng = np.random.RandomState(0)
+    X = rng.randn(2000, 6)
+    y = (X[:, 0] > 0).astype(np.float64)
+    p = {**SMALL, "objective": "binary"}
+    ds = lgb.Dataset(X, y, params=p)
+    t0 = time.perf_counter()
+    ds.construct()
+    construct_s = time.perf_counter() - t0
+    bst = lgb.Booster(params=p, train_set=ds)
+    for _ in range(3):
+        bst.update()
+    snap = bst.train_record.snapshot()
+    secs = snap["setup_seconds"]
+    for key in ("to_float64", "bin_find", "bin_matrix", "upload", "layout",
+                "first_update", "jax_trace_lower", "compile_or_load"):
+        assert key in secs and secs[key] >= 0, (key, secs)
+    assert 0 < secs["bin_find"] + secs["bin_matrix"] <= construct_s
+    # the first update carries the compile; the later two are phases only
+    assert secs["first_update"] > 0
+    assert snap["phase_calls"]["grow"] == 3
+    # a second snapshot reads the same set-up: nothing accumulates twice
+    assert bst.train_record.snapshot()["setup_seconds"]["bin_find"] == \
+        secs["bin_find"]
+
+
+def test_compile_seconds_are_a_union_not_a_sum():
+    """An inner jit's trace event ends inside its caller's: the kind's
+    seconds are the union of the intervals."""
+    from lightgbm_tpu.telemetry import train_record as tr
+    assert tr._union_seconds([(0.0, 10.0), (2.0, 3.0), (9.0, 12.0),
+                              (20.0, 21.0)]) == pytest.approx(13.0)
+    assert tr._union_seconds([]) == 0.0
+    rec = tr.TrainRecord()
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def outer(x):
+        return jnp.cumsum(x * 2.0 + float(time.perf_counter() % 1))  # fresh trace
+
+    t0 = time.perf_counter()
+    outer(jnp.arange(7.0)).block_until_ready()
+    wall = time.perf_counter() - t0
+    secs = rec.snapshot()["setup_seconds"]
+    assert 0 < secs["jax_trace_lower"] <= wall
+    assert 0 < secs["compile_or_load"] <= wall
+    assert secs["jax_trace_lower"] + secs["compile_or_load"] <= wall
+
+
+@pytest.mark.parametrize("quantized", [True, False], ids=["q8", "exact"])
+def test_pass_kinds_sum_to_hist_passes(quantized):
+    """1 + wave_passes + endgame_passes == hist_passes for every tree of
+    the wave grower, and the ramp commits at most W-1 splits."""
+    bst, _ = _train_binary(n=3000, trees=3,
+                           extra={"tree_grow_mode": "wave", "num_leaves": 63,
+                                  "use_quantized_grad": quantized,
+                                  "min_data_in_leaf": 2})
+    rows = bst.train_record.snapshot()["trees"]
+    assert len(rows) == 3
+    for r in rows:
+        assert r["hist_passes"] >= 1
+        assert 1 + r["wave_passes"] + r["endgame_passes"] == r["hist_passes"], r
+        assert r["wave_passes"] >= 0 and r["endgame_passes"] >= 0
+        assert 0 <= r["ramp_committed"] <= 41
+    # 63 leaves is past 2W on the exact grower (W=25)... at least one kind ran
+    assert any(r["wave_passes"] + r["endgame_passes"] > 0 for r in rows)
+
+
+def test_other_growers_report_zero_pass_kinds():
+    bst, _ = _train_binary(trees=2)      # partition-ordered serial grower
+    for r in bst.train_record.snapshot()["trees"]:
+        assert (r["hist_passes"], r["wave_passes"], r["endgame_passes"],
+                r["ramp_committed"]) == (0, 0, 0, 0)
 
 
 def test_training_bit_identical_with_telemetry_disabled():
@@ -427,12 +496,10 @@ def test_profile_cli_verb(tmp_path):
         f"profile_dir={prof}", "jax_trace=0",
     ])
     assert rc == 0
-    # the verb enables the tracer/timer process-wide; undo for the rest
-    # of the suite
-    from lightgbm_tpu.utils.timer import global_timer
+    # the verb enables the tracer process-wide; undo for the rest of the
+    # suite
     telemetry.global_tracer.disable()
     telemetry.global_tracer.clear()
-    global_timer.enabled = False
     dump = json.loads((prof / "telemetry.json").read_text())
     assert dump["schema"] == "telemetry-snapshot-v1"
     assert dump["train_record"]["num_trees"] == 3
