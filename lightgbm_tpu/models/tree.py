@@ -212,6 +212,19 @@ class Tree:
         return out
 
 
+def _floor_f32(a: np.ndarray) -> np.ndarray:
+    """Largest float32 <= ``a``.  The device predictors compare float32
+    feature values with float32 thresholds; with the threshold rounded
+    DOWN, ``v <= threshold`` decides as the float64 compare of the model
+    text does for every float32 ``v``.  Rounded to nearest, a value equal
+    to the float32 just above a threshold went to the left child."""
+    a = np.asarray(a, np.float64)
+    with np.errstate(over="ignore"):
+        f = a.astype(np.float32)
+    return np.where(f.astype(np.float64) > a,
+                    np.nextafter(f, np.float32(-np.inf)), f)
+
+
 class TreeBatch:
     """Stacked device arrays for T trees of identical max size; the ensemble
     prediction structure (replaces the reference's per-tree virtual calls in
@@ -227,7 +240,7 @@ class TreeBatch:
         self.max_leaves = max(max(t.max_leaves, t.num_leaves) for t in trees)
         ml = self.max_leaves
 
-        def stack(attr, size, dtype=None, fill=0):
+        def stack(attr, size, dtype=None, fill=0, cast=None):
             arrs = []
             for t in trees:
                 a = np.asarray(getattr(t, attr))
@@ -237,12 +250,14 @@ class TreeBatch:
                                                    np.float64)])
                 arrs.append(a[:size])
             out = np.stack(arrs)
+            if cast is not None:
+                out = cast(out)
             return jnp.asarray(out if dtype is None else out.astype(dtype))
 
         self.split_feature = stack("split_feature", ml - 1, np.int32)
         self.threshold_bin = stack("threshold_bin", ml - 1, np.int32)
         self.nan_bin = stack("nan_bin", ml - 1, np.int32, fill=-1)
-        self.threshold = stack("threshold", ml - 1, np.float32)
+        self.threshold = stack("threshold", ml - 1, cast=_floor_f32)
         self.decision_type = stack("decision_type", ml - 1, np.uint8)
         self.left_child = stack("left_child", ml - 1, np.int32)
         self.right_child = stack("right_child", ml - 1, np.int32)

@@ -41,9 +41,12 @@ nibble-PACKED (``pack_bins4``: two 4-bit codes per int8 lane, the
 reference dense_bin.hpp 4-bit layout) — half the streamed bin bytes;
 the kernel unpacks in VMEM against pre-split even/odd weight halves.
 Small-B one-hot tiles group MORE features per 128-row MXU tile instead
-of padding bins (``_tile_params``).  Contract: quantized int32 sums
-are bit-for-bit identical across every variant; f32 stays within the
-hi/lo exactness budget.  ``interpret=None`` interprets on the CPU,
+of padding bins (``_tile_params``).  The leaf-batched DMA kernels tile a
+wide feature axis (``_leaves_dma_tiling``) and contract only the feature
+steps that hold a real feature: the padding of a ragged last tile is
+streamed but never one-hot encoded or multiplied.  Contract: quantized
+int32 sums are bit-for-bit identical across every variant; f32 stays
+within the hi/lo exactness budget.  ``interpret=None`` interprets on the CPU,
 so all of this is testable there, and the entry points batch under
 ``vmap`` through jax's pallas_call batching rule (the batch axis
 becomes a leading grid dimension — what lets multitrain ride these
@@ -201,12 +204,16 @@ def traced_kernels() -> tuple:
     return tuple(_TRACED_KERNELS)
 
 
-def _note_kernel(site: str, streamed_bytes: int) -> None:
+def _note_kernel(site: str, streamed_bytes: int, features: int = 0,
+                 contracted_features: int = 0) -> None:
     """Tally one kernel build (trace-time inside jitted growers; per call
-    on eager paths) — exported by TrainRecord like the collective sites."""
+    on eager paths) — exported by TrainRecord like the collective sites.
+    The DMA leaf kernels also say how many of their padded feature rows
+    they contract (:func:`_leaves_dma_tiling`)."""
     try:
         from ..telemetry.train_record import note_hist_kernel
-        note_hist_kernel(site, streamed_bytes)
+        note_hist_kernel(site, streamed_bytes, features,
+                         contracted_features)
     except Exception:
         pass
 
@@ -693,17 +700,26 @@ def _build_histogram_pallas_leaves_bs(bins_t: jnp.ndarray, w8: jnp.ndarray,
 
 
 def _leaves_dma_common(bins_hbm, w_hbm, ch_hbm, out_ref, *, num_features,
-                       num_bins, group, fstep, kr, nsteps, packed,
-                       make_w128, onehot_dtype, acc_dtype):
+                       contracted, num_bins, group, fstep, kr, nsteps,
+                       packed, make_w128, onehot_dtype, acc_dtype):
     """Shared DMA pipeline of the two leaf-batched kernels: bins,
     feature-major weights and the leaf-channel row stream HBM->VMEM via
     double-buffered async copies overlapping the contraction.
     ``make_w128(w_chunk, ch_chunk)`` expands the (8, r) weights into the
-    lane-packed (128, r) right operand (bf16 hi/lo or int8 form)."""
+    lane-packed (128, r) right operand (bf16 hi/lo or int8 form).
+
+    Only the first ``contracted`` feature rows (the real features rounded
+    up to ``fstep``) are one-hot encoded and contracted: a ragged last
+    tile runs fewer feature steps, and the accumulator rows it skips
+    keep the zeros they start with."""
     out_ref[...] = jnp.zeros_like(out_ref)
     ft = num_features
     b = num_bins
     f0 = pl.program_id(0) * ft
+    if contracted % ft == 0:
+        fsteps = ft // fstep                  # every tile is full: static
+    else:
+        fsteps = jnp.minimum(ft, contracted - f0) // fstep
     kb = kr // 2 if packed else kr
     iota_gb = jax.lax.broadcasted_iota(jnp.int32, (group * b, kb), 0) % b
 
@@ -772,7 +788,7 @@ def _leaves_dma_common(bins_hbm, w_hbm, ch_hbm, out_ref, *, num_features,
                     out_ref[pl.ds((fi + k * group) * b, group * b)] += part
                 return c
 
-            jax.lax.fori_loop(0, num_features // fstep, do, 0)
+            jax.lax.fori_loop(0, fsteps, do, 0)
             return carry
 
         jax.lax.fori_loop(0, nsteps, step, 0)
@@ -812,26 +828,42 @@ def _make_w128_q8(w, ch):
     return (wtile * sel).astype(jnp.int8)
 
 
+# stacked one-hot M dim (group * b) cap of the two DMA leaf kernels
+_LEAVES_M_CAP = 1024
+_LEAVES_Q8_M_CAP = 2048
+
+
+def _leaves_dma_tiling(f: int, num_bins: int, m_cap: int):
+    """Static tiling of the DMA leaf kernels: ``(b, group, fstep, ft,
+    f_pad, fc)``.  The feature axis is streamed in ``f_pad // ft`` tiles
+    of ``ft`` rows (the (ft*b, 128) accumulator block is capped at 8192
+    sublanes, 4 MB), swept ``fstep`` rows a step; ``fc`` = ``f`` rounded
+    up to ``fstep`` is how many of the ``f_pad`` rows hold data and are
+    contracted (67 features at B=256: 72 of 96)."""
+    b, group = _tile_params(num_bins, f, m_cap)
+    fstep = max(group, 8)
+    ft_cap = max(fstep, 8192 // b // fstep * fstep)
+    ft = min(_round_up(f, fstep), ft_cap)
+    return b, group, fstep, ft, _round_up(f, ft), _round_up(f, fstep)
+
+
 def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
                      m_cap, kr0, make_w128, onehot_dtype, acc_dtype,
                      out_dtype, row_block):
     """Shared wrapper plumbing of the two DMA leaf-kernel builders."""
     f = bins_t.shape[0]
     n = bins_t.shape[1] * (2 if packed else 1)
-    b, group = _tile_params(num_bins, f, m_cap)
+    b, group, fstep, ft, f_pad, fc = _leaves_dma_tiling(f, num_bins, m_cap)
     if packed:
         w = jnp.stack([w[:, 0::2], w[:, 1::2]])       # (2, 8, N/2)
         ch2 = jnp.stack([ch2[:, 0::2], ch2[:, 1::2]])  # (2, 1, N/2)
-    fstep = max(group, 8)
-    ft_cap = max(fstep, 8192 // b // fstep * fstep)
-    ft = min(_round_up(f, fstep), ft_cap)
-    f_pad = _round_up(f, ft)
     if f_pad != f:
         bins_t = jnp.pad(bins_t, ((0, f_pad - f), (0, 0)))
     kr = math.gcd(row_block, kr0)
     out = pl.pallas_call(
-        functools.partial(_leaves_dma_common, num_features=ft, num_bins=b,
-                          group=group, fstep=fstep, kr=kr, nsteps=n // kr,
+        functools.partial(_leaves_dma_common, num_features=ft,
+                          contracted=fc, num_bins=b, group=group,
+                          fstep=fstep, kr=kr, nsteps=n // kr,
                           packed=packed, make_w128=make_w128,
                           onehot_dtype=onehot_dtype, acc_dtype=acc_dtype),
         grid=(f_pad // ft,),
@@ -842,13 +874,13 @@ def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((f_pad * b, 128), out_dtype),
         cost_estimate=pl.CostEstimate(
-            flops=2 * f_pad * b * n * 128,
+            flops=2 * fc * b * n * 128,
             bytes_accessed=f_pad * (n // 2 if packed else n) +
             n * (_C * 2 + 4) + f_pad * b * 512,
             transcendentals=0),
         interpret=interpret,
         name=_kname(kind + "_dma" + ("_packed4" if packed else ""),
-                    f=f_pad, b=b, g=group, kr=kr, n=n),
+                    f=f_pad, fc=fc, b=b, g=group, kr=kr, n=n),
     )(bins_t, w, ch2)
     return out, f_pad
 
@@ -863,7 +895,8 @@ def _build_histogram_pallas_leaves_dma(bins_t, w8, ch, *, num_bins,
     out, f_pad = _leaves_dma_call(
         bins_t, w8, ch2, kind="hist_leaves", num_bins=num_bins,
         interpret=interpret,
-        packed=packed, m_cap=1024, kr0=4096, make_w128=_make_w128_bf16,
+        packed=packed, m_cap=_LEAVES_M_CAP, kr0=4096,
+        make_w128=_make_w128_bf16,
         onehot_dtype=jnp.bfloat16, acc_dtype=jnp.float32,
         out_dtype=jnp.float32, row_block=row_block)
     f = bins_t.shape[0]
@@ -905,10 +938,12 @@ def build_histogram_pallas_leaves(bins_t: jnp.ndarray, w8: jnp.ndarray,
             raise ValueError(f"bins_packed requires num_bins <= "
                              f"{PACK4_MAX_BINS}, got {num_bins}")
         pipeline = "dma"
+    rows = (_leaves_dma_tiling(f, num_bins, _LEAVES_M_CAP)[4:]
+            if pipeline == "dma" else ())             # (f_pad, fc)
     _note_kernel(f"ops/hist_kernel/leaves/{pipeline}"
                  + ("/packed4" if bins_packed else ""),
                  f * np_ * bins_t.dtype.itemsize + n * (_C * 2 + 4) +
-                 LEAF_CHANNELS * f * num_bins * 3 * 4)
+                 LEAF_CHANNELS * f * num_bins * 3 * 4, *rows)
     if pipeline == "dma":
         return _build_histogram_pallas_leaves_dma(
             bins_t, w8, ch, num_bins=num_bins, row_block=row_block,
@@ -1048,7 +1083,8 @@ def _build_histogram_pallas_leaves_q8_dma(bins_t, wch, ch, *, num_bins,
     out, f_pad = _leaves_dma_call(
         bins_t, wch, ch2, kind="hist_leaves_q8", num_bins=num_bins,
         interpret=interpret,
-        packed=packed, m_cap=2048, kr0=4096, make_w128=_make_w128_q8,
+        packed=packed, m_cap=_LEAVES_Q8_M_CAP, kr0=4096,
+        make_w128=_make_w128_q8,
         onehot_dtype=jnp.int8, acc_dtype=jnp.int32,
         out_dtype=jnp.int32, row_block=row_block)
     f = bins_t.shape[0]
@@ -1095,10 +1131,12 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
             raise ValueError(f"bins_packed requires num_bins <= "
                              f"{PACK4_MAX_BINS}, got {num_bins}")
         pipeline = "dma"
+    rows = (_leaves_dma_tiling(f, num_bins, _LEAVES_Q8_M_CAP)[4:]
+            if pipeline == "dma" else ())             # (f_pad, fc)
     _note_kernel(f"ops/hist_kernel/leaves_q8/{pipeline}"
                  + ("/packed4" if bins_packed else ""),
                  f * np_ * bins_t.dtype.itemsize + n * 9 +
-                 Q_LEAF_CHANNELS * f * num_bins * 3 * 4)
+                 Q_LEAF_CHANNELS * f * num_bins * 3 * 4, *rows)
     if pipeline == "dma":
         return _build_histogram_pallas_leaves_q8_dma(
             bins_t, wch, ch, num_bins=num_bins, row_block=row_block,

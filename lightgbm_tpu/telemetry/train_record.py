@@ -112,11 +112,15 @@ def collectives_reset() -> None:
 # ---------------------------------------------------------------------------
 
 _hist_lock = threading.Lock()
-# site -> {"count": int, "bytes": int}
+# site -> {"count": int, "bytes": int[, "features", "contracted_features"]}
 _hist_kernels: Dict[str, Dict[str, int]] = {}
 
 
-def note_hist_kernel(site: str, streamed_bytes: int) -> None:
+def note_hist_kernel(site: str, streamed_bytes: int, features: int = 0,
+                     contracted_features: int = 0) -> None:
+    """``features`` / ``contracted_features``: the feature rows the kernel
+    streams (its padded feature axis) and how many of them it one-hot
+    encodes and contracts — the latest build's, where the kernel says."""
     if not _config.enabled():
         return
     with _hist_lock:
@@ -125,6 +129,9 @@ def note_hist_kernel(site: str, streamed_bytes: int) -> None:
             rec = _hist_kernels[site] = {"count": 0, "bytes": 0}
         rec["count"] += 1
         rec["bytes"] += int(streamed_bytes)
+        if features:
+            rec["features"] = int(features)
+            rec["contracted_features"] = int(contracted_features)
 
 
 def hist_kernel_snapshot() -> Dict[str, Dict[str, int]]:
@@ -449,7 +456,7 @@ class TrainRecord:
             dc = rec["count"] - base["count"]
             db = rec["bytes"] - base["bytes"]
             if dc > 0:
-                hist_kernels[site] = {"count": dc, "bytes": db}
+                hist_kernels[site] = {**rec, "count": dc, "bytes": db}
         mon_counts, mon_secs = _monitoring_snapshot()
         events = {}
         for k, v in _compile_events(mon_counts).items():

@@ -60,6 +60,35 @@ def test_dense_vs_walk_parity_categorical(cat_booster):
     np.testing.assert_allclose(out_d, out_w, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("compiler", ["dense", "walk"])
+def test_threshold_between_float32_neighbours(compiler):
+    """A float64 threshold that rounds UP to float32 (found on the chip,
+    PR 27: benchmark seed 2147495087, held-out row 18094): the row whose
+    value is that float32 lies ABOVE the threshold and goes right, as the
+    model text's float64 compare sends it; its lower neighbour goes left."""
+    import re
+    thr = -0.3956146985292434
+    above = np.float32(thr)
+    assert float(above) > thr
+    below = np.nextafter(above, np.float32(-np.inf))
+    rng = np.random.RandomState(3)
+    X = rng.randn(200, 2)
+    y = (X[:, 0] > 0).astype(np.float64)
+    p = {"objective": "binary", "num_leaves": 2, "min_data_in_leaf": 5,
+         "verbosity": -1}
+    text = lgb.train(p, lgb.Dataset(X, y, params=p), 1).model_to_string()
+    text, n = re.subn(r"(?m)^threshold=.*$", f"threshold={thr!r}", text)
+    assert n == 1
+    bst = lgb.Booster(model_str=text)
+    root = bst.dump_model()["tree_info"][0]["tree_structure"]
+    left = root["left_child"]["leaf_value"]
+    right = root["right_child"]["leaf_value"]
+    assert left != right
+    Xq = np.array([[above, 0.0], [below, 0.0]], np.float64)
+    got = bst.to_predictor(compiler=compiler).predict(Xq, raw_score=True)
+    np.testing.assert_allclose(got, [right, left], rtol=1e-6)
+
+
 def test_dense_multiclass_parity(multiclass_data):
     X, y = multiclass_data
     p = {**SMALL, "objective": "multiclass", "num_class": 3}

@@ -13,12 +13,14 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from lightgbm_tpu.ops.histogram import build_histogram, build_histogram_leaves
 from lightgbm_tpu.ops.histogram_pallas import (
-    LEAF_CHANNELS, Q_LEAF_CHANNELS, build_histogram_pallas,
+    LEAF_CHANNELS, Q_LEAF_CHANNELS, _make_w128_bf16, build_histogram_pallas,
     build_histogram_pallas_leaves, build_histogram_pallas_leaves_q8,
-    pack_bins4, pack_weights8, pad_rows, unpack_bins4,
+    pack_bins4, pack_weights8, pad_rows, traced_kernels, unpack_bins4,
     wave_row_update_pallas, wave_trial_channels_pallas)
 
 N, F = 4096, 5  # one exact row block — the boundary shape
@@ -150,6 +152,138 @@ def test_q8_kernel_bitwise_across_variants():
         np.testing.assert_array_equal(got, base, err_msg=str(kw))
 
 
+# -- ragged last feature tile (ISSUE 27): B=255 tiles the feature axis 32
+# rows at a time, so F > 32 pads to 64 and the DMA leaf kernels skip the
+# feature steps of the last tile that hold padding only ----------------------
+
+RAGGED_N = 8192  # two row blocks
+
+
+def _parent_leaves_dma_bf16(bt, w8, ch, *, num_bins=255):
+    """The bf16 ``dma`` leaf kernel as it was before ISSUE 27, kept as the
+    oracle: EVERY tile runs ``ft // fstep`` (4) feature steps, padding
+    included.  Same DMAs, one-hot, contraction and accumulation order."""
+    f, n = bt.shape
+    b, group, fstep, ft, kr = 256, 4, 8, 32, 4096  # the tiling at B=255
+    f_pad = -(-f // ft) * ft
+    nsteps = n // kr
+
+    def kernel(bins_hbm, w_hbm, ch_hbm, out_ref):
+        out_ref[...] = jnp.zeros_like(out_ref)
+        f0 = pl.program_id(0) * ft
+        iota_gb = jax.lax.broadcasted_iota(jnp.int32, (group * b, kr), 0) % b
+
+        def body(bbuf, wbuf, cbuf, bsem, wsem, csem):
+            def dmas(slot, j):
+                rows = pl.ds(j * kr, kr)
+                return (pltpu.make_async_copy(
+                            bins_hbm.at[pl.ds(f0, ft), rows], bbuf.at[slot],
+                            bsem.at[slot]),
+                        pltpu.make_async_copy(
+                            w_hbm.at[:, rows], wbuf.at[slot], wsem.at[slot]),
+                        pltpu.make_async_copy(
+                            ch_hbm.at[:, rows], cbuf.at[slot], csem.at[slot]))
+
+            for d in dmas(0, 0):
+                d.start()
+
+            def step(j, carry):
+                slot = j % 2
+
+                @pl.when(j + 1 < nsteps)
+                def _():
+                    for d in dmas((j + 1) % 2, j + 1):
+                        d.start()
+
+                for d in dmas(slot, j):
+                    d.wait()
+                w128t = _make_w128_bf16(wbuf[slot], cbuf[slot])
+
+                def do(i, c):
+                    fi = pl.multiple_of(i * fstep, fstep)
+                    cols_blk = bbuf[slot, pl.ds(fi, fstep), :].astype(
+                        jnp.int32)
+                    for k in range(fstep // group):
+                        cols = cols_blk[k * group:(k + 1) * group]
+                        colrep = jnp.repeat(cols, b, axis=0)
+                        onehot = (colrep == iota_gb).astype(jnp.bfloat16)
+                        part = jax.lax.dot_general(
+                            onehot, w128t, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                        out_ref[pl.ds((fi + k * group) * b,
+                                      group * b)] += part
+                    return c
+
+                jax.lax.fori_loop(0, ft // fstep, do, 0)
+                return carry
+
+            jax.lax.fori_loop(0, nsteps, step, 0)
+
+        pl.run_scoped(body,
+                      pltpu.VMEM((2, ft, kr), bins_hbm.dtype),
+                      pltpu.VMEM((2, 8, kr), w_hbm.dtype),
+                      pltpu.VMEM((2, 1, kr), ch_hbm.dtype),
+                      pltpu.SemaphoreType.DMA((2,)),
+                      pltpu.SemaphoreType.DMA((2,)),
+                      pltpu.SemaphoreType.DMA((2,)))
+
+    out = pl.pallas_call(
+        kernel, grid=(f_pad // ft,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=pl.BlockSpec((ft * b, 128), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((f_pad * b, 128), jnp.float32),
+        interpret=True,
+    )(jnp.pad(bt, ((0, f_pad - f), (0, 0))), w8,
+      ch.astype(jnp.int32).reshape(1, n))
+    out = out[:, :LEAF_CHANNELS * 5].reshape(f_pad, b, LEAF_CHANNELS, 5)
+    hist = jnp.stack([out[..., 0] + out[..., 1], out[..., 2] + out[..., 3],
+                      out[..., 4]], axis=-1)
+    return jnp.transpose(hist, (2, 0, 1, 3))[:, :f, :num_bins, :]
+
+
+@pytest.mark.parametrize("f", [33, 35, 40, 64])
+def test_leaves_dma_ragged_last_tile(f):
+    """q8: bitwise the ``blockspec`` kernel and a numpy scatter-add.
+    bf16 hi/lo: bitwise the kernel that contracted the padding too, and
+    inside the exactness budget of the f32 reference."""
+    B, n = 255, RAGGED_N
+    bins, grad, hess, mask = _data(n=n, f=f, B=B, seed=f)
+    rng = np.random.RandomState(100 + f)
+    bt = jnp.asarray(bins.T.copy())
+    act = mask > 0
+
+    wch = np.zeros((8, n), np.int8)
+    wch[0] = rng.randint(-127, 128, n) * act
+    wch[1] = rng.randint(0, 128, n) * act
+    wch[2] = act
+    chq = rng.randint(-1, Q_LEAF_CHANNELS, n).astype(np.int8)
+    got = np.asarray(build_histogram_pallas_leaves_q8(
+        bt, jnp.asarray(wch), jnp.asarray(chq), num_bins=B, pipeline="dma"))
+    want = np.zeros((Q_LEAF_CHANNELS, f, B, 3), np.int32)
+    rows = np.nonzero(chq >= 0)[0]
+    for j in range(f):
+        np.add.at(want[:, j], (chq[rows], bins[rows, j]), wch[:3, rows].T)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, np.asarray(
+        build_histogram_pallas_leaves_q8(
+            bt, jnp.asarray(wch), jnp.asarray(chq), num_bins=B,
+            pipeline="blockspec")))
+
+    ch = rng.randint(-1, LEAF_CHANNELS, n).astype(np.int32)
+    g, h, m, chd = map(jnp.asarray, (grad, hess, mask, ch))
+    w8 = pack_weights8(g, h, m)
+    got = np.asarray(build_histogram_pallas_leaves(
+        bt, w8, chd, num_bins=B, pipeline="dma"))
+    np.testing.assert_array_equal(
+        got, np.asarray(_parent_leaves_dma_bf16(bt, w8, chd)))
+    ref = np.asarray(build_histogram_leaves(
+        jnp.asarray(bins), g, h, m, chd, num_channels=LEAF_CHANNELS,
+        num_bins=B, impl="segment"))
+    assert np.abs(got - ref).max() / max(1.0, np.abs(ref).max()) < 1e-5
+    np.testing.assert_array_equal(got[..., 2], ref[..., 2])
+
+
 def test_leaves_kernels_bad_rows_raise():
     bins, grad, hess, mask = _data(B=16)
     bt = jnp.asarray(bins.T.copy())
@@ -190,12 +324,15 @@ def test_row_update_dma_bitwise_and_trial():
 
 # -- vmap-to-grid batching rule (the multitrain unlock) ----------------------
 
-def test_vmap_batching_bitwise():
+@pytest.mark.parametrize("f,B", [(6, 16), (35, 255)])
+def test_vmap_batching_bitwise(f, B):
     """jax's pallas_call batching rule lowers the model axis to a
     leading grid dimension; per-lane results must be bit-identical to
     the unbatched calls for BOTH pipelines (lifts the multitrain
-    segment|onehot gate, ROADMAP item 4)."""
-    bins, _, _, mask = _data(B=16, f=6)
+    segment|onehot gate, ROADMAP item 4).  At F=35, B=255 the DMA
+    kernel's ragged last tile finds its feature-tile index behind the
+    batch dimension."""
+    bins, _, _, mask = _data(B=B, f=f)
     rng = np.random.RandomState(4)
     M = 2
     wch = np.zeros((M, 8, N), np.int8)
@@ -209,7 +346,7 @@ def test_vmap_batching_bitwise():
     for pipe in ("blockspec", "dma"):
         def one(w_, pipe=pipe):
             return build_histogram_pallas_leaves_q8(bt, w_, ch,
-                                                    num_bins=16,
+                                                    num_bins=B,
                                                     pipeline=pipe)
         got = np.asarray(jax.jit(jax.vmap(one))(wchb))
         want = np.stack([np.asarray(one(wchb[k])) for k in range(M)])
@@ -312,6 +449,34 @@ def test_hist_kernel_telemetry_site():
     assert sites[site]["count"] >= 1
     assert sites[site]["bytes"] >= N * F  # at least the bin bytes
     assert hist_kernel_snapshot()  # process-wide tally holds it too
+    assert "features" not in sites[site]  # only the leaf dma kernels say
+
+
+@pytest.mark.parametrize("f,padded,contracted",
+                         [(35, 64, 40), (67, 96, 72), (28, 32, 32)])
+def test_hist_kernel_telemetry_contracted_features(f, padded, contracted):
+    """The DMA leaf kernels say how many of their padded feature rows
+    they contract, in the site's record and in the kernel's name (traced
+    only: nothing runs)."""
+    from lightgbm_tpu.telemetry.train_record import TrainRecord
+    S = jax.ShapeDtypeStruct
+    rec = TrainRecord()
+    jax.eval_shape(
+        lambda b, w, c: build_histogram_pallas_leaves_q8(
+            b, w, c, num_bins=255, pipeline="dma"),
+        S((f, N), jnp.uint8), S((8, N), jnp.int8), S((N,), jnp.int8))
+    jax.eval_shape(
+        lambda b, w, c: build_histogram_pallas_leaves(
+            b, w, c, num_bins=255, pipeline="dma"),
+        S((f, N), jnp.uint8), S((8, N), jnp.bfloat16), S((N,), jnp.int32))
+    sites = rec.snapshot()["hist_kernel"]
+    for site, kind, g in (("ops/hist_kernel/leaves_q8/dma", "_q8", 8),
+                          ("ops/hist_kernel/leaves/dma", "", 4)):
+        assert sites[site]["count"] == 1
+        assert sites[site]["features"] == padded
+        assert sites[site]["contracted_features"] == contracted
+        assert (f"lgbm_hist_leaves{kind}_dma_f{padded}_fc{contracted}"
+                f"_b256_g{g}_kr4096_n{N}") in traced_kernels()
 
 
 # -- Dataset 4-bit packed storage --------------------------------------------

@@ -1,0 +1,71 @@
+"""The main path's kernels, compiled for a DESCRIBED TPU v5e at the
+benchmark cells' real width and rows (ISSUE 27).
+
+The TPU's compiler is installed in the sandbox and compiles for a chip
+that is described, not attached: what Mosaic would refuse on the chip (a
+slice off the tiling, too much VMEM, a loop it cannot lower) it refuses
+here, at no chip time.  Nothing runs, so this says nothing about results
+or speed.  The topology is described inside a module-scoped fixture,
+never at import (one process at a time may load the TPU's library, and
+every xdist worker imports every test file), and these tests live in
+this ONE file so a single worker loads it."""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from lightgbm_tpu.ops.histogram_pallas import (
+    build_histogram_pallas_leaves, build_histogram_pallas_leaves_q8,
+    traced_kernels)
+
+# the cells of BENCHMARK.json: 21.25M rows (padded to the row block) x 67
+F, N, MAX_BIN = 67, 21_250_048, 255
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    for k, v in (("TPU_LOG_DIR", "disabled"),
+                 ("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                 ("TPU_WORKER_HOSTNAMES", "localhost"),
+                 ("TPU_SKIP_MDS_QUERY", "1")):
+        os.environ.setdefault(k, v)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable for a described chip is written to the persistent
+    # cache but cannot be read back without the chip (the next run would
+    # warn and compile again): keep these compiles out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("kind", ["q8", "bf16"])
+def test_leaf_dma_kernel_compiles_at_cell_shape(one_chip, kind):
+    if kind == "q8":
+        build, wdt, cdt = build_histogram_pallas_leaves_q8, jnp.int8, jnp.int8
+        name = f"lgbm_hist_leaves_q8_dma_f96_fc72_b256_g8_kr4096_n{N}"
+    else:
+        build, wdt, cdt = (build_histogram_pallas_leaves, jnp.bfloat16,
+                           jnp.int32)
+        name = f"lgbm_hist_leaves_dma_f96_fc72_b256_g4_kr4096_n{N}"
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda bins, w, ch: build(bins, w, ch, num_bins=MAX_BIN,
+                                  pipeline="dma", interpret=False)
+    ).lower(S((F, N), jnp.uint8), S((8, N), wdt), S((N,), cdt)).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # Mosaic took the kernel
+    assert name in traced_kernels()
