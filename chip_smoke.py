@@ -14,7 +14,10 @@ What it runs, through the entry points a user calls:
   exact    the same few steps with ``use_quantized_grad=false`` (bf16 hi/lo)
   data     (more than one chip visible) the q8 configuration under
            ``tree_learner=data`` across all chips: parity with the serial
-           learner, then the flagship defaults with sharding asserted
+           learner, then the flagship defaults with sharding asserted.
+           A pass/fail proof only: the MEASURED four-chip path is the
+           benchmark cell, ``chiprun --chips 4 -- python3 -m chipbench.run
+           --workload criteo-q8-dp4.train --seed N --seconds 51 --trace 0|1``
 
 It asserts what actually ran (grower, kernels, interpret mode, sharding),
 not what a warning said.  Any failed phase ends the run non-zero; off-TPU

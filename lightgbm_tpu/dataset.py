@@ -31,7 +31,7 @@ from .config import Config
 from .telemetry.trace import timed_span
 from .utils.log import log_info
 
-__all__ = ["Dataset", "Metadata", "DatasetCorruptError"]
+__all__ = ["Dataset", "Metadata", "DatasetCorruptError", "RowBlocks"]
 
 
 class DatasetCorruptError(ValueError):
@@ -92,12 +92,52 @@ class Metadata:
         return 0 if self.group is None else len(self.group)
 
 
+class RowBlocks:
+    """``data`` given as a list of 2-D numpy row blocks of equal width (the
+    reference's "list of numpy arrays"): the rows of one matrix, never
+    joined.  ``construct`` gathers its bin-finding sample from the blocks
+    and bins them one by one, each from its own dtype, so no copy of the
+    whole matrix exists at any time."""
+
+    def __init__(self, blocks: Sequence[np.ndarray]) -> None:
+        self.blocks = list(blocks)
+        f = self.blocks[0].shape[1]
+        if any(b.shape[1] != f for b in self.blocks):
+            raise ValueError(
+                "row blocks must have equal width; got "
+                f"{sorted({b.shape[1] for b in self.blocks})}")
+        self.offsets = np.concatenate(
+            [[0], np.cumsum([b.shape[0] for b in self.blocks])]).astype(
+            np.int64)
+        self.shape = (int(self.offsets[-1]), int(f))
+
+    def __iter__(self):
+        """(global row offset, block) in row order."""
+        return zip(self.offsets[:-1].tolist(), self.blocks)
+
+    def take_rows(self, idx: np.ndarray) -> np.ndarray:
+        """float64 (len(idx), F): the rows ``idx`` (global, sorted)."""
+        from .ingest.sketch import sampled_rows
+        return np.concatenate([sampled_rows(b, lo, idx) for lo, b in self])
+
+    def columns(self, used: np.ndarray, dtype) -> np.ndarray:
+        """(N, len(used)) of ``dtype``: the used columns, block by block."""
+        out = np.empty((self.shape[0], len(used)), dtype)
+        for lo, b in self:
+            out[lo:lo + b.shape[0]] = b[:, used]
+        return out
+
+
 class Dataset:
     """User-facing dataset, lazily constructed (reference python-package
     basic.py ``Dataset`` + C++ ``Dataset``/``DatasetLoader``).
 
     Parameters mirror the reference Python API.  ``data`` may be a numpy
-    array, a pandas DataFrame, or a path to a CSV/TSV/LibSVM file.
+    array, a pandas DataFrame, a path to a CSV/TSV/LibSVM file, or a list
+    of 2-D numpy row blocks of equal width.  Use the list for a matrix
+    that does not fit the host twice: a whole array is copied to float64
+    (three times at ``construct``'s peak), blocks are binned one by one
+    from their own dtype into the same mappers and bin codes.
     """
 
     def __init__(self, data: Any, label: Optional[_ArrayLike] = None,
@@ -145,6 +185,7 @@ class Dataset:
         sparse = hasattr(raw, "tocsc")
         if sparse:
             raw = raw.tocsc()
+        blocks = isinstance(raw, RowBlocks)
         n, f = raw.shape
         self.num_total_features = f
         self.feature_names_ = feature_names
@@ -189,6 +230,9 @@ class Dataset:
         with timed_span(secs, "bin_find", "dataset/construct/sample"):
             sample_idx = sample_row_indices(n, sample_cnt,
                                             cfg.data_random_seed, rng=rng)
+            # the sampled rows of a block input, gathered once; a whole
+            # matrix is indexed column by column below
+            sample = raw.take_rows(sample_idx) if blocks else None
         dist_sketch = None
         dist_sparse_cols = None
         n_total = n
@@ -247,7 +291,8 @@ class Dataset:
                 # than the raw sample-row gather it replaces
                 from .ingest.sketch import BinningSketch
                 dist_sketch = BinningSketch(f, cat_indices)
-                dist_sketch.update(np.asarray(raw[sample_idx], np.float64))
+                dist_sketch.update(sample if blocks else
+                                   np.asarray(raw[sample_idx], np.float64))
                 dist_sketch.allgather_merge()
 
         if self.reference is not None:
@@ -297,6 +342,8 @@ class Dataset:
                             if zfrac < 1.0 else sample_cnt
                         nz = min(nz, sample_cnt)
                         col_sample = np.concatenate([vals, np.zeros(nz)])
+                    elif blocks:
+                        col_sample = sample[:, j]
                     else:
                         col_sample = raw[sample_idx, j]
                     # the reference's pre-filter threshold scales
@@ -323,7 +370,7 @@ class Dataset:
 
         if self.efb is None:
             self.efb = self._maybe_bundle(cfg, raw, sparse, used, mappers,
-                                          sample_idx, n)
+                                          sample_idx, n, sample)
         if self.efb is not None:
             from .efb import bundle_binned_matrix, bundle_sparse_csc
             if sparse:
@@ -355,7 +402,8 @@ class Dataset:
             # (under pre_partition this is the LOCAL row shard — padded
             # in _finalize_distributed_rows and assembled row-sharded on
             # the mesh by the GBDT driver)
-            self.raw_used = raw[:, used].astype(np.float32)
+            self.raw_used = raw.columns(used, np.float32) if blocks else \
+                raw[:, used].astype(np.float32)
         else:
             self.raw_used = None
         if self.distributed_rows:
@@ -367,8 +415,25 @@ class Dataset:
         return self
 
     def _bin_dense(self, raw, used, mappers) -> np.ndarray:
-        """Bin codes of the used columns of a dense float64 matrix."""
+        """Bin codes of the used columns of a dense float64 matrix, or of
+        row blocks: those are binned one by one, each converted from its
+        own dtype, into one preallocated code matrix."""
         secs = self.setup_seconds
+        if isinstance(raw, RowBlocks):
+            out = None
+            keep_all = len(used) == raw.shape[1]
+            for lo, block in raw:
+                with timed_span(secs, "bin_matrix",
+                                "dataset/construct/select_columns"):
+                    cols = np.asarray(block if keep_all else block[:, used],
+                                      np.float64)
+                with timed_span(secs, "bin_matrix",
+                                "dataset/construct/bin_matrix"):
+                    codes = bin_matrix(cols, mappers)
+                    if out is None:
+                        out = np.empty((raw.shape[0], len(used)), codes.dtype)
+                    out[lo:lo + len(codes)] = codes
+            return out
         with timed_span(secs, "bin_matrix", "dataset/construct/select_columns"):
             cols = raw[:, used]
         with timed_span(secs, "bin_matrix", "dataset/construct/bin_matrix"):
@@ -451,7 +516,8 @@ class Dataset:
                  f"{_dist.process_count()} processes")
         return self._dist_global_rows
 
-    def _maybe_bundle(self, cfg, raw, sparse, used, mappers, sample_idx, n):
+    def _maybe_bundle(self, cfg, raw, sparse, used, mappers, sample_idx, n,
+                      sample=None):
         """Decide + build EFB bundles (dataset.cpp:239 FastFeatureBundling);
         serial-learner training only, and only when it shrinks the device
         matrix."""
@@ -479,7 +545,8 @@ class Dataset:
                                      np.intersect1d(raw.indices[lo:hi],
                                                     sample_idx))] = True
             else:
-                col = mappers[jj].value_to_bin(raw[sample_idx, j])
+                col = mappers[jj].value_to_bin(
+                    raw[sample_idx, j] if sample is None else sample[:, j])
                 mask = col != mappers[jj].default_bin
             # only near-sparse features are worth bundling
             if mask.mean() <= 0.5:
@@ -524,9 +591,13 @@ class Dataset:
             if self.feature_name != "auto" and self.feature_name is not None:
                 return raw, list(self.feature_name)
             return raw, [f"Column_{i}" for i in range(raw.shape[1])]
-        raw = np.asarray(data, dtype=np.float64)
-        if raw.ndim == 1:
-            raw = raw.reshape(-1, 1)
+        if isinstance(data, (list, tuple)) and len(data) > 0 and all(
+                isinstance(b, np.ndarray) and b.ndim == 2 for b in data):
+            raw = RowBlocks(data)
+        else:
+            raw = np.asarray(data, dtype=np.float64)
+            if raw.ndim == 1:
+                raw = raw.reshape(-1, 1)
         if self.feature_name != "auto" and self.feature_name is not None:
             names = list(self.feature_name)
         else:

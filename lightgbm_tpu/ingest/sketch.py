@@ -34,7 +34,7 @@ import numpy as np
 from ..binning import (BinMapper, ColumnSummary, find_bin_from_summary,
                        merge_column_summaries, summarize_column)
 
-__all__ = ["BinningSketch", "sample_row_indices"]
+__all__ = ["BinningSketch", "sample_row_indices", "sampled_rows"]
 
 
 def sample_row_indices(n: int, sample_cnt: int, seed: int,
@@ -51,6 +51,15 @@ def sample_row_indices(n: int, sample_cnt: int, seed: int,
     if sample_cnt < n:
         return np.sort(rng.choice(n, size=sample_cnt, replace=False))
     return np.arange(n)
+
+
+def sampled_rows(X, offset: int, sample_idx: np.ndarray) -> np.ndarray:
+    """Of the row block ``X``, whose first row is global row ``offset``,
+    the rows that the sorted global sample ``sample_idx`` holds, as
+    float64 (maybe none).  The streamed sketch pass and the block-input
+    ``Dataset.construct`` both gather their sample through this."""
+    lo, hi = np.searchsorted(sample_idx, [offset, offset + X.shape[0]])
+    return np.asarray(X[sample_idx[lo:hi] - offset], np.float64)
 
 
 class BinningSketch:

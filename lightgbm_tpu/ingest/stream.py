@@ -47,7 +47,7 @@ from ..dataset import Dataset
 from ..telemetry.metrics import default_registry
 from ..telemetry.trace import span
 from ..utils.log import log_info
-from .sketch import BinningSketch, sample_row_indices
+from .sketch import BinningSketch, sample_row_indices, sampled_rows
 from .source import ChunkSource, DEFAULT_CHUNK_ROWS
 
 __all__ = ["StreamedDataset", "ingest_chunk_hbm_bytes"]
@@ -167,11 +167,9 @@ class StreamedDataset(Dataset):
         with span("ingest/sketch_pass"):
             for chunk in src.chunks():
                 m = chunk.X.shape[0]
-                lo = np.searchsorted(sample_idx, chunk.offset)
-                hi = np.searchsorted(sample_idx, chunk.offset + m)
-                if ref is None and hi > lo:
-                    local = sample_idx[lo:hi] - chunk.offset
-                    sketch.update(np.asarray(chunk.X, np.float64)[local])
+                if ref is None:
+                    sketch.update(sampled_rows(chunk.X, chunk.offset,
+                                               sample_idx))
                 if chunk.label is not None:
                     if label is None:
                         label = np.empty(n, np.float64)

@@ -15,6 +15,7 @@ model, plus metric scalars when evaluation runs.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -491,7 +492,12 @@ class GBDT:
                 self._row_valid = jax.make_array_from_process_local_data(
                     NamedSharding(_mesh, _P(_ax)), train_set._dist_valid_local)
             else:
-                self.X_dev = self._put_rows(train_set.X_binned)
+                # under a row-sharded learner the bin codes leave the host
+                # as row shards, one per device: no whole copy on device 0
+                sharding = self._mesh_record()["chips"] > 1
+                with timed_span(setup_s, "shard", "train/init/shard") \
+                        if sharding else contextlib.nullcontext():
+                    self.X_dev = self._put_rows(train_set.X_binned)
                 self._row_valid = None
             self._is_cat_np = is_cat
             # bundle-space tree-walk decode arrays (EFB valid sets / rebuilds)
@@ -596,7 +602,7 @@ class GBDT:
             "num_leaves": int(cfg.num_leaves),
             "num_data": int(self.num_data),
             "num_features": int(self.num_features),
-        }, compile_since=t_init)
+        }, compile_since=t_init, mesh=self._mesh_record())
         self.train_record.add_setup_seconds(
             getattr(train_set, "setup_seconds", {}))
         self.train_record.add_setup_seconds(setup_s)
@@ -724,6 +730,17 @@ class GBDT:
             cegb_lazy=self._inner_cegb_lazy(),
             forced_splits=self._parse_forced_splits(),
             feature_contri=self._inner_contri())
+
+    def _mesh_record(self) -> Dict[str, Any]:
+        """``TrainRecord.snapshot()["mesh"]``: the mesh the learner built
+        for its row shards (one chip and no axis where rows stay whole:
+        the serial and the feature-parallel learner)."""
+        mesh = getattr(self.learner, "mesh", None)
+        if mesh is None or not getattr(self.learner, "rows_sharded", False):
+            return {"chips": 1, "axis": None,
+                    "rows_per_chip": int(self.num_data)}
+        return {"chips": int(mesh.size), "axis": str(mesh.axis_names[0]),
+                "rows_per_chip": -(-int(self.num_data) // int(mesh.size))}
 
     def _put_rows(self, arr):
         """Host per-row array -> device.  Under a row-sharded learner

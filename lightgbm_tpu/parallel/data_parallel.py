@@ -34,6 +34,7 @@ from ..learner.serial import (CommStrategy, GrownTree, local_best_candidate,
                               split_params_from_config)
 from ..analysis.contracts import (collective_contract, memory_budget,
                                   world_size)
+from ..telemetry.trace import timed_span
 from ..telemetry.train_record import note_collective
 from .mesh import get_mesh, shard_rows
 
@@ -216,15 +217,22 @@ class WaveDPStrategy(CommStrategy):
         self.hist_scatter = bool(hist_scatter)
         self.monotone_full = None
 
+    # Every collective sits in an innermost ``lgbm.dp.*`` scope, so a
+    # device trace tells the exchange from the glue around it:
+    # ``lgbm.dp.hist_reduce`` (the one histogram merge a pass makes),
+    # ``lgbm.dp.exchange`` (the winner exchange), ``lgbm.dp.scalar``.
+
     def reduce_sum(self, v):
         note_collective("data_parallel/wave/scalar_sum", "psum", v)
-        return jax.lax.psum(v, self.axis_name)
+        with jax.named_scope("lgbm.dp.scalar"):
+            return jax.lax.psum(v, self.axis_name)
 
     def reduce_max(self, v):
         """Global quantization scales: every shard must see the same max
         (gradient_discretizer scales are global in the reference too)."""
         note_collective("data_parallel/wave/quant_scale", "pmax", v)
-        return jax.lax.pmax(v, self.axis_name)
+        with jax.named_scope("lgbm.dp.scalar"):
+            return jax.lax.pmax(v, self.axis_name)
 
     def shard_key(self, key):
         """Independent stochastic-rounding streams per row shard."""
@@ -236,18 +244,21 @@ class WaveDPStrategy(CommStrategy):
         # asserted on the traced program in tests/test_specramp.py — this
         # tally counts the same sites at trace time)
         note_collective("data_parallel/wave/hist_psum", "psum", hist)
-        return jax.lax.psum(hist, self.axis_name)
+        with jax.named_scope("lgbm.dp.hist_reduce"):
+            return jax.lax.psum(hist, self.axis_name)
 
     def reduce_hist_scatter(self, hist):
         """Feature-sliced merge: reduce-scatter the (k, Fp, B, 3) batch
         over the padded feature axis so this shard receives only its
         Fp/nshards block, fully reduced.  The telemetry note records the
-        scattered OUTPUT (the per-device received payload — 1/k of the
-        psum mode's full-batch residency)."""
-        out = jax.lax.psum_scatter(hist, self.axis_name,
-                                   scatter_dimension=1, tiled=True)
+        scattered OUTPUT as ``bytes`` (the per-device received payload —
+        1/k of the psum mode's full-batch residency) and the local batch
+        that goes in as ``operand_bytes``."""
+        with jax.named_scope("lgbm.dp.hist_reduce"):
+            out = jax.lax.psum_scatter(hist, self.axis_name,
+                                       scatter_dimension=1, tiled=True)
         note_collective("data_parallel/wave/hist_reduce_scatter",
-                        "psum_scatter", out)
+                        "psum_scatter", out, operand=hist)
         return out
 
     def exchange_collectives(self):
@@ -258,15 +269,18 @@ class WaveDPStrategy(CommStrategy):
 
         def xmax(v):
             note_collective("data_parallel/wave/winner_exchange", "pmax", v)
-            return jax.lax.pmax(v, ax)
+            with jax.named_scope("lgbm.dp.exchange"):
+                return jax.lax.pmax(v, ax)
 
         def xmin(v):
             note_collective("data_parallel/wave/winner_exchange", "pmin", v)
-            return jax.lax.pmin(v, ax)
+            with jax.named_scope("lgbm.dp.exchange"):
+                return jax.lax.pmin(v, ax)
 
         def xsum(v):
             note_collective("data_parallel/wave/winner_exchange", "psum", v)
-            return jax.lax.psum(v, ax)
+            with jax.named_scope("lgbm.dp.exchange"):
+                return jax.lax.psum(v, ax)
 
         return xmax, xmin, xsum
 
@@ -295,6 +309,7 @@ class DataParallelTreeLearner:
         self.cegb_lazy = tuple(float(v) for v in cegb_lazy)
         self.forced_splits = tuple(tuple(f) for f in forced_splits)
         self.mesh = get_mesh(int(config.num_devices))
+        self.setup_seconds = {}   # "layout": see train()
         self.ndev = self.mesh.devices.size
         self.axis = self.mesh.axis_names[0]
         mode = str(config.tree_grow_mode)
@@ -488,9 +503,11 @@ class DataParallelTreeLearner:
                 quantum = self.ndev * 8
             pad = (-n) % quantum
             if self._x_src is not X_dev:
-                Xp = jnp.pad(X_dev, ((0, pad), (0, 0))) if pad else X_dev
-                self._XpT = shard_rows(self.mesh, jnp.swapaxes(Xp, 0, 1),
-                                       self.axis, dim=1)
+                # one-time pad + transpose; host seconds of ENQUEUEING it
+                with timed_span(self.setup_seconds, "layout", "train/layout"):
+                    Xp = jnp.pad(X_dev, ((0, pad), (0, 0))) if pad else X_dev
+                    self._XpT = shard_rows(self.mesh, jnp.swapaxes(Xp, 0, 1),
+                                           self.axis, dim=1)
                 self._x_src = X_dev
                 self._lazy_used = None  # fresh data -> fresh bitmap
             if pad:

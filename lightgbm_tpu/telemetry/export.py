@@ -103,7 +103,7 @@ def _render_train_record(snap: Dict, out: List[str]) -> None:
              "host seconds of the run's set-up, by phase" if first else "")
         first = False
     first = True
-    for site, rec in sorted(snap["collectives_traced"].items()):
+    for site, rec in sorted(snap["collectives"].items()):
         lbl = {"site": site, "op": rec["op"]}
         line("collectives_traced_total", rec["count"], lbl, "counter",
              "collective call sites per traced program (trace-time "

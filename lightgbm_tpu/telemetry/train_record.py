@@ -63,37 +63,51 @@ __all__ = ["TrainRecord", "note_collective", "collectives_snapshot",
 # ---------------------------------------------------------------------------
 
 _coll_lock = threading.Lock()
-# site -> {"op": str, "count": int, "bytes": int}
+# site -> {"op": str, "count": int, "bytes": int, "operand_bytes": int,
+#          "operand_sizes": [int per traced call, in order]}
 _collectives: Dict[str, Dict[str, Any]] = {}
 
 
-def note_collective(site: str, op: str, value) -> None:
-    """Record one collective call site being traced.
-
-    ``value`` is the operand (concrete array or tracer — both expose
-    shape/dtype).  Called from inside jit/shard_map tracing, so this
-    runs once per traced program, never per executed step; runtime cost
-    of the compiled program is zero."""
-    if not _config.enabled():
-        return
+def _nbytes(value) -> int:
     try:
-        nbytes = 1
+        nbytes = value.dtype.itemsize
         for d in value.shape:
             nbytes *= int(d)
-        nbytes *= value.dtype.itemsize
+        return int(nbytes)
     except Exception:
-        nbytes = 0
+        return 0
+
+
+def note_collective(site: str, op: str, value, operand=None) -> None:
+    """Record one collective call site being traced.
+
+    ``value`` is the payload the site is accounted by (concrete array or
+    tracer — both expose shape/dtype); ``operand`` is what goes INTO the
+    collective where that differs (a reduce-scatter is accounted by what
+    each device receives, and its operand is the whole local batch).
+    Called from inside jit/shard_map tracing, so this runs once per traced
+    program, never per executed step; runtime cost of the compiled
+    program is zero."""
+    if not _config.enabled():
+        return
+    nbytes = _nbytes(value)
+    operand_bytes = nbytes if operand is None else _nbytes(operand)
     with _coll_lock:
         rec = _collectives.get(site)
         if rec is None:
-            rec = _collectives[site] = {"op": op, "count": 0, "bytes": 0}
+            rec = _collectives[site] = {"op": op, "count": 0, "bytes": 0,
+                                        "operand_bytes": 0,
+                                        "operand_sizes": []}
         rec["count"] += 1
-        rec["bytes"] += int(nbytes)
+        rec["bytes"] += nbytes
+        rec["operand_bytes"] += operand_bytes
+        rec["operand_sizes"].append(operand_bytes)
 
 
 def collectives_snapshot() -> Dict[str, Dict[str, Any]]:
     with _coll_lock:
-        return {k: dict(v) for k, v in _collectives.items()}
+        return {k: dict(v, operand_sizes=list(v["operand_sizes"]))
+                for k, v in _collectives.items()}
 
 
 def collectives_reset() -> None:
@@ -312,9 +326,11 @@ class TrainRecord:
     also published process-wide for the ``/metrics`` exporter."""
 
     def __init__(self, meta: Optional[Dict[str, Any]] = None,
-                 compile_since: Optional[float] = None) -> None:
+                 compile_since: Optional[float] = None,
+                 mesh: Optional[Dict[str, Any]] = None) -> None:
         self._lock = threading.Lock()
         self.meta = dict(meta or {})
+        self.mesh = dict(mesh or {})
         self._t_created = time.perf_counter()
         # JAX's trace/lower/compile events count from here (perf_counter):
         # the start of the set-up the record belongs to, if it began earlier
@@ -430,7 +446,22 @@ class TrainRecord:
         which encloses the persistent cache's
         ``/jax/compilation_cache/cache_retrieval_time_sec``), each the
         union of its events' intervals, up to the end of the latest
-        boosting iteration."""
+        boosting iteration; ``shard`` (``train/init/shard``, inside
+        ``upload``: the bin codes going to the mesh as row shards).
+
+        ``collectives`` (``collectives_traced`` before PR 28): per
+        ``note_collective`` site, what was traced SINCE THIS RECORD WAS
+        MADE: ``op``, ``count`` (traced call sites), ``bytes`` and
+        ``operand_bytes`` (summed over them) and ``max_operand_bytes``
+        (the largest single operand among them: at a histogram site, the
+        batch one full wave pass merges).  A program served from the
+        persistent compile cache is traced before the cache is asked, so
+        its sites are here; a program found in the process's own jit
+        cache (the same jitted function called again) is not traced
+        again, and a record that saw no trace of a site leaves it out.
+        ``mesh``: ``{"chips", "axis", "rows_per_chip"}``
+        as the learner built it (one chip, no axis: the serial
+        learner)."""
         self._flush()
         self.note_memory()  # final watermark: periodic samples miss the tail
         with self._lock:
@@ -444,11 +475,16 @@ class TrainRecord:
         coll_now = collectives_snapshot()
         coll = {}
         for site, rec in coll_now.items():
-            base = self._coll_base.get(site, {"count": 0, "bytes": 0})
-            dc = rec["count"] - base["count"]
-            db = rec["bytes"] - base["bytes"]
+            base = self._coll_base.get(site, {})
+            dc = rec["count"] - base.get("count", 0)
             if dc > 0:
-                coll[site] = {"op": rec["op"], "count": dc, "bytes": db}
+                coll[site] = {
+                    "op": rec["op"], "count": dc,
+                    "bytes": rec["bytes"] - base.get("bytes", 0),
+                    "operand_bytes": rec["operand_bytes"] -
+                    base.get("operand_bytes", 0),
+                    "max_operand_bytes": max(
+                        rec["operand_sizes"][base.get("count", 0):])}
         hk_now = hist_kernel_snapshot()
         hist_kernels = {}
         for site, rec in hk_now.items():
@@ -481,7 +517,8 @@ class TrainRecord:
             "phase_seconds": {k: round(v, 6) for k, v in phase_s.items()},
             "phase_calls": phase_n,
             "setup_seconds": {k: round(v, 6) for k, v in setup_s.items()},
-            "collectives_traced": coll,
+            "collectives": coll,
+            "mesh": dict(self.mesh),
             "hist_kernel": hist_kernels,
             "compile_events": events,
             "compile_seconds": secs,

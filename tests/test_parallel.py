@@ -109,16 +109,46 @@ def test_voting_with_many_features():
     assert y[order[: n // 4]].mean() > 0.8
 
 
-def test_data_parallel_wave_matches_serial_wave(data):
+@pytest.mark.parametrize("fed", ["matrix", "blocks"])
+def test_data_parallel_wave_matches_serial_wave(data, fed):
     """The wave grower under shard_map (one histogram psum per wave) must
     reproduce the single-device wave grower: psum'd histograms make every
-    shard's candidate scans identical."""
+    shard's candidate scans identical.  Fed as row blocks, the share is
+    still the whole: the same trees as the serial learner on the matrix."""
     X, y = data
     p = {**SMALL, "objective": "binary", "tree_grow_mode": "wave"}
     serial = lgb.train(p, lgb.Dataset(X, y), 5).predict(X)
+    rows = X if fed == "matrix" else [X[:300], X[300:301], X[301:]]
     dp = lgb.train({**p, "tree_learner": "data"},
-                   lgb.Dataset(X, y), 5).predict(X)
+                   lgb.Dataset(rows, y), 5).predict(X)
     np.testing.assert_allclose(dp, serial, atol=2e-5)
+
+
+def test_four_device_trees_equal_serial_trees_with_the_ramp_off():
+    """The tie between the share and the whole on the cell's path: q8
+    through the Pallas kernels, four devices, rows in blocks, the ramp off
+    and rounding to nearest (with the ramp on each shard strides its own
+    rows for the provisional subsample, and the trees differ by design):
+    the integer histograms sum exactly, so the trees are the serial ones."""
+    rng = np.random.RandomState(5)
+    X = rng.randn(4000, 6).astype(np.float32)
+    y = ((X[:, 0] + 0.5 * X[:, 1] - 0.2 * X[:, 2] ** 2) > 0).astype(np.float32)
+    p = {"objective": "binary", "num_leaves": 15, "max_bin": 63,
+         "min_data_in_leaf": 5, "verbosity": -1, "tree_grow_mode": "wave",
+         "tpu_histogram_impl": "pallas", "use_quantized_grad": True,
+         "quant_train_renew_leaf": True, "stochastic_rounding": False,
+         "tpu_speculative_ramp": False}
+    serial = lgb.train(p, lgb.Dataset(X, y), 3)
+    dp = lgb.train({**p, "tree_learner": "data", "num_devices": 4},
+                   lgb.Dataset([X[:1024], X[1024:3000], X[3000:]], y), 3)
+
+    def structure(bst):
+        return [ln for ln in bst.model_to_string().splitlines()
+                if ln.startswith(("split_feature=", "threshold=",
+                                  "left_child=", "right_child=",
+                                  "leaf_count="))]
+    assert structure(dp) == structure(serial)
+    np.testing.assert_allclose(dp.predict(X), serial.predict(X), atol=2e-6)
 
 
 def test_data_parallel_wave_bagging_multiclass(data):
