@@ -135,8 +135,8 @@ def kernel_selfcheck(rows: int = KERNEL_ROWS, features: int = FEATURES,
     from lightgbm_tpu.ops.histogram_pallas import (
         LEAF_CHANNELS, Q_LEAF_CHANNELS, build_histogram_pallas,
         build_histogram_pallas_leaves, build_histogram_pallas_leaves_q8,
-        pack_bins4, pack_weights8, pad_rows, wave_row_update_pallas,
-        wave_trial_channels_pallas)
+        bin_rows_view, pack_bins4, pack_weights8, pad_rows,
+        wave_row_update_pallas, wave_trial_channels_pallas)
 
     n = pad_rows(rows)
     kw = dict(interpret=interpret, pipeline=pipeline)
@@ -209,11 +209,22 @@ def kernel_selfcheck(rows: int = KERNEL_ROWS, features: int = FEATURES,
     rl_got, ch_got = wave_row_update_pallas(cols, rl, tab, **kw)
     out["row_update_mismatches"] = int(jnp.sum(rl_got != rl_ref) +
                                        jnp.sum(ch_got != ch_ref))
+    # ... and as the grower calls it: the kernel handed a bin matrix (the
+    # columns, shuffled) and the W feature ids, fetching its own columns
+    order = rng.permutation(W)
+    fetch = dict(kw, feats=jnp.asarray(np.argsort(order).astype(np.int32)))
+    bins = bin_rows_view(cols[order], pipeline)
+    rl_got, ch_got = wave_row_update_pallas(bins, rl, tab, **fetch)
+    out["row_update_fetch_mismatches"] = int(jnp.sum(rl_got != rl_ref) +
+                                             jnp.sum(ch_got != ch_ref))
     trial_tab = tab.at[5].set(tab[4])        # new_right_id = split leaf
     _, ch_ref = _row_update_reference(cols, rl, trial_tab)
     ch_got = wave_trial_channels_pallas(
         cols, rl, tab[4], tab[0], tab[1], tab[2], tab[3], tab[6], **kw)
     out["trial_channels_mismatches"] = int(jnp.sum(ch_got != ch_ref))
+    ch_got = wave_trial_channels_pallas(
+        bins, rl, tab[4], tab[0], tab[1], tab[2], tab[3], tab[6], **fetch)
+    out["trial_channels_fetch_mismatches"] = int(jnp.sum(ch_got != ch_ref))
 
     for name, v in out.items():
         exact = not name.endswith("_rel")
@@ -290,7 +301,9 @@ def train_phase(name: str, train_set, holdout, params: dict, steps: int, *,
     want = [f"ops/hist_kernel/leaves_q8/{pipe}",
             f"ops/hist_kernel/single/{pipe}"] if quantized else \
         [f"ops/hist_kernel/leaves/{pipe}"]
-    want.append(f"ops/hist_kernel/row_update/{pipe}")
+    # the grower's row updates fetch their own columns (dma pipeline)
+    want.append(f"ops/hist_kernel/row_update/{pipe}"
+                + ("/fetch" if pipe == "dma" else ""))
     for site in want:
         check(site in sites, f"{name}: kernel site {site} never traced "
               f"(got {sites})")

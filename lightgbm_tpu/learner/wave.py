@@ -210,7 +210,9 @@ collective_contract(
 # builds Q_WAVE_SIZE=42 channels, the f32 one 2*wave trial channels) and
 # the wave loop's subtraction/scan temporaries — measured ~5 channel
 # layers of working set per batch layer at the lint geometry; the curve
-# budgets 6 for headroom.  Row arrays: bins (F,N) uint8 + grad/hess/
+# budgets 6 for headroom.  Row arrays: bins (F,N) uint8, held twice
+# while a tree grows (as the histogram kernels stream them and as the
+# view the row-update kernel fetches its columns from) + grad/hess/
 # mask/row_leaf/quantized lanes, ~24 B/row beyond the bin matrix.
 # ---------------------------------------------------------------------------
 
@@ -227,7 +229,7 @@ def wave_grow_hbm_bytes(ctx):
     kernel_ch = Q_WAVE_SIZE if ctx.get("quantized") else WAVE_SIZE
     layers = int(ctx.get("leaves", 2)) + 6 * max(2 * wave, kernel_ch)
     hist = layers * f * b * 3 * it
-    rows = r * (f + 24)
+    rows = r * (2 * f + 24)
     return hist + rows + (1 << 20)
 
 
@@ -288,8 +290,9 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
     if pallas:
         from ..ops.histogram_pallas import (
             build_histogram_pallas, build_histogram_pallas_leaves,
-            build_histogram_pallas_leaves_q8, pack_weights8,
-            unpack_bins4, wave_row_update_pallas)
+            bin_rows_view, build_histogram_pallas_leaves_q8,
+            gather_bin_rows, pack_weights8, unpack_bins4,
+            wave_row_update_pallas, wave_trial_channels_pallas)
     if pack4 and not pallas:
         raise ValueError("pack4 bins require hist_impl='pallas'")
     if pack4 and (efb_dims is not None or max_bins > 16 or any_cat):
@@ -465,26 +468,36 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
              lazy_used: jnp.ndarray = None):
         # Under ``pack4`` X_T is the nibble-packed (G, N//2) byte matrix
         # (ops/histogram_pallas.pack_bins4): the histogram kernels
-        # consume it directly (half the streamed bin bytes) and the few
-        # per-wave winning-feature column fetches unpack on the fly.
+        # consume it directly (half the streamed bin bytes) and the row
+        # updates gather their W winning features' packed columns and
+        # unpack them on the fly.  Every other shape the fused row-update
+        # kernel takes hands it a feature-major view of the WHOLE matrix
+        # and the W feature ids: the kernel fetches the columns it needs
+        # itself, and no (W, N) array is built between X_T and it.
         n = X_T.shape[1] * 2 if pack4 else X_T.shape[1]
 
-        def take_rows(mat, feats):
-            """``mat[feats]`` for a static-length ``feats``, as a stack of
-            one-row dynamic slices.  A row gather (``jnp.take(mat, feats,
-            axis=0)``) of a (F, N) matrix costs XLA:TPU compile time in
-            proportion to N — ~35 s per gather at 10.5M rows on the chip
-            host, and a 255-leaf grower holds ~9 of them (PERF.md, PR 21);
-            the slices move the same bytes and compile in constant time."""
-            width = mat.shape[1]
-            return jnp.concatenate(
-                [jax.lax.dynamic_slice(mat, (feats[j], 0), (1, width))
-                 for j in range(feats.shape[0])], axis=0)
+        def router_bins(mat):
+            """What the fused row-update kernel reads ``mat``'s columns
+            from: made once per tree (a relayout of ``mat`` on a TPU)."""
+            if pack4 or not (pallas and small_bins and not any_cat):
+                return mat
+            return bin_rows_view(mat, pipeline)
 
-        def take_cols(feats):
-            """(k, N) UNPACKED bin columns of the given features."""
-            cols = take_rows(X_T, feats)
-            return unpack_bins4(cols) if pack4 else cols
+        def router_args(bins, feats):
+            """``(bins, feats=)`` of the fused row-update kernel's two
+            entries for the rows of ``bins`` (a :func:`router_bins`)."""
+            if pack4:
+                return unpack_bins4(gather_bin_rows(bins, feats)), None
+            return bins, feats
+
+        def route_rows(bins, feats, rl, tab):
+            bins, feats = router_args(bins, feats)
+            return wave_row_update_pallas(
+                bins, rl, tab, feats=feats, interpret=interpret,
+                pipeline=pipeline)
+
+        with jax.named_scope("lgbm.wave.row_update"):
+            X_R = router_bins(X_T)
         if strategy is not None:
             # shallow per-trace copy: traced array attributes must not
             # outlive the trace on the learner's long-lived strategy object
@@ -904,6 +917,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             else:
                 X_ss = X_T[:, ::stride][:, :n_ss]
                 w_ss = w_src[:, ::stride][:, :n_ss]
+            R_ss = router_bins(X_ss)
             nan_of = jnp.where(hn_full, nb_full - 1, -1)       # (F,)
             fm_k = jnp.broadcast_to(feature_mask, (Kc, F))
             jar = jnp.arange(Kc, dtype=jnp.int32)
@@ -989,12 +1003,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     thr_s, fnan_s, dl_s, jnp.ones((Kc,), jnp.int32),
                     sel_l, newids, sel.astype(jnp.int32),
                     jnp.zeros((Kc,), jnp.int32)])
-                cols_ss = take_rows(X_ss, feats_cl)
-                if pack4:
-                    cols_ss = unpack_bins4(cols_ss)
-                rl2, _ = wave_row_update_pallas(cols_ss, rl_ss, tab,
-                                                interpret=interpret,
-                                                pipeline=pipeline)
+                rl2, _ = route_rows(R_ss, feats_cl, rl_ss, tab)
                 rl_ss = rl2.astype(jnp.uint8)
                 tabs.append((tab, feats_cl))
                 nlp = nlp + prefix[-1]
@@ -1004,10 +1013,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             # partition matches how committed splits will route) --
             rl_full = jnp.zeros((n,), jnp.uint8)
             for tab, feats_cl in tabs:
-                cols = take_cols(feats_cl)
-                rlf, _ = wave_row_update_pallas(cols, rl_full, tab,
-                                                interpret=interpret,
-                                                pipeline=pipeline)
+                rlf, _ = route_rows(X_R, feats_cl, rl_full, tab)
                 rl_full = rlf.astype(jnp.uint8)
 
             # -- ONE full-data pass: exact per-prov-leaf channel sums --
@@ -1353,14 +1359,11 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     # one fused kernel pass instead of W masked XLA sweeps
                     # (each sweep's fused-loop launch overhead alone costs
                     # ~0.7 ms at 10.5M rows)
-                    cols_w = take_cols(feat)                      # (W, N) u8
                     tab = jnp.stack([
                         thr, f_nan_bin, dleft.astype(jnp.int32),
                         left_smaller.astype(jnp.int32), sel_leaves, new_ids,
                         sel.astype(jnp.int32), jnp.zeros_like(thr)])
-                    rl_new, ch = wave_row_update_pallas(
-                        cols_w, rl, tab, interpret=interpret,
-                        pipeline=pipeline)
+                    rl_new, ch = route_rows(X_R, feat, rl, tab)
                     rl = rl_new.astype(rl.dtype)
                 else:
                     # Vectorized XLA fallback (categorical / EFB / wide-bin
@@ -1758,7 +1761,6 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     if pallas:
                         for c in range(EG // W):
                             sl = slice(c * W, (c + 1) * W)
-                            cols = take_cols(pend["feat"][sl])
                             tab = jnp.stack([
                                 pend["thr"][sl], pend["nan"][sl],
                                 pend["dleft"][sl],
@@ -1766,9 +1768,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                                 pend["leaf"][sl], pend["newid"][sl],
                                 pend["act"][sl],
                                 jnp.zeros((W,), jnp.int32)])
-                            rl2, _ = wave_row_update_pallas(
-                                cols, rl, tab, interpret=interpret,
-                                pipeline=pipeline)
+                            rl2, _ = route_rows(X_R, pend["feat"][sl],
+                                                rl, tab)
                             rl = rl2.astype(rl_dtype)
                         return rl
 
@@ -1792,12 +1793,11 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 """(N,) int8 candidate slot whose SMALLER side each row
                 would take (-1 = none) — the splits stay uncommitted."""
                 if pallas:
-                    from ..ops.histogram_pallas import (
-                        wave_trial_channels_pallas)
-                    cols = take_cols(feat)
+                    bins, feats = router_args(X_R, feat)
                     return wave_trial_channels_pallas(
-                        cols, rl, sel_leaves, thr, fnanb, dleft, small,
-                        sel, interpret=interpret, pipeline=pipeline)
+                        bins, rl, sel_leaves, thr, fnanb, dleft, small,
+                        sel, feats=feats, interpret=interpret,
+                        pipeline=pipeline)
                 cols = jax.vmap(feature_col)(feat).astype(jnp.int32)
                 go = jnp.where(cols == fnanb[:, None], dleft[:, None],
                                cols <= thr[:, None])

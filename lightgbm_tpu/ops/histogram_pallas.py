@@ -66,6 +66,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["build_histogram_pallas", "build_histogram_pallas_leaves",
            "build_histogram_pallas_leaves_q8", "pack_weights8",
            "wave_trial_channels_pallas", "wave_row_update_pallas",
+           "bin_rows_view", "gather_bin_rows",
            "DEFAULT_ROW_BLOCK", "pad_rows", "LEAF_CHANNELS",
            "Q_LEAF_CHANNELS", "resolve_pipeline",
            "resolve_interpret", "pack_bins4", "unpack_bins4",
@@ -1150,21 +1151,64 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
 # Wave row update: one fused pass assigning rows to their post-wave leaf
 # and leaf channel.  The XLA form (learner/wave.py's W sequential masked
 # wheres) launches ~W fused loop nests over N rows — per-nest overhead
-# alone costs ~30 ms/wave at 10.5M rows.  Here the W winning feature
-# columns are gathered once (a cheap major-axis take) and ONE kernel
-# sweeps the rows, keeping rl/ch blocks VMEM-resident across the W
-# per-split updates.  Numeric splits only — the categorical membership
-# lookup is a per-row gather Mosaic cannot express; wave.py keeps the XLA
-# path when categorical features or EFB bundles are present.
+# alone costs ~30 ms/wave at 10.5M rows.  Here ONE kernel sweeps the rows,
+# keeping rl/ch blocks VMEM-resident across the W per-split updates.  The
+# ``dma`` pipeline fetches the W winning features' bin columns itself: it
+# is handed a feature-major view of the whole bin matrix
+# (:func:`bin_rows_view`) and the W feature ids (row 7 of the split table,
+# in SMEM), and copies each row block of each winning feature straight
+# from that view into VMEM — nothing of shape (W, N) is built in HBM.  A
+# caller that already holds the W columns passes them as the matrix, with
+# feature ids 0..W-1: the same kernel.  The ``blockspec`` pipeline still
+# gathers the columns in front of its kernel (:func:`gather_bin_rows`).
+# Numeric splits only — the categorical membership lookup is a per-row
+# gather Mosaic cannot express; wave.py keeps the XLA path when
+# categorical features or EFB bundles are present.
 # ---------------------------------------------------------------------------
 
+_RU_SUB = 8      # the row update lays rows out as (_RU_SUB, N // _RU_SUB)
+_RU_LANES = 512  # lanes swept at a time: rl, ch and a column in 12 vregs
+# Rows per fetched block of the dma pipeline, at most: a block costs W
+# column copies, and at 4096 rows (4 KB a copy) issuing them shows — at
+# 21.25M rows x 67, W=42 a call took 9.6 ms at 4096, 7.6 at 8192, 6.9 at
+# 16384 (PERF.md, PR 29).  The block is the largest power of two up to
+# this that divides N.
+_RU_KR = 16384
 
-def _row_update_kernel(cols_ref, rl_ref, tab_ref, rl_out, ch_out, *,
-                       w: int):
-    rl = rl_ref[...].astype(jnp.int32)            # (8, KRD)
+
+def bin_rows_view(bins_t: jnp.ndarray, pipeline: str = None) -> jnp.ndarray:
+    """What :func:`wave_row_update_pallas` fetches a tree's columns from,
+    made ONCE per tree and passed to every call with the splits' feature
+    ids.  Under the ``dma`` pipeline: (F, N) bin codes as (F, 8, N // 8),
+    so the feature is the LEADING, untiled axis and one feature's row
+    block is one contiguous strip a DMA can address by a runtime feature
+    id (on a TPU a relayout: one copy of the matrix).  The ``blockspec``
+    pipeline gathers from the matrix as it is."""
+    if resolve_pipeline(pipeline) != "dma":
+        return bins_t
+    f, n = bins_t.shape
+    return bins_t.reshape(f, _RU_SUB, n // _RU_SUB)
+
+
+def gather_bin_rows(bins_t: jnp.ndarray, feats: jnp.ndarray) -> jnp.ndarray:
+    """``bins_t[feats]`` for a static-length ``feats``, as a stack of
+    one-row dynamic slices.  A row gather (``jnp.take(bins_t, feats,
+    axis=0)``) of a (F, N) matrix costs XLA:TPU compile time in
+    proportion to N — ~35 s per gather at 10.5M rows (PERF.md, PR 21);
+    the slices move the same bytes and compile in constant time.  Only
+    the paths the fetching kernel does not serve pay for it (nibble-packed
+    bins, the ``blockspec`` pipeline)."""
+    width = bins_t.shape[1]
+    return jnp.concatenate(
+        [jax.lax.dynamic_slice(bins_t, (feats[j], 0), (1, width))
+         for j in range(feats.shape[0])], axis=0)
+
+
+def _row_update_sweep(col_of, rl, tab_ref, w: int):
+    """The W splits applied one after the other to a block of rows."""
     ch = jnp.full_like(rl, -1)
     for j in range(w):
-        col = cols_ref[j].astype(jnp.int32)       # (8, KRD)
+        col = col_of(j).astype(jnp.int32)
         thr = tab_ref[0, j]
         nanb = tab_ref[1, j]
         dlft = tab_ref[2, j]
@@ -1180,23 +1224,38 @@ def _row_update_kernel(cols_ref, rl_ref, tab_ref, rl_out, ch_out, *,
         upd = (rl == selj) & (act > 0)
         ch = jnp.where(upd & (go_left == small), j, ch)
         rl = jnp.where(upd & (go_left == 0), newid, rl)
+    return rl, ch
+
+
+def _row_update_kernel(cols_ref, rl_ref, tab_ref, rl_out, ch_out, *,
+                       w: int):
+    rl, ch = _row_update_sweep(lambda j: cols_ref[j],
+                               rl_ref[...].astype(jnp.int32), tab_ref, w)
     rl_out[...] = rl
     ch_out[...] = ch.astype(jnp.int8)
 
 
-def _row_update_kernel_dma(cols_hbm, rl_hbm, tab_ref, rl_out, ch_out, *,
+def _row_update_kernel_dma(bins_hbm, rl_hbm, tab_ref, rl_out, ch_out, *,
                            w: int, krd: int, nsteps: int):
-    """Fully manual DMA pipeline of the wave row update: the W winning
-    feature columns and the row->leaf vector stream in through
-    double-buffered async copies, the updated rl/ch blocks stream back
-    out, and the copy of block j+1 overlaps block j's W-split sweep —
-    the kernel is pure VPU work, so it is bandwidth-bound end to end."""
+    """Fully manual DMA pipeline of the wave row update: per row block,
+    W copies bring the winning features' strips ``bins_hbm[tab[7, jj], :,
+    block]`` (the feature ids ride in the split table's eighth row) and
+    one brings the row->leaf block, into double buffers; the updated
+    rl/ch blocks stream back out, and the copies of block j+1 overlap
+    block j's W-split sweep — the kernel is pure VPU work, so it is
+    bandwidth-bound end to end."""
+
+    # a block wider than _RU_LANES is swept that many lanes at a time, so
+    # rl, ch and the column stay in registers across the W splits
+    lw = min(krd, _RU_LANES)
 
     def body(cbuf, ibuf, robuf, cobuf, csem, isem, rosem, cosem):
-        def cols_dma(slot, j):
+        def col_dma(slot, j, jj):
+            # W copies share the slot's semaphore: started W times,
+            # waited W times
             return pltpu.make_async_copy(
-                cols_hbm.at[:, :, pl.ds(j * krd, krd)], cbuf.at[slot],
-                csem.at[slot])
+                bins_hbm.at[tab_ref[7, jj], :, pl.ds(j * krd, krd)],
+                cbuf.at[slot, jj], csem.at[slot])
 
         def rl_dma(slot, j):
             return pltpu.make_async_copy(
@@ -1213,35 +1272,23 @@ def _row_update_kernel_dma(cols_hbm, rl_hbm, tab_ref, rl_out, ch_out, *,
                 cobuf.at[slot], ch_out.at[:, pl.ds(j * krd, krd)],
                 cosem.at[slot])
 
-        cols_dma(0, 0).start()
-        rl_dma(0, 0).start()
+        def start_in(slot, j):
+            for jj in range(w):
+                col_dma(slot, j, jj).start()
+            rl_dma(slot, j).start()
+
+        start_in(0, 0)
 
         def step(j, carry):
             slot = j % 2
 
             @pl.when(j + 1 < nsteps)
             def _():
-                cols_dma((j + 1) % 2, j + 1).start()
-                rl_dma((j + 1) % 2, j + 1).start()
+                start_in((j + 1) % 2, j + 1)
 
-            cols_dma(slot, j).wait()
-            rl_dma(slot, j).wait()
-            rl = ibuf[slot].astype(jnp.int32)            # (8, KRD)
-            ch = jnp.full_like(rl, -1)
             for jj in range(w):
-                col = cbuf[slot, jj].astype(jnp.int32)   # (8, KRD)
-                thr = tab_ref[0, jj]
-                nanb = tab_ref[1, jj]
-                dlft = tab_ref[2, jj]
-                small = tab_ref[3, jj]
-                selj = tab_ref[4, jj]
-                newid = tab_ref[5, jj]
-                act = tab_ref[6, jj]
-                go_left = jnp.where(col == nanb, dlft,
-                                    (col <= thr).astype(jnp.int32))
-                upd = (rl == selj) & (act > 0)
-                ch = jnp.where(upd & (go_left == small), jj, ch)
-                rl = jnp.where(upd & (go_left == 0), newid, rl)
+                col_dma(slot, j, jj).wait()
+            rl_dma(slot, j).wait()
 
             # the out buffers double-buffer too: wait this slot's
             # previous write-back before overwriting it
@@ -1250,8 +1297,20 @@ def _row_update_kernel_dma(cols_hbm, rl_hbm, tab_ref, rl_out, ch_out, *,
                 ro_dma(slot, j - 2).wait()
                 co_dma(slot, j - 2).wait()
 
-            robuf[slot] = rl
-            cobuf[slot] = ch.astype(jnp.int8)
+            def sweep(c, carry):
+                lanes = (slice(None) if lw == krd else
+                         pl.ds(pl.multiple_of(c * lw, lw), lw))
+                rl, ch = _row_update_sweep(
+                    lambda jj: cbuf[slot, jj, :, lanes],
+                    ibuf[slot, :, lanes].astype(jnp.int32), tab_ref, w)
+                robuf[slot, :, lanes] = rl
+                cobuf[slot, :, lanes] = ch.astype(jnp.int8)
+                return carry
+
+            if lw == krd:
+                sweep(0, 0)
+            else:
+                jax.lax.fori_loop(0, krd // lw, sweep, 0)
             ro_dma(slot, j).start()
             co_dma(slot, j).start()
             return carry
@@ -1265,27 +1324,27 @@ def _row_update_kernel_dma(cols_hbm, rl_hbm, tab_ref, rl_out, ch_out, *,
         co_dma((nsteps - 1) % 2, nsteps - 1).wait()
 
     pl.run_scoped(body,
-                  pltpu.VMEM((2, w, 8, krd), cols_hbm.dtype),
-                  pltpu.VMEM((2, 8, krd), rl_hbm.dtype),
-                  pltpu.VMEM((2, 8, krd), jnp.int32),
-                  pltpu.VMEM((2, 8, krd), jnp.int8),
+                  pltpu.VMEM((2, w, _RU_SUB, krd), bins_hbm.dtype),
+                  pltpu.VMEM((2, _RU_SUB, krd), rl_hbm.dtype),
+                  pltpu.VMEM((2, _RU_SUB, krd), jnp.int32),
+                  pltpu.VMEM((2, _RU_SUB, krd), jnp.int8),
                   pltpu.SemaphoreType.DMA((2,)),
                   pltpu.SemaphoreType.DMA((2,)),
                   pltpu.SemaphoreType.DMA((2,)),
                   pltpu.SemaphoreType.DMA((2,)))
 
 
-@functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
-def _wave_row_update_dma(cols_w: jnp.ndarray, rl: jnp.ndarray,
-                         tab: jnp.ndarray, *,
-                         row_block: int = DEFAULT_ROW_BLOCK,
-                         interpret: bool = False):
-    w, n = cols_w.shape
-    kr = math.gcd(row_block, 4096)
-    krd = kr // 8
-    nd = n // 8
-    cols3 = cols_w.reshape(w, 8, nd)
-    rl2 = rl.astype(jnp.int32).reshape(8, nd)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _wave_row_update_dma(bins3: jnp.ndarray, rl: jnp.ndarray,
+                         tab: jnp.ndarray, *, interpret: bool = False):
+    """``bins3``: a :func:`bin_rows_view`; ``tab[7]``: W ids into its
+    leading axis."""
+    f, _, nd = bins3.shape
+    w = tab.shape[1]
+    n = nd * _RU_SUB
+    kr = math.gcd(n, _RU_KR)
+    krd = kr // _RU_SUB
+    rl2 = rl.astype(jnp.int32).reshape(_RU_SUB, nd)
     rl_new, ch = pl.pallas_call(
         functools.partial(_row_update_kernel_dma, w=w, krd=krd,
                           nsteps=n // kr),
@@ -1299,12 +1358,12 @@ def _wave_row_update_dma(cols_w: jnp.ndarray, rl: jnp.ndarray,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((8, nd), jnp.int32),
-            jax.ShapeDtypeStruct((8, nd), jnp.int8),
+            jax.ShapeDtypeStruct((_RU_SUB, nd), jnp.int32),
+            jax.ShapeDtypeStruct((_RU_SUB, nd), jnp.int8),
         ],
         interpret=interpret,
-        name=_kname("wave_row_update_dma", w=w, kr=kr, n=n),
-    )(cols3, rl2, tab)
+        name=_kname("wave_row_update_dma", w=w, f=f, kr=kr, n=n),
+    )(bins3, rl2, tab)
     return rl_new.reshape(n), ch.reshape(n)
 
 
@@ -1348,19 +1407,26 @@ def _wave_row_update_bs(cols_w: jnp.ndarray, rl: jnp.ndarray,
     return rl_new.reshape(n), ch.reshape(n)
 
 
-def wave_row_update_pallas(cols_w: jnp.ndarray, rl: jnp.ndarray,
-                           tab: jnp.ndarray, *,
+def wave_row_update_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
+                           tab: jnp.ndarray, *, feats: jnp.ndarray = None,
                            row_block: int = DEFAULT_ROW_BLOCK,
                            interpret: bool = None, pipeline: str = None):
     """Apply a wave's W numeric splits to every row in one fused pass.
 
     Args:
-      cols_w: (W, N) uint8 — the wave's winning feature columns
-        (``jnp.take(X_T, feat, axis=0)``), N a multiple of ``row_block``.
+      bins: where the splits' bin columns come from, N a multiple of
+        ``row_block``.  Without ``feats``: (W, N) uint8, the W winning
+        feature columns themselves, in the splits' order.  With ``feats``:
+        the feature-major bin matrix, as (F, N) uint8 or (the form to make
+        once and pass to every call of a tree) its :func:`bin_rows_view`
+        (F, 8, N // 8); the ``dma`` kernel then fetches the W rows it
+        needs itself and no (W, N) array exists.
       rl: (N,) integer row->leaf vector (any integer dtype).
       tab: (8, W) int32 per-split table: rows are [threshold_bin,
         nan_bin (-1 = none), default_left, left_is_smaller, split_leaf,
-        new_right_id, active, unused].
+        new_right_id, active, (overwritten: the feature ids)].
+      feats: (W,) integer feature id of each split (clipped to [0, F)),
+        or None when ``bins`` holds the columns.
       interpret / pipeline: as :func:`build_histogram_pallas` ("dma"
         streams the column blocks AND the rl/ch write-backs through
         double-buffered async copies).
@@ -1368,36 +1434,52 @@ def wave_row_update_pallas(cols_w: jnp.ndarray, rl: jnp.ndarray,
       (rl_new int32 (N,), ch int8 (N,)) — post-wave leaf ids and the
       smaller-child channel (-1 = row not in any split's smaller child).
     """
-    w, n = cols_w.shape
+    w = tab.shape[1]
+    fetch = feats is not None
+    f = bins.shape[0]
+    n = math.prod(bins.shape[1:])
+    if not fetch and f != w:
+        raise ValueError(f"wave_row_update_pallas: {f} columns for {w} "
+                         f"splits (pass feats= with a bin matrix)")
     _check_rows(n, row_block, "wave_row_update_pallas")
     _check_same_rows("wave_row_update_pallas", n, rl=rl.shape[0])
     pipeline = resolve_pipeline(pipeline)
     interpret = resolve_interpret(interpret)
-    _note_kernel(f"ops/hist_kernel/row_update/{pipeline}",
-                 w * n * cols_w.dtype.itemsize + n * 4 + n * 5)
+    _note_kernel(f"ops/hist_kernel/row_update/{pipeline}"
+                 + ("/fetch" if fetch and pipeline == "dma" else ""),
+                 w * n * bins.dtype.itemsize + n * 4 + n * 5)
+    if fetch:
+        feats = jnp.clip(feats.astype(jnp.int32), 0, f - 1)
     if pipeline == "dma":
-        return _wave_row_update_dma(cols_w, rl, tab, row_block=row_block,
+        if bins.ndim == 2:
+            bins = bin_rows_view(bins, pipeline)
+        ids = feats if fetch else jnp.arange(w, dtype=jnp.int32)
+        return _wave_row_update_dma(bins, rl, tab.at[7].set(ids),
                                     interpret=interpret)
-    return _wave_row_update_bs(cols_w, rl, tab, row_block=row_block,
+    if fetch:
+        bins = gather_bin_rows(bins.reshape(f, n), feats)
+    return _wave_row_update_bs(bins, rl, tab, row_block=row_block,
                                interpret=interpret)
 
 
-def wave_trial_channels_pallas(cols_w: jnp.ndarray, rl: jnp.ndarray,
+def wave_trial_channels_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
                                sel_leaves: jnp.ndarray, thr: jnp.ndarray,
                                nan_bin: jnp.ndarray, default_left: jnp.ndarray,
                                left_smaller: jnp.ndarray, active: jnp.ndarray,
-                               *, row_block: int = DEFAULT_ROW_BLOCK,
+                               *, feats: jnp.ndarray = None,
+                               row_block: int = DEFAULT_ROW_BLOCK,
                                interpret: bool = None,
                                pipeline: str = None) -> jnp.ndarray:
     """TRIAL leaf-channel assignment for W *candidate* splits.
 
-    Same fused kernel as :func:`wave_row_update_pallas`, but the splits are
-    NOT committed: each candidate's ``new_right_id`` is set to its own
-    split leaf, so ``rl`` is provably unchanged and only the smaller-child
-    channel vector comes back.  The wave grower's exact endgame uses this
-    to precompute the frontier candidates' smaller-child histograms in one
-    batched pass before the sequential best-first selection commits any of
-    them (learner/wave.py).
+    Same fused kernel as :func:`wave_row_update_pallas` (``bins`` /
+    ``feats`` as there), but the splits are NOT committed: each
+    candidate's ``new_right_id`` is set to its own split leaf, so ``rl``
+    is provably unchanged and only the smaller-child channel vector comes
+    back.  The wave grower's exact endgame uses this to precompute the
+    frontier candidates' smaller-child histograms in one batched pass
+    before the sequential best-first selection commits any of them
+    (learner/wave.py).
 
     Returns ``ch`` int8 (N,): the candidate slot whose smaller side the
     row would take, or -1.
@@ -1405,6 +1487,7 @@ def wave_trial_channels_pallas(cols_w: jnp.ndarray, rl: jnp.ndarray,
     tab = jnp.stack([thr, nan_bin, default_left.astype(jnp.int32),
                      left_smaller.astype(jnp.int32), sel_leaves, sel_leaves,
                      active.astype(jnp.int32), jnp.zeros_like(thr)])
-    _, ch = wave_row_update_pallas(cols_w, rl, tab, row_block=row_block,
+    _, ch = wave_row_update_pallas(bins, rl, tab, feats=feats,
+                                   row_block=row_block,
                                    interpret=interpret, pipeline=pipeline)
     return ch

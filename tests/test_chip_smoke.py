@@ -29,11 +29,15 @@ def tiny_data():
     return chip_smoke.bin_data(X, y), (Xh, yh)
 
 
-def test_kernel_selfcheck_interpreted():
-    out = chip_smoke.kernel_selfcheck(4096, features=4, interpret=True)
+@pytest.mark.parametrize("pipeline", [None, "dma"])
+def test_kernel_selfcheck_interpreted(pipeline):
+    out = chip_smoke.kernel_selfcheck(4096, features=4, interpret=True,
+                                      pipeline=pipeline)
     assert out["leaves_q8_b256_abs"] == 0
     assert out["leaves_q8_b16_packed4_abs"] == 0
     assert out["row_update_mismatches"] == 0
+    assert out["row_update_fetch_mismatches"] == 0
+    assert out["trial_channels_fetch_mismatches"] == 0
     assert {k for k in out if k.endswith("_rel")} == {
         "single_b256_rel", "leaves_b256_rel", "single_b16_packed4_rel",
         "leaves_b16_packed4_rel", "single_refit_rel"}

@@ -20,8 +20,8 @@ from lightgbm_tpu.ops.histogram import build_histogram, build_histogram_leaves
 from lightgbm_tpu.ops.histogram_pallas import (
     LEAF_CHANNELS, Q_LEAF_CHANNELS, _make_w128_bf16, build_histogram_pallas,
     build_histogram_pallas_leaves, build_histogram_pallas_leaves_q8,
-    pack_bins4, pack_weights8, pad_rows, traced_kernels, unpack_bins4,
-    wave_row_update_pallas, wave_trial_channels_pallas)
+    bin_rows_view, pack_bins4, pack_weights8, pad_rows, traced_kernels,
+    unpack_bins4, wave_row_update_pallas, wave_trial_channels_pallas)
 
 N, F = 4096, 5  # one exact row block — the boundary shape
 
@@ -300,26 +300,104 @@ def test_leaves_kernels_bad_rows_raise():
 
 # -- row-update / trial-channel kernel ---------------------------------------
 
-def test_row_update_dma_bitwise_and_trial():
-    bins, *_ = _data(B=16, f=6)
+def _row_update_plain(cols, rl, tab):
+    """The W splits applied one after the other in plain XLA (the
+    reference ``chip_smoke.py`` holds the kernel to on the chip)."""
+    from chip_smoke import _row_update_reference
+    rl_new, ch = _row_update_reference(*map(jnp.asarray, (cols, rl, tab)))
+    return np.asarray(rl_new), np.asarray(ch)
+
+
+def _feature_ids(form, f, w, rng):
+    if form == "shuffled":
+        return rng.permutation(f)[np.arange(w) % f]
+    if form == "repeated":
+        return np.resize(rng.randint(0, f, 2), w)
+    return rng.randint(-3, f + 4, w)            # clipped: ids off both ends
+
+
+# row blocks of 4096 rows (one, and three) and of 16384 (two, each swept
+# in four strips of lanes)
+@pytest.mark.parametrize("n", [4096, 3 * 4096, 2 * 16384])
+@pytest.mark.parametrize("inactive", [False, True])
+@pytest.mark.parametrize("form,f", [
+    ("cols", 6), ("shuffled", 67), ("repeated", 67), ("clipped", 67),
+    ("shuffled", 8), ("repeated", 8), ("clipped", 8)])
+def test_row_update_dma_bitwise_and_trial(form, f, inactive, n):
+    """The ``dma`` row update, handed the W columns (``cols``) or the bin
+    matrix and W feature ids it fetches by itself: ``rl`` and ``ch``
+    bitwise equal to the splits applied one by one in numpy, to the
+    ``blockspec`` kernel and (fetch forms) to the ``cols`` form on the
+    gathered columns; the trial form leaves ``rl`` as it was."""
     rng = np.random.RandomState(3)
-    W = 4
-    cols_w = jnp.asarray(bins.T[:W].copy())
-    rl = jnp.asarray(rng.randint(0, 3, N).astype(np.int32))
-    tab = jnp.asarray(np.stack([
-        rng.randint(0, 16, W), np.full(W, -1), rng.randint(0, 2, W),
-        rng.randint(0, 2, W), rng.randint(0, 3, W), np.arange(3, 3 + W),
-        np.ones(W, int), np.zeros(W, int)]).astype(np.int32))
-    rb, cb = wave_row_update_pallas(cols_w, rl, tab, pipeline="blockspec")
-    rd, cd = wave_row_update_pallas(cols_w, rl, tab, pipeline="dma")
-    np.testing.assert_array_equal(np.asarray(rb), np.asarray(rd))
-    np.testing.assert_array_equal(np.asarray(cb), np.asarray(cd))
-    # trial form commits nothing
-    sel_leaves = tab[4]
+    W = 5
+    bins = rng.randint(0, 16, (f, n)).astype(np.uint8)
+    feats = np.arange(W) if form == "cols" else _feature_ids(form, f, W, rng)
+    cols = bins[np.clip(feats, 0, f - 1)]
+    rl = rng.randint(0, 3, n).astype(np.int32)
+    act = (rng.rand(W) < 0.5) if inactive else np.ones(W, bool)
+    tab = np.stack([
+        rng.randint(0, 16, W), np.where(rng.rand(W) < 0.5, 15, -1),
+        rng.randint(0, 2, W), rng.randint(0, 2, W), rng.randint(0, 3, W),
+        np.arange(3, 3 + W), act, rng.randint(0, 99, W)]).astype(np.int32)
+    want_rl, want_ch = _row_update_plain(cols, rl, tab)
+
+    rl_d, tab_d = jnp.asarray(rl), jnp.asarray(tab)
+    if form == "cols":
+        kw, src = {}, jnp.asarray(cols)
+    else:
+        kw = {"feats": jnp.asarray(feats.astype(np.int32))}
+        # as the grower passes it: the view made once, ahead of the calls
+        src = bin_rows_view(jnp.asarray(bins), "dma")
+        assert src.shape == (f, 8, n // 8)
+    rd, cd = wave_row_update_pallas(src, rl_d, tab_d, pipeline="dma", **kw)
+    np.testing.assert_array_equal(np.asarray(rd), want_rl)
+    np.testing.assert_array_equal(np.asarray(cd), want_ch)
+    # the same kernel from the columns, and the blockspec kernel (which
+    # gathers the columns in front of itself)
+    rc, cc = wave_row_update_pallas(jnp.asarray(cols), rl_d, tab_d,
+                                    pipeline="dma")
+    rb, cb = wave_row_update_pallas(
+        jnp.asarray(cols if form == "cols" else bins), rl_d, tab_d,
+        pipeline="blockspec", **kw)
+    for got_rl, got_ch in ((rc, cc), (rb, cb)):
+        np.testing.assert_array_equal(np.asarray(got_rl), np.asarray(rd))
+        np.testing.assert_array_equal(np.asarray(got_ch), np.asarray(cd))
+    # trial form commits nothing: new_right_id = the split leaf itself
+    trial = tab.copy()
+    trial[5] = trial[4]
+    rt, _ = wave_row_update_pallas(src, rl_d, jnp.asarray(trial),
+                                   pipeline="dma", **kw)
+    np.testing.assert_array_equal(np.asarray(rt), rl)
     ch = wave_trial_channels_pallas(
-        cols_w, rl, sel_leaves, tab[0], tab[1], tab[2] > 0, tab[3],
-        tab[6] > 0, pipeline="dma")
-    assert ch.shape == (N,)
+        src, rl_d, tab_d[4], tab_d[0], tab_d[1], tab_d[2] > 0, tab_d[3],
+        tab_d[6] > 0, pipeline="dma", **kw)
+    np.testing.assert_array_equal(
+        np.asarray(ch), _row_update_plain(cols, rl, trial)[1])
+
+
+def test_row_update_sites_say_who_fetched():
+    """``TrainRecord``'s kernel sites tell the two forms apart, with the
+    bytes the kernel reads (W x N + 9 N) either way."""
+    from lightgbm_tpu.telemetry.train_record import (hist_kernel_reset,
+                                                     hist_kernel_snapshot)
+    n, f, W = 4096, 8, 3
+    bins = jnp.zeros((f, n), jnp.uint8)
+    rl = jnp.zeros((n,), jnp.int32)
+    tab = jnp.zeros((8, W), jnp.int32)
+    hist_kernel_reset()
+    wave_row_update_pallas(bins[:W], rl, tab, pipeline="dma")
+    wave_row_update_pallas(bins, rl, tab, feats=jnp.arange(W),
+                           pipeline="dma")
+    wave_row_update_pallas(bins, rl, tab, feats=jnp.arange(W),
+                           pipeline="blockspec")
+    sites = hist_kernel_snapshot()
+    want = {"count": 1, "bytes": W * n + 9 * n}
+    assert sites["ops/hist_kernel/row_update/dma"] == want
+    assert sites["ops/hist_kernel/row_update/dma/fetch"] == want
+    assert sites["ops/hist_kernel/row_update/blockspec"] == want
+    with pytest.raises(ValueError, match="feats="):
+        wave_row_update_pallas(bins, rl, tab, pipeline="dma")
 
 
 # -- vmap-to-grid batching rule (the multitrain unlock) ----------------------
@@ -500,3 +578,86 @@ def test_dataset_device_bins_packed4():
     if int(np.max(ds255.num_bins_per_feature)) > 16:
         with pytest.raises(ValueError, match="max_bin"):
             ds255.device_bins_packed4()
+
+
+# -- the grower hands the row update the bin matrix, not columns --------------
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters
+    (pjit, while, cond branches; a pallas_call's kernel too)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("quantized", [True, False], ids=["q8", "exact"])
+def test_grower_builds_no_columns_for_the_row_update(quantized):
+    """Ramp, waves, endgame flush and trial channels all route rows
+    through the fetching kernel: the jitted grower slices no row out of
+    the bin matrix (or the ramp's subsample of it) and concatenates no
+    (W, N) block, and grows the tree the ``blockspec`` pipeline (which
+    gathers columns in front of its kernel) grows, bit for bit.  Exact
+    arithmetic at wave size 1 is also the partitioned grower's tree."""
+    from lightgbm_tpu.learner.wave import make_wave_grow_fn
+    from lightgbm_tpu.ops.split import SplitParams
+    f, b, n, w = 6, 64, 2 * 4096, 4
+    rng = np.random.RandomState(0)
+    bins = rng.randint(0, b - 1, (f, n)).astype(np.uint8)
+    args = (jnp.asarray(bins), jnp.asarray(rng.randn(n).astype(np.float32)),
+            jnp.full((n,), 0.25, jnp.float32), jnp.ones((n,), jnp.float32),
+            jnp.full((f,), b, jnp.int32), jnp.zeros((f,), bool),
+            jnp.zeros((f,), bool), jnp.zeros((f,), jnp.int32),
+            jnp.zeros((f,), jnp.float32), (), jnp.ones((f,), bool))
+    sp = SplitParams(min_data_in_leaf=5, min_sum_hessian_in_leaf=0.0,
+                     any_cat=False)
+
+    def grower(pipeline):
+        return make_wave_grow_fn(
+            num_leaves=13, num_features=f, max_bins=b, max_depth=0,
+            split_params=sp, hist_impl="pallas", any_cat=False,
+            interpret=True, jit=True, wave_size=w, stochastic=False,
+            exact_endgame=True, spec_ramp=True, quantized=quantized,
+            renew_leaf=quantized, pipeline=pipeline)
+
+    grow = grower("dma")
+    sliced, stacked, kernels = [], [], set()
+    for eqn in _eqns(jax.make_jaxpr(grow)(*args).jaxpr):
+        name = eqn.primitive.name
+        if name in ("dynamic_slice", "gather") and \
+                eqn.invars[0].aval.shape[0] == f and \
+                eqn.invars[0].aval.dtype == jnp.uint8:
+            sliced.append(eqn)
+        if name == "concatenate" and eqn.outvars[0].aval.shape == (w, n):
+            stacked.append(eqn)
+        if name == "pallas_call":
+            kernels.add(eqn.params["name"])
+    assert not sliced, sliced
+    assert not stacked, stacked
+    assert f"lgbm_wave_row_update_dma_w{w}_f{f}_kr{n}_n{n}" in kernels
+
+    got, want = grow(*args), grower("blockspec")(*args)
+    assert int(got.num_leaves) == 13 and int(got.endgame_passes) > 0 \
+        and int(got.ramp_committed) > 0
+    for name, a, c in zip(got._fields, got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c),
+                                      err_msg=name)
+
+    if not quantized:
+        import lightgbm_tpu as lgb
+        X = rng.randn(4000, 8).astype(np.float32)
+        X[rng.rand(4000, 8) < 0.05] = np.nan
+        y = ((np.nan_to_num(X) @ rng.randn(8) + 0.5 * rng.randn(4000)) > 0
+             ).astype(np.float64)
+        p = {"objective": "binary", "num_leaves": 31, "max_bin": 63,
+             "learning_rate": 0.2, "verbosity": -1, "min_data_in_leaf": 20}
+        part = lgb.train(dict(p, tree_grow_mode="partition"),
+                         lgb.Dataset(X, y), num_boost_round=4).predict(X)
+        wave = lgb.train(dict(p, tree_grow_mode="wave", tpu_wave_size=1,
+                              tpu_histogram_impl="pallas",
+                              tpu_pallas_pipeline="dma"),
+                         lgb.Dataset(X, y), num_boost_round=4).predict(X)
+        np.testing.assert_allclose(wave, part, atol=2e-4)

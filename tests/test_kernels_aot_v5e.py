@@ -18,8 +18,9 @@ import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.ops.histogram_pallas import (
-    build_histogram_pallas_leaves, build_histogram_pallas_leaves_q8,
-    traced_kernels)
+    bin_rows_view, build_histogram_pallas_leaves,
+    build_histogram_pallas_leaves_q8, traced_kernels,
+    wave_row_update_pallas)
 
 # the cells of BENCHMARK.json: 21.25M rows (padded to the row block) x 67
 F, N, MAX_BIN = 67, 21_250_048, 255
@@ -50,8 +51,15 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
+@pytest.fixture(scope="module")
+def S(one_chip):
+    """``S(shape, dtype)``: an argument's shape on the described chip."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
 @pytest.mark.parametrize("kind", ["q8", "bf16"])
-def test_leaf_dma_kernel_compiles_at_cell_shape(one_chip, kind):
+def test_leaf_dma_kernel_compiles_at_cell_shape(S, kind):
     if kind == "q8":
         build, wdt, cdt = build_histogram_pallas_leaves_q8, jnp.int8, jnp.int8
         name = f"lgbm_hist_leaves_q8_dma_f96_fc72_b256_g8_kr4096_n{N}"
@@ -60,12 +68,30 @@ def test_leaf_dma_kernel_compiles_at_cell_shape(one_chip, kind):
                            jnp.int32)
         name = f"lgbm_hist_leaves_dma_f96_fc72_b256_g4_kr4096_n{N}"
 
-    def S(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
     compiled = jax.jit(
         lambda bins, w, ch: build(bins, w, ch, num_bins=MAX_BIN,
                                   pipeline="dma", interpret=False)
     ).lower(S((F, N), jnp.uint8), S((8, N), wdt), S((N,), cdt)).compile()
     assert "tpu_custom_call" in compiled.as_text()  # Mosaic took the kernel
     assert name in traced_kernels()
+
+
+@pytest.mark.parametrize("w", [42, 25])       # q8's and exact's wave widths
+def test_row_update_fetch_kernel_compiles_at_cell_shape(S, w):
+    """The row update as the grower calls it: the (F, 8, N/8) view of the
+    bin matrix and W feature ids in, W column copies a row block."""
+    def route(bins, feats, rl, tab):
+        return wave_row_update_pallas(
+            bin_rows_view(bins, "dma"), rl, tab, feats=feats,
+            pipeline="dma", interpret=False)
+
+    compiled = jax.jit(route).lower(
+        S((F, N), jnp.uint8), S((w,), jnp.int32), S((N,), jnp.uint8),
+        S((8, w), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"lgbm_wave_row_update_dma_w{w}_f{F}_kr16384_n{N}" in \
+        traced_kernels()
+    # nothing of W columns is built in front of the kernel
+    assert "concatenate" not in text
+    assert f"u8[{F},8,{N // 8}]" in text
