@@ -157,14 +157,8 @@ def _serial_entry(grow):
 
 
 def _dp_entry(grow, mesh, ax):
-    import jax
-    from jax.sharding import PartitionSpec as P
-    from ..parallel.data_parallel import DataParallelTreeLearner
-    return jax.jit(jax.shard_map(
-        _serial_entry(grow), mesh=mesh,
-        in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P(), P(), P(),
-                  P(), P()),
-        out_specs=DataParallelTreeLearner._tree_specs(ax), check_vma=False))
+    from ..parallel.mesh import shard_wave_grower
+    return shard_wave_grower(_serial_entry(grow), mesh, ax)
 
 
 def _trace_with_tally(fn, args) -> Tuple[Any, Dict[str, Dict[str, Any]]]:

@@ -246,8 +246,8 @@ class DtypeRule(Rule):
 class ConstantFoldRule(Rule):
     """Closed-over constants / literal operands above a size threshold.
 
-    The MULTICHIP_r05 stall class: XLA constant-folds ops over large
-    literal operands at compile time (%reduce.227 spent >2s folding an
+    The compile-stall class: XLA constant-folds ops over large literal
+    operands at compile time (an 8-device dry run spent >2s folding one
     argmax over an all-False constant); a big constant baked into the
     program is also re-shipped with every executable.  Threshold in
     elements via ``ctx['const_fold_max_elems']`` (default 2**16)."""

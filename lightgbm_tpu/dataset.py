@@ -888,8 +888,8 @@ class Dataset:
         self._check_constructed()
         import numpy as _np
         import jax.numpy as jnp
-        from .ops.histogram_pallas import (PACK4_MAX_BINS, pack_bins4,
-                                           pad_rows)
+        from .learner.serial import feature_major_bins
+        from .ops.histogram_pallas import PACK4_MAX_BINS
         key = ("bins_packed4", row_block)
         if key not in self._device_cache:
             max_b = int(_np.max(self.num_bins_per_feature))
@@ -898,9 +898,6 @@ class Dataset:
                     f"device_bins_packed4 requires every feature to fit "
                     f"{PACK4_MAX_BINS} bins (max is {max_b}); set "
                     f"max_bin<={PACK4_MAX_BINS}")
-            n = self.X_binned.shape[0]
-            n_pad = pad_rows(n, row_block)
-            xp = _np.pad(self.X_binned, ((0, n_pad - n), (0, 0)))
-            self._device_cache[key] = pack_bins4(
-                jnp.asarray(_np.ascontiguousarray(xp.T), jnp.uint8))
+            self._device_cache[key] = feature_major_bins(
+                jnp.asarray(self.X_binned), row_block, pack4=True)
         return self._device_cache[key]

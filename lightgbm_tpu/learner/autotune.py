@@ -116,14 +116,11 @@ def _make_runner(impl: str, X_binned: np.ndarray, max_bins: int):
     mask = jnp.ones((n,), jnp.float32)
     base, _, variant = impl.partition(":")
     if base == "pallas":
-        from ..ops.histogram_pallas import (build_histogram_pallas,
-                                            pack_bins4, pad_rows)
+        from ..ops.histogram_pallas import build_histogram_pallas, pad_rows
+        from .serial import feature_major_bins
         n_pad = pad_rows(n)
-        bins_t = jnp.asarray(
-            np.pad(X_binned, ((0, n_pad - n), (0, 0))).T.copy())
         packed = variant == "packed4"
-        if packed:
-            bins_t = pack_bins4(bins_t.astype(jnp.uint8))
+        bins_t = feature_major_bins(jnp.asarray(X_binned), n_pad, packed)
         pipeline = "blockspec" if variant == "blockspec" else "dma"
         gp = jnp.pad(grad, (0, n_pad - n))
         hp = jnp.pad(hess, (0, n_pad - n))

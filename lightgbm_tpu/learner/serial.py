@@ -693,17 +693,166 @@ def _cache_hit(key):
     return fn
 
 
-class SerialTreeLearner:
-    """Host-side wrapper: owns the jitted grower and the dataset's static
-    feature descriptors (reference tree_learner.h:27 ``TreeLearner``)."""
+def full_space_split_params(config: Config, num_bins, is_cat) -> SplitParams:
+    """``split_params_from_config`` for a grower whose scans and row
+    updates run in the FULL feature space (serial; the wave grower under
+    any strategy): the static cat-column positions ride along (they
+    bound the subset search's argsort and enable the embedding-style
+    membership lookup)."""
+    sp = split_params_from_config(config, num_bins, is_cat)
+    cat = np.where(np.asarray(is_cat))[0]
+    return sp._replace(cat_idx=tuple(int(j) for j in cat)) if len(cat) \
+        else sp
+
+
+def wave_grow_kwargs(config: Config, num_features: int, max_bins: int,
+                     num_bins, is_cat, hist_impl: str, *, efb_dims=None,
+                     forced_splits: tuple = (),
+                     interaction_groups: tuple = (),
+                     feature_contri: tuple = (), cegb_lazy: tuple = (),
+                     strategy=None) -> dict:
+    """THE translation ``Config`` -> keyword arguments of
+    ``learner/wave.py make_wave_grow_fn`` (all but ``jit``), for every
+    learner that grows with it."""
+    from ..ops.histogram_pallas import PACK4_MAX_BINS
+    from ..ops.quantize import quant_levels
+    any_cat = bool(np.any(np.asarray(is_cat)))
+    sp = full_space_split_params(config, num_bins, is_cat)
+    # kernel-v2 knobs: the DMA/blockspec pipeline choice and the 4-bit
+    # packed bin layout (two codes per int8 lane when every feature fits
+    # a nibble, reference dense_bin.hpp's 4-bit bins).  pack4 exists only
+    # on the DMA pipeline: an explicit blockspec request (the
+    # measured-dead-ends A/B knob) must actually run the v1 layout, so it
+    # disables packing
+    pipeline = (None if config.tpu_pallas_pipeline == "auto"
+                else str(config.tpu_pallas_pipeline))
+    pack4 = bool(config.tpu_hist_pack4 and hist_impl == "pallas" and
+                 max_bins <= PACK4_MAX_BINS and not any_cat and
+                 efb_dims is None and pipeline != "blockspec")
+    if strategy is not None:
+        # inherited, not chosen: the mesh wrappers never handed these two
+        # on, so a mesh ignores tpu_hist_pack4 / tpu_pallas_pipeline
+        # (ROADMAP D2: honour or raise)
+        pack4, pipeline = False, None
+    gq_max, hq_max = quant_levels(int(config.num_grad_quant_bins))
+    return dict(
+        num_leaves=int(config.num_leaves), num_features=num_features,
+        max_bins=int(max_bins), max_depth=int(config.max_depth),
+        split_params=sp, hist_impl=hist_impl, any_cat=any_cat,
+        wave_size=int(config.tpu_wave_size), pack4=pack4, pipeline=pipeline,
+        efb_dims=efb_dims,
+        feature_contri=tuple(float(v) for v in feature_contri),
+        strategy=strategy, quantized=bool(config.use_quantized_grad),
+        gq_max=gq_max, hq_max=hq_max,
+        renew_leaf=bool(config.quant_train_renew_leaf),
+        stochastic=bool(config.stochastic_rounding),
+        interaction_groups=tuple(tuple(g) for g in interaction_groups),
+        cegb_lazy=tuple(float(v) for v in cegb_lazy),
+        spec_ramp=bool(config.tpu_speculative_ramp),
+        spec_tol=float(config.tpu_spec_tolerance),
+        forced_splits=tuple(tuple(f) for f in forced_splits),
+        mc_inter=resolve_monotone_method(config, sp.use_monotone, wave=True),
+        exact_endgame=bool(config.tpu_exact_endgame))
+
+
+# grower arguments that leave the traced function alone in exact mode
+_QUANT_ONLY = ("gq_max", "hq_max", "renew_leaf", "stochastic")
+
+
+def feature_major_bins(X: jnp.ndarray, quantum: int,
+                       pack4: bool = False) -> jnp.ndarray:
+    """THE device layout of the bins the wave grower reads: rows padded
+    up to a multiple of ``quantum``, then feature-major ``(F, N)``; under
+    ``pack4`` nibble-packed to ``(F, N/2)`` (two 4-bit codes per int8
+    lane).  Only this copy is consumed, so the padded row-major matrix is
+    not kept alive next to it in HBM."""
+    pad = (-X.shape[0]) % quantum
+    Xp = jnp.pad(X, ((0, pad), (0, 0))) if pad else X
+    xt = jnp.asarray(jnp.swapaxes(Xp, 0, 1))
+    if pack4:
+        from ..ops.histogram_pallas import pack_bins4
+        xt = pack_bins4(xt.astype(jnp.uint8))
+    return xt
+
+
+class WaveTreeLearner:
+    """The one host-side owner of the wave grower (learner/wave.py): the
+    translation ``Config`` -> grower arguments (:func:`wave_grow_kwargs`),
+    the device layout of the bins (:meth:`bind`) and the call convention
+    of ``grow`` (:meth:`train`).
+
+    ``mesh=None`` is one device: the grower is the jitted product cached
+    in ``_GROW_FN_CACHE``.  With a ``mesh`` and its ``strategy``
+    (``WaveDPStrategy`` / ``WaveVotingStrategy``) the rows are sharded
+    over it and the grower runs under ``shard_map``
+    (``shard_wave_grower``).  The serial, data-parallel and voting
+    learners subclass it; each calls this ``__init__`` only if it grows
+    by waves and keeps its other growers behind ``_train_other``."""
+
+    wave = False            # the wave route was taken (__init__ ran)
+    # what models/gbdt.py reads off any learner; set here and in
+    # __init__ for the wave route, overridden where a subclass differs
+    mesh = None             # the mesh the rows are sharded over, if any
+    rows_sharded = False    # gbdt places per-row arrays on ``mesh``
+    supports_extras = True  # train() takes cegb_penalty / node_key
+    quantized = False       # train() wants a per-tree quant_key
 
     def __init__(self, config: Config, num_features: int, max_bins: int,
-                 num_bins: np.ndarray, is_cat: np.ndarray, has_nan: np.ndarray,
-                 monotone: Optional[np.ndarray] = None,
-                 forced_splits: tuple = (), efb=None,
-                 interaction_groups: tuple = (),
-                 feature_contri: tuple = (), cegb_lazy: tuple = ()):
+                 num_bins: np.ndarray, is_cat: np.ndarray,
+                 has_nan: np.ndarray, monotone: Optional[np.ndarray] = None,
+                 *, hist_impl: str, efb=None, forced_splits: tuple = (),
+                 interaction_groups: tuple = (), feature_contri: tuple = (),
+                 cegb_lazy: tuple = (), mesh=None, strategy=None):
+        self._describe(config, num_features, max_bins, num_bins, is_cat,
+                       has_nan, monotone, efb, mesh)
+        self.wave = True
+        self.pallas = hist_impl == "pallas"
+        from .wave import make_wave_grow_fn
+        self._grow_factory = make_wave_grow_fn
+        kw = self._grow_kwargs = wave_grow_kwargs(
+            config, num_features, self.max_bins, num_bins, is_cat, hist_impl,
+            efb_dims=self._efb_dims, forced_splits=forced_splits,
+            interaction_groups=interaction_groups,
+            feature_contri=feature_contri, cegb_lazy=cegb_lazy,
+            strategy=strategy)
+        self.split_params = sp = kw["split_params"]
+        self.quantized = kw["quantized"]
+        self.pack4 = kw["pack4"]
+        # the optional operands of ``grow``, in their ONE order; which of
+        # them ride along is static configuration
+        self._use_lazy = bool(kw["cegb_lazy"])
+        self._key_names = tuple(name for name, on in (
+            ("quant_key", self.quantized),
+            ("node_key", sp.feature_fraction_bynode < 1.0 or sp.extra_trees),
+            ("lazy_used", self._use_lazy)) if on)
+        self._lazy_used = None
+        self._quant_calls = 0
+        if mesh is None:
+            # in exact mode the quant params don't affect the traced fn,
+            # so they leave the key and sweeps over them don't recompile
+            self._grow = self._cached_grow_fn(
+                "wave", () if self.quantized else _QUANT_ONLY)
+            return
+        from ..parallel.mesh import shard_wave_grower
+        grow_w = self.build_grow_fn(jit=False)
+        names = self._key_names
+
+        def grow(X_T, g, h, m, nb, ic, hn, mono, fm, cegb, *keys):
+            return grow_w(X_T, g, h, m, nb, ic, hn, mono, cegb, (), fm,
+                          **dict(zip(names, keys)))
+
+        self._grow = shard_wave_grower(
+            grow, mesh, self.axis, lazy=self._use_lazy,
+            n_keys=len(names) - int(self._use_lazy))
+
+    def _describe(self, config, num_features, max_bins, num_bins, is_cat,
+                  has_nan, monotone, efb=None, mesh=None):
+        """The dataset's static feature descriptors, as every grower of
+        this learner is handed them, and the mesh it runs over."""
         self.config = config
+        self.mesh = mesh
+        self.ndev = 1 if mesh is None else mesh.devices.size
+        self.axis = None if mesh is None else mesh.axis_names[0]
         self.efb = efb
         if efb is not None:
             self._efb_args = (jnp.asarray(efb.exp_map),
@@ -717,167 +866,15 @@ class SerialTreeLearner:
             self._efb_args = ()
             self._efb_dims = None
         self.max_bins = int(max_bins)
+        self.num_features = num_features
         self.num_bins = jnp.asarray(num_bins, jnp.int32)
         self.is_cat = jnp.asarray(is_cat, jnp.bool_)
         self.has_nan = jnp.asarray(has_nan, jnp.bool_)
         self.monotone = jnp.asarray(
             monotone if monotone is not None else np.zeros(num_features),
             jnp.int32)
-        self.num_features = num_features
-        self.split_params = split_params_from_config(config, num_bins, is_cat)
-        if np.any(np.asarray(is_cat)):
-            # serial scans + the wave row update run in FULL feature
-            # space: record the static cat-column positions (bounds the
-            # subset search's argsort and enables the embedding-style
-            # membership lookup)
-            self.split_params = self.split_params._replace(
-                cat_idx=tuple(int(j) for j in
-                              np.where(np.asarray(is_cat))[0]))
-        pool_f, pool_b = (self._efb_dims if self._efb_dims is not None
-                          else (num_features, self.max_bins))
-        self.use_hist_pool = hist_pool_fits(config, pool_f, pool_b)
-        if efb is not None and not self.use_hist_pool:
-            raise ValueError("EFB requires the partitioned grower; raise "
-                             "histogram_pool_size or disable enable_bundle")
-        impl = resolve_hist_impl(config, max_bins=self.max_bins)
-        if impl == "packed4" and efb is not None:
-            # EFB histograms run in BUNDLE space whose bin count can
-            # exceed the 4-bit range even when every feature fits it
-            impl = "segment"
-        if not self.use_hist_pool and impl == "pallas":
-            # the pool-less fallback grower takes no transposed X and no row
-            # padding — downgrade to the XLA onehot formulation (same MXU
-            # math, without the VMEM layout contract)
-            impl = "onehot"
-        self.pallas = impl == "pallas"
         self._x_src = None
-        self.setup_seconds = {}   # "layout": see train()
-        # The partition-ordered grower (learner/partitioned.py) is the
-        # exact sequential serial path — no full-N work per split.  The
-        # wave grower (learner/wave.py) trades row movement for MXU
-        # leaf-batched histogram passes and wins on TPU.  The masked
-        # grower below remains for the pool-less huge-feature fallback and
-        # as the shared body of the parallel strategies.
-        self.partitioned = self.use_hist_pool
-        forced_splits = tuple(tuple(f) for f in forced_splits)
-        interaction_groups = tuple(tuple(g) for g in interaction_groups)
-        feature_contri = tuple(float(v) for v in feature_contri)
-        cegb_lazy = tuple(float(v) for v in cegb_lazy)
-        wave_ok = (self.use_hist_pool and int(config.num_leaves) > 2)
-        mode = str(config.tree_grow_mode)
-        if mode == "wave" and not wave_ok:
-            from ..utils.log import log_warning
-            log_warning("tree_grow_mode=wave is incompatible with "
-                        "num_leaves<=2 / pool-less growth; "
-                        "falling back to the partitioned grower")
-            mode = "partition"
-        elif mode == "auto":
-            mode = "wave" if (wave_ok and impl == "pallas") else "partition"
-        self.grow_mode = mode if self.use_hist_pool else "masked"
-        if self.grow_mode != "wave":
-            resolve_monotone_method(config, self.split_params.use_monotone,
-                                    wave=False)
-        self._use_lazy = bool(cegb_lazy) and self.grow_mode == "wave"
-        self._lazy_used = None
-        if cegb_lazy and self.grow_mode != "wave":
-            from ..utils.log import log_warning
-            log_warning("cegb_penalty_feature_lazy is applied by the wave "
-                        "grower only; this grower ignores it")
-        self.quantized = bool(config.use_quantized_grad) and \
-            self.grow_mode == "wave"
-        if config.use_quantized_grad and not self.quantized:
-            from ..utils.log import log_warning
-            log_warning("use_quantized_grad requires the wave grower "
-                        "(tree_grow_mode=wave/auto on TPU); training "
-                        "with exact gradients instead")
-        # kernel-v2 knobs: the DMA/blockspec pipeline choice and the
-        # 4-bit packed bin layout (two codes per int8 lane when every
-        # feature fits a nibble — reference dense_bin.hpp's 4-bit bins)
-        from ..ops.histogram_pallas import PACK4_MAX_BINS
-        self.pallas_pipeline = (None if config.tpu_pallas_pipeline == "auto"
-                                else str(config.tpu_pallas_pipeline))
-        self.pack4 = False
-        if self.grow_mode == "wave":
-            from ..ops.quantize import quant_levels
-            wave_size = int(config.tpu_wave_size)
-            any_cat = bool(np.any(np.asarray(is_cat)))
-            # pack4 exists only on the DMA pipeline: an explicit
-            # blockspec request (the measured-dead-ends A/B knob) must
-            # actually run the v1 layout, so it disables packing
-            self.pack4 = bool(
-                config.tpu_hist_pack4 and impl == "pallas" and
-                self.max_bins <= PACK4_MAX_BINS and not any_cat and
-                efb is None and self.pallas_pipeline != "blockspec")
-            gq_max, hq_max = quant_levels(int(config.num_grad_quant_bins))
-            # in exact mode the quant params don't affect the traced fn —
-            # collapse the cache key so sweeps over them don't recompile
-            qtuple = (self.quantized, gq_max, hq_max,
-                      bool(config.quant_train_renew_leaf),
-                      bool(config.stochastic_rounding)) \
-                if self.quantized else (False,)
-            spec_ramp = bool(config.tpu_speculative_ramp)
-            spec_tol = float(config.tpu_spec_tolerance)
-            endg = bool(config.tpu_exact_endgame)
-            mc_inter = resolve_monotone_method(
-                config, self.split_params.use_monotone, wave=True)
-            key = ("wave", int(config.num_leaves), num_features,
-                   self.max_bins, int(config.max_depth), self.split_params,
-                   impl, any_cat, wave_size, self._efb_dims, feature_contri,
-                   qtuple, interaction_groups, cegb_lazy, spec_ramp,
-                   spec_tol, forced_splits, mc_inter, endg,
-                   self.pack4, self.pallas_pipeline)
-            from .wave import make_wave_grow_fn
-            self._grow_factory = make_wave_grow_fn
-            self._grow_kwargs = dict(
-                num_leaves=int(config.num_leaves),
-                num_features=num_features, max_bins=self.max_bins,
-                max_depth=int(config.max_depth),
-                split_params=self.split_params, hist_impl=impl,
-                any_cat=any_cat, wave_size=wave_size,
-                pack4=self.pack4, pipeline=self.pallas_pipeline,
-                efb_dims=self._efb_dims, feature_contri=feature_contri,
-                quantized=self.quantized, gq_max=gq_max, hq_max=hq_max,
-                renew_leaf=bool(config.quant_train_renew_leaf),
-                stochastic=bool(config.stochastic_rounding),
-                interaction_groups=interaction_groups,
-                cegb_lazy=cegb_lazy, spec_ramp=spec_ramp,
-                spec_tol=spec_tol, forced_splits=forced_splits,
-                mc_inter=mc_inter, exact_endgame=endg)
-            if key not in _GROW_FN_CACHE:
-                _cache_put(key, self.build_grow_fn())
-        elif self.partitioned:
-            key = ("part", int(config.num_leaves), num_features,
-                   self.max_bins, int(config.max_depth), self.split_params,
-                   impl, forced_splits, self._efb_dims,
-                   interaction_groups, feature_contri,
-                   self.pallas_pipeline)
-            from .partitioned import make_partitioned_grow_fn
-            self._grow_factory = make_partitioned_grow_fn
-            self._grow_kwargs = dict(
-                num_leaves=int(config.num_leaves),
-                num_features=num_features, max_bins=self.max_bins,
-                max_depth=int(config.max_depth),
-                split_params=self.split_params, hist_impl=impl,
-                pipeline=self.pallas_pipeline,
-                forced_splits=forced_splits, efb_dims=self._efb_dims,
-                interaction_groups=interaction_groups,
-                feature_contri=feature_contri)
-            if key not in _GROW_FN_CACHE:
-                _cache_put(key, self.build_grow_fn())
-        else:
-            key = ("serial", int(config.num_leaves), self.max_bins,
-                   int(config.max_depth), self.split_params, impl,
-                   int(config.tpu_rows_per_chunk), self.use_hist_pool)
-            self._grow_factory = make_grow_fn
-            self._grow_kwargs = dict(
-                num_leaves=int(config.num_leaves), max_bins=self.max_bins,
-                max_depth=int(config.max_depth),
-                split_params=self.split_params, hist_impl=impl,
-                rows_per_chunk=int(config.tpu_rows_per_chunk),
-                use_hist_pool=self.use_hist_pool)
-            if key not in _GROW_FN_CACHE:
-                _cache_put(key, self.build_grow_fn())
-        self._grow = _cache_hit(key)
+        self.setup_seconds = {}   # "layout": see bind()
 
     def build_grow_fn(self, split_params=None, jit: bool = True):
         """(Re)build this learner's grower from its recorded factory
@@ -891,7 +888,43 @@ class SerialTreeLearner:
             kw["split_params"] = split_params
         return self._grow_factory(jit=jit, **kw)
 
-    supports_extras = True  # cegb_penalty / node_key keyword args
+    def _cached_grow_fn(self, kind: str, unkeyed: tuple = ()):
+        """This learner's jitted grower out of ``_GROW_FN_CACHE``, built
+        on a miss.  The key is the factory's whole configuration: every
+        grower argument is static."""
+        key = (kind,) + tuple(sorted(
+            (k, v) for k, v in self._grow_kwargs.items()
+            if k not in unkeyed))
+        if key not in _GROW_FN_CACHE:
+            _cache_put(key, self.build_grow_fn())
+        return _cache_hit(key)
+
+    def bind(self, X_dev: jnp.ndarray) -> jnp.ndarray:
+        """The ``(F, N)`` matrix ``grow`` is given for the row-major bins
+        ``X_dev``, made once per matrix (:func:`feature_major_bins`, rows
+        padded so that every shard meets the kernels' row block, placed
+        as row shards on a mesh) and kept as ``_XpT``."""
+        if self._x_src is not X_dev:  # strong ref: ids can be recycled
+            # Host seconds of ENQUEUEING the pad + transpose (+ pack4);
+            # the eager ops compile one module each, which takes no named
+            # scope, so the device side has no `lgbm.` name
+            with timed_span(self.setup_seconds, "layout", "train/layout"):
+                if self.pallas:
+                    from ..ops.histogram_pallas import DEFAULT_ROW_BLOCK
+                    block = DEFAULT_ROW_BLOCK
+                else:
+                    # x8 so each shard's rows (and the packed lazy-CEGB
+                    # bitmap's byte columns) stay 8-divisible; one device
+                    # takes the rows as they come
+                    block = 1 if self.mesh is None else 8
+                xt = feature_major_bins(X_dev, self.ndev * block, self.pack4)
+                if self.mesh is not None:
+                    from ..parallel.mesh import shard_rows
+                    xt = shard_rows(self.mesh, xt, self.axis, dim=1)
+                self._XpT = xt
+                self._lazy_used = None  # fresh data -> fresh used bitmap
+            self._x_src = X_dev
+        return self._XpT
 
     def train(self, X_dev: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
               sample_mask: jnp.ndarray,
@@ -903,6 +936,156 @@ class SerialTreeLearner:
             feature_mask = jnp.ones((self.num_features,), jnp.bool_)
         if cegb_penalty is None:
             cegb_penalty = jnp.zeros((self.num_features,), jnp.float32)
+        if not self.wave:
+            return self._train_other(X_dev, grad, hess, sample_mask,
+                                     feature_mask, cegb_penalty, node_key)
+        n = X_dev.shape[0]
+        XpT = self.bind(X_dev)
+        n_pad = XpT.shape[1] * (2 if self.pack4 else 1)
+        rows = (grad, hess, sample_mask)
+        if n_pad != n:
+            rows = tuple(jnp.pad(v, (0, n_pad - n)) for v in rows)
+        if self.mesh is not None:
+            from ..parallel.mesh import shard_rows
+            rows = tuple(shard_rows(self.mesh, v, self.axis) for v in rows)
+        if self.quantized and quant_key is None:
+            # per-call stream so direct callers (no gbdt driver threading
+            # a per-tree key) still decorrelate the stochastic rounding
+            # across trees
+            self._quant_calls += 1
+            quant_key = jax.random.PRNGKey(self._quant_calls)
+        if node_key is None and "node_key" in self._key_names:
+            node_key = jnp.zeros((2, 2), jnp.uint32)
+        if self._use_lazy:
+            # the used-feature bitmap persists across trees (the
+            # reference's feature_used_in_data_ lives for the whole
+            # training run)
+            from .wave import LAZY_PACK, lazy_bitmap_init
+            bitpack = n_pad % LAZY_PACK == 0  # pallas pads to 4096
+            width = n_pad // LAZY_PACK if bitpack else n_pad
+            if self._lazy_used is None or \
+                    self._lazy_used.shape[1] != width:
+                self._lazy_used = lazy_bitmap_init(
+                    self.num_features, n_pad, bitpack)
+        given = {"quant_key": quant_key, "node_key": node_key,
+                 "lazy_used": self._lazy_used}
+        keys = {name: given[name] for name in self._key_names}
+        static = (self.num_bins, self.is_cat, self.has_nan, self.monotone)
+        # each compiled program keeps the parameter order it was born
+        # with (its executable is cached under it)
+        if self.mesh is None:
+            out = self._grow(XpT, *rows, *static, cegb_penalty,
+                             self._efb_args, feature_mask, **keys)
+        else:
+            out = self._grow(XpT, *rows, *static, feature_mask,
+                             cegb_penalty, *keys.values())
+        if self._use_lazy:
+            grown, self._lazy_used = out
+        else:
+            grown = out
+        if n_pad != n:
+            grown = grown._replace(row_leaf=grown.row_leaf[:n])
+        return grown
+
+
+class SerialTreeLearner(WaveTreeLearner):
+    """Host-side wrapper: owns the jitted grower and the dataset's static
+    feature descriptors (reference tree_learner.h:27 ``TreeLearner``).
+    Chooses between the wave grower (its base class), the
+    partition-ordered grower and the masked fallback."""
+
+    def __init__(self, config: Config, num_features: int, max_bins: int,
+                 num_bins: np.ndarray, is_cat: np.ndarray, has_nan: np.ndarray,
+                 monotone: Optional[np.ndarray] = None,
+                 forced_splits: tuple = (), efb=None,
+                 interaction_groups: tuple = (),
+                 feature_contri: tuple = (), cegb_lazy: tuple = ()):
+        pool_f, pool_b = ((int(efb.n_bundles), int(efb.bundle_bins))
+                          if efb is not None else (num_features, int(max_bins)))
+        self.use_hist_pool = hist_pool_fits(config, pool_f, pool_b)
+        if efb is not None and not self.use_hist_pool:
+            raise ValueError("EFB requires the partitioned grower; raise "
+                             "histogram_pool_size or disable enable_bundle")
+        impl = resolve_hist_impl(config, max_bins=int(max_bins))
+        if impl == "packed4" and efb is not None:
+            # EFB histograms run in BUNDLE space whose bin count can
+            # exceed the 4-bit range even when every feature fits it
+            impl = "segment"
+        if not self.use_hist_pool and impl == "pallas":
+            # the pool-less fallback grower takes no transposed X and no row
+            # padding — downgrade to the XLA onehot formulation (same MXU
+            # math, without the VMEM layout contract)
+            impl = "onehot"
+        self.pallas = impl == "pallas"
+        # The partition-ordered grower (learner/partitioned.py) is the
+        # exact sequential serial path — no full-N work per split.  The
+        # wave grower (learner/wave.py) trades row movement for MXU
+        # leaf-batched histogram passes and wins on TPU.  The masked
+        # grower below remains for the pool-less huge-feature fallback and
+        # as the shared body of the parallel strategies.
+        self.partitioned = self.use_hist_pool
+        wave_ok = (self.use_hist_pool and int(config.num_leaves) > 2)
+        mode = str(config.tree_grow_mode)
+        if mode == "wave" and not wave_ok:
+            from ..utils.log import log_warning
+            log_warning("tree_grow_mode=wave is incompatible with "
+                        "num_leaves<=2 / pool-less growth; "
+                        "falling back to the partitioned grower")
+            mode = "partition"
+        elif mode == "auto":
+            mode = "wave" if (wave_ok and impl == "pallas") else "partition"
+        self.grow_mode = mode if self.use_hist_pool else "masked"
+        self.pack4 = False
+        if self.grow_mode == "wave":
+            super().__init__(
+                config, num_features, max_bins, num_bins, is_cat, has_nan,
+                monotone, hist_impl=impl, efb=efb,
+                forced_splits=forced_splits,
+                interaction_groups=interaction_groups,
+                feature_contri=feature_contri, cegb_lazy=cegb_lazy)
+            return
+        self._describe(config, num_features, max_bins, num_bins, is_cat,
+                       has_nan, monotone, efb)
+        self.split_params = full_space_split_params(config, num_bins, is_cat)
+        resolve_monotone_method(config, self.split_params.use_monotone,
+                                wave=False)
+        if cegb_lazy:
+            from ..utils.log import log_warning
+            log_warning("cegb_penalty_feature_lazy is applied by the wave "
+                        "grower only; this grower ignores it")
+        if config.use_quantized_grad:
+            from ..utils.log import log_warning
+            log_warning("use_quantized_grad requires the wave grower "
+                        "(tree_grow_mode=wave/auto on TPU); training "
+                        "with exact gradients instead")
+        if self.partitioned:
+            from .partitioned import make_partitioned_grow_fn
+            self._grow_factory = make_partitioned_grow_fn
+            self._grow_kwargs = dict(
+                num_leaves=int(config.num_leaves),
+                num_features=num_features, max_bins=self.max_bins,
+                max_depth=int(config.max_depth),
+                split_params=self.split_params, hist_impl=impl,
+                pipeline=(None if config.tpu_pallas_pipeline == "auto"
+                          else str(config.tpu_pallas_pipeline)),
+                forced_splits=tuple(tuple(f) for f in forced_splits),
+                efb_dims=self._efb_dims,
+                interaction_groups=tuple(
+                    tuple(g) for g in interaction_groups),
+                feature_contri=tuple(float(v) for v in feature_contri))
+        else:
+            self._grow_factory = make_grow_fn
+            self._grow_kwargs = dict(
+                num_leaves=int(config.num_leaves), max_bins=self.max_bins,
+                max_depth=int(config.max_depth),
+                split_params=self.split_params, hist_impl=impl,
+                rows_per_chunk=int(config.tpu_rows_per_chunk),
+                use_hist_pool=self.use_hist_pool)
+        self._grow = self._cached_grow_fn(
+            "part" if self.partitioned else "serial")
+
+    def _train_other(self, X_dev, grad, hess, sample_mask, feature_mask,
+                     cegb_penalty, node_key) -> GrownTree:
         if node_key is None:
             node_key = jnp.zeros((2, 2), jnp.uint32)
         if not self.partitioned:
@@ -920,71 +1103,21 @@ class SerialTreeLearner:
             n_pad = pad_rows(n)
         else:
             n_pad = n
-        if self._x_src is not X_dev:  # strong ref: ids can be recycled
-            # once per matrix: pad + transpose (+ pack4).  Host seconds of
-            # ENQUEUEING them; the eager ops compile one module each, which
-            # takes no named scope, so the device side has no `lgbm.` name
-            with timed_span(self.setup_seconds, "layout", "train/layout"):
-                self._lazy_used = None  # fresh data -> fresh used bitmap
-                Xp = jnp.pad(X_dev, ((0, n_pad - n), (0, 0))) \
-                    if n_pad != n else X_dev
-                if self.grow_mode == "wave":
-                    # only the feature-major copy is consumed; do not keep the
-                    # padded row-major matrix alive next to it in HBM — and
-                    # under pack4 only the nibble-packed HALF-width matrix
-                    # (two 4-bit codes per int8 lane) lives on device
-                    xpt = jnp.asarray(jnp.swapaxes(Xp, 0, 1))
-                    if self.pack4:
-                        from ..ops.histogram_pallas import pack_bins4
-                        xpt = pack_bins4(xpt.astype(jnp.uint8))
-                    self._XpT = xpt
-                    self._Xp = None
-                else:
-                    self._Xp = Xp
-                self._x_src = X_dev
         pad = n_pad - n
+        if self._x_src is not X_dev:  # strong ref: ids can be recycled
+            # once per matrix: the row pad, timed like bind()'s layout
+            with timed_span(self.setup_seconds, "layout", "train/layout"):
+                self._Xp = jnp.pad(X_dev, ((0, pad), (0, 0))) if pad \
+                    else X_dev
+                self._x_src = X_dev
         if pad:
             grad = jnp.pad(grad, (0, pad))
             hess = jnp.pad(hess, (0, pad))
             sample_mask = jnp.pad(sample_mask, (0, pad))
-        if self.grow_mode == "wave":
-            kw = {}
-            if self.quantized:
-                if quant_key is None:
-                    # per-call stream so direct callers (no gbdt driver
-                    # threading a per-tree key) still decorrelate the
-                    # stochastic rounding across trees
-                    self._quant_calls = getattr(self, "_quant_calls", 0) + 1
-                    quant_key = jax.random.PRNGKey(self._quant_calls)
-                kw["quant_key"] = quant_key
-            if self.split_params.feature_fraction_bynode < 1.0 or \
-                    self.split_params.extra_trees:
-                kw["node_key"] = node_key
-            if self._use_lazy:
-                # the used-feature bitmap persists across trees (the
-                # reference's feature_used_in_data_ lives for the whole
-                # training run)
-                from .wave import LAZY_PACK, lazy_bitmap_init
-                bitpack = n_pad % LAZY_PACK == 0  # pallas pads to 4096
-                width = n_pad // LAZY_PACK if bitpack else n_pad
-                if self._lazy_used is None or \
-                        self._lazy_used.shape[1] != width:
-                    self._lazy_used = lazy_bitmap_init(
-                        self.num_features, n_pad, bitpack)
-                kw["lazy_used"] = self._lazy_used
-            out = self._grow(self._XpT, grad, hess, sample_mask,
-                             self.num_bins, self.is_cat, self.has_nan,
-                             self.monotone, cegb_penalty,
-                             self._efb_args, feature_mask, **kw)
-            if self._use_lazy:
-                grown, self._lazy_used = out
-            else:
-                grown = out
-        else:
-            grown = self._grow(self._Xp, grad, hess, sample_mask,
-                               self.num_bins, self.is_cat, self.has_nan,
-                               self.monotone, cegb_penalty, node_key,
-                               self._efb_args, feature_mask)
+        grown = self._grow(self._Xp, grad, hess, sample_mask,
+                           self.num_bins, self.is_cat, self.has_nan,
+                           self.monotone, cegb_penalty, node_key,
+                           self._efb_args, feature_mask)
         if pad:
             grown = grown._replace(row_leaf=grown.row_leaf[:n])
         return grown

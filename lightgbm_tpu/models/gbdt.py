@@ -512,9 +512,7 @@ class GBDT:
             # penalties charge once until the feature is first used; tracked
             # host-side across trees (per-tree granularity)
             self._cegb_coupled = None
-            serial = isinstance(self.learner, SerialTreeLearner)
-            supports_extras = serial or getattr(self.learner,
-                                                "supports_extras", False)
+            supports_extras = self.learner.supports_extras
             if cfg.cegb_penalty_feature_coupled or cfg.cegb_penalty_split > 0:
                 if not supports_extras:
                     log_warning("CEGB penalties are applied by the serial and "
@@ -735,8 +733,8 @@ class GBDT:
         """``TrainRecord.snapshot()["mesh"]``: the mesh the learner built
         for its row shards (one chip and no axis where rows stay whole:
         the serial and the feature-parallel learner)."""
-        mesh = getattr(self.learner, "mesh", None)
-        if mesh is None or not getattr(self.learner, "rows_sharded", False):
+        mesh = self.learner.mesh
+        if mesh is None or not self.learner.rows_sharded:
             return {"chips": 1, "axis": None,
                     "rows_per_chip": int(self.num_data)}
         return {"chips": int(mesh.size), "axis": str(mesh.axis_names[0]),
@@ -752,8 +750,8 @@ class GBDT:
         process holds the full host data there and reads labels and
         scores back, which a cross-process array does not allow); the
         learner scatters those per call."""
-        mesh = getattr(self.learner, "mesh", None)
-        if mesh is not None and getattr(self.learner, "rows_sharded", False) \
+        mesh = self.learner.mesh
+        if mesh is not None and self.learner.rows_sharded \
                 and jax.process_count() == 1 \
                 and arr.shape[0] % mesh.size == 0:
             from ..parallel.mesh import shard_rows
@@ -874,7 +872,7 @@ class GBDT:
             self._first_update = False
             with rec.setup("first_update", "train/first_update"):
                 finished = self._train_one_iter(grad, hess)
-            rec.add_setup_seconds(getattr(self.learner, "setup_seconds", {}))
+            rec.add_setup_seconds(self.learner.setup_seconds)
         else:
             with span("train/iter"):
                 finished = self._train_one_iter(grad, hess)
@@ -942,7 +940,7 @@ class GBDT:
             self._cur_gh = (g, h)
             extra = {}
             it = self.iter_ * k + cid
-            if getattr(self.learner, "supports_extras", False):
+            if self.learner.supports_extras:
                 if self._cegb_coupled is not None:
                     extra["cegb_penalty"] = jnp.asarray(
                         np.where(self._cegb_used, 0.0,
@@ -957,7 +955,7 @@ class GBDT:
                             cfg.feature_fraction_seed), it),
                         jax.random.fold_in(jax.random.PRNGKey(
                             cfg.extra_seed), it)])
-            if getattr(self.learner, "quantized", False):
+            if self.learner.quantized:
                 # per-tree stochastic-rounding stream
                 # (gradient_discretizer.cpp seeds from config seed)
                 extra["quant_key"] = jax.random.fold_in(

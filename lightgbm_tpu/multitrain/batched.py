@@ -486,24 +486,24 @@ class BatchTrainer:
         lrn = self.learner
         wave = lrn.grow_mode == "wave"
         # the pallas kernels' padded-row layout (pad_rows): the binned
-        # matrix pads ONCE here; per-model gradient/mask lanes pad inside
-        # the vmapped grower and row_leaf trims back to N
-        n_pad = self.n
-        if getattr(lrn, "pallas", False):
-            from ..ops.histogram_pallas import pad_rows
-            n_pad = pad_rows(self.n)
-        self._row_pad = n_pad - self.n
-        if wave and getattr(lrn, "pack4", False):
+        # matrix is laid out ONCE here, as the standalone learner lays it
+        # out; per-model gradient/mask lanes pad inside the vmapped
+        # grower and row_leaf trims back to N
+        from ..learner.serial import feature_major_bins
+        from ..ops.histogram_pallas import pad_rows
+        self._row_pad = (pad_rows(self.n) if lrn.pallas else self.n) - self.n
+        if wave and lrn.pack4:
             # the Dataset caches the packed feature-major layout (half
             # the bytes), so repeated BatchTrainers (cv folds, sweeps)
-            # share it — the row-major matrix never reaches the device
+            # share it
             self._X_arg = self.train_set.device_bins_packed4()
         else:
             X_dev = jnp.asarray(self.train_set.X_binned)
-            if self._row_pad:
-                X_dev = jnp.pad(X_dev, ((0, self._row_pad), (0, 0)))
-            self._X_arg = jnp.asarray(jnp.swapaxes(X_dev, 0, 1)) if wave \
-                else X_dev
+            if wave:  # not lrn.bind(): it would keep X_dev alive too
+                self._X_arg = feature_major_bins(X_dev, self.n + self._row_pad)
+            else:
+                self._X_arg = jnp.pad(X_dev, ((0, self._row_pad), (0, 0))) \
+                    if self._row_pad else X_dev
 
         base_sp = lrn.split_params
         sweep_fields = self.sweep_fields
@@ -511,7 +511,6 @@ class BatchTrainer:
         num_bins, is_cat, has_nan = lrn.num_bins, lrn.is_cat, lrn.has_nan
         monotone = lrn.monotone
         F = self.num_features
-        quantized = self._need_quant_key
         need_nk = self._need_node_key
         objective = self.objective
         walk_fn = make_walk_fn(
@@ -541,11 +540,9 @@ class BatchTrainer:
                 h = jnp.pad(h, (0, row_pad))
                 mk = jnp.pad(mk, (0, row_pad))
             if wave:
-                kw = {}
-                if quantized:
-                    kw["quant_key"] = qkey
-                if need_nk:
-                    kw["node_key"] = nkey
+                kw = {k: v for k, v in (("quant_key", qkey),
+                                        ("node_key", nkey))
+                      if k in lrn._key_names}
                 grown = grow(X_arg, g, h, mk, num_bins, is_cat, has_nan,
                              monotone, cegb0, efb_args, fmask, **kw)
             else:

@@ -521,8 +521,8 @@ def _best_split_impl(hist: jnp.ndarray, parent_sum: jnp.ndarray,
         # cat_member is the all-False constant here; running the argmax
         # anyway hands XLA a constant-foldable variadic (pred, iota)
         # reduce that costs >2s of compile time per vmapped scan on
-        # multichip programs (MULTICHIP_r05's %reduce.227 stall) — skip
-        # the reduce instead of folding it
+        # multichip programs (seen as `%reduce.227` of an 8-device dry
+        # run) — skip the reduce instead of folding it
         cat_thr = jnp.zeros((f,), jnp.int32)
     thr = jnp.where(is_cat, cat_thr, num_thr)
     left_sum = jnp.where(is_cat_b, cat_left_sum, left_num)

@@ -32,7 +32,6 @@ def create_parallel_learner(config, num_features, max_bins, num_bins, is_cat,
     if cls is None:
         raise ValueError(f"Unknown tree_learner: {kind}")
     import jax
-    from .mesh import get_mesh
     if get_mesh(int(config.num_devices)).devices.size == 1 and \
             jax.process_count() == 1:
         # a parallel learner over a 1-device mesh IS the serial learner
@@ -48,6 +47,8 @@ def create_parallel_learner(config, num_features, max_bins, num_bins, is_cat,
             monotone, forced_splits,
             interaction_groups=interaction_groups, cegb_lazy=cegb_lazy,
             feature_contri=feature_contri)
+    # feature_contri stops here for every mesh learner: inherited, not
+    # chosen (ROADMAP D2: honour or raise)
     if kind == "data":
         return cls(config, num_features, max_bins, num_bins, is_cat,
                    has_nan, monotone, interaction_groups=interaction_groups,

@@ -26,17 +26,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from ..config import Config
-from ..learner.serial import (CommStrategy, GrownTree, local_best_candidate,
-                              make_grow_fn, hist_pool_fits, resolve_hist_impl,
+from ..learner.serial import (CommStrategy, GrownTree, WaveTreeLearner,
+                              local_best_candidate, make_grow_fn,
+                              hist_pool_fits, resolve_hist_impl,
+                              resolve_monotone_method,
                               split_params_from_config)
 from ..analysis.contracts import (collective_contract, memory_budget,
                                   world_size)
-from ..telemetry.trace import timed_span
 from ..telemetry.train_record import note_collective
-from .mesh import get_mesh, shard_rows
+from .mesh import get_mesh, shard_masked_grower
 
 __all__ = ["DataParallelTreeLearner", "DataParallelStrategy"]
 
@@ -285,12 +285,13 @@ class WaveDPStrategy(CommStrategy):
         return xmax, xmin, xsum
 
 
-class DataParallelTreeLearner:
+class DataParallelTreeLearner(WaveTreeLearner):
     """Host-side wrapper building the shard_map'd grower.
 
     Two growers: the WAVE grower (TPU default — leaf-batched histograms,
-    one psum per wave, no row movement) and the masked sequential grower
-    with per-split psum_scatter blocks (the reference DP layout,
+    one psum per wave, no row movement: ``WaveTreeLearner`` with a
+    ``WaveDPStrategy``) and the masked sequential grower with per-split
+    psum_scatter blocks (the reference DP layout,
     data_parallel_tree_learner.cpp:155-173; used off-TPU and when wave is
     gated off)."""
 
@@ -302,49 +303,40 @@ class DataParallelTreeLearner:
                  monotone: Optional[np.ndarray] = None,
                  interaction_groups: tuple = (),
                  cegb_lazy: tuple = (), forced_splits: tuple = ()):
-        self.config = config
-        self.max_bins = int(max_bins)
-        self.num_features = num_features
-        self.interaction_groups = tuple(tuple(g) for g in interaction_groups)
-        self.cegb_lazy = tuple(float(v) for v in cegb_lazy)
-        self.forced_splits = tuple(tuple(f) for f in forced_splits)
-        self.mesh = get_mesh(int(config.num_devices))
-        self.setup_seconds = {}   # "layout": see train()
-        self.ndev = self.mesh.devices.size
-        self.axis = self.mesh.axis_names[0]
+        mesh = get_mesh(int(config.num_devices))
         mode = str(config.tree_grow_mode)
         impl_wave = resolve_hist_impl(config, parallel=True, wave=True,
-                                      max_bins=self.max_bins)
+                                      max_bins=int(max_bins))
         # same gates as SerialTreeLearner's wave_ok: the wave state carries
         # the full (L, G, B, 3) histogram pool — fall back to the masked
         # sequential grower when it would blow the HBM budget
         wave_able = (int(config.num_leaves) > 2 and
-                     hist_pool_fits(config, num_features, self.max_bins))
-        self.wave = wave_able and (mode == "wave" or
-                                   (mode == "auto" and
-                                    impl_wave == "pallas"))
-        if self.wave:
-            self._init_wave(config, num_features, num_bins, is_cat, has_nan,
-                            monotone, impl_wave)
+                     hist_pool_fits(config, num_features, int(max_bins)))
+        if wave_able and (mode == "wave" or
+                          (mode == "auto" and impl_wave == "pallas")):
+            super().__init__(
+                config, num_features, max_bins, num_bins, is_cat, has_nan,
+                monotone, hist_impl=impl_wave,
+                interaction_groups=interaction_groups, cegb_lazy=cegb_lazy,
+                forced_splits=forced_splits, mesh=mesh,
+                strategy=WaveDPStrategy(
+                    mesh.axis_names[0], nshards=mesh.devices.size,
+                    hist_scatter=bool(config.tpu_dp_hist_scatter)))
             return
-        self.quantized = False
         self.supports_extras = False
         if config.use_quantized_grad:
             from ..utils.log import log_warning
             log_warning("use_quantized_grad requires the wave grower; the "
                         "masked data-parallel grower trains with exact "
                         "gradients")
-        if self.forced_splits:
+        if forced_splits:
             from ..utils.log import log_warning
             log_warning("forcedsplits_filename is applied by the DP-wave "
                         "grower only; the masked data-parallel grower "
                         "ignores it")
-        from ..learner.serial import (resolve_monotone_method,
-                                      split_params_from_config as _spc)
-        resolve_monotone_method(config, _spc(config, num_bins,
-                                             is_cat).use_monotone,
-                                wave=False)
-        if self.interaction_groups or self.cegb_lazy or \
+        sp = split_params_from_config(config, num_bins, is_cat)
+        resolve_monotone_method(config, sp.use_monotone, wave=False)
+        if interaction_groups or cegb_lazy or \
                 config.extra_trees or \
                 config.feature_fraction_bynode < 1.0 or \
                 config.cegb_penalty_split > 0 or \
@@ -357,197 +349,30 @@ class DataParallelTreeLearner:
         # pad the feature axis to a multiple of the mesh so psum_scatter
         # blocks are uniform (padded features are trivial: 1 bin, never
         # splittable — the analog of the reference's balanced block layout)
-        self.f_pad = (-num_features) % self.ndev
+        self.f_pad = (-num_features) % mesh.devices.size
         fp = num_features + self.f_pad
-        self.f_local = fp // self.ndev
-        self.num_bins = jnp.asarray(
-            np.concatenate([num_bins, np.ones(self.f_pad, np.int32)]),
-            jnp.int32)
-        self.is_cat = jnp.asarray(
-            np.concatenate([is_cat, np.zeros(self.f_pad, bool)]), jnp.bool_)
-        self.has_nan = jnp.asarray(
-            np.concatenate([has_nan, np.zeros(self.f_pad, bool)]), jnp.bool_)
+        self.f_local = fp // mesh.devices.size
         mono_np = monotone if monotone is not None else np.zeros(num_features)
-        self.monotone = jnp.asarray(
-            np.concatenate([mono_np, np.zeros(self.f_pad)]), jnp.int32)
+        self._describe(
+            config, num_features, max_bins,
+            np.concatenate([num_bins, np.ones(self.f_pad, np.int32)]),
+            np.concatenate([is_cat, np.zeros(self.f_pad, bool)]),
+            np.concatenate([has_nan, np.zeros(self.f_pad, bool)]),
+            np.concatenate([mono_np, np.zeros(self.f_pad)]), mesh=mesh)
         strategy = DataParallelStrategy(self.axis, self.f_local,
                                         self.num_bins, self.is_cat,
                                         self.has_nan)
-        grow_t = make_grow_fn(
+        self._grow = shard_masked_grower(make_grow_fn(
             num_leaves=int(config.num_leaves), max_bins=self.max_bins,
-            max_depth=int(config.max_depth),
-            split_params=split_params_from_config(config, num_bins, is_cat),
+            max_depth=int(config.max_depth), split_params=sp,
             hist_impl=resolve_hist_impl(config, parallel=True),
             rows_per_chunk=int(config.tpu_rows_per_chunk),
             use_hist_pool=hist_pool_fits(config, fp, self.max_bins),
-            strategy=strategy, jit=False)
+            strategy=strategy, jit=False), mesh, self.axis)
 
-        def grow(X, g, h, m, nb, ic, hn, mono, fm):
-            return grow_t(X, None, g, h, m, nb, ic, hn, mono, fm)
-        tree_specs = self._tree_specs(self.axis)
-        self._grow = jax.jit(jax.shard_map(
-            grow, mesh=self.mesh,
-            in_specs=(P(self.axis), P(self.axis), P(self.axis), P(self.axis),
-                      P(), P(), P(), P(), P()),
-            out_specs=tree_specs,
-            check_vma=False))
-
-    @staticmethod
-    def _tree_specs(axis):
-        return GrownTree(
-            split_feature=P(), threshold_bin=P(), nan_bin=P(),
-            cat_member=P(), decision_type=P(), left_child=P(),
-            right_child=P(), split_gain=P(), internal_value=P(),
-            internal_weight=P(), internal_count=P(), leaf_value=P(),
-            leaf_weight=P(), leaf_count=P(), num_leaves=P(),
-            row_leaf=P(axis), hist_passes=P(), wave_passes=P(),
-            endgame_passes=P(), ramp_committed=P())
-
-    def _init_wave(self, config, num_features, num_bins, is_cat, has_nan,
-                   monotone, impl):
-        from ..learner.wave import make_wave_grow_fn
-        self.f_pad = 0
-        self.pallas = impl == "pallas"
-        self.num_bins = jnp.asarray(num_bins, jnp.int32)
-        self.is_cat = jnp.asarray(is_cat, jnp.bool_)
-        self.has_nan = jnp.asarray(has_nan, jnp.bool_)
-        mono_np = monotone if monotone is not None else np.zeros(num_features)
-        self.monotone = jnp.asarray(mono_np, jnp.int32)
-        self._x_src = None
-        self.supports_extras = True
-        from ..ops.quantize import quant_levels
-        self.quantized = bool(config.use_quantized_grad)
-        sp = split_params_from_config(config, num_bins, is_cat)
-        if np.any(np.asarray(is_cat)):
-            # the DP-WAVE scan runs replicated in FULL feature space
-            # (unlike the masked psum_scatter blocks) — attach the static
-            # cat positions that bound the subset search's argsort
-            sp = sp._replace(cat_idx=tuple(
-                int(j) for j in np.where(np.asarray(is_cat))[0]))
-        self.split_params = sp
-        from ..learner.serial import resolve_monotone_method
-        mc_inter = resolve_monotone_method(config, sp.use_monotone,
-                                           wave=True)
-        self._use_node_key = sp.feature_fraction_bynode < 1.0 or \
-            sp.extra_trees
-        gq_max, hq_max = quant_levels(int(config.num_grad_quant_bins))
-        strategy = WaveDPStrategy(
-            self.axis, nshards=self.ndev,
-            hist_scatter=bool(config.tpu_dp_hist_scatter))
-        grow_w = make_wave_grow_fn(
-            num_leaves=int(config.num_leaves), num_features=num_features,
-            max_bins=self.max_bins, max_depth=int(config.max_depth),
-            split_params=sp,
-            hist_impl=impl, any_cat=bool(np.any(np.asarray(is_cat))),
-            wave_size=int(config.tpu_wave_size), strategy=strategy,
-            jit=False, quantized=self.quantized, gq_max=gq_max,
-            hq_max=hq_max,
-            renew_leaf=bool(config.quant_train_renew_leaf),
-            stochastic=bool(config.stochastic_rounding),
-            interaction_groups=self.interaction_groups,
-            cegb_lazy=self.cegb_lazy, forced_splits=self.forced_splits,
-            mc_inter=mc_inter,
-            spec_ramp=bool(config.tpu_speculative_ramp),
-            spec_tol=float(config.tpu_spec_tolerance),
-            exact_endgame=bool(config.tpu_exact_endgame))
-
-        # cegb penalties, the quantization/bynode keys and the persistent
-        # lazy-CEGB bitmap ride extra operands; arity is static config
-        nq = int(self.quantized)
-        nn = int(self._use_node_key)
-        nl = int(bool(self.cegb_lazy))
-
-        def grow(X_T, g, h, m, nb, ic, hn, mono, fm, cegb, *rest):
-            kw = {}
-            ki = 0
-            if nq:
-                kw["quant_key"] = rest[ki]
-                ki += 1
-            if nn:
-                kw["node_key"] = rest[ki]
-                ki += 1
-            if nl:
-                kw["lazy_used"] = rest[ki]
-            return grow_w(X_T, g, h, m, nb, ic, hn, mono, cegb, (), fm,
-                          **kw)
-
-        tree_specs = self._tree_specs(self.axis)
-        out_specs = (tree_specs, P(None, self.axis)) if nl else tree_specs
-        self._grow = jax.jit(jax.shard_map(
-            grow, mesh=self.mesh,
-            in_specs=(P(None, self.axis), P(self.axis), P(self.axis),
-                      P(self.axis), P(), P(), P(), P(), P(), P()) +
-            (P(),) * (nq + nn) +
-            ((P(None, self.axis),) if nl else ()),
-            out_specs=out_specs,
-            check_vma=False))
-        self._lazy_used = None
-
-    def train(self, X_dev: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
-              sample_mask: jnp.ndarray,
-              feature_mask: Optional[jnp.ndarray] = None,
-              quant_key: Optional[jnp.ndarray] = None,
-              cegb_penalty: Optional[jnp.ndarray] = None,
-              node_key: Optional[jnp.ndarray] = None) -> GrownTree:
-        if feature_mask is None:
-            feature_mask = jnp.ones((self.num_features,), jnp.bool_)
+    def _train_other(self, X_dev, grad, hess, sample_mask, feature_mask,
+                     cegb_penalty, node_key) -> GrownTree:
         n = X_dev.shape[0]
-        if self.wave:
-            # each shard's rows must satisfy the Pallas row-block contract
-            if self.pallas:
-                from ..ops.histogram_pallas import DEFAULT_ROW_BLOCK
-                quantum = self.ndev * DEFAULT_ROW_BLOCK
-            else:
-                # x8 so each shard's rows (and the packed lazy-CEGB
-                # bitmap's byte columns) stay 8-divisible
-                quantum = self.ndev * 8
-            pad = (-n) % quantum
-            if self._x_src is not X_dev:
-                # one-time pad + transpose; host seconds of ENQUEUEING it
-                with timed_span(self.setup_seconds, "layout", "train/layout"):
-                    Xp = jnp.pad(X_dev, ((0, pad), (0, 0))) if pad else X_dev
-                    self._XpT = shard_rows(self.mesh, jnp.swapaxes(Xp, 0, 1),
-                                           self.axis, dim=1)
-                self._x_src = X_dev
-                self._lazy_used = None  # fresh data -> fresh bitmap
-            if pad:
-                grad = jnp.pad(grad, (0, pad))
-                hess = jnp.pad(hess, (0, pad))
-                sample_mask = jnp.pad(sample_mask, (0, pad))
-            grad, hess, sample_mask = (
-                shard_rows(self.mesh, v, self.axis)
-                for v in (grad, hess, sample_mask))
-            if cegb_penalty is None:
-                cegb_penalty = jnp.zeros((self.num_features,), jnp.float32)
-            keys = []
-            if self.quantized:
-                if quant_key is None:
-                    self._quant_calls = getattr(self, "_quant_calls", 0) + 1
-                    quant_key = jax.random.PRNGKey(self._quant_calls)
-                keys.append(quant_key)
-            if self._use_node_key:
-                if node_key is None:
-                    node_key = jnp.zeros((2, 2), jnp.uint32)
-                keys.append(node_key)
-            if self.cegb_lazy:
-                from ..learner.wave import LAZY_PACK, lazy_bitmap_init
-                n_pad_all = self._XpT.shape[1]
-                if self._lazy_used is None or \
-                        self._lazy_used.shape[1] != n_pad_all // LAZY_PACK:
-                    self._lazy_used = lazy_bitmap_init(
-                        self.num_features, n_pad_all)
-                keys.append(self._lazy_used)
-            out = self._grow(self._XpT, grad, hess, sample_mask,
-                             self.num_bins, self.is_cat, self.has_nan,
-                             self.monotone, feature_mask, cegb_penalty,
-                             *keys)
-            if self.cegb_lazy:
-                grown, self._lazy_used = out
-            else:
-                grown = out
-            if pad:
-                grown = grown._replace(row_leaf=grown.row_leaf[:n])
-            return grown
         if self.f_pad:
             X_dev = jnp.pad(X_dev, ((0, 0), (0, self.f_pad)))
             feature_mask = jnp.pad(feature_mask, (0, self.f_pad))

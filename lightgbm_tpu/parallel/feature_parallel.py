@@ -24,7 +24,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from ..config import Config
 from ..learner.serial import (CommStrategy, GrownTree, local_best_candidate,
@@ -32,7 +31,7 @@ from ..learner.serial import (CommStrategy, GrownTree, local_best_candidate,
                               split_params_from_config)
 from ..analysis.contracts import collective_contract
 from ..telemetry.train_record import note_collective
-from .mesh import get_mesh
+from .mesh import get_mesh, shard_masked_grower
 
 __all__ = ["FeatureParallelTreeLearner", "FeatureParallelStrategy"]
 
@@ -131,6 +130,12 @@ class FeatureParallelStrategy(CommStrategy):
 
 class FeatureParallelTreeLearner:
     name = "feature"
+    # what models/gbdt.py reads off any learner (learner/serial.py
+    # WaveTreeLearner): rows stay whole on every device, train() takes
+    # no cegb_penalty / node_key / quant_key
+    rows_sharded = False
+    supports_extras = False
+    quantized = False
 
     def __init__(self, config: Config, num_features: int, max_bins: int,
                  num_bins: np.ndarray, is_cat: np.ndarray, has_nan: np.ndarray,
@@ -144,6 +149,7 @@ class FeatureParallelTreeLearner:
         self.max_bins = int(max_bins)
         self.num_features = num_features
         self.mesh = get_mesh(int(config.num_devices))
+        self.setup_seconds = {}   # nothing of its own to time
         self.ndev = self.mesh.devices.size
         self.axis = self.mesh.axis_names[0]
         # pad the feature axis to a multiple of the mesh (padded features are
@@ -168,7 +174,8 @@ class FeatureParallelTreeLearner:
             config, bool(config.monotone_constraints and
                          any(int(v) for v in config.monotone_constraints)),
             wave=False)
-        grow_t = make_grow_fn(
+        # X is feature-sharded; rows + every descriptor replicated
+        self._grow = shard_masked_grower(make_grow_fn(
             num_leaves=int(config.num_leaves), max_bins=self.max_bins,
             max_depth=int(config.max_depth),
             split_params=split_params_from_config(config, num_bins,
@@ -176,28 +183,8 @@ class FeatureParallelTreeLearner:
             hist_impl=resolve_hist_impl(config, parallel=True),
             rows_per_chunk=int(config.tpu_rows_per_chunk),
             use_hist_pool=hist_pool_fits(config, self.f_local, self.max_bins),
-            strategy=strategy, jit=False)
-
-        def grow(X, g, h, m, nb, ic, hn, mono, fm):
-            return grow_t(X, None, g, h, m, nb, ic, hn, mono, fm)
-        tree_specs = GrownTree(
-            split_feature=P(), threshold_bin=P(), nan_bin=P(),
-            cat_member=P(), decision_type=P(), left_child=P(), right_child=P(),
-            split_gain=P(), internal_value=P(), internal_weight=P(),
-            internal_count=P(), leaf_value=P(), leaf_weight=P(),
-            leaf_count=P(), num_leaves=P(), row_leaf=P(),
-            hist_passes=P(), wave_passes=P(),
-            endgame_passes=P(), ramp_committed=P())
-        # X is feature-sharded; rows + every descriptor replicated.  The
-        # descriptor args reaching the grower must be FULL arrays (global
-        # feature indexing), so they ride in replicated and the strategy
-        # slices per shard.
-        self._grow = jax.jit(jax.shard_map(
-            grow, mesh=self.mesh,
-            in_specs=(P(None, self.axis), P(), P(), P(), P(), P(), P(), P(),
-                      P()),
-            out_specs=tree_specs,
-            check_vma=False))
+            strategy=strategy, jit=False), self.mesh, self.axis,
+            by_features=True)
 
     def train(self, X_dev: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
               sample_mask: jnp.ndarray,

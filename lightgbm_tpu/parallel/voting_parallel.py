@@ -22,16 +22,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from ..config import Config
-from ..learner.serial import (CommStrategy, GrownTree, local_best_candidate,
-                              make_grow_fn, hist_pool_fits, resolve_hist_impl,
+from ..learner.serial import (CommStrategy, GrownTree, WaveTreeLearner,
+                              local_best_candidate, make_grow_fn,
+                              hist_pool_fits, resolve_hist_impl,
+                              resolve_monotone_method,
                               split_params_from_config)
 from ..ops.split import NEG_INF, best_split_per_feature
 from ..analysis.contracts import collective_contract, memory_budget
 from ..telemetry.train_record import note_collective
-from .mesh import get_mesh, shard_rows
+from .mesh import get_mesh, shard_masked_grower
 
 __all__ = ["VotingParallelTreeLearner", "VotingStrategy",
            "WaveVotingStrategy", "QuantizedGradUnsupportedError",
@@ -383,14 +384,14 @@ class VotingStrategy(CommStrategy):
                     bound_r, depth, po_r))
 
 
-class VotingParallelTreeLearner:
+class VotingParallelTreeLearner(WaveTreeLearner):
     """Two growers, like the DP learner: the WAVE grower with the voted
     merge (first-class: quantized gradients, exact endgame, spec ramp —
-    learner/wave.py use_voting + WaveVotingStrategy) and the masked
-    sequential grower with per-scan voting (VotingStrategy; off-TPU
-    fallback).  The masked fallback cannot train quantized — that combo
-    raises QuantizedGradUnsupportedError instead of silently training a
-    different model."""
+    ``WaveTreeLearner`` with a ``WaveVotingStrategy``, learner/wave.py
+    use_voting) and the masked sequential grower with per-scan voting
+    (VotingStrategy; off-TPU fallback).  The masked fallback cannot train
+    quantized — that combo raises QuantizedGradUnsupportedError instead
+    of silently training a different model."""
 
     name = "voting"
     rows_sharded = True  # models/gbdt.py places per-row arrays on the mesh
@@ -398,42 +399,38 @@ class VotingParallelTreeLearner:
     def __init__(self, config: Config, num_features: int, max_bins: int,
                  num_bins: np.ndarray, is_cat: np.ndarray, has_nan: np.ndarray,
                  monotone: Optional[np.ndarray] = None):
-        self.config = config
-        self.max_bins = int(max_bins)
-        self.num_features = num_features
-        self.mesh = get_mesh(int(config.num_devices))
-        self.ndev = self.mesh.devices.size
-        self.axis = self.mesh.axis_names[0]
-        self.num_bins = jnp.asarray(num_bins, jnp.int32)
-        self.is_cat = jnp.asarray(is_cat, jnp.bool_)
-        self.has_nan = jnp.asarray(has_nan, jnp.bool_)
-        self.monotone = jnp.asarray(
-            monotone if monotone is not None else np.zeros(num_features),
-            jnp.int32)
+        mesh = get_mesh(int(config.num_devices))
+        ndev = mesh.devices.size
         self.top_k = max(1, min(int(config.top_k), num_features))
         sp = split_params_from_config(config, num_bins, is_cat)
         local_sp = sp._replace(
-            min_data_in_leaf=max(1, sp.min_data_in_leaf // self.ndev),
-            min_sum_hessian_in_leaf=sp.min_sum_hessian_in_leaf / self.ndev)
-        self._local_sp = local_sp
+            min_data_in_leaf=max(1, sp.min_data_in_leaf // ndev),
+            min_sum_hessian_in_leaf=sp.min_sum_hessian_in_leaf / ndev)
         mode = str(config.tree_grow_mode)
         impl_wave = resolve_hist_impl(config, parallel=True, wave=True,
-                                      max_bins=self.max_bins)
+                                      max_bins=int(max_bins))
         wave_able = (int(config.num_leaves) > 2 and
-                     hist_pool_fits(config, num_features, self.max_bins))
-        self.wave = wave_able and (mode == "wave" or
-                                   (mode == "auto" and
-                                    impl_wave == "pallas"))
-        if not self.wave and config.use_quantized_grad and wave_able \
+                     hist_pool_fits(config, num_features, int(max_bins)))
+        wave = wave_able and (mode == "wave" or
+                              (mode == "auto" and impl_wave == "pallas"))
+        if not wave and config.use_quantized_grad and wave_able \
                 and mode != "partition":
             # quantized voting is a wave-grower feature; ride it rather
             # than refuse when the config merely defaulted off-TPU
-            self.wave = True
-        if self.wave:
-            self._init_wave(config, num_features, num_bins, is_cat,
-                            has_nan, monotone, impl_wave, sp, local_sp)
+            wave = True
+        if wave:
+            # voting gates cats / lazy CEGB / forced splits off inside the
+            # grower (full-batch psum fallback); the wave scan still runs
+            # in full feature space
+            super().__init__(
+                config, num_features, max_bins, num_bins, is_cat, has_nan,
+                monotone, hist_impl=impl_wave, mesh=mesh,
+                strategy=WaveVotingStrategy(
+                    mesh.axis_names[0], nshards=ndev, top_k=self.top_k,
+                    local_params=local_sp))
             return
-        self.quantized = False
+        self._describe(config, num_features, max_bins, num_bins, is_cat,
+                       has_nan, monotone, mesh=mesh)
         self.supports_extras = False
         if config.use_quantized_grad:
             raise QuantizedGradUnsupportedError(
@@ -441,151 +438,21 @@ class VotingParallelTreeLearner:
                 "wave grower (tree_grow_mode=wave, or auto on TPU); the "
                 "masked voting grower trains exact gradients only — "
                 "drop use_quantized_grad or enable the wave grower")
-        from ..learner.serial import resolve_monotone_method
-        resolve_monotone_method(
-            config, bool(config.monotone_constraints and
-                         any(int(v) for v in
-                             config.monotone_constraints)),
-            wave=False)
+        resolve_monotone_method(config, sp.use_monotone, wave=False)
         strategy = VotingStrategy(self.axis, self.top_k, num_features,
                                   self.ndev, self.num_bins, self.is_cat,
                                   self.has_nan, local_sp)
-        grow_t = make_grow_fn(
+        self._grow = shard_masked_grower(make_grow_fn(
             num_leaves=int(config.num_leaves), max_bins=self.max_bins,
             max_depth=int(config.max_depth), split_params=sp,
             hist_impl=resolve_hist_impl(config, parallel=True),
             rows_per_chunk=int(config.tpu_rows_per_chunk),
             use_hist_pool=hist_pool_fits(config, num_features, self.max_bins),
-            strategy=strategy, jit=False)
+            strategy=strategy, jit=False), mesh, self.axis)
 
-        def grow(X, g, h, m, nb, ic, hn, mono, fm):
-            return grow_t(X, None, g, h, m, nb, ic, hn, mono, fm)
-        tree_specs = self._tree_specs(self.axis)
-        self._grow = jax.jit(jax.shard_map(
-            grow, mesh=self.mesh,
-            in_specs=(P(self.axis), P(self.axis), P(self.axis), P(self.axis),
-                      P(), P(), P(), P(), P()),
-            out_specs=tree_specs,
-            check_vma=False))
-
-    @staticmethod
-    def _tree_specs(axis):
-        return GrownTree(
-            split_feature=P(), threshold_bin=P(), nan_bin=P(),
-            cat_member=P(), decision_type=P(), left_child=P(),
-            right_child=P(), split_gain=P(), internal_value=P(),
-            internal_weight=P(), internal_count=P(), leaf_value=P(),
-            leaf_weight=P(), leaf_count=P(), num_leaves=P(),
-            row_leaf=P(axis), hist_passes=P(), wave_passes=P(),
-            endgame_passes=P(), ramp_committed=P())
-
-    def _init_wave(self, config, num_features, num_bins, is_cat, has_nan,
-                   monotone, impl, sp, local_sp):
-        from ..learner.wave import make_wave_grow_fn
-        from ..ops.quantize import quant_levels
-        self.pallas = impl == "pallas"
-        self._x_src = None
-        self.supports_extras = True
-        self.quantized = bool(config.use_quantized_grad)
-        if np.any(np.asarray(is_cat)):
-            # voting gates cats off inside the grower (full-batch psum
-            # fallback) but the wave scan still runs full feature space
-            sp = sp._replace(cat_idx=tuple(
-                int(j) for j in np.where(np.asarray(is_cat))[0]))
-        self.split_params = sp
-        from ..learner.serial import resolve_monotone_method
-        mc_inter = resolve_monotone_method(config, sp.use_monotone,
-                                           wave=True)
-        self._use_node_key = sp.feature_fraction_bynode < 1.0 or \
-            sp.extra_trees
-        gq_max, hq_max = quant_levels(int(config.num_grad_quant_bins))
-        strategy = WaveVotingStrategy(self.axis, nshards=self.ndev,
-                                      top_k=self.top_k,
-                                      local_params=local_sp)
-        grow_w = make_wave_grow_fn(
-            num_leaves=int(config.num_leaves), num_features=num_features,
-            max_bins=self.max_bins, max_depth=int(config.max_depth),
-            split_params=sp,
-            hist_impl=impl, any_cat=bool(np.any(np.asarray(is_cat))),
-            wave_size=int(config.tpu_wave_size), strategy=strategy,
-            jit=False, quantized=self.quantized, gq_max=gq_max,
-            hq_max=hq_max,
-            renew_leaf=bool(config.quant_train_renew_leaf),
-            stochastic=bool(config.stochastic_rounding),
-            mc_inter=mc_inter,
-            spec_ramp=bool(config.tpu_speculative_ramp),
-            spec_tol=float(config.tpu_spec_tolerance),
-            exact_endgame=bool(config.tpu_exact_endgame))
-
-        nq = int(self.quantized)
-        nn = int(self._use_node_key)
-
-        def grow(X_T, g, h, m, nb, ic, hn, mono, fm, cegb, *rest):
-            kw = {}
-            ki = 0
-            if nq:
-                kw["quant_key"] = rest[ki]
-                ki += 1
-            if nn:
-                kw["node_key"] = rest[ki]
-            return grow_w(X_T, g, h, m, nb, ic, hn, mono, cegb, (), fm,
-                          **kw)
-
-        tree_specs = self._tree_specs(self.axis)
-        self._grow = jax.jit(jax.shard_map(
-            grow, mesh=self.mesh,
-            in_specs=(P(None, self.axis), P(self.axis), P(self.axis),
-                      P(self.axis), P(), P(), P(), P(), P(), P()) +
-            (P(),) * (nq + nn),
-            out_specs=tree_specs,
-            check_vma=False))
-
-    def train(self, X_dev: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
-              sample_mask: jnp.ndarray,
-              feature_mask: Optional[jnp.ndarray] = None,
-              quant_key=None, cegb_penalty=None,
-              node_key=None) -> GrownTree:
-        if feature_mask is None:
-            feature_mask = jnp.ones((self.num_features,), jnp.bool_)
+    def _train_other(self, X_dev, grad, hess, sample_mask, feature_mask,
+                     cegb_penalty, node_key) -> GrownTree:
         n = X_dev.shape[0]
-        if self.wave:
-            if self.pallas:
-                from ..ops.histogram_pallas import DEFAULT_ROW_BLOCK
-                quantum = self.ndev * DEFAULT_ROW_BLOCK
-            else:
-                quantum = self.ndev * 8
-            pad = (-n) % quantum
-            if self._x_src is not X_dev:
-                Xp = jnp.pad(X_dev, ((0, pad), (0, 0))) if pad else X_dev
-                self._XpT = shard_rows(self.mesh, jnp.swapaxes(Xp, 0, 1),
-                                       self.axis, dim=1)
-                self._x_src = X_dev
-            if pad:
-                grad = jnp.pad(grad, (0, pad))
-                hess = jnp.pad(hess, (0, pad))
-                sample_mask = jnp.pad(sample_mask, (0, pad))
-            grad, hess, sample_mask = (
-                shard_rows(self.mesh, v, self.axis)
-                for v in (grad, hess, sample_mask))
-            if cegb_penalty is None:
-                cegb_penalty = jnp.zeros((self.num_features,), jnp.float32)
-            keys = []
-            if self.quantized:
-                if quant_key is None:
-                    self._quant_calls = getattr(self, "_quant_calls", 0) + 1
-                    quant_key = jax.random.PRNGKey(self._quant_calls)
-                keys.append(quant_key)
-            if self._use_node_key:
-                if node_key is None:
-                    node_key = jnp.zeros((2, 2), jnp.uint32)
-                keys.append(node_key)
-            grown = self._grow(self._XpT, grad, hess, sample_mask,
-                               self.num_bins, self.is_cat, self.has_nan,
-                               self.monotone, feature_mask, cegb_penalty,
-                               *keys)
-            if pad:
-                grown = grown._replace(row_leaf=grown.row_leaf[:n])
-            return grown
         pad = (-n) % self.ndev
         if pad:
             X_dev = jnp.pad(X_dev, ((0, pad), (0, 0)))

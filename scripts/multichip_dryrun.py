@@ -28,13 +28,10 @@ def collective_bytes_snapshot(n_devices: int) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import PartitionSpec as P
-
     from lightgbm_tpu.learner.wave import make_wave_grow_fn
     from lightgbm_tpu.ops.split import SplitParams
-    from lightgbm_tpu.parallel.data_parallel import (
-        DataParallelTreeLearner, WaveDPStrategy)
-    from lightgbm_tpu.parallel.mesh import get_mesh
+    from lightgbm_tpu.parallel.data_parallel import WaveDPStrategy
+    from lightgbm_tpu.parallel.mesh import get_mesh, shard_wave_grower
     from lightgbm_tpu.parallel.voting_parallel import WaveVotingStrategy
     from lightgbm_tpu.telemetry.train_record import (collectives_reset,
                                                      collectives_snapshot)
@@ -65,13 +62,9 @@ def collective_bytes_snapshot(n_devices: int) -> dict:
             split_params=sp, hist_impl="pallas", any_cat=False,
             interpret=True, jit=False, wave_size=4, stochastic=False,
             quantized=True, strategy=strategy)
-        wrapped = jax.shard_map(
+        wrapped = shard_wave_grower(
             lambda X_T, g, h, m, nb, ic, hn, mono, cp, fm: grow(
-                X_T, g, h, m, nb, ic, hn, mono, cp, (), fm),
-            mesh=mesh,
-            in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P(), P(),
-                      P(), P(), P()),
-            out_specs=DataParallelTreeLearner._tree_specs(ax), check_vma=False)
+                X_T, g, h, m, nb, ic, hn, mono, cp, (), fm), mesh, ax)
         collectives_reset()
         jax.make_jaxpr(lambda *a: wrapped(*a))(*args)
         out[mode] = collectives_snapshot()

@@ -15,9 +15,8 @@ from lightgbm_tpu.analysis import ir
 from lightgbm_tpu.learner.wave import make_wave_grow_fn
 from lightgbm_tpu.ops.histogram_pallas import pad_rows
 from lightgbm_tpu.ops.split import SplitParams
-from lightgbm_tpu.parallel.data_parallel import (DataParallelTreeLearner,
-                                                 WaveDPStrategy)
-from lightgbm_tpu.parallel.mesh import get_mesh
+from lightgbm_tpu.parallel.data_parallel import WaveDPStrategy
+from lightgbm_tpu.parallel.mesh import get_mesh, shard_wave_grower
 
 F, B, CHIPS, W = 6, 64, 4, 4
 DP_SCOPES = {"lgbm.dp.hist_reduce", "lgbm.dp.exchange", "lgbm.dp.scalar"}
@@ -59,17 +58,12 @@ def _args(n):
 
 
 def _dp_program(scatter):
-    from jax.sharding import PartitionSpec as P
     mesh = get_mesh(CHIPS)
     ax = mesh.axis_names[0]
     grow = _grow_fn(WaveDPStrategy(ax, nshards=CHIPS, hist_scatter=scatter))
-    return jax.jit(jax.shard_map(
+    return shard_wave_grower(
         lambda X_T, g, h, m, nb, ic, hn, mono, cp, fm: grow(
-            X_T, g, h, m, nb, ic, hn, mono, cp, (), fm),
-        mesh=mesh,
-        in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P(), P(), P(),
-                  P(), P()),
-        out_specs=DataParallelTreeLearner._tree_specs(ax), check_vma=False))
+            X_T, g, h, m, nb, ic, hn, mono, cp, (), fm), mesh, ax)
 
 
 @pytest.mark.parametrize("scatter", [True, False],

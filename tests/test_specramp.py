@@ -133,16 +133,10 @@ def _mk_grow_dp(strategy, spec, wave=4, leaves=13, quantized=True):
 
 
 def _wrap_dp(grow, mesh, ax):
-    import jax
-    from jax.sharding import PartitionSpec as P
-    from lightgbm_tpu.parallel.data_parallel import DataParallelTreeLearner
-    return jax.jit(jax.shard_map(
+    from lightgbm_tpu.parallel.mesh import shard_wave_grower
+    return shard_wave_grower(
         lambda X_T, g, h, m, nb, ic, hn, mono, cp, fm: grow(
-            X_T, g, h, m, nb, ic, hn, mono, cp, (), fm),
-        mesh=mesh,
-        in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P(), P(), P(),
-                  P(), P()),
-        out_specs=DataParallelTreeLearner._tree_specs(ax), check_vma=False))
+            X_T, g, h, m, nb, ic, hn, mono, cp, (), fm), mesh, ax)
 
 
 def test_spec_dp_matches_serial_on_mesh():

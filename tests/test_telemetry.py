@@ -373,12 +373,10 @@ def _trace_dp_grow(spec, wave=4):
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.analysis import ir
-    from jax.sharding import PartitionSpec as P
     from lightgbm_tpu.learner.wave import make_wave_grow_fn
     from lightgbm_tpu.ops.split import SplitParams
-    from lightgbm_tpu.parallel.data_parallel import (DataParallelTreeLearner,
-                                                     WaveDPStrategy)
-    from lightgbm_tpu.parallel.mesh import get_mesh
+    from lightgbm_tpu.parallel.data_parallel import WaveDPStrategy
+    from lightgbm_tpu.parallel.mesh import get_mesh, shard_wave_grower
     mesh = get_mesh(8)
     ax = mesh.axis_names[0]
     sp = SplitParams(min_data_in_leaf=5, min_sum_hessian_in_leaf=0.0,
@@ -389,13 +387,9 @@ def _trace_dp_grow(spec, wave=4):
         jit=False, wave_size=wave, quantized=True, stochastic=False,
         spec_ramp=spec, spec_tol=0.02,
         strategy=WaveDPStrategy(ax, nshards=8))
-    wrapped = jax.jit(jax.shard_map(
+    wrapped = shard_wave_grower(
         lambda X_T, g, h, m, nb, ic, hn, mono, cp, fm: grow(
-            X_T, g, h, m, nb, ic, hn, mono, cp, (), fm),
-        mesh=mesh,
-        in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P(), P(), P(),
-                  P(), P()),
-        out_specs=DataParallelTreeLearner._tree_specs(ax), check_vma=False))
+            X_T, g, h, m, nb, ic, hn, mono, cp, (), fm), mesh, ax)
     bins, grad, hess, mask, n = _mk_dp_data(8 * 4096 - 100)
     nb = jnp.full((6,), 64, jnp.int32)
     args = (jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),

@@ -26,16 +26,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jax.sharding import PartitionSpec as P
-
 import lightgbm_tpu as lgb
 from lightgbm_tpu.learner.wave import make_wave_grow_fn
 from lightgbm_tpu.ops.split import SplitParams
 from lightgbm_tpu.parallel.data_parallel import WaveDPStrategy
-from lightgbm_tpu.parallel.mesh import get_mesh
+from lightgbm_tpu.parallel.mesh import get_mesh, shard_wave_grower
 from lightgbm_tpu.parallel.voting_parallel import (
-    QuantizedGradUnsupportedError, VotingParallelTreeLearner,
-    WaveVotingStrategy, modeled_pass_bytes, voting_favored)
+    QuantizedGradUnsupportedError, WaveVotingStrategy, modeled_pass_bytes,
+    voting_favored)
 
 F, B, LEAVES, WAVE = 6, 64, 13, 4
 NSH = 4            # shards: pallas row_block=4096 per shard bounds n
@@ -66,13 +64,9 @@ def _mk_grow(strategy, quantized=True, spec=False):
 
 
 def _wrap_dp(grow, mesh, ax):
-    return jax.jit(jax.shard_map(
+    return shard_wave_grower(
         lambda X_T, g, h, m, nb, ic, hn, mono, cp, fm: grow(
-            X_T, g, h, m, nb, ic, hn, mono, cp, (), fm),
-        mesh=mesh,
-        in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P(), P(), P(),
-                  P(), P()),
-        out_specs=VotingParallelTreeLearner._tree_specs(ax), check_vma=False))
+            X_T, g, h, m, nb, ic, hn, mono, cp, (), fm), mesh, ax)
 
 
 def _meta_args():
