@@ -541,5 +541,6 @@ class ChunkedWaveGrower:
             # every streamed pass after the root's is a wave: this grower
             # has neither a ramp nor an endgame
             wave_passes=host["hist_passes"] - 1,
-            endgame_passes=np.int32(0), ramp_committed=np.int32(0))
+            endgame_passes=np.int32(0), ramp_committed=np.int32(0),
+            hist_rows_contracted=np.zeros((1, 2), np.int32))
         return grown, rl_chunks

@@ -81,13 +81,19 @@ class GrownTree(NamedTuple):
     ramp_committed: jnp.ndarray    # () int32 — splits the speculative
     #                                ramp's verifying pass committed, of
     #                                W-1 provisional (0 with the ramp off)
+    hist_rows_contracted: jnp.ndarray  # (shards, 2) int32 — per row shard
+    #                                [count, unit]: count * unit rows were
+    #                                looped over by the histogram kernels of
+    #                                those passes (hist_passes * N unless a
+    #                                pass compacted its rows; 0 = untracked)
 
 
 def untracked_passes() -> dict:
     """The pass-count fields of a grower that does not count passes."""
     z = jnp.asarray(0, jnp.int32)
     return dict(hist_passes=z, wave_passes=z, endgame_passes=z,
-                ramp_committed=z)
+                ramp_committed=z,
+                hist_rows_contracted=jnp.zeros((1, 2), jnp.int32))
 
 
 def local_best_candidate(hist, leaf_sum, num_bins, is_cat, has_nan,

@@ -50,12 +50,15 @@ grower's sampling exactly).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from ..analysis.contracts import collective_contract, memory_budget
 from ..models.tree import CAT_MASK, DEFAULT_LEFT_MASK, MISSING_NAN
 from ..ops.histogram import build_histogram_leaves, histogram_subtract
+from ..ops.histogram_pallas import DEFAULT_ROW_BLOCK
 from ..ops.quantize import dequant_scales, quantize_wch
 from ..ops.split import (BIG, NEG_INF, _leaf_gain, best_split_per_feature,
                          leaf_output,
@@ -475,6 +478,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
         # and the W feature ids: the kernel fetches the columns it needs
         # itself, and no (W, N) array is built between X_T and it.
         n = X_T.shape[1] * 2 if pack4 else X_T.shape[1]
+        # what ``hist_rows`` counts in (exact in int32 where rows are not)
+        row_unit = math.gcd(n, DEFAULT_ROW_BLOCK)
 
         def router_bins(mat):
             """What the fused row-update kernel reads ``mat``'s columns
@@ -661,39 +666,51 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 return hmg
             return hmg, _dqh(hmg[:, 0].sum(axis=1))
 
-        def hist_waves(ch, k=W, with_totals=False):
+        def hist_waves(ch, k=W, with_totals=False, sparse=False):
             """(k, G_loc, Bb, 3) histograms of the wave's leaf channels,
             reduced across row shards (serial: identity; DP scatter mode:
             this shard's feature block of the merged batch).  ``k`` trims
             the cross-shard reduction to the channels actually used (the
             root pass needs only channel 0).  Quantized mode returns
-            exact int32 channel sums (dequantize with ``dq``)."""
-            if quantized:
-                if pallas:
-                    h = build_histogram_pallas_leaves_q8(
-                        X_T, wch0, ch, num_bins=Bb, interpret=interpret,
-                        pipeline=pipeline, bins_packed=pack4)
-                else:
-                    # off-TPU emulation: f32 sums of integer levels are
-                    # exact while |sum| < 2^24 per bin — ample for the
-                    # CPU/test shards this path serves (the Pallas path
-                    # accumulates true int32 and has no such cap)
-                    h = build_histogram_leaves(
-                        bins_rows, wch0[0].astype(jnp.float32),
-                        wch0[1].astype(jnp.float32),
-                        wch0[2].astype(jnp.float32), ch,
-                        num_channels=W, num_bins=Bb, impl=hist_impl)
-                    h = jnp.round(h).astype(jnp.int32)
-            elif pallas:
-                h = build_histogram_pallas_leaves(X_T, w8, ch, num_bins=Bb,
-                                                  interpret=interpret,
-                                                  pipeline=pipeline,
-                                                  bins_packed=pack4)
+            exact int32 channel sums (dequantize with ``dq``).
+
+            ``sparse`` is a property of the call site, not an option: the
+            wave body and the endgame pass channels that hold the
+            splits' SMALLER children and -1 for every other row, so
+            their Pallas ``dma`` kernels first move the active rows to
+            the front and contract only the row blocks that hold them
+            (ops/histogram_pallas.py ``compact``; each shard compacts its
+            own rows, before the collective).  The verify pass of the
+            ramp and the root pass put every row in a channel and the
+            ramp's provisional passes run on a subsample: they keep the
+            direct call.  Returns ``(histograms, rows)``, ``rows`` the
+            rows this shard's kernel looped over, in ``row_unit``s."""
+            rows = n // row_unit
+            if pallas:
+                build = build_histogram_pallas_leaves_q8 if quantized \
+                    else build_histogram_pallas_leaves
+                h = build(X_T, wch0 if quantized else w8, ch, num_bins=Bb,
+                          interpret=interpret, pipeline=pipeline,
+                          bins_packed=pack4, compact=sparse)
+                if sparse:
+                    h, rows = h
+                    rows = rows // row_unit
+            elif quantized:
+                # off-TPU emulation: f32 sums of integer levels are
+                # exact while |sum| < 2^24 per bin — ample for the
+                # CPU/test shards this path serves (the Pallas path
+                # accumulates true int32 and has no such cap)
+                h = build_histogram_leaves(
+                    bins_rows, wch0[0].astype(jnp.float32),
+                    wch0[1].astype(jnp.float32),
+                    wch0[2].astype(jnp.float32), ch,
+                    num_channels=W, num_bins=Bb, impl=hist_impl)
+                h = jnp.round(h).astype(jnp.int32)
             else:
                 h = build_histogram_leaves(
                     bins_rows, gm, hm, cnt_mask, ch,
                     num_channels=W, num_bins=Bb, impl=hist_impl)
-            return _reduce_waves(h, k, with_totals)
+            return _reduce_waves(h, k, with_totals), rows
 
         def feature_col(feat):
             """FEATURE-space bin codes (N,) of one feature (decoded from
@@ -1017,8 +1034,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 rl_full = rlf.astype(jnp.uint8)
 
             # -- ONE full-data pass: exact per-prov-leaf channel sums --
-            h_ch, leaf_tot = hist_waves(rl_full.astype(jnp.int8), k=Kc,
-                                        with_totals=True)     # (Kc, 3)
+            (h_ch, leaf_tot), _ = hist_waves(
+                rl_full.astype(jnp.int8), k=Kc, with_totals=True)  # (Kc, 3)
             # voting: keep the batch RAW and shard-local — the node-sum
             # einsum is exact in int32 and _voting_candidates merges
             # (and dequantizes) only the voted slices
@@ -1145,6 +1162,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 # mega-pass (the ~log2(W) provisional passes run at
                 # subsample scale and are not counted)
                 "hist_passes": jnp.asarray(1, jnp.int32),
+                "hist_rows": jnp.asarray(n // row_unit, jnp.int32),
             }
 
         if use_spec:
@@ -1160,12 +1178,13 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     # for every feature, shard and merge mode) so candidate
                     # left+right sums stay consistent with the totals
                     # downstream
-                    rh, rtot = hist_waves(jnp.zeros((n,), jnp.int8), k=1,
-                                          with_totals=True)
+                    (rh, rtot), _ = hist_waves(jnp.zeros((n,), jnp.int8),
+                                               k=1, with_totals=True)
                     root_hist = rh[0]
                     root_sum = rtot[0]
                 else:
-                    root_hist = hist_waves(jnp.zeros((n,), jnp.int8), k=1)[0]
+                    root_hist = hist_waves(jnp.zeros((n,), jnp.int8),
+                                           k=1)[0][0]
                     root_sum = strat.reduce_sum(jnp.stack([
                         jnp.sum(gm), jnp.sum(hm), jnp.sum(cnt_mask)]))
                 root_hist_f = dq(root_hist) if quantized else root_hist
@@ -1251,6 +1270,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     "num_leaves": jnp.asarray(1, jnp.int32),
                     "done": jnp.asarray(False),
                     "hist_passes": jnp.asarray(1, jnp.int32),  # the root pass
+                    "hist_rows": jnp.asarray(n // row_unit, jnp.int32),
                 }
                 if use_mc:
                     state["leaf_mn"] = jnp.full((L,), -BIG, jnp.float32)
@@ -1449,7 +1469,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
 
             # ---- one kernel pass: all W smaller-child histograms ----
             with jax.named_scope("lgbm.wave.hist"):
-                hist_small = hist_waves(ch)                    # (W, G, Bb, 3)
+                hist_small, rows = hist_waves(ch, sparse=True)  # (W, G, Bb, 3)
                 parents = s["hists"][sel_leaves]
                 hist_big = parents - hist_small
                 ls4 = left_smaller[:, None, None, None]
@@ -1723,6 +1743,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 out["num_leaves"] = nl0 + total_new
                 out["done"] = total_new == 0
                 out["hist_passes"] = s["hist_passes"] + 1
+                out["hist_rows"] = s["hist_rows"] + rows
             return out
 
         if use_endgame:
@@ -1922,7 +1943,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     ch = _trial_channels(rl, sel, sel_leaves, feat, thr,
                                          fnanb, dleft, small)
                 with jax.named_scope("lgbm.endgame.hist"):
-                    bank = hist_waves(ch)       # (W, G, Bb, 3); DP: one psum
+                    # (W, G, Bb, 3); DP: one psum
+                    bank, rows = hist_waves(ch, sparse=True)
                 slot = jnp.full((L,), -1, jnp.int32).at[
                     jnp.where(sel, sel_leaves, L)].set(
                         jnp.arange(W, dtype=jnp.int32), mode="drop")
@@ -1932,6 +1954,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                         (s, slot, pend, pcnt))
                 s = dict(s)
                 s["hist_passes"] = s["hist_passes"] + 1
+                s["hist_rows"] = s["hist_rows"] + rows
                 return (s, pend, pcnt)
 
         def cond(s):
@@ -2011,7 +2034,9 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             row_leaf=s["row_leaf"].astype(jnp.int32),
             hist_passes=s["hist_passes"], wave_passes=wave_passes,
             endgame_passes=s["hist_passes"] - 1 - wave_passes,
-            ramp_committed=ramp_committed)
+            ramp_committed=ramp_committed,
+            hist_rows_contracted=jnp.stack(
+                [s["hist_rows"], jnp.asarray(row_unit, jnp.int32)])[None])
         if use_lazy:
             return tree_out, s["used"]
         return tree_out

@@ -974,7 +974,8 @@ class GBDT:
             self.last_hist_passes = grown.hist_passes
             rec.add_tree(self.iter_, cid, grown.hist_passes,
                          grown.num_leaves, grown.wave_passes,
-                         grown.endgame_passes, grown.ramp_committed)
+                         grown.endgame_passes, grown.ramp_committed,
+                         grown.hist_rows_contracted)
             if self.flight.enabled:
                 # last grown tree's fields for this iteration's
                 # flight event (device scalars, pulled lazily on
@@ -1107,9 +1108,12 @@ class GBDT:
             # keep only what _grown_to_tree reads: dropping row_leaf
             # releases the (N,) per-tree assignment (42 MB/tree at Higgs
             # scale) instead of holding it in HBM until flush and hauling
-            # it through the device->host pull
+            # it through the device->host pull (the two row-sharded
+            # fields: in a multi-process world no process can pull them)
             self._pending.append(
-                (grown._replace(row_leaf=jnp.zeros((0,), jnp.int32)),
+                (grown._replace(
+                    row_leaf=jnp.zeros((0,), jnp.int32),
+                    hist_rows_contracted=np.zeros((0, 2), np.int32)),
                  shrinkage, bias))
             tree = None
         else:

@@ -700,9 +700,9 @@ def _build_histogram_pallas_leaves_bs(bins_t: jnp.ndarray, w8: jnp.ndarray,
     return jnp.transpose(hist, (2, 0, 1, 3))[:, :f, :num_bins, :]
 
 
-def _leaves_dma_common(bins_hbm, w_hbm, ch_hbm, out_ref, *, num_features,
-                       contracted, num_bins, group, fstep, kr, nsteps,
-                       packed, make_w128, onehot_dtype, acc_dtype):
+def _leaves_dma_common(*refs, num_features, contracted, num_bins, group,
+                       fstep, kr, nsteps, packed, make_w128, onehot_dtype,
+                       acc_dtype):
     """Shared DMA pipeline of the two leaf-batched kernels: bins,
     feature-major weights and the leaf-channel row stream HBM->VMEM via
     double-buffered async copies overlapping the contraction.
@@ -712,7 +712,19 @@ def _leaves_dma_common(bins_hbm, w_hbm, ch_hbm, out_ref, *, num_features,
     Only the first ``contracted`` feature rows (the real features rounded
     up to ``fstep``) are one-hot encoded and contracted: a ragged last
     tile runs fewer feature steps, and the accumulator rows it skips
-    keep the zeros they start with."""
+    keep the zeros they start with.
+
+    ``nsteps``, the row blocks swept, is static (every row block of the
+    operands: the verify, root and subsample passes, where every row is
+    in a channel) or, when None, read from a prefetched scalar: a pass
+    over rows compacted by :func:`_compact_rows_dma` loops over the
+    blocks that hold active lanes and no further.  The operands keep
+    their static shapes; only the trip count depends on the data."""
+    if nsteps is None:
+        steps_ref, bins_hbm, w_hbm, ch_hbm, out_ref = refs
+        nsteps = steps_ref[0]
+    else:
+        bins_hbm, w_hbm, ch_hbm, out_ref = refs
     out_ref[...] = jnp.zeros_like(out_ref)
     ft = num_features
     b = num_bins
@@ -829,6 +841,303 @@ def _make_w128_q8(w, ch):
     return (wtile * sel).astype(jnp.int8)
 
 
+# ---------------------------------------------------------------------------
+# Row compaction in front of the DMA leaf kernels.
+#
+# After a tree's first pass, the channel row ``ch`` of a histogram pass
+# is mostly -1: the wave and endgame passes build the SMALLER children
+# only (learner/wave.py), 8-37%% of the rows.  A row with ch == -1 gets
+# an all-zero column of the (128, kr) right operand and the MXU does its
+# fc x B x 128 MACs on it all the same.  :func:`_compact_rows_dma` moves
+# the lanes with ch >= 0 of every operand the leaf kernel streams (bins,
+# weights, ch) to the front, in their original order, block by block;
+# the leaf kernel then loops over the row blocks that hold active lanes.
+#
+# How: rows are streamed in blocks of ``kb`` lanes, each handled in
+# sub-blocks of ``_CP_SUB`` lanes.  A sub-block's active lanes go to a
+# 128-aligned WINDOW of ``_CP_SUB + 128`` lanes of the block's output
+# buffer by one 0/1 selection matmul on the MXU: ``(rows, sub) .
+# (window, sub)^T`` with ``sel[p, s] = (dest[s] == p)``.  Bin codes,
+# int8 levels, bf16 halves and channel ids are all exact in bf16, every
+# output lane has at most one non-zero product, and the accumulator is
+# f32, so the moved values are exact.  The window's first tile carries
+# the partial tile the previous sub-block left (a loop-carried value).
+# ``dest`` (each lane's place in its window, -1 for inactive lanes), the
+# windows' tile offsets and the blocks' output offsets are small XLA
+# work on ``ch`` (:func:`_compact_plan`) but the in-sub-block prefix
+# count, one triangular matmul in a kernel of its own.  Blocks
+# are written to HBM end to end at 128-aligned offsets as FIXED-size
+# (kb-lane) copies: a block's tail beyond its active lanes is
+# overwritten by the next block's copy (each copy is waited for before
+# the next starts), the last by one block of padding.  Padding lanes
+# carry ch = -1 and zero weights.  No gather, no sort, no permutation
+# kept from pass to pass.
+# ---------------------------------------------------------------------------
+
+_CP_SUB = 512     # source lanes per selection matmul
+_CP_KB = 8192     # lanes per compaction block, at most (a power of two)
+_CP_CH_BITS = 6   # ch + 1 (0..42) rides in the low bits of ``code``
+_CP_GROUP = 96    # bin rows per selection matmul, at most (whole u8 tiles)
+_CP_VMEM = 6 << 20  # the bin buffers' budget (in + out, double-buffered)
+
+
+_CP_PLAN_ROWS = 1024   # sub-blocks per step of the plan kernel
+
+
+def _compact_code_kernel(ch_ref, rem_ref, code_ref):
+    """``code`` of ``_CP_PLAN_ROWS`` sub-blocks, one a row: the exclusive
+    prefix count of each row's active lanes is one triangular matmul.
+    (In XLA the same matmul costs the grower 45 s of compile time and a
+    cumulative sum 1.6 ms a pass more at 21M rows: PERF.md section 6.)"""
+    sub = ch_ref.shape[1]
+    ch = ch_ref[...]
+    act = ch >= 0
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0) <
+           jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1))
+    pre = jnp.dot(act.astype(jnp.bfloat16), tri.astype(jnp.bfloat16),
+                  preferred_element_type=jnp.float32).astype(jnp.int32)
+    code_ref[...] = jnp.where(
+        act, ((pre + rem_ref[...]) << _CP_CH_BITS) | (ch + 1), -1)
+
+
+def _compact_plan(ch, *, kb: int, kr: int, interpret: bool):
+    """What the compaction kernel is told about ``ch`` (N,) int32:
+
+    ``code`` (1, N) int32: ``dest << 6 | (ch + 1)`` for an active lane,
+      ``dest`` its lane in its sub-block's window; -1 for the others.
+    ``wt`` (N / sub + 1,) int32: each sub-block's window start in its
+      block's output buffer, in 128-lane tiles.
+    ``off`` (N / kb + 1,) int32: each block's offset in the compacted
+      arrays, in lanes (a multiple of 128); the last entry is the total.
+    ``steps`` (1,) int32: the ``kr``-lane blocks that hold the total (at
+      least one: the leaf kernel's pipeline always fetches block 0).
+
+    The counts and offsets are XLA work on N / sub integers; ``code``,
+    which needs each lane's place among its sub-block's active lanes, is
+    a kernel of its own (``lgbm_hist_compact_plan_...``).
+    """
+    n = ch.shape[0]
+    sub = _CP_SUB
+    nsub = kb // sub
+    ch2 = ch.reshape(n // sub, sub)
+    cnt = jnp.sum(ch2 >= 0, axis=1, dtype=jnp.int32).reshape(n // kb, nsub)
+    start = jnp.cumsum(cnt, axis=1) - cnt          # lane in the block
+    rows = min(_CP_PLAN_ROWS, n // sub)
+    code = pl.pallas_call(
+        _compact_code_kernel,
+        grid=(pl.cdiv(n // sub, rows),),
+        in_specs=[pl.BlockSpec((rows, sub), lambda i: (i, 0)),
+                  pl.BlockSpec((rows, 1), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rows, sub), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // sub, sub), jnp.int32),
+        interpret=interpret,
+        name=_kname("hist_compact_plan", s=sub, r=rows, n=n),
+    )(ch2, (start % 128).reshape(n // sub, 1))
+    wt = jnp.concatenate([(start // 128).reshape(-1),
+                          jnp.zeros((1,), jnp.int32)])
+    padded = _round_up(jnp.sum(cnt, axis=1), 128)
+    off = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                           jnp.cumsum(padded)])
+    steps = jnp.maximum(1, -(-off[-1:] // kr))
+    return code.reshape(1, n), wt, off, steps
+
+
+def _compact_kernel(wt_ref, off_ref, bins_hbm, w_hbm, code_hbm, bins_out,
+                    w_out, ch_out, *, kb: int, nblk: int, fc: int,
+                    npad: int):
+    """See the section comment.  ``fc``: the bin rows moved (the rows the
+    leaf kernel contracts; the operands' other rows are padding)."""
+    sub = _CP_SUB
+    nsub = kb // sub
+    wd = sub + 128
+    ntile = wd // 128
+    f_pad = bins_hbm.shape[0]
+    # matmul row groups: ``_CP_GROUP`` bin rows each, the 8 weight rows
+    # (the channel id in the last) behind the last group's
+    groups = [(r0, min(r0 + _CP_GROUP, fc)) for r0 in range(0, fc, _CP_GROUP)]
+    iota_wd = jax.lax.broadcasted_iota(jnp.int32, (wd, sub), 0)
+    row8 = jax.lax.broadcasted_iota(jnp.int32, (8, sub), 0)
+
+    def body(bbuf, wbuf, cbuf, bo, wo, co, isem, osem):
+        def in_dmas(slot, j):
+            lanes = pl.ds(j * kb, kb)
+            return (pltpu.make_async_copy(bins_hbm.at[:, lanes],
+                                          bbuf.at[slot], isem.at[0, slot]),
+                    pltpu.make_async_copy(w_hbm.at[:, lanes],
+                                          wbuf.at[slot], isem.at[1, slot]),
+                    pltpu.make_async_copy(code_hbm.at[:, lanes],
+                                          cbuf.at[slot], isem.at[2, slot]))
+
+        def out_dmas(slot, at):
+            lanes = pl.ds(pl.multiple_of(at, 128), kb)
+            return tuple(
+                pltpu.make_async_copy(buf.at[slot, :, pl.ds(0, kb)],
+                                      out.at[:, lanes], osem.at[i, slot])
+                for i, (buf, out) in enumerate(
+                    ((bo, bins_out), (wo, w_out), (co, ch_out))))
+
+        def store_bins(slot, r0, r1, lanes, res):
+            """Rows [r0, r1) of the bins, and up to the next group's
+            start the zeros the leaf kernel streams and never reads."""
+            end = min(_round_up(r1, _CP_GROUP), f_pad)
+            bins = res.astype(jnp.int32)
+            if end > r1:
+                bins = jnp.concatenate(
+                    [bins, jnp.zeros((end - r1, res.shape[1]), jnp.int32)],
+                    axis=0)
+            bo[slot, r0:end, lanes] = bins.astype(bo.dtype)
+
+        def store_w(slot, lanes, res):
+            """The 8 weight rows in their own dtype; the channel id comes
+            out of the last, which goes back to the zero it was."""
+            co[slot, :, lanes] = res[7:8].astype(jnp.int32) - 1
+            wrow = jax.lax.broadcasted_iota(jnp.int32, res.shape, 0)
+            wv = jnp.where(wrow == 7, 0.0, res)
+            if jnp.issubdtype(wo.dtype, jnp.integer):
+                wv = wv.astype(jnp.int32)
+            wo[slot, :, lanes] = wv.astype(wo.dtype)
+
+        for d in in_dmas(0, 0):
+            d.start()
+
+        def step(j, carry):
+            slot = j % 2
+
+            @pl.when(j + 1 < nblk)
+            def _():
+                for d in in_dmas((j + 1) % 2, j + 1):
+                    d.start()
+
+            for d in in_dmas(slot, j):
+                d.wait()
+
+            def move(k, tiles0):
+                lanes = pl.ds(pl.multiple_of(k * sub, sub), sub)
+                code = cbuf[slot, :, lanes]                    # (1, sub)
+                sel = ((code >> _CP_CH_BITS) == iota_wd).astype(jnp.bfloat16)
+                chp1 = (code & ((1 << _CP_CH_BITS) - 1)).astype(jnp.float32)
+                i = j * nsub + k
+                w0 = wt_ref[i]
+                full = wt_ref[i + 1] - w0
+                window = pl.ds(pl.multiple_of(w0 * 128, 128), wd)
+                nxt = []
+                for gi, (r0, r1) in enumerate(groups):
+                    data = [bbuf[slot, r0:r1, lanes].astype(jnp.int32)
+                            .astype(jnp.float32)]
+                    last = gi == len(groups) - 1
+                    if last:
+                        wf = wbuf[slot, :, lanes].astype(jnp.float32)
+                        data.append(jnp.where(row8 == 7, chp1, wf))
+                    rows = r1 - r0 + (8 if last else 0)
+                    if rows % 16:      # whole bf16 tiles for the MXU
+                        data.append(jnp.zeros((16 - rows % 16, sub),
+                                              jnp.float32))
+                    res = jax.lax.dot_general(
+                        jnp.concatenate(data, axis=0).astype(jnp.bfloat16),
+                        sel, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)[:rows]
+                    # the window's first tile: what the sub-block before
+                    # left of it, and this one's lanes behind
+                    res = jnp.concatenate(
+                        [res[:, :128] + tiles0[gi], res[:, 128:]], axis=1)
+                    store_bins(slot, r0, r1, window, res[:r1 - r0])
+                    if last:
+                        store_w(slot, window, res[r1 - r0:])
+                    # the partial tile the next window starts with
+                    t_ = res[:, (ntile - 1) * 128:]
+                    for t in range(ntile - 2, -1, -1):
+                        t_ = jnp.where(full == t,
+                                       res[:, t * 128:(t + 1) * 128], t_)
+                    nxt.append(t_)
+                return tuple(nxt)
+
+            jax.lax.fori_loop(
+                0, nsub, move,
+                tuple(jnp.zeros((r1 - r0 + (8 if gi == len(groups) - 1
+                                            else 0), 128), jnp.float32)
+                      for gi, (r0, r1) in enumerate(groups)))
+
+            # copies land in order: block j's tail is block j+1's to
+            # overwrite, so j-1's copy ends before j's starts
+            @pl.when(j >= 1)
+            def _():
+                for d in out_dmas((j + 1) % 2, off_ref[j - 1]):
+                    d.wait()
+
+            for d in out_dmas(slot, off_ref[j]):
+                d.start()
+            return carry
+
+        jax.lax.fori_loop(0, nblk, step, 0)
+        for d in out_dmas((nblk - 1) % 2, off_ref[nblk - 1]):
+            d.wait()
+        # padding behind the last active lane, as far as the leaf
+        # kernel's last row block can reach
+        pad = nblk % 2
+        for r0, r1 in groups:
+            store_bins(pad, r0, r1, pl.ds(0, kb),
+                       jnp.zeros((r1 - r0, kb), jnp.float32))
+        store_w(pad, pl.ds(0, kb), jnp.zeros((8, kb), jnp.float32))
+        for i in range(npad):
+            for d in out_dmas(pad, off_ref[nblk] + i * kb):
+                d.start()
+            for d in out_dmas(pad, off_ref[nblk] + i * kb):
+                d.wait()
+
+    pl.run_scoped(body,
+                  pltpu.VMEM((2, f_pad, kb), bins_hbm.dtype),
+                  pltpu.VMEM((2, 8, kb), w_hbm.dtype),
+                  pltpu.VMEM((2, 1, kb), jnp.int32),
+                  # a dense block's last window ends a tile past kb
+                  pltpu.VMEM((2, f_pad, kb + 128), bins_out.dtype),
+                  pltpu.VMEM((2, 8, kb + 128), w_out.dtype),
+                  pltpu.VMEM((2, 1, kb + 128), jnp.int32),
+                  pltpu.SemaphoreType.DMA((3, 2)),
+                  pltpu.SemaphoreType.DMA((3, 2)))
+
+
+def _compact_block(n: int, f_pad: int) -> int:
+    """Lanes per compaction block: the largest power of two up to
+    ``_CP_KB`` that divides ``n`` and keeps the bin buffers in budget."""
+    kb = math.gcd(n, _CP_KB)
+    while kb > _CP_SUB and 4 * f_pad * kb > _CP_VMEM:
+        kb //= 2
+    if kb % _CP_SUB:
+        raise ValueError(f"row compaction needs {_CP_SUB} | N, got N={n}")
+    return kb
+
+
+def _compact_rows_dma(bins_t, w, ch2, *, fc: int, kr: int, interpret: bool):
+    """Move the lanes with ``ch2 >= 0`` of ``bins_t`` (f_pad, N) uint8
+    (its first ``fc`` rows: the contracted ones), ``w`` (8, N) and ``ch2``
+    (1, N) int32 to the front, block by block and in their order.
+    Returns ``(bins, w, ch, steps)``: the arrays with some lanes more
+    than N, of which the first ``steps[0] * kr`` hold every active lane
+    and else padding (ch -1, zero weights), and ``steps`` (1,) int32 for
+    the leaf kernel's scalar prefetch."""
+    f_pad, n = bins_t.shape
+    kb = _compact_block(n, f_pad)
+    npad = -(-kr // kb)
+    code, wt, off, steps = _compact_plan(ch2[0], kb=kb, kr=kr,
+                                         interpret=interpret)
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    lanes = n + npad * kb
+    outs = pl.pallas_call(
+        functools.partial(_compact_kernel, kb=kb, nblk=n // kb, fc=fc,
+                          npad=npad),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[any_, any_, any_], out_specs=[any_, any_, any_]),
+        out_shape=[jax.ShapeDtypeStruct((f_pad, lanes), bins_t.dtype),
+                   jax.ShapeDtypeStruct((8, lanes), w.dtype),
+                   jax.ShapeDtypeStruct((1, lanes), jnp.int32)],
+        interpret=interpret,
+        name=_kname("hist_compact_dma", f=f_pad, fc=fc, s=_CP_SUB, kb=kb,
+                    n=n),
+    )(wt, off, bins_t, w, code)
+    return (*outs, steps)
+
+
 # stacked one-hot M dim (group * b) cap of the two DMA leaf kernels
 _LEAVES_M_CAP = 1024
 _LEAVES_Q8_M_CAP = 2048
@@ -850,8 +1159,10 @@ def _leaves_dma_tiling(f: int, num_bins: int, m_cap: int):
 
 def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
                      m_cap, kr0, make_w128, onehot_dtype, acc_dtype,
-                     out_dtype, row_block):
-    """Shared wrapper plumbing of the two DMA leaf-kernel builders."""
+                     out_dtype, row_block, compact=False):
+    """Shared wrapper plumbing of the two DMA leaf-kernel builders.
+    Returns ``(out, f_pad, rows)``: ``rows`` is how many rows the kernel
+    looped over, the static N or (``compact``) a device scalar."""
     f = bins_t.shape[0]
     n = bins_t.shape[1] * (2 if packed else 1)
     b, group, fstep, ft, f_pad, fc = _leaves_dma_tiling(f, num_bins, m_cap)
@@ -861,18 +1172,32 @@ def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
     if f_pad != f:
         bins_t = jnp.pad(bins_t, ((0, f_pad - f), (0, 0)))
     kr = math.gcd(row_block, kr0)
-    out = pl.pallas_call(
-        functools.partial(_leaves_dma_common, num_features=ft,
-                          contracted=fc, num_bins=b, group=group,
-                          fstep=fstep, kr=kr, nsteps=n // kr,
-                          packed=packed, make_w128=make_w128,
-                          onehot_dtype=onehot_dtype, acc_dtype=acc_dtype),
+    specs = dict(
         grid=(f_pad // ft,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((ft * b, 128), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((ft * b, 128), lambda i, *_: (i, 0),
+                               memory_space=pltpu.VMEM))
+    if compact:
+        bins_t, w, ch2, steps = _compact_rows_dma(
+            bins_t, w, ch2, fc=fc, kr=kr, interpret=interpret)
+        n = bins_t.shape[1]
+        operands = (steps, bins_t, w, ch2)
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **specs))
+        rows = steps[0] * kr
+    else:
+        operands = (bins_t, w, ch2)
+        rows = n
+    out = pl.pallas_call(
+        functools.partial(_leaves_dma_common, num_features=ft,
+                          contracted=fc, num_bins=b, group=group,
+                          fstep=fstep, kr=kr,
+                          nsteps=None if compact else n // kr,
+                          packed=packed, make_w128=make_w128,
+                          onehot_dtype=onehot_dtype, acc_dtype=acc_dtype),
+        **specs,
         out_shape=jax.ShapeDtypeStruct((f_pad * b, 128), out_dtype),
         cost_estimate=pl.CostEstimate(
             flops=2 * fc * b * n * 128,
@@ -882,31 +1207,32 @@ def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
         interpret=interpret,
         name=_kname(kind + "_dma" + ("_packed4" if packed else ""),
                     f=f_pad, fc=fc, b=b, g=group, kr=kr, n=n),
-    )(bins_t, w, ch2)
-    return out, f_pad
+    )(*operands)
+    return out, f_pad, rows
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "row_block", "interpret",
-                                    "packed"))
+                                    "packed", "compact"))
 def _build_histogram_pallas_leaves_dma(bins_t, w8, ch, *, num_bins,
-                                       row_block, interpret, packed):
+                                       row_block, interpret, packed,
+                                       compact=False):
     n = w8.shape[1]
     ch2 = ch.astype(jnp.int32).reshape(1, n)
-    out, f_pad = _leaves_dma_call(
+    out, f_pad, rows = _leaves_dma_call(
         bins_t, w8, ch2, kind="hist_leaves", num_bins=num_bins,
         interpret=interpret,
         packed=packed, m_cap=_LEAVES_M_CAP, kr0=4096,
         make_w128=_make_w128_bf16,
         onehot_dtype=jnp.bfloat16, acc_dtype=jnp.float32,
-        out_dtype=jnp.float32, row_block=row_block)
+        out_dtype=jnp.float32, row_block=row_block, compact=compact)
     f = bins_t.shape[0]
     b = out.shape[0] // f_pad
     out = out[:, :LEAF_CHANNELS * _CB].reshape(f_pad, b, LEAF_CHANNELS, _CB)
     hist = jnp.stack([out[..., 0] + out[..., 1],
                       out[..., 2] + out[..., 3],
                       out[..., 4]], axis=-1)
-    return jnp.transpose(hist, (2, 0, 1, 3))[:, :f, :num_bins, :]
+    return jnp.transpose(hist, (2, 0, 1, 3))[:, :f, :num_bins, :], rows
 
 
 def build_histogram_pallas_leaves(bins_t: jnp.ndarray, w8: jnp.ndarray,
@@ -914,7 +1240,8 @@ def build_histogram_pallas_leaves(bins_t: jnp.ndarray, w8: jnp.ndarray,
                                   row_block: int = DEFAULT_ROW_BLOCK,
                                   interpret: bool = None,
                                   pipeline: str = None,
-                                  bins_packed: bool = False) -> jnp.ndarray:
+                                  bins_packed: bool = False,
+                                  compact: bool = False):
     """(LEAF_CHANNELS, F, B, 3) histograms of 25 leaf channels in one pass.
 
     Args:
@@ -926,6 +1253,14 @@ def build_histogram_pallas_leaves(bins_t: jnp.ndarray, w8: jnp.ndarray,
         that belong to no batched leaf (they contribute nothing).
       num_bins: static global bin count B.
       interpret / pipeline / bins_packed: as :func:`build_histogram_pallas`.
+      compact: the caller knows ``ch`` to be mostly -1 (a wave's or the
+        endgame's smaller children).  The ``dma`` pipeline then moves the
+        active rows to the front (:func:`_compact_rows_dma`) and contracts
+        the row blocks that hold them; the result is ``(hist, rows)``
+        with ``rows`` the rows the kernel looped over, a device scalar
+        (N itself under ``blockspec`` and nibble-packed bins, which keep
+        the dense form).  f32 sums may differ from the dense pass's in
+        the last bit: the same products meet in other row blocks.
     """
     f, np_ = bins_t.shape
     n = np_ * 2 if bins_packed else np_
@@ -946,12 +1281,15 @@ def build_histogram_pallas_leaves(bins_t: jnp.ndarray, w8: jnp.ndarray,
                  f * np_ * bins_t.dtype.itemsize + n * (_C * 2 + 4) +
                  LEAF_CHANNELS * f * num_bins * 3 * 4, *rows)
     if pipeline == "dma":
-        return _build_histogram_pallas_leaves_dma(
+        hist, rows = _build_histogram_pallas_leaves_dma(
             bins_t, w8, ch, num_bins=num_bins, row_block=row_block,
-            interpret=interpret, packed=bins_packed)
-    return _build_histogram_pallas_leaves_bs(
-        bins_t, w8, ch, num_bins=num_bins, row_block=row_block,
-        interpret=interpret)
+            interpret=interpret, packed=bins_packed,
+            compact=compact and not bins_packed)
+    else:
+        hist, rows = _build_histogram_pallas_leaves_bs(
+            bins_t, w8, ch, num_bins=num_bins, row_block=row_block,
+            interpret=interpret), n
+    return (hist, rows) if compact else hist
 
 
 # ---------------------------------------------------------------------------
@@ -1074,25 +1412,26 @@ def _build_histogram_pallas_leaves_q8_bs(bins_t: jnp.ndarray,
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "row_block", "interpret",
-                                    "packed"))
+                                    "packed", "compact"))
 def _build_histogram_pallas_leaves_q8_dma(bins_t, wch, ch, *, num_bins,
-                                          row_block, interpret, packed):
+                                          row_block, interpret, packed,
+                                          compact=False):
     n = wch.shape[1]
     # int32 channel row: Mosaic cannot slice a (1, kr) slab out of a
     # one-row int8 array (its tiles are 4 sublanes deep)
     ch2 = ch.astype(jnp.int32).reshape(1, n)
-    out, f_pad = _leaves_dma_call(
+    out, f_pad, rows = _leaves_dma_call(
         bins_t, wch, ch2, kind="hist_leaves_q8", num_bins=num_bins,
         interpret=interpret,
         packed=packed, m_cap=_LEAVES_Q8_M_CAP, kr0=4096,
         make_w128=_make_w128_q8,
         onehot_dtype=jnp.int8, acc_dtype=jnp.int32,
-        out_dtype=jnp.int32, row_block=row_block)
+        out_dtype=jnp.int32, row_block=row_block, compact=compact)
     f = bins_t.shape[0]
     b = out.shape[0] // f_pad
     out = out[:, :Q_LEAF_CHANNELS * _QCB].reshape(f_pad, b,
                                                   Q_LEAF_CHANNELS, _QCB)
-    return jnp.transpose(out, (2, 0, 1, 3))[:, :f, :num_bins, :]
+    return jnp.transpose(out, (2, 0, 1, 3))[:, :f, :num_bins, :], rows
 
 
 def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
@@ -1100,8 +1439,8 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
                                      row_block: int = DEFAULT_ROW_BLOCK,
                                      interpret: bool = None,
                                      pipeline: str = None,
-                                     bins_packed: bool = False
-                                     ) -> jnp.ndarray:
+                                     bins_packed: bool = False,
+                                     compact: bool = False):
     """(Q_LEAF_CHANNELS, F, B, 3) int32 histograms of 42 leaf channels.
 
     Args:
@@ -1115,10 +1454,12 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
         weight lanes).
       num_bins: static global bin count B (<= 256).
       interpret / pipeline / bins_packed: as :func:`build_histogram_pallas`.
+      compact: as :func:`build_histogram_pallas_leaves`; the result is
+        then ``(hist, rows)``.
     Returns:
       (42, F, B, 3) int32: channel sums (sum g_q, sum h_q, count) —
-      exact integer sums, so every pipeline/packing variant is
-      bit-for-bit identical.
+      exact integer sums, so every pipeline/packing variant, compacted
+      or not, is bit-for-bit identical.
     """
     f, np_ = bins_t.shape
     n = np_ * 2 if bins_packed else np_
@@ -1139,12 +1480,15 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
                  f * np_ * bins_t.dtype.itemsize + n * 9 +
                  Q_LEAF_CHANNELS * f * num_bins * 3 * 4, *rows)
     if pipeline == "dma":
-        return _build_histogram_pallas_leaves_q8_dma(
+        hist, rows = _build_histogram_pallas_leaves_q8_dma(
             bins_t, wch, ch, num_bins=num_bins, row_block=row_block,
-            interpret=interpret, packed=bins_packed)
-    return _build_histogram_pallas_leaves_q8_bs(
-        bins_t, wch, ch, num_bins=num_bins, row_block=row_block,
-        interpret=interpret)
+            interpret=interpret, packed=bins_packed,
+            compact=compact and not bins_packed)
+    else:
+        hist, rows = _build_histogram_pallas_leaves_q8_bs(
+            bins_t, wch, ch, num_bins=num_bins, row_block=row_block,
+            interpret=interpret), n
+    return (hist, rows) if compact else hist
 
 
 # ---------------------------------------------------------------------------

@@ -41,7 +41,8 @@ def replicate(mesh: Mesh, arr):
 def tree_specs(axis) -> GrownTree:
     """``shard_map`` out_specs of a grown tree: every field replicated
     but ``row_leaf``, which stays with its rows on ``axis`` (``None``
-    where the rows are not sharded)."""
+    where the rows are not sharded), and ``hist_rows_contracted``, one
+    row a shard."""
     return GrownTree(
         split_feature=P(), threshold_bin=P(), nan_bin=P(),
         cat_member=P(), decision_type=P(), left_child=P(),
@@ -49,7 +50,8 @@ def tree_specs(axis) -> GrownTree:
         internal_weight=P(), internal_count=P(), leaf_value=P(),
         leaf_weight=P(), leaf_count=P(), num_leaves=P(),
         row_leaf=P(axis), hist_passes=P(), wave_passes=P(),
-        endgame_passes=P(), ramp_committed=P())
+        endgame_passes=P(), ramp_committed=P(),
+        hist_rows_contracted=P(axis))
 
 
 def shard_wave_grower(grow, mesh, axis: str, *, n_keys: int = 0,

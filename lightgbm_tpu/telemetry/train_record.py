@@ -10,9 +10,14 @@ boosting loop runs:
     counter already asserted by tests/test_endgame.py), their kinds
     (``wave_passes``, ``endgame_passes``; the wave grower's
     ``1 + wave_passes + endgame_passes == hist_passes``), the splits the
-    speculative ramp's verifying pass committed (``ramp_committed``) and
-    leaf counts — kept as device scalars and pulled in batched, lazy
-    fetches so the async dispatch pipeline never stalls;
+    speculative ramp's verifying pass committed (``ramp_committed``),
+    the rows the histogram kernels of those passes looped over
+    (``hist_rows_contracted``: ``hist_passes * N`` padded rows unless
+    the wave and endgame passes compacted theirs,
+    ops/histogram_pallas.py; summed over the row shards this process
+    holds) and leaf counts —
+    kept as device scalars and pulled in batched, lazy fetches so the
+    async dispatch pipeline never stalls;
   * collective count and reduced bytes, tallied at the
     ``parallel/*.py`` collective call sites.  Those sites execute at
     TRACE time (the growers are jit/shard_map programs), so the tally
@@ -340,7 +345,8 @@ class TrainRecord:
         self._phase_s: Dict[str, float] = {}
         self._phase_n: Dict[str, int] = {}
         # per-tree device scalars pending a batched host pull
-        # (iteration, class_id, (hp, nl, wave, endgame, ramp_committed))
+        # (iteration, class_id, (hp, nl, wave, endgame, ramp_committed,
+        #  hist_rows_contracted))
         self._pending: List[tuple] = []
         self._trees: List[Dict[str, int]] = []
         self._setup_s: Dict[str, float] = {}
@@ -385,16 +391,21 @@ class TrainRecord:
 
     def add_tree(self, iteration: int, class_id: int, hist_passes,
                  num_leaves, wave_passes=0, endgame_passes=0,
-                 ramp_committed=0) -> None:
+                 ramp_committed=0, hist_rows_contracted=((0, 0),)) -> None:
         """Record one grown tree.  The counts may be device scalars; they
         are NOT synced here — batches are pulled lazily so the async
         dispatch pipeline keeps flowing."""
         if not _config.enabled():
             return
+        if not getattr(hist_rows_contracted, "is_fully_addressable", True):
+            # a multi-process world: the row shards this process holds
+            hist_rows_contracted = [
+                s.data for s in hist_rows_contracted.addressable_shards]
         with self._lock:
             self._pending.append((int(iteration), int(class_id),
                                   (hist_passes, num_leaves, wave_passes,
-                                   endgame_passes, ramp_committed)))
+                                   endgame_passes, ramp_committed,
+                                   hist_rows_contracted)))
             flush = len(self._pending) >= _FLUSH_EVERY
         if flush:
             self._flush()
@@ -412,6 +423,7 @@ class TrainRecord:
             pending, self._pending = self._pending, []
         if not pending:
             return
+        import numpy as np
         try:
             import jax
             vals = jax.device_get([p[2] for p in pending])
@@ -420,8 +432,12 @@ class TrainRecord:
         rows = [{"iteration": it, "class_id": cid,
                  "hist_passes": int(hp), "num_leaves": int(nl),
                  "wave_passes": int(wp), "endgame_passes": int(ep),
-                 "ramp_committed": int(rc)}
-                for (it, cid, _), (hp, nl, wp, ep, rc) in zip(pending, vals)]
+                 "ramp_committed": int(rc),
+                 # (shards, 2) [count, unit] -> rows, over the shards
+                 "hist_rows_contracted": sum(
+                     int(c) * int(u) for c, u in np.reshape(rows, (-1, 2)))}
+                for (it, cid, _), (hp, nl, wp, ep, rc, rows)
+                in zip(pending, vals)]
         with self._lock:
             self._trees.extend(rows)
 

@@ -643,6 +643,12 @@ def test_grower_builds_no_columns_for_the_row_update(quantized):
     assert int(got.num_leaves) == 13 and int(got.endgame_passes) > 0 \
         and int(got.ramp_committed) > 0
     for name, a, c in zip(got._fields, got, want):
+        if name == "hist_rows_contracted":
+            # [count, unit]: the dma pipeline compacts the wave and
+            # endgame passes' rows, blockspec loops over all of them
+            assert int(a[0, 0]) <= int(c[0, 0]) == \
+                int(want.hist_passes) * (n // int(c[0, 1]))
+            continue
         np.testing.assert_array_equal(np.asarray(a), np.asarray(c),
                                       err_msg=name)
 

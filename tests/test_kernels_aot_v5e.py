@@ -76,6 +76,31 @@ def test_leaf_dma_kernel_compiles_at_cell_shape(S, kind):
     assert name in traced_kernels()
 
 
+@pytest.mark.parametrize("kind", ["q8", "bf16"])
+def test_compacted_leaf_pass_compiles_at_cell_shape(S, kind):
+    """A wave's or the endgame's pass as the grower calls it (ISSUE 31):
+    the plan kernel (a ragged last step), the compaction kernel (selection
+    matmuls, stores at a runtime lane offset, N / 512 window offsets in
+    scalar memory) and behind it the
+    leaf kernel with its trip count read from a prefetched scalar."""
+    if kind == "q8":
+        build, wdt, g = build_histogram_pallas_leaves_q8, jnp.int8, 8
+    else:
+        build, wdt, g = build_histogram_pallas_leaves, jnp.bfloat16, 4
+    compiled = jax.jit(
+        lambda bins, w, ch: build(bins, w, ch, num_bins=MAX_BIN,
+                                  pipeline="dma", interpret=False,
+                                  compact=True)
+    ).lower(S((F, N), jnp.uint8), S((8, N), wdt), S((N,), jnp.int8)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    names = traced_kernels()
+    assert f"lgbm_hist_compact_plan_s512_r1024_n{N}" in names
+    assert f"lgbm_hist_compact_dma_f96_fc72_s512_kb8192_n{N}" in names
+    q = "_q8" if kind == "q8" else ""
+    assert (f"lgbm_hist_leaves{q}_dma_f96_fc72_b256_g{g}_kr4096_n{N + 8192}"
+            in names)
+
+
 @pytest.mark.parametrize("w", [42, 25])       # q8's and exact's wave widths
 def test_row_update_fetch_kernel_compiles_at_cell_shape(S, w):
     """The row update as the grower calls it: the (F, 8, N/8) view of the

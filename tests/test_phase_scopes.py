@@ -84,3 +84,51 @@ def test_a_scope_outside_any_jit_names_nothing():
     with jax.named_scope("lgbm.outside"):
         text = f.lower(jnp.ones((8,))).compile().as_text()
     assert scopes_of(text) == set()
+
+
+def kernel_scopes(hlo_text: str) -> dict:
+    """Kernel name -> the innermost ``lgbm.`` scopes its operations carry
+    (an interpreted kernel's operations keep the ``pallas_call``'s name)."""
+    found = {}
+    for op_name in re.findall(r'op_name="([^"]*)"', hlo_text):
+        kernel = re.search(r"lgbm_hist_[a-z0-9_]+", op_name)
+        if kernel:
+            named = [c for c in op_name.split("/") if c.startswith("lgbm.")]
+            found.setdefault(kernel.group(0), set()).add(
+                named[-1] if named else "")
+    return found
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compaction_kernel_is_listed_and_carries_its_callers_scope(case):
+    """The ``dma`` pipeline's wave and endgame passes compact their rows
+    first: the compaction kernel (``lgbm_hist_`` like the kernels it
+    feeds, so a trace counts it with them) and the leaf kernel behind it
+    sit in the ``.hist`` scope of the site that called them, and the
+    verify / root pass keeps the direct call on all N rows."""
+    from lightgbm_tpu.ops.histogram_pallas import traced_kernels
+    kw, _, _ = CASES[case]
+    sp = SplitParams(min_data_in_leaf=5, min_sum_hessian_in_leaf=0.0,
+                     any_cat=False)
+    grow = make_wave_grow_fn(
+        num_leaves=13, num_features=F, max_bins=B, max_depth=0,
+        split_params=sp, hist_impl="pallas", any_cat=False, interpret=True,
+        jit=True, wave_size=4, stochastic=False, exact_endgame=True,
+        pipeline="dma", **kw)
+    n = pad_rows(3000)
+    scopes = kernel_scopes(grow.lower(*_args(n)).compile().as_text())
+    sparse = {"lgbm.wave.hist", "lgbm.endgame.hist"}
+    compact = [k for k in scopes if k.startswith("lgbm_hist_compact_dma_")]
+    assert len(compact) == 1 and compact[0] in traced_kernels(), scopes
+    assert compact[0].endswith(f"_n{n}") and scopes[compact[0]] == sparse
+    # the lanes' places, a kernel of its own in front of it
+    plan = [k for k in scopes if k.startswith("lgbm_hist_compact_plan_")]
+    assert len(plan) == 1 and scopes[plan[0]] == sparse, scopes
+    leaves = {k: v for k, v in scopes.items()
+              if k.startswith("lgbm_hist_leaves")}
+    # behind the compaction the leaf kernel reads the compacted arrays
+    # (some lanes more than N); on all N rows it is the first pass only
+    assert [v for k, v in leaves.items() if not k.endswith(f"_n{n}")] == \
+        [sparse], leaves
+    assert [v for k, v in leaves.items() if k.endswith(f"_n{n}")] == \
+        [{"lgbm.ramp" if kw["spec_ramp"] else "lgbm.root"}], leaves
