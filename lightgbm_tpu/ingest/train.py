@@ -18,10 +18,12 @@ model text is bit-identical to an in-core ``engine.train`` run of the
 same configuration (tests/test_ingest_train.py).
 
 GOSS (arXiv:1806.11248's gradient-based sampling recipe for the
-out-of-core tail): the per-tree bag rides the SHARED host sampler
-(``models.gbdt.goss_sample_np`` — one Philox stream per
-(bagging_seed, iteration) across the standalone, chunked and
-multi-model trainers), so the streamed run thins exactly the rows the
+out-of-core tail): the per-tree bag comes from the ONE sampler
+(``models.gbdt.goss_sample``, a jitted function on the device: exact
+threshold, a draw keyed by (bagging_seed, iteration); the in-core
+trainer calls it on its device gradients, this driver and the
+multi-model trainer through its host face ``goss_sample_np``; there is
+no host random stream), so the streamed run thins exactly the rows the
 in-core run thins, warmup included.
 
 DART replays the in-core drop bookkeeping (models/boosting.py DART)
@@ -572,7 +574,7 @@ def train_streamed(params: Dict[str, Any], train_set: StreamedDataset,
         if goss:
             # GOSS replaces bagging (in-core GOSS overrides
             # _prepare_iter_sampling and never draws a bag); the draw is
-            # the SHARED host sampler, warmup handled inside
+            # the one jitted sampler's host face, warmup handled inside
             mask = np.ones(n, np.float32)
             gm = goss_sample_np(cfg, grad, hess, it)
             if gm is not None:

@@ -6,7 +6,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -14,7 +13,7 @@ from ..config import Config
 from ..dataset import Dataset
 from ..utils.log import log_warning
 from ..utils.random import host_rng
-from .gbdt import GBDT
+from .gbdt import GBDT, goss_rates, goss_sample
 
 
 class GOSS(GBDT):
@@ -25,10 +24,13 @@ class GOSS(GBDT):
 
     The reference samples an exact count with a per-thread RNG; here the
     "rest" rows are sampled i.i.d. Bernoulli — same distribution,
-    deterministic per (seed, iteration).  The draw itself is HOST-side
-    (``gbdt.goss_sample_np``): one shared Philox stream serves this
-    trainer, the chunked streamed driver and the multi-model batcher, so
-    all three thin the same rows and stay bit-identical to each other."""
+    deterministic per (seed, iteration).  The draw is ONE jitted function
+    on the device (``gbdt.goss_sample``: exact threshold, draw and the
+    scaling of the gradients in one program, no host copy, so the host
+    still runs a tree ahead); the chunked streamed driver and the
+    multi-model batcher reach the same function through its host face
+    (``gbdt.goss_sample_np``), so all three thin the same rows and stay
+    bit-identical to each other.  There is no host random stream."""
 
     name = "goss"
 
@@ -39,16 +41,15 @@ class GOSS(GBDT):
             log_warning("cannot use bagging in GOSS (ignored)")
 
     def _prepare_iter_sampling(self, grad, hess):
-        from .gbdt import goss_sample_np
-        gm = goss_sample_np(self.config, jax.device_get(grad),
-                            jax.device_get(hess), self.iter_)
-        if gm is None:
-            return grad, hess, jnp.ones(self.num_data, jnp.float32)
-        mask, mult = gm
-        scale = jnp.asarray(mult)
-        if grad.ndim == 2:
-            scale = scale[:, None]
-        return grad * scale, hess * scale, jnp.asarray(mask)
+        cfg = self.config
+        rates = goss_rates(cfg, self.iter_)
+        if rates is None:
+            self._last_sample = None
+            return grad, hess, self._all_rows_mask(), self.num_data
+        self._last_sample, mask, grad, hess, sampled_rows = goss_sample(
+            grad, hess, self.iter_, top_rate=rates[0], other_rate=rates[1],
+            bagging_seed=int(cfg.bagging_seed))
+        return grad, hess, mask, sampled_rows
 
 
 class DART(GBDT):

@@ -16,6 +16,7 @@ model, plus metric scalars when evaluation runs.
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -193,11 +194,13 @@ _contracts.donation_contract(
              jnp.zeros((8,), jnp.float32), np.float32(0.1)))
 
 
-# -- host-side per-iteration sampling (pure functions of (config, iter)) ----
+# -- per-iteration sampling (pure functions of (config, iter)) --------------
 # Single-sourced here so the multi-model trainer (lightgbm_tpu/multitrain/)
 # draws bit-identical bags/feature sets for every model in a batch: a
 # train_many() variant and a standalone train() with the same seeds MUST
 # sample the same rows/features or the bit-identity contract breaks.
+# Bagging and feature masks are host draws; the GOSS draw is one jitted
+# function on the device, with a host face for the host-side trainers.
 
 def bagging_mask_np(cfg, n: int, iteration: int,
                     label: Optional[np.ndarray] = None,
@@ -241,46 +244,111 @@ def bagging_mask_np(cfg, n: int, iteration: int,
     return mask
 
 
-def goss_sample_np(cfg, grad: np.ndarray, hess: np.ndarray, iteration: int,
-                   rows: Optional[np.ndarray] = None):
-    """Host GOSS draw (goss.hpp:103-152): keep the top ``top_rate`` rows by
-    |grad*hess|, Bernoulli-sample ``other_rate`` of the rest at b/(1-a) and
-    amplify the survivors' gradients by (1-a)/b; sampling is skipped for the
-    first 1/learning_rate iterations (goss.hpp:157).
+# per-row classes of a GOSS draw
+GOSS_OUT, GOSS_TOP, GOSS_REST = 0, 1, 2
 
-    Single-sourced for the standalone trainer (models/boosting.py), the
-    chunked streamed driver (ingest/train.py) and the multi-model trainer
-    (multitrain/batched.py): one Philox stream per (bagging_seed, iteration)
-    means all three paths thin exactly the same rows and the bit-identity
-    contracts hold across them.  ``rows`` restricts the draw to those row
-    indices (the masked-fold CV path): thresholds and Bernoulli draws are
-    computed over the compacted subset — exactly what a standalone run on
-    ``dataset[rows]`` would draw — and scattered back to full length.
 
-    Returns ``(mask, mult)`` float32 (n,) arrays — 0/1 survivorship and the
-    per-row gradient multiplier — or None when sampling is inactive this
-    iteration (warmup, or top_rate+other_rate >= 1)."""
+def goss_rates(cfg, iteration: int) -> Optional[Tuple[float, float]]:
+    """``(top_rate, other_rate)`` where GOSS samples this iteration; None
+    during the first 1/learning_rate iterations (goss.hpp:157) and where
+    top_rate + other_rate >= 1.  Decided on the host, from the iteration
+    alone."""
     a, b = float(cfg.top_rate), float(cfg.other_rate)
     warmup = int(1.0 / max(float(cfg.learning_rate), 1e-12))
     if iteration < warmup or a + b >= 1.0:
         return None
-    grad = np.asarray(grad)
-    hess = np.asarray(hess)
-    score = np.abs(grad * hess)
-    if score.ndim == 2:  # multiclass: sum |g*h| over classes (goss.hpp:118)
-        score = score.sum(axis=1)
-    n = len(score)
-    sub = score if rows is None else score[rows]
-    nn = len(sub)
-    k = max(1, int(nn * a))
-    thr = np.partition(sub, nn - k)[nn - k]
-    top = sub >= thr
-    rng = host_rng(cfg.bagging_seed, iteration)
-    rest_p = b / max(1.0 - a, 1e-12)
-    keep_rest = (~top) & (rng.random(nn) < rest_p)
-    amp = (1.0 - a) / max(b, 1e-12)
-    sub_mask = (top | keep_rest).astype(np.float32)
-    sub_mult = np.where(keep_rest, np.float32(amp),
+    return a, b
+
+
+def _kth_largest_u32(u: jnp.ndarray, k: int) -> jnp.ndarray:
+    """The exact k-th largest of a uint32 vector: the largest ``t`` with
+    ``count(u >= t) >= k``, found bit by bit from the top (a radix select
+    of radix 2: 32 counting passes, no sort, and a row-sharded ``u`` costs
+    one scalar sum a pass)."""
+    def body(i, t):
+        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        return jnp.where(jnp.sum(u >= cand, dtype=jnp.int32) >= k, cand, t)
+    return jax.lax.fori_loop(0, 32, body, jnp.uint32(0))
+
+
+@functools.partial(jax.jit, static_argnames=("top_rate", "other_rate",
+                                             "bagging_seed"))
+def goss_sample(grad: jnp.ndarray, hess: jnp.ndarray, iteration, *,
+                top_rate: float, other_rate: float, bagging_seed: int):
+    """THE GOSS draw (goss.hpp:103-152), one jitted program on whatever
+    device holds ``grad`` / ``hess`` ((n,) or, multiclass, (n, K) float32).
+
+    Score ``|g*h|`` in float32, summed over classes (goss.hpp:118);
+    ``k = max(1, int(n * top_rate))``; the threshold is the EXACT k-th
+    largest score (non-negative floats order as their bit patterns, see
+    :func:`_kth_largest_u32`) and every row at or above it is kept, ties
+    included; every other row is kept independently with probability
+    ``other_rate / (1 - top_rate)`` from a key that depends on
+    ``(bagging_seed, iteration)`` alone, and carries the multiplier
+    ``(1 - top_rate) / other_rate``.
+
+    Returns ``(cls, mask, grad, hess, sampled_rows)``: the per-row class
+    (uint8: ``GOSS_OUT`` out of the bag, ``GOSS_TOP`` kept at weight 1,
+    ``GOSS_REST`` kept at the multiplier), the float32 0/1 bag mask, the
+    gradients and hessians times their rows' multipliers, and the rows in
+    the bag (int32 scalar).  Row-sharded inputs give row-sharded outputs
+    and the same classes: the threshold is global, and the draw does not
+    depend on the sharding (``jax_threefry_partitionable``)."""
+    a, b = float(top_rate), float(other_rate)
+    with jax.named_scope("lgbm.goss.sample"):
+        score = jnp.abs(grad * hess)
+        if score.ndim == 2:
+            score = score.sum(axis=1)
+        n = score.shape[0]
+        k = max(1, int(n * a))
+        u = jax.lax.bitcast_convert_type(score.astype(jnp.float32),
+                                         jnp.uint32)
+        top = u >= _kth_largest_u32(u, k)
+        key = jax.random.fold_in(jax.random.PRNGKey(bagging_seed), iteration)
+        rest_p = b / max(1.0 - a, 1e-12)
+        keep_rest = ~top & (jax.random.uniform(key, (n,)) < rest_p)
+        cls = jnp.where(top, GOSS_TOP,
+                        jnp.where(keep_rest, GOSS_REST, GOSS_OUT)
+                        ).astype(jnp.uint8)
+        mult = jnp.where(keep_rest, jnp.float32((1.0 - a) / max(b, 1e-12)),
+                         jnp.float32(1.0))
+        if grad.ndim == 2:
+            mult = mult[:, None]
+        in_bag = top | keep_rest
+        return (cls, in_bag.astype(jnp.float32), grad * mult, hess * mult,
+                jnp.sum(in_bag, dtype=jnp.int32))
+
+
+def goss_sample_np(cfg, grad: np.ndarray, hess: np.ndarray, iteration: int,
+                   rows: Optional[np.ndarray] = None):
+    """The host face of :func:`goss_sample`, for the trainers that keep
+    their gradients on the host (the chunked streamed driver,
+    ingest/train.py, and the multi-model trainer, multitrain/batched.py):
+    the SAME jitted function, its classes fetched.  So all three trainers
+    thin exactly the same rows and the bit-identity contracts hold across
+    them; there is no second sampler and no host random stream.  ``rows``
+    restricts the draw to those row indices (the masked-fold CV path):
+    threshold and draw are computed over the compacted subset — exactly
+    what a standalone run on ``dataset[rows]`` would draw — and scattered
+    back to full length.
+
+    Returns ``(mask, mult)`` float32 (n,) arrays — 0/1 survivorship and the
+    per-row gradient multiplier — or None when sampling is inactive this
+    iteration (warmup, or top_rate+other_rate >= 1)."""
+    rates = goss_rates(cfg, iteration)
+    if rates is None:
+        return None
+    a, b = rates
+    grad = np.asarray(grad, np.float32)
+    hess = np.asarray(hess, np.float32)
+    n = len(grad)
+    if rows is not None:
+        grad, hess = grad[rows], hess[rows]
+    cls = jax.device_get(goss_sample(
+        grad, hess, iteration, top_rate=a, other_rate=b,
+        bagging_seed=int(cfg.bagging_seed))[0])
+    sub_mask = (cls != GOSS_OUT).astype(np.float32)
+    sub_mult = np.where(cls == GOSS_REST, np.float32((1.0 - a) / max(b, 1e-12)),
                         np.float32(1.0)).astype(np.float32)
     if rows is None:
         return sub_mask, sub_mult
@@ -839,21 +907,38 @@ class GBDT:
 
     # -- sampling (bagging / GOSS hooks) -------------------------------------
     def _prepare_iter_sampling(self, grad: jnp.ndarray, hess: jnp.ndarray
-                               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """Per-iteration row sampling: returns (grad, hess, mask).  Base GBDT
-        implements bagging (gbdt.cpp:228 Bagging, resampled every
-        bagging_freq iters); GOSS/RF override."""
+                               ) -> Tuple[jnp.ndarray, jnp.ndarray,
+                                          jnp.ndarray, Any]:
+        """Per-iteration row sampling: returns (grad, hess, mask, rows in
+        the bag: a host int or a device scalar).  Base GBDT implements
+        bagging (gbdt.cpp:228 Bagging, resampled every bagging_freq
+        iters); GOSS overrides."""
         cfg = self.config
         n = self.num_data
         label = (np.asarray(self.train_set.metadata.label)
                  if cfg.objective == "binary" and
                  self.train_set.metadata.label is not None else None)
         mask = bagging_mask_np(cfg, n, self.iter_, label=label)
-        if mask is not None:
-            self._bag_mask = jnp.asarray(mask)
-        elif not hasattr(self, "_bag_mask") or self._bag_mask.shape[0] != n:
-            self._bag_mask = self._put_rows(np.ones(n, np.float32))
-        return grad, hess, self._bag_mask
+        if mask is None:
+            self._bag_mask = self._all_rows_mask()
+            return grad, hess, self._bag_mask, n
+        self._bag_mask = jnp.asarray(mask)
+        return grad, hess, self._bag_mask, int(mask.sum())
+
+    def _all_rows_mask(self) -> jnp.ndarray:
+        """The mask of a tree that sees every row: ones, placed once as
+        ``_put_rows`` places per-row arrays, and kept."""
+        if getattr(self, "_ones_mask", None) is None or \
+                self._ones_mask.shape[0] != self.num_data:
+            self._ones_mask = self._put_rows(
+                np.ones(self.num_data, np.float32))
+        return self._ones_mask
+
+    def last_sample(self) -> Optional[jnp.ndarray]:
+        """Read-only: the per-row classes of the newest iteration's GOSS
+        draw (uint8 device array: ``GOSS_OUT`` / ``GOSS_TOP`` /
+        ``GOSS_REST``), or None where that iteration drew no sample."""
+        return getattr(self, "_last_sample", None)
 
     def _feature_mask(self) -> Optional[jnp.ndarray]:
         mask = feature_mask_np(self.config, self.num_features, self.iter_)
@@ -927,7 +1012,10 @@ class GBDT:
         finished = True
         fl_leaves = fl_gain = None  # flight-event fields (last class)
         fmask = self._feature_mask()
-        grad, hess, mask = self._prepare_iter_sampling(grad, hess)
+        with rec.phase("sample"):
+            # sampled_rows is fetched with the tree's other counters
+            grad, hess, mask, sampled_rows = \
+                self._prepare_iter_sampling(grad, hess)
         if getattr(self, "_row_valid", None) is not None:
             # pre_partition padding rows never enter a tree (applied
             # centrally so GOSS's override is covered too)
@@ -975,7 +1063,7 @@ class GBDT:
             rec.add_tree(self.iter_, cid, grown.hist_passes,
                          grown.num_leaves, grown.wave_passes,
                          grown.endgame_passes, grown.ramp_committed,
-                         grown.hist_rows_contracted)
+                         grown.hist_rows_contracted, sampled_rows)
             if self.flight.enabled:
                 # last grown tree's fields for this iteration's
                 # flight event (device scalars, pulled lazily on

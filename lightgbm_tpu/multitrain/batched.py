@@ -15,8 +15,9 @@ This holds because
 * the grower's histogram build + split scan are value-deterministic
   under vmap (each model's lane runs the same reduction tree — asserted
   by tests/test_multitrain.py on the partition and wave paths);
-* host-side sampling draws are single-sourced
-  (models/gbdt.py ``bagging_mask_np`` / ``feature_mask_np`` /
+* sampling draws are single-sourced (models/gbdt.py
+  ``bagging_mask_np`` / ``feature_mask_np`` on the host; the GOSS draw
+  is the one jitted ``goss_sample``, reached here through its host face
   ``goss_sample_np``) and keyed per model by the variant's own seeds;
 * swept hyperparameters enter the traced program as per-model scalars
   that flow through the exact arithmetic the constant-folded standalone
@@ -33,8 +34,9 @@ This holds because
 Boosting/objective variants ride the same axis (the PR-20 lift):
 
 * **GOSS** (arXiv:1806.11248) — per-lane top-a%/random-b% draws come
-  from the shared host sampler (``gbdt.goss_sample_np``) applied to the
-  already-eager (M, N) gradient matrix; the amplified small-gradient
+  from the one sampler (``gbdt.goss_sample``, jitted; called per lane
+  through its host face ``gbdt.goss_sample_np``, no Philox stream) on
+  the already-eager (M, N) gradient matrix; the amplified small-gradient
   multipliers hit the stacked gradients in one eager elementwise
   multiply and the 0/1 survivorship folds into the per-lane grower
   mask, so every lane's inputs equal its standalone counterpart's.
@@ -671,9 +673,9 @@ class BatchTrainer:
                 out[m] = fm
         return out
 
-    # -- GOSS (host draws over the eager gradient matrix) --------------------
+    # -- GOSS (per-lane draws over the eager gradient matrix) ---------------
     def _apply_goss(self, it: int, grad, hess):
-        """Shared host GOSS draws per lane: multiplies the amplified
+        """The shared GOSS draw per lane: multiplies the amplified
         small-gradient weights into the stacked gradients (one eager
         elementwise multiply — warmup/inactive lanes multiply by 1.0,
         which is bit-exact) and records the 0/1 survivorship per model

@@ -36,8 +36,9 @@ __all__ = ["TRACED_SWEEP", "HOST_SWEEP", "SWEEPABLE", "normalize_variants",
 TRACED_SWEEP: Tuple[str, ...] = TRACEABLE_PARAMS
 
 # sweepable host-side (per-model masks / seeds / bookkeeping); the GOSS
-# rates and DART drop knobs are host draws too (gbdt.goss_sample_np /
-# the per-lane drop bookkeeping in batched._ModelState), so they sweep
+# rates and DART drop knobs are per-lane draws too (gbdt.goss_sample_np,
+# the host face of the jitted sampler / the per-lane drop bookkeeping in
+# batched._ModelState), so they sweep
 # inside one batch — boosting TYPE itself stays structural
 HOST_SWEEP: Tuple[str, ...] = (
     "learning_rate", "bagging_seed", "bagging_fraction",

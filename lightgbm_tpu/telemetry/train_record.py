@@ -15,7 +15,9 @@ boosting loop runs:
     (``hist_rows_contracted``: ``hist_passes * N`` padded rows unless
     the wave and endgame passes compacted theirs,
     ops/histogram_pallas.py; summed over the row shards this process
-    holds) and leaf counts —
+    holds), the rows in the tree's bag (``sampled_rows``: N where
+    nothing samples, else the bagging mask's or the GOSS draw's count)
+    and leaf counts —
     kept as device scalars and pulled in batched, lazy fetches so the
     async dispatch pipeline never stalls;
   * collective count and reduced bytes, tallied at the
@@ -346,7 +348,7 @@ class TrainRecord:
         self._phase_n: Dict[str, int] = {}
         # per-tree device scalars pending a batched host pull
         # (iteration, class_id, (hp, nl, wave, endgame, ramp_committed,
-        #  hist_rows_contracted))
+        #  hist_rows_contracted, sampled_rows))
         self._pending: List[tuple] = []
         self._trees: List[Dict[str, int]] = []
         self._setup_s: Dict[str, float] = {}
@@ -391,7 +393,8 @@ class TrainRecord:
 
     def add_tree(self, iteration: int, class_id: int, hist_passes,
                  num_leaves, wave_passes=0, endgame_passes=0,
-                 ramp_committed=0, hist_rows_contracted=((0, 0),)) -> None:
+                 ramp_committed=0, hist_rows_contracted=((0, 0),),
+                 sampled_rows=0) -> None:
         """Record one grown tree.  The counts may be device scalars; they
         are NOT synced here — batches are pulled lazily so the async
         dispatch pipeline keeps flowing."""
@@ -401,11 +404,14 @@ class TrainRecord:
             # a multi-process world: the row shards this process holds
             hist_rows_contracted = [
                 s.data for s in hist_rows_contracted.addressable_shards]
+        if not getattr(sampled_rows, "is_fully_addressable", True):
+            # a count over rows that span processes: this process's copy
+            sampled_rows = sampled_rows.addressable_shards[0].data
         with self._lock:
             self._pending.append((int(iteration), int(class_id),
                                   (hist_passes, num_leaves, wave_passes,
                                    endgame_passes, ramp_committed,
-                                   hist_rows_contracted)))
+                                   hist_rows_contracted, sampled_rows)))
             flush = len(self._pending) >= _FLUSH_EVERY
         if flush:
             self._flush()
@@ -435,8 +441,9 @@ class TrainRecord:
                  "ramp_committed": int(rc),
                  # (shards, 2) [count, unit] -> rows, over the shards
                  "hist_rows_contracted": sum(
-                     int(c) * int(u) for c, u in np.reshape(rows, (-1, 2)))}
-                for (it, cid, _), (hp, nl, wp, ep, rc, rows)
+                     int(c) * int(u) for c, u in np.reshape(rows, (-1, 2))),
+                 "sampled_rows": int(sr)}
+                for (it, cid, _), (hp, nl, wp, ep, rc, rows, sr)
                 in zip(pending, vals)]
         with self._lock:
             self._trees.extend(rows)

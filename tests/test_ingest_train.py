@@ -205,8 +205,8 @@ def test_chunked_bagging_feature_fraction_identity():
 
 
 def test_chunked_goss_bit_identity():
-    """GOSS rides the SHARED host sampler (models.gbdt.goss_sample_np):
-    the streamed run thins exactly the rows the in-core run thins,
+    """GOSS rides the ONE sampler (models.gbdt.goss_sample, here through
+    its host face goss_sample_np): the streamed run thins exactly the rows the in-core run thins,
     warmup included, so the quantized model text matches byte for
     byte."""
     X, y = _data(4096, 6)

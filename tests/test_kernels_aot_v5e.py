@@ -120,3 +120,20 @@ def test_row_update_fetch_kernel_compiles_at_cell_shape(S, w):
     # nothing of W columns is built in front of the kernel
     assert "concatenate" not in text
     assert f"u8[{F},8,{N // 8}]" in text
+
+
+def test_goss_sampler_compiles_at_cell_rows(S):
+    """The GOSS draw of ``criteo-q8-goss.train`` (PR 32): one program over
+    the cell's 21,250,000 rows whose exact threshold is a loop of counting
+    passes, with no sort and no temporaries of its own."""
+    from lightgbm_tpu.models.gbdt import goss_sample
+    rows = 21_250_000
+    compiled = goss_sample.lower(
+        S((rows,), jnp.float32), S((rows,), jnp.float32), S((), jnp.int32),
+        top_rate=0.2, other_rate=0.1, bagging_seed=3).compile()
+    text = compiled.as_text()
+    assert "while" in text and " sort(" not in text
+    mem = compiled.memory_analysis()
+    # classes (1 B a row), mask and the two scaled vectors (4 B a row each)
+    assert mem.output_size_in_bytes < 13.5 * rows
+    assert mem.temp_size_in_bytes < 4 * rows

@@ -175,8 +175,8 @@ def _rank_data(seed=0, n=N, f=F, gsize=30):
 
 @pytest.mark.slow
 def test_bit_identity_goss_batch():
-    """GOSS batches (PR 20): the per-lane host sampler is the SHARED
-    goss_sample_np stream, so every lane's thinning equals its
+    """GOSS batches (PR 20): the per-lane draw is the one jitted sampler
+    (goss_sample_np, its host face), so every lane's thinning equals its
     standalone run — top/other rates sweep host-side in one batch."""
     X, y = _data()
     params = {**BASE, "boosting": "goss", "learning_rate": 0.5}
