@@ -516,6 +516,23 @@ class Config:
     def is_parallel(self) -> bool:
         return self.tree_learner != "serial"
 
+    @property
+    def bagging_active(self) -> bool:
+        """Bagging draws a row mask every ``bagging_freq`` iterations
+        (gbdt.cpp:228; the balanced pos/neg form for binary labels)."""
+        pos_neg = (self.objective == "binary" and
+                   (self.pos_bagging_fraction < 1.0 or
+                    self.neg_bagging_fraction < 1.0))
+        return self.bagging_freq > 0 and (self.bagging_fraction < 1.0 or
+                                          pos_neg)
+
+    @property
+    def samples_rows(self) -> bool:
+        """Some tree of this booster may see a row mask with zeros: what
+        ``_prepare_iter_sampling`` (models/gbdt.py, models/boosting.py)
+        decides per iteration, asked once per booster."""
+        return self.boosting == "goss" or self.bagging_active
+
     def to_dict(self) -> Dict[str, Any]:
         d = {p.name: getattr(self, p.name) for p in _PARAMS}
         d.update(self.extra)

@@ -716,10 +716,13 @@ def wave_grow_kwargs(config: Config, num_features: int, max_bins: int,
                      forced_splits: tuple = (),
                      interaction_groups: tuple = (),
                      feature_contri: tuple = (), cegb_lazy: tuple = (),
-                     strategy=None) -> dict:
+                     strategy=None, sampled: bool = False) -> dict:
     """THE translation ``Config`` -> keyword arguments of
     ``learner/wave.py make_wave_grow_fn`` (all but ``jit``), for every
-    learner that grows with it."""
+    learner that grows with it.  ``sampled``: the caller's row masks can
+    hold zeros that ``config`` does not speak of (the masked folds of
+    ``train_many``); a booster whose own sampling can
+    (``Config.samples_rows``) needs no telling."""
     from ..ops.histogram_pallas import PACK4_MAX_BINS
     from ..ops.quantize import quant_levels
     any_cat = bool(np.any(np.asarray(is_cat)))
@@ -758,7 +761,8 @@ def wave_grow_kwargs(config: Config, num_features: int, max_bins: int,
         spec_tol=float(config.tpu_spec_tolerance),
         forced_splits=tuple(tuple(f) for f in forced_splits),
         mc_inter=resolve_monotone_method(config, sp.use_monotone, wave=True),
-        exact_endgame=bool(config.tpu_exact_endgame))
+        exact_endgame=bool(config.tpu_exact_endgame),
+        sampled=bool(sampled or config.samples_rows))
 
 
 # grower arguments that leave the traced function alone in exact mode
@@ -808,7 +812,8 @@ class WaveTreeLearner:
                  has_nan: np.ndarray, monotone: Optional[np.ndarray] = None,
                  *, hist_impl: str, efb=None, forced_splits: tuple = (),
                  interaction_groups: tuple = (), feature_contri: tuple = (),
-                 cegb_lazy: tuple = (), mesh=None, strategy=None):
+                 cegb_lazy: tuple = (), mesh=None, strategy=None,
+                 sampled: bool = False):
         self._describe(config, num_features, max_bins, num_bins, is_cat,
                        has_nan, monotone, efb, mesh)
         self.wave = True
@@ -820,7 +825,7 @@ class WaveTreeLearner:
             efb_dims=self._efb_dims, forced_splits=forced_splits,
             interaction_groups=interaction_groups,
             feature_contri=feature_contri, cegb_lazy=cegb_lazy,
-            strategy=strategy)
+            strategy=strategy, sampled=sampled)
         self.split_params = sp = kw["split_params"]
         self.quantized = kw["quantized"]
         self.pack4 = kw["pack4"]
@@ -1005,7 +1010,8 @@ class SerialTreeLearner(WaveTreeLearner):
                  monotone: Optional[np.ndarray] = None,
                  forced_splits: tuple = (), efb=None,
                  interaction_groups: tuple = (),
-                 feature_contri: tuple = (), cegb_lazy: tuple = ()):
+                 feature_contri: tuple = (), cegb_lazy: tuple = (),
+                 sampled: bool = False):
         pool_f, pool_b = ((int(efb.n_bundles), int(efb.bundle_bins))
                           if efb is not None else (num_features, int(max_bins)))
         self.use_hist_pool = hist_pool_fits(config, pool_f, pool_b)
@@ -1048,7 +1054,8 @@ class SerialTreeLearner(WaveTreeLearner):
                 monotone, hist_impl=impl, efb=efb,
                 forced_splits=forced_splits,
                 interaction_groups=interaction_groups,
-                feature_contri=feature_contri, cegb_lazy=cegb_lazy)
+                feature_contri=feature_contri, cegb_lazy=cegb_lazy,
+                sampled=sampled)
             return
         self._describe(config, num_features, max_bins, num_bins, is_cat,
                        has_nan, monotone, efb)

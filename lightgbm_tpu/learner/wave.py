@@ -257,7 +257,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                       forced_splits: tuple = (),
                       mc_inter: bool = False,
                       exact_endgame: bool = True,
-                      lazy_bitpack: bool = True):
+                      lazy_bitpack: bool = True,
+                      sampled: bool = False):
     """Build the wave single-tree grower.
 
     Returned signature matches the partitioned grower:
@@ -282,6 +283,14 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
       match), and an O(W*k) winner exchange (``exchange_collectives``)
       recombines the block-local bests into the global per-leaf winners
       — 1/k the wire residency and scan FLOPs, identical results.
+
+    ``sampled``: the booster this grower is built for can hand it a
+    ``bag_mask`` with zeros (GOSS, bagging, a masked CV fold).  Every
+    histogram pass of the Pallas route then leaves the out-of-bag rows
+    out of its channels and contracts the in-bag rows alone
+    (``leaf_hists``; the ramp's passes on its subsample too); the tree
+    and every row's leaf are the same either way.  A booster that never
+    samples keeps the dense first pass.
     """
     L = num_leaves
     F = num_features
@@ -581,7 +590,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
         with jax.named_scope("lgbm.quantize"):
             gm = (grad * bag_mask).astype(jnp.float32)
             hm = (hess * bag_mask).astype(jnp.float32)
-            cnt_mask = (bag_mask > 0).astype(jnp.float32)
+            in_bag = bag_mask > 0
+            cnt_mask = in_bag.astype(jnp.float32)
         if use_lazy:
             # packed vs bool layout of the persistent `used` bitmap: follow
             # whatever the learner threads in (its dtype is static at trace
@@ -666,6 +676,22 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 return hmg
             return hmg, _dqh(hmg[:, 0].sum(axis=1))
 
+        def leaf_hists(bins, w, ch, bag, sparse):
+            """``(histograms, rows looped over)`` of one call of the
+            Pallas leaf kernel on ``bins`` / ``w`` (the tree's or the
+            ramp's subsample's).  THE place where a grower built for a
+            booster that samples rows leaves the out-of-bag ones out:
+            their weight levels are all 0, so with channel -1 and the
+            compacting entry the sums are the same without their zero
+            terms, over the rows of ``bag`` alone."""
+            if sampled:
+                ch, sparse = jnp.where(bag, ch, -1), True
+            build = build_histogram_pallas_leaves_q8 if quantized \
+                else build_histogram_pallas_leaves
+            h = build(bins, w, ch, num_bins=Bb, interpret=interpret,
+                      pipeline=pipeline, bins_packed=pack4, compact=sparse)
+            return h if sparse else (h, w.shape[1])
+
         def hist_waves(ch, k=W, with_totals=False, sparse=False):
             """(k, G_loc, Bb, 3) histograms of the wave's leaf channels,
             reduced across row shards (serial: identity; DP scatter mode:
@@ -681,20 +707,16 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             the front and contract only the row blocks that hold them
             (ops/histogram_pallas.py ``compact``; each shard compacts its
             own rows, before the collective).  The verify pass of the
-            ramp and the root pass put every row in a channel and the
-            ramp's provisional passes run on a subsample: they keep the
-            direct call.  Returns ``(histograms, rows)``, ``rows`` the
-            rows this shard's kernel looped over, in ``row_unit``s."""
+            ramp and the root pass put every row in a channel: they keep
+            the direct call, unless the grower is built for a booster
+            that samples rows: there every pass is sparse
+            (``leaf_hists``).  Returns ``(histograms, rows)``, ``rows``
+            the rows this shard's kernel looped over, in ``row_unit``s."""
             rows = n // row_unit
             if pallas:
-                build = build_histogram_pallas_leaves_q8 if quantized \
-                    else build_histogram_pallas_leaves
-                h = build(X_T, wch0 if quantized else w8, ch, num_bins=Bb,
-                          interpret=interpret, pipeline=pipeline,
-                          bins_packed=pack4, compact=sparse)
-                if sparse:
-                    h, rows = h
-                    rows = rows // row_unit
+                h, rows = leaf_hists(X_T, wch0 if quantized else w8, ch,
+                                     in_bag, sparse)
+                rows = rows // row_unit
             elif quantized:
                 # off-TPU emulation: f32 sums of integer levels are
                 # exact while |sum| < 2^24 per bin — ample for the
@@ -917,8 +939,10 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             and commit tests then run on psum'd full-data sums."""
             import math as _m
             Kc, K1 = W, W - 1
-            # -- statically-strided row subsample (weights carry bagging/
-            # GOSS masks, so out-of-bag rows contribute nothing) --
+            # -- statically-strided row subsample (an out-of-bag row's
+            # weight levels are 0, so it adds nothing to any pass; a
+            # grower built for a booster that samples leaves it out of
+            # every pass, ``leaf_hists``) --
             stride = max(1, n // max(int(spec_subsample) // spec_shards,
                                      4096))
             n_ss = max((n // stride) // 4096 * 4096, 4096)
@@ -935,6 +959,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 X_ss = X_T[:, ::stride][:, :n_ss]
                 w_ss = w_src[:, ::stride][:, :n_ss]
             R_ss = router_bins(X_ss)
+            # the count level of the subsample's weights
+            in_bag_ss = w_ss[2 if quantized else 4] > 0 if sampled else None
             nan_of = jnp.where(hn_full, nb_full - 1, -1)       # (F,)
             fm_k = jnp.broadcast_to(feature_mask, (Kc, F))
             jar = jnp.arange(Kc, dtype=jnp.int32)
@@ -961,16 +987,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             Rm = jnp.zeros((K1, Kc), jnp.bool_)   # right-descendant leaves
             tabs = []
             for _t in range(max(1, int(_m.ceil(_m.log2(Kc))))):
-                if quantized:
-                    h_ss = build_histogram_pallas_leaves_q8(
-                        X_ss, w_ss, rl_ss.astype(jnp.int8), num_bins=Bb,
-                        interpret=interpret, pipeline=pipeline,
-                        bins_packed=pack4)[:Kc]
-                else:
-                    h_ss = build_histogram_pallas_leaves(
-                        X_ss, w_ss, rl_ss.astype(jnp.int8), num_bins=Bb,
-                        interpret=interpret, pipeline=pipeline,
-                        bins_packed=pack4)[:Kc]
+                h_ss = leaf_hists(X_ss, w_ss, rl_ss.astype(jnp.int8),
+                                  in_bag_ss, False)[0][:Kc]
                 # DP: the one histogram collective of this provisional
                 # pass — the provisional batches ride the same merge mode
                 # as committed waves (psum, or the feature-sliced
@@ -1034,7 +1052,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 rl_full = rlf.astype(jnp.uint8)
 
             # -- ONE full-data pass: exact per-prov-leaf channel sums --
-            (h_ch, leaf_tot), _ = hist_waves(
+            (h_ch, leaf_tot), rows_v = hist_waves(
                 rl_full.astype(jnp.int8), k=Kc, with_totals=True)  # (Kc, 3)
             # voting: keep the batch RAW and shard-local — the node-sum
             # einsum is exact in int32 and _voting_candidates merges
@@ -1162,7 +1180,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 # mega-pass (the ~log2(W) provisional passes run at
                 # subsample scale and are not counted)
                 "hist_passes": jnp.asarray(1, jnp.int32),
-                "hist_rows": jnp.asarray(n // row_unit, jnp.int32),
+                "hist_rows": jnp.asarray(rows_v, jnp.int32),
             }
 
         if use_spec:
@@ -1178,13 +1196,13 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     # for every feature, shard and merge mode) so candidate
                     # left+right sums stay consistent with the totals
                     # downstream
-                    (rh, rtot), _ = hist_waves(jnp.zeros((n,), jnp.int8),
-                                               k=1, with_totals=True)
+                    (rh, rtot), rows_r = hist_waves(
+                        jnp.zeros((n,), jnp.int8), k=1, with_totals=True)
                     root_hist = rh[0]
                     root_sum = rtot[0]
                 else:
-                    root_hist = hist_waves(jnp.zeros((n,), jnp.int8),
-                                           k=1)[0][0]
+                    rh, rows_r = hist_waves(jnp.zeros((n,), jnp.int8), k=1)
+                    root_hist = rh[0]
                     root_sum = strat.reduce_sum(jnp.stack([
                         jnp.sum(gm), jnp.sum(hm), jnp.sum(cnt_mask)]))
                 root_hist_f = dq(root_hist) if quantized else root_hist
@@ -1270,7 +1288,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     "num_leaves": jnp.asarray(1, jnp.int32),
                     "done": jnp.asarray(False),
                     "hist_passes": jnp.asarray(1, jnp.int32),  # the root pass
-                    "hist_rows": jnp.asarray(n // row_unit, jnp.int32),
+                    "hist_rows": jnp.asarray(rows_r, jnp.int32),
                 }
                 if use_mc:
                     state["leaf_mn"] = jnp.full((L,), -BIG, jnp.float32)

@@ -214,11 +214,11 @@ def bagging_mask_np(cfg, n: int, iteration: int,
     ``range(len(rows))`` — exactly the draws a standalone run on the
     compacted ``dataset[rows]`` would make — and scatters back to full
     length (the masked-fold CV path of train_many)."""
+    if not cfg.bagging_active:
+        return None
     pos_neg = (cfg.objective == "binary" and
                (cfg.pos_bagging_fraction < 1.0 or
                 cfg.neg_bagging_fraction < 1.0))
-    if not (cfg.bagging_freq > 0 and (cfg.bagging_fraction < 1.0 or pos_neg)):
-        return None
     block = iteration // cfg.bagging_freq
     rng = host_rng(cfg.bagging_seed, block)
     nn = n if rows is None else len(rows)

@@ -289,7 +289,11 @@ class BatchTrainer:
             efb=train_set.efb,
             interaction_groups=GBDT._parse_interaction_constraints(shim),
             feature_contri=GBDT._inner_contri(shim),
-            cegb_lazy=())
+            cegb_lazy=(),
+            # a lane's folds and its own bagging reach the grower as
+            # masks with zeros, whatever the first lane's Config says
+            sampled=(sample_rows is not None or sample_masks is not None
+                     or any(c.samples_rows for c in self.cfgs)))
         if self.learner.grow_mode == "masked":
             raise MultiTrainError(
                 "pool-less (masked) grower: histogram pool exceeds budget")
@@ -630,12 +634,7 @@ class BatchTrainer:
         def _bagged(st):
             if self._goss:
                 return False
-            c = st.cfg
-            pos_neg = (c.objective == "binary" and
-                       (c.pos_bagging_fraction < 1.0 or
-                        c.neg_bagging_fraction < 1.0))
-            return c.bagging_freq > 0 and (c.bagging_fraction < 1.0 or
-                                           pos_neg)
+            return st.cfg.bagging_active
         if it > 0 and not any(
                 _bagged(st) and it % max(1, int(st.cfg.bagging_freq)) == 0
                 for st in self.states):

@@ -846,7 +846,10 @@ def _make_w128_q8(w, ch):
 #
 # After a tree's first pass, the channel row ``ch`` of a histogram pass
 # is mostly -1: the wave and endgame passes build the SMALLER children
-# only (learner/wave.py), 8-37%% of the rows.  A row with ch == -1 gets
+# only (learner/wave.py), 8-37%% of the rows; in a tree of a booster
+# that samples rows every pass, the first included, also leaves out the
+# rows that are not in the bag (0.3 of 8-37%% under GOSS's defaults, 0.3
+# of all rows in the first pass).  A row with ch == -1 gets
 # an all-zero column of the (128, kr) right operand and the MXU does its
 # fc x B x 128 MACs on it all the same.  :func:`_compact_rows_dma` moves
 # the lanes with ch >= 0 of every operand the leaf kernel streams (bins,
@@ -1254,7 +1257,8 @@ def build_histogram_pallas_leaves(bins_t: jnp.ndarray, w8: jnp.ndarray,
       num_bins: static global bin count B.
       interpret / pipeline / bins_packed: as :func:`build_histogram_pallas`.
       compact: the caller knows ``ch`` to be mostly -1 (a wave's or the
-        endgame's smaller children).  The ``dma`` pipeline then moves the
+        endgame's smaller children; any pass of a sampled tree, whose
+        out-of-bag rows carry -1).  The ``dma`` pipeline then moves the
         active rows to the front (:func:`_compact_rows_dma`) and contracts
         the row blocks that hold them; the result is ``(hist, rows)``
         with ``rows`` the rows the kernel looped over, a device scalar

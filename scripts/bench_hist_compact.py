@@ -40,7 +40,7 @@ def main():
     ap.add_argument("--rows", type=int, default=21_250_048)
     ap.add_argument("--features", type=int, default=67)
     ap.add_argument("--kinds", default="q8,bf16")
-    ap.add_argument("--shares", default="1.0,0.5,0.25,0.1,0.02")
+    ap.add_argument("--shares", default="1.0,0.5,0.3,0.25,0.1,0.02")
     ap.add_argument("--tilings", default="512:8192",
                     help="comma list of sub:kb")
     ap.add_argument("--reps", type=int, default=5)

@@ -90,3 +90,17 @@ def rank_data():
 
 
 SMALL = {"num_leaves": 7, "min_data_in_leaf": 5, "verbosity": -1}
+
+
+@pytest.fixture
+def dma_everywhere(monkeypatch):
+    """The chip's default kernel pipeline here too: a mesh takes the
+    default (learner/serial.py ``wave_grow_kwargs``), which on the CPU is
+    ``blockspec``."""
+    from lightgbm_tpu.learner import serial
+    from lightgbm_tpu.ops import histogram_pallas as hp
+    monkeypatch.setattr(hp, "resolve_pipeline",
+                        lambda pipeline=None: pipeline or "dma")
+    serial._GROW_FN_CACHE.clear()
+    yield
+    serial._GROW_FN_CACHE.clear()

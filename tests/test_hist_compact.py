@@ -197,18 +197,6 @@ def _data(n):
 
 
 @pytest.fixture
-def dma_everywhere(monkeypatch):
-    """The chip's default pipeline here too: a mesh takes the default
-    (learner/serial.py ``wave_grow_kwargs``), which on the CPU is
-    ``blockspec``."""
-    monkeypatch.setattr(hp, "resolve_pipeline",
-                        lambda pipeline=None: pipeline or "dma")
-    serial._GROW_FN_CACHE.clear()
-    yield
-    serial._GROW_FN_CACHE.clear()
-
-
-@pytest.fixture
 def dense_call_sites(monkeypatch):
     """The wave and endgame call sites forced onto the direct call: the
     builders drop ``compact`` and say they looped over every row."""
