@@ -270,6 +270,10 @@ class BinMapper:
     # set by the pre-filter when no boundary separates enough rows
     # (bin.cpp NeedFilter); the feature is dropped like num_bin <= 1
     forced_trivial: bool = False
+    # largest share of the binning sample's rows that one bin holds (1.0 =
+    # not known: a mapper that was not made by find_bin); the quantized
+    # grower sizes its int32 accumulation from it (ops/quantize.py)
+    max_bin_share: float = 1.0
 
     @property
     def is_trivial(self) -> bool:
@@ -281,18 +285,21 @@ class BinMapper:
         """Vectorized value -> bin (reference bin.h:464 ValueToBin)."""
         values = np.asarray(values, dtype=np.float64)
         if self.is_categorical:
-            out = np.zeros(values.shape, dtype=np.int32)
-            nan_mask = ~np.isfinite(values)
-            ivals = np.where(nan_mask, -1, np.nan_to_num(values, nan=-1)).astype(np.int64)
-            # vectorized dict lookup through a dense table when ids are small
-            if self.bin_to_cat is not None and len(self.cat_to_bin):
-                max_cat = max(self.cat_to_bin)
-                table = np.zeros(max_cat + 2, dtype=np.int32)  # unseen -> bin 0
-                for cat, b in self.cat_to_bin.items():
-                    table[cat] = b
-                ivals = np.clip(ivals, -1, max_cat)
-                out = np.where(ivals < 0, 0, table[np.clip(ivals, 0, max_cat)])
-            return out.astype(np.int32)
+            if not len(self.cat_to_bin):
+                return np.zeros(values.shape, dtype=np.int32)
+            # dense lookup table; bin 0 takes what is no binned category:
+            # NaN / inf, a negative or non-integer value, an id the
+            # binning folded away or never saw (the walks on raw values
+            # send the same values right, models/tree.py)
+            max_cat = max(self.cat_to_bin)
+            table = np.zeros(max_cat + 2, dtype=np.int32)
+            table[list(self.cat_to_bin)] = list(self.cat_to_bin.values())
+            with np.errstate(invalid="ignore"):
+                ivals = np.clip(values, -1, max_cat + 1)
+                ivals = np.where(ivals == ivals, ivals, -1).astype(np.int64)
+            known = (ivals >= 0) & (ivals == values)
+            return np.where(known, table[np.maximum(ivals, 0)], 0
+                            ).astype(np.int32)
 
         if len(values) >= (1 << 16):
             from .utils import native
@@ -515,6 +522,7 @@ def find_bin_from_summary(summary: ColumnSummary, max_bin: int,
     if most_freq != mapper.default_bin and sparse_rate < 0.8:
         most_freq = mapper.default_bin  # kSparseThreshold
     mapper.most_freq_bin = most_freq
+    mapper.max_bin_share = float(cnt_in_bin.max() / max(1, cnt_in_bin.sum()))
     return mapper
 
 
@@ -551,20 +559,27 @@ def _find_bin_categorical_counts(cats: np.ndarray, counts: np.ndarray,
         keep = int(np.searchsorted(cum, 0.99 * total) + 1)
         keep = min(keep, len(cats))
     cats = cats[:keep]
-    cat_to_bin = {int(c): i for i, c in enumerate(cats)}
-    num_bin = max(len(cats), 1)
-    # NaN categoricals map to the most frequent category (bin 0) at both
-    # train and inference (tree.py stores default_left = (split category ==
-    # most frequent) on cat nodes), so no NaN bin is allocated and
-    # missing_type stays NONE — mirrors reference CategoricalDecision
-    # semantics for missing values.
+    # Bin 0 belongs to no category (reference bin.cpp: "Push the dummy bin
+    # for NaN", bin_2_categorical_[0] = -1): a missing value, a negative
+    # one, a category the cut above folded away and one never seen all
+    # land there, and the split search never puts bin 0 in a left set
+    # (ops/split.py), so every such row goes RIGHT in training — which is
+    # where Tree::CategoricalDecision sends NaN and unknown categories
+    # at prediction.  missing_type stays NONE: no trailing NaN bin.
+    cat_to_bin = {int(c): i + 1 for i, c in enumerate(cats)}
+    num_bin = len(cats) + 1
+    kept = int(counts[:keep].sum())
+    in_bin = np.concatenate([[total - kept + na_cnt], counts[:keep]])
     mapper = BinMapper(
         num_bin=num_bin,
         is_categorical=True,
         missing_type=MissingType.NONE,
         cat_to_bin=cat_to_bin,
-        bin_to_cat=cats.copy(),
-        most_freq_bin=0,
+        bin_to_cat=np.concatenate([[-1], cats]).astype(np.int64),
+        most_freq_bin=int(in_bin.argmax()),
+        max_bin_share=float(in_bin.max() / max(1, in_bin.sum())),
+        # one category and nothing beside it: no split can part the rows
+        forced_trivial=bool(len(cats) <= 1 and in_bin[0] == 0),
     )
     return mapper
 
@@ -589,6 +604,18 @@ def bin_matrix(X: np.ndarray, mappers: Sequence[BinMapper]) -> np.ndarray:
         if nat is not None:
             return nat
     out = np.empty((n, f), dtype=dtype)
-    for j, m in enumerate(mappers):
-        out[:, j] = m.value_to_bin(X[:, j]).astype(dtype)
+
+    def one(j: int) -> None:
+        out[:, j] = mappers[j].value_to_bin(X[:, j]).astype(dtype)
+
+    if n >= (1 << 16) and f > 1:
+        # a matrix with categorical columns is binned column by column:
+        # numpy and the native per-column binner release the GIL
+        from concurrent.futures import ThreadPoolExecutor
+        import os
+        with ThreadPoolExecutor(max(1, min(f, 12, (os.cpu_count() or 2) - 1))) as pool:
+            list(pool.map(one, range(f)))
+    else:
+        for j in range(f):
+            one(j)
     return out

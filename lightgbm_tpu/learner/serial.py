@@ -66,7 +66,8 @@ class GrownTree(NamedTuple):
     internal_count: jnp.ndarray    # (L-1,) float32
     leaf_value: jnp.ndarray        # (L,) float32
     leaf_weight: jnp.ndarray       # (L,) float32
-    leaf_count: jnp.ndarray        # (L,) float32
+    leaf_count: jnp.ndarray        # (L,) float32 (int32, exact past 2^24
+                                   # rows, from a grower that keeps limbs)
     num_leaves: jnp.ndarray        # () int32 — actual leaves grown
     row_leaf: jnp.ndarray          # (N,) int32 — final leaf of every row
     hist_passes: jnp.ndarray       # () int32 — full-data histogram passes
@@ -716,13 +717,17 @@ def wave_grow_kwargs(config: Config, num_features: int, max_bins: int,
                      forced_splits: tuple = (),
                      interaction_groups: tuple = (),
                      feature_contri: tuple = (), cegb_lazy: tuple = (),
-                     strategy=None, sampled: bool = False) -> dict:
+                     strategy=None, sampled: bool = False,
+                     acc_rows: int = 0) -> dict:
     """THE translation ``Config`` -> keyword arguments of
     ``learner/wave.py make_wave_grow_fn`` (all but ``jit``), for every
     learner that grows with it.  ``sampled``: the caller's row masks can
     hold zeros that ``config`` does not speak of (the masked folds of
     ``train_many``); a booster whose own sampling can
-    (``Config.samples_rows``) needs no telling."""
+    (``Config.samples_rows``) needs no telling.  ``acc_rows``: the most
+    rows a quantized histogram pass may add into one int32
+    (ops/quantize.py ``hist_acc_rows``, from the data set's rows a shard
+    and its fullest bin); 0, the narrow program, where no sum can wrap."""
     from ..ops.histogram_pallas import PACK4_MAX_BINS
     from ..ops.quantize import quant_levels
     any_cat = bool(np.any(np.asarray(is_cat)))
@@ -762,7 +767,8 @@ def wave_grow_kwargs(config: Config, num_features: int, max_bins: int,
         forced_splits=tuple(tuple(f) for f in forced_splits),
         mc_inter=resolve_monotone_method(config, sp.use_monotone, wave=True),
         exact_endgame=bool(config.tpu_exact_endgame),
-        sampled=bool(sampled or config.samples_rows))
+        sampled=bool(sampled or config.samples_rows),
+        hist_acc_rows=int(acc_rows) if config.use_quantized_grad else 0)
 
 
 # grower arguments that leave the traced function alone in exact mode
@@ -806,6 +812,8 @@ class WaveTreeLearner:
     rows_sharded = False    # gbdt places per-row arrays on ``mesh``
     supports_extras = True  # train() takes cegb_penalty / node_key
     quantized = False       # train() wants a per-tree quant_key
+    hist_acc_rows = 0       # rows a q8 pass adds into one int32 (0: all)
+    grower_paths = None     # the wave grower's ``static_paths``
 
     def __init__(self, config: Config, num_features: int, max_bins: int,
                  num_bins: np.ndarray, is_cat: np.ndarray,
@@ -813,7 +821,7 @@ class WaveTreeLearner:
                  *, hist_impl: str, efb=None, forced_splits: tuple = (),
                  interaction_groups: tuple = (), feature_contri: tuple = (),
                  cegb_lazy: tuple = (), mesh=None, strategy=None,
-                 sampled: bool = False):
+                 sampled: bool = False, acc_rows: int = 0):
         self._describe(config, num_features, max_bins, num_bins, is_cat,
                        has_nan, monotone, efb, mesh)
         self.wave = True
@@ -825,7 +833,8 @@ class WaveTreeLearner:
             efb_dims=self._efb_dims, forced_splits=forced_splits,
             interaction_groups=interaction_groups,
             feature_contri=feature_contri, cegb_lazy=cegb_lazy,
-            strategy=strategy, sampled=sampled)
+            strategy=strategy, sampled=sampled, acc_rows=acc_rows)
+        self.hist_acc_rows = kw["hist_acc_rows"]
         self.split_params = sp = kw["split_params"]
         self.quantized = kw["quantized"]
         self.pack4 = kw["pack4"]
@@ -843,9 +852,11 @@ class WaveTreeLearner:
             # so they leave the key and sweeps over them don't recompile
             self._grow = self._cached_grow_fn(
                 "wave", () if self.quantized else _QUANT_ONLY)
+            self.grower_paths = dict(self._grow.static_paths)
             return
         from ..parallel.mesh import shard_wave_grower
         grow_w = self.build_grow_fn(jit=False)
+        self.grower_paths = dict(grow_w.static_paths)
         names = self._key_names
 
         def grow(X_T, g, h, m, nb, ic, hn, mono, fm, cegb, *keys):
@@ -1011,7 +1022,7 @@ class SerialTreeLearner(WaveTreeLearner):
                  forced_splits: tuple = (), efb=None,
                  interaction_groups: tuple = (),
                  feature_contri: tuple = (), cegb_lazy: tuple = (),
-                 sampled: bool = False):
+                 sampled: bool = False, acc_rows: int = 0):
         pool_f, pool_b = ((int(efb.n_bundles), int(efb.bundle_bins))
                           if efb is not None else (num_features, int(max_bins)))
         self.use_hist_pool = hist_pool_fits(config, pool_f, pool_b)
@@ -1055,7 +1066,7 @@ class SerialTreeLearner(WaveTreeLearner):
                 forced_splits=forced_splits,
                 interaction_groups=interaction_groups,
                 feature_contri=feature_contri, cegb_lazy=cegb_lazy,
-                sampled=sampled)
+                sampled=sampled, acc_rows=acc_rows)
             return
         self._describe(config, num_features, max_bins, num_bins, is_cat,
                        has_nan, monotone, efb)

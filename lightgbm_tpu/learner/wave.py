@@ -59,7 +59,8 @@ from ..analysis.contracts import collective_contract, memory_budget
 from ..models.tree import CAT_MASK, DEFAULT_LEFT_MASK, MISSING_NAN
 from ..ops.histogram import build_histogram_leaves, histogram_subtract
 from ..ops.histogram_pallas import DEFAULT_ROW_BLOCK
-from ..ops.quantize import dequant_scales, quantize_wch
+from ..ops.quantize import (dequant_limbs, dequant_scales, hist_limbs,
+                            quantize_wch)
 from ..ops.split import (BIG, NEG_INF, _leaf_gain, best_split_per_feature,
                          leaf_output,
                          leaf_output_smoothed)
@@ -258,7 +259,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                       mc_inter: bool = False,
                       exact_endgame: bool = True,
                       lazy_bitpack: bool = True,
-                      sampled: bool = False):
+                      sampled: bool = False,
+                      hist_acc_rows: int = 0):
     """Build the wave single-tree grower.
 
     Returned signature matches the partitioned grower:
@@ -291,6 +293,14 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
     (``leaf_hists``; the ramp's passes on its subsample too); the tree
     and every row's leaf are the same either way.  A booster that never
     samples keeps the dense first pass.
+
+    ``hist_acc_rows`` (quantized): 0, or the most rows a histogram pass
+    may add into one int32 on this data set (ops/quantize.py: rows a
+    shard x the fullest bin's share x 127 can pass 2^31).  The kernels
+    then sum a pass in segments and every integer histogram of the grower
+    carries five lanes a bin, [g_lo, h_lo, count, g_hi, h_hi]: limbs add,
+    subtract and cross the mesh as the three sums do; ``dq`` alone puts
+    them together.
     """
     L = num_leaves
     F = num_features
@@ -299,6 +309,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
     use_efb = efb_dims is not None
     G, Bb = efb_dims if use_efb else (F, max_bins)
     pallas = hist_impl == "pallas"
+    wide = bool(quantized and hist_acc_rows)
+    HC = 5 if wide else 3      # int32 lanes a bin of an integer histogram
     if pallas:
         from ..ops.histogram_pallas import (
             build_histogram_pallas, build_histogram_pallas_leaves,
@@ -493,7 +505,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
         def router_bins(mat):
             """What the fused row-update kernel reads ``mat``'s columns
             from: made once per tree (a relayout of ``mat`` on a TPU)."""
-            if pack4 or not (pallas and small_bins and not any_cat):
+            if pack4 or not (pallas and small_bins):
                 return mat
             return bin_rows_view(mat, pipeline)
 
@@ -504,10 +516,10 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 return unpack_bins4(gather_bin_rows(bins, feats)), None
             return bins, feats
 
-        def route_rows(bins, feats, rl, tab):
+        def route_rows(bins, feats, rl, tab, cat=None):
             bins, feats = router_args(bins, feats)
             return wave_row_update_pallas(
-                bins, rl, tab, feats=feats, interpret=interpret,
+                bins, rl, tab, feats=feats, cat=cat, interpret=interpret,
                 pipeline=pipeline)
 
         with jax.named_scope("lgbm.wave.row_update"):
@@ -627,6 +639,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
 
             def dq(h):
                 """int32 channel sums -> f32 (sum_grad, sum_hess, count)."""
+                if wide:
+                    return dequant_limbs(h) * qscales
                 return h.astype(jnp.float32) * qscales
 
         _dqh = dq if quantized else (lambda h: h)
@@ -689,7 +703,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             build = build_histogram_pallas_leaves_q8 if quantized \
                 else build_histogram_pallas_leaves
             h = build(bins, w, ch, num_bins=Bb, interpret=interpret,
-                      pipeline=pipeline, bins_packed=pack4, compact=sparse)
+                      pipeline=pipeline, bins_packed=pack4, compact=sparse,
+                      **({"acc_rows": hist_acc_rows} if wide else {}))
             return h if sparse else (h, w.shape[1])
 
         def hist_waves(ch, k=W, with_totals=False, sparse=False):
@@ -728,6 +743,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     wch0[2].astype(jnp.float32), ch,
                     num_channels=W, num_bins=Bb, impl=hist_impl)
                 h = jnp.round(h).astype(jnp.int32)
+                if wide:
+                    h = hist_limbs([h])
             else:
                 h = build_histogram_leaves(
                     bins_rows, gm, hm, cnt_mask, ch,
@@ -1137,7 +1154,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             # -- pools + frontier candidates --
             rl0 = jnp.take(s_map, rl_full.astype(jnp.int32))
             hists0 = jnp.zeros(
-                (L, G_loc, Bb, 3), h_ch.dtype).at[s_map].add(h_ch[:Kc])
+                (L, G_loc, Bb, HC), h_ch.dtype).at[s_map].add(h_ch[:Kc])
             lsum0 = jnp.zeros((L, 3), jnp.float32).at[s_map].add(leaf_tot)
             ldep0 = jnp.zeros((L,), jnp.int32).at[s_map].set(depth_pl)
             live = jnp.arange(L, dtype=jnp.int32) < nl_run
@@ -1268,7 +1285,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     "cand_member": jnp.zeros((L, max_bins), jnp.bool_).at[0].set(
                         cand[6]),
                     "hists": jnp.zeros(
-                        (L, G_loc, Bb, 3),
+                        (L, G_loc, Bb, HC),
                         jnp.int32 if quantized else jnp.float32).at[0].set(
                             root_hist),
                     "split_feature": jnp.full((L - 1,), -1, jnp.int32),
@@ -1393,26 +1410,29 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             with jax.named_scope("lgbm.wave.row_update"):
                 rl = s["row_leaf"]
                 rl_old = rl
-                if pallas and small_bins and not any_cat:
+                if pallas and small_bins:
                     # one fused kernel pass instead of W masked XLA sweeps
                     # (each sweep's fused-loop launch overhead alone costs
-                    # ~0.7 ms at 10.5M rows)
+                    # ~0.7 ms at 10.5M rows); a data set with categorical
+                    # columns hands it the slots' left sets as bit sets
                     tab = jnp.stack([
                         thr, f_nan_bin, dleft.astype(jnp.int32),
                         left_smaller.astype(jnp.int32), sel_leaves, new_ids,
                         sel.astype(jnp.int32), jnp.zeros_like(thr)])
-                    rl_new, ch = route_rows(X_R, feat, rl, tab)
+                    rl_new, ch = route_rows(
+                        X_R, feat, rl, tab,
+                        cat=(fcat, member) if any_cat else None)
                     rl = rl_new.astype(rl.dtype)
                 else:
-                    # Vectorized XLA fallback (categorical / EFB / wide-bin
-                    # shapes the fused kernel cannot take).  The former W
-                    # SEQUENTIAL masked sweeps cost ~0.7-2 ms of fused-loop
-                    # launch overhead EACH (~50 ms/wave at small N — the
-                    # dominant cost of the whole benchmark-matrix shapes);
-                    # one batched (W, N) formulation replaces them: every
-                    # row belongs to at most one split leaf, so an argmax
-                    # over the match matrix picks its slot and a single
-                    # take_along_axis resolves the decision.
+                    # Vectorized XLA form, for what the fused kernel does
+                    # not take (EFB bundles, more than 255 bins, a
+                    # histogram implementation other than Pallas) and, off
+                    # the TPU, the kernel's test oracle: every row belongs
+                    # to at most one split leaf, so an argmax over the
+                    # (W, N) match matrix picks its slot and a single
+                    # take_along_axis resolves the decision.  (The [old]
+                    # timings this comment carried were of shapes a TPU no
+                    # longer routes this way.)
                     if small_bins:
                         thr_c = thr.astype(jnp.uint8)[:, None]
                         nan_c = jnp.where(f_nan_bin < 0, 255,
@@ -1443,12 +1463,13 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                             go_w = num_go
                         elif 0 < len(cat_static) <= 8:
                             # per-slot bitset lookup as FEW-INDICES x
-                            # WIDE-ROW embedding takes: a (W, N)-indexed
-                            # gather from the (W, B) membership table costs
-                            # ~45 ms at 145K rows on TPU for every dtype,
-                            # while N row-takes from the transposed (B, W)
-                            # table cost ~6 ms — loop the STATIC cat
-                            # features, combine by split-feature match
+                            # WIDE-ROW embedding takes: N row-takes from
+                            # the transposed (B, W) table instead of a
+                            # (W, N)-indexed gather from the (W, B) one
+                            # (7x apart [old]; Pallas shapes with 255 bins
+                            # or fewer take the kernel above instead) —
+                            # loop the STATIC cat features, combine by
+                            # split-feature match
                             acc = jnp.zeros((m, W), jnp.int8)
                             for cf in cat_static:
                                 colv = fcol(jnp.asarray(cf, jnp.int32))
@@ -2038,6 +2059,18 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 s["leaf_value"] = jnp.where(ok, vals, s["leaf_value"])
                 s["leaf_weight"] = jnp.where(ok, gh[:, 1], s["leaf_weight"])
 
+        leaf_count = s["leaf_count"]
+        if wide and not (use_scatter or use_voting):
+            # The state's counts went through float32 (the scan's sums),
+            # exact to 2^24 rows; a data set that needs limbs has leaves
+            # past that.  Every leaf's integer histogram is in the bank
+            # (the root's, then both children's of every split), and the
+            # bins of its first column hold each of its rows once: exact
+            # int32 counts, at no pass over the rows.
+            live = jnp.arange(L, dtype=jnp.int32) < s["num_leaves"]
+            leaf_count = jnp.where(
+                live, s["hists"][:, 0, :, 2].sum(axis=1), 0)
+
         tree_out = GrownTree(
             split_feature=s["split_feature"],
             threshold_bin=s["threshold_bin"],
@@ -2047,7 +2080,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             split_gain=s["split_gain"], internal_value=s["internal_value"],
             internal_weight=s["internal_weight"],
             internal_count=s["internal_count"], leaf_value=s["leaf_value"],
-            leaf_weight=s["leaf_weight"], leaf_count=s["leaf_count"],
+            leaf_weight=s["leaf_weight"], leaf_count=leaf_count,
             num_leaves=s["num_leaves"],
             row_leaf=s["row_leaf"].astype(jnp.int32),
             hist_passes=s["hist_passes"], wave_passes=wave_passes,
@@ -2059,4 +2092,14 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             return tree_out, s["used"]
         return tree_out
 
-    return jax.jit(grow) if jit else grow
+    fn = jax.jit(grow) if jit else grow
+    # the grower's own statement of the static paths it was built with
+    # (TrainRecord.snapshot()["grower"]): what a data set's shape switched
+    # on or off, for a reader to hold a run to
+    fn.static_paths = {
+        "ramp": bool(use_spec), "endgame": bool(use_endgame),
+        "scatter": bool(use_scatter), "voting": bool(use_voting),
+        "efb": bool(use_efb), "any_cat": bool(any_cat),
+        "row_update": "kernel" if pallas and small_bins else "xla",
+        "hist_acc_rows": int(hist_acc_rows) if wide else 0}
+    return fn

@@ -528,19 +528,17 @@ class GBDT:
                         "exactness range; set use_quantized_grad=true for "
                         "exact int32 counts (and faster training) at this "
                         "scale")
-        if cfg.use_quantized_grad:
-            # int32 g_q/h_q channel sums overflow once one bin can hold
-            # more than 2^31/gq_max quantized units per shard (the count
-            # channel alone is exact to 2^31); warn at the per-shard bound
-            from ..ops.quantize import quant_levels
-            _gq = max(quant_levels(int(cfg.num_grad_quant_bins)))
-            if self.num_data > (1 << 31) // _gq * _shards:
-                log_warning(
-                    f"num_data={self.num_data} exceeds the quantized "
-                    f"histogram's int32 channel-sum exactness bound "
-                    f"(2^31/{_gq} rows per shard at num_grad_quant_bins="
-                    f"{cfg.num_grad_quant_bins}); lower num_grad_quant_bins "
-                    "or shard rows across more devices")
+        if self._hist_acc_rows(cfg) and \
+                not getattr(self.learner, "hist_acc_rows", 0):
+            # the wave grower sums such a pass in segments
+            # (ops/quantize.py); a learner that took another grower
+            # cannot
+            log_warning(
+                f"num_data={self.num_data}: one bin of this data set can "
+                "hold more rows than the quantized histogram's int32 sums "
+                "are exact for, and this tree learner does not segment "
+                "them; lower num_grad_quant_bins or shard rows across "
+                "more devices")
         # bins, labels, weights and scores go to the device: host seconds of
         # ENQUEUEING the copies (device_put returns before they land)
         with timed_span(setup_s, "upload", "train/init/upload"):
@@ -668,7 +666,8 @@ class GBDT:
             "num_leaves": int(cfg.num_leaves),
             "num_data": int(self.num_data),
             "num_features": int(self.num_features),
-        }, compile_since=t_init, mesh=self._mesh_record())
+        }, compile_since=t_init, mesh=self._mesh_record(),
+            grower=getattr(self.learner, "grower_paths", None))
         self.train_record.add_setup_seconds(
             getattr(train_set, "setup_seconds", {}))
         self.train_record.add_setup_seconds(setup_s)
@@ -787,7 +786,8 @@ class GBDT:
                                      interaction_groups=
                                      self._parse_interaction_constraints(),
                                      feature_contri=self._inner_contri(),
-                                     cegb_lazy=self._inner_cegb_lazy())
+                                     cegb_lazy=self._inner_cegb_lazy(),
+                                     acc_rows=self._hist_acc_rows(cfg))
         from ..parallel import create_parallel_learner
         return create_parallel_learner(
             cfg, self.num_features, self.max_bins, num_bins, is_cat,
@@ -795,7 +795,27 @@ class GBDT:
             interaction_groups=self._parse_interaction_constraints(),
             cegb_lazy=self._inner_cegb_lazy(),
             forced_splits=self._parse_forced_splits(),
-            feature_contri=self._inner_contri())
+            feature_contri=self._inner_contri(),
+            acc_rows=self._hist_acc_rows(cfg))
+
+    def _hist_acc_rows(self, cfg) -> int:
+        """Rows a quantized histogram pass may add into one int32 on this
+        data set (ops/quantize.py ``hist_acc_rows``; 0: no sum can wrap),
+        from the rows a shard holds, the levels and the fullest bin of
+        the binning sample.  Bundled columns count as share 1: a bundle's
+        default bin holds the rows that are default in ALL its members."""
+        if not cfg.use_quantized_grad:
+            return 0
+        from ..ops.quantize import hist_acc_rows, quant_levels
+        ts = self.train_set
+        share = 1.0 if ts.efb is not None else max(
+            (float(ts.bin_mappers[j].max_bin_share)
+             for j in ts.used_feature_map), default=1.0)
+        shards = jax.device_count() \
+            if cfg.tree_learner in ("data", "voting") else 1
+        return hist_acc_rows(-(-int(self.num_data) // shards),
+                             *quant_levels(int(cfg.num_grad_quant_bins)),
+                             share)
 
     def _mesh_record(self) -> Dict[str, Any]:
         """``TrainRecord.snapshot()["mesh"]``: the mesh the learner built
@@ -1063,7 +1083,8 @@ class GBDT:
             rec.add_tree(self.iter_, cid, grown.hist_passes,
                          grown.num_leaves, grown.wave_passes,
                          grown.endgame_passes, grown.ramp_committed,
-                         grown.hist_rows_contracted, sampled_rows)
+                         grown.hist_rows_contracted, sampled_rows,
+                         grown.decision_type)
             if self.flight.enabled:
                 # last grown tree's fields for this iteration's
                 # flight event (device scalars, pulled lazily on

@@ -63,6 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .quantize import hist_limbs
+
 __all__ = ["build_histogram_pallas", "build_histogram_pallas_leaves",
            "build_histogram_pallas_leaves_q8", "pack_weights8",
            "wave_trial_channels_pallas", "wave_row_update_pallas",
@@ -702,7 +704,7 @@ def _build_histogram_pallas_leaves_bs(bins_t: jnp.ndarray, w8: jnp.ndarray,
 
 def _leaves_dma_common(*refs, num_features, contracted, num_bins, group,
                        fstep, kr, nsteps, packed, make_w128, onehot_dtype,
-                       acc_dtype):
+                       acc_dtype, segment=False):
     """Shared DMA pipeline of the two leaf-batched kernels: bins,
     feature-major weights and the leaf-channel row stream HBM->VMEM via
     double-buffered async copies overlapping the contraction.
@@ -719,12 +721,22 @@ def _leaves_dma_common(*refs, num_features, contracted, num_bins, group,
     in a channel) or, when None, read from a prefetched scalar: a pass
     over rows compacted by :func:`_compact_rows_dma` loops over the
     blocks that hold active lanes and no further.  The operands keep
-    their static shapes; only the trip count depends on the data."""
+    their static shapes; only the trip count depends on the data.
+    ``segment`` (with ``nsteps`` None): the prefetched scalars are (trip
+    count, first block), for a pass that is accumulated in several calls
+    (ops/quantize.py: int32 sums that could wrap over the whole pass)."""
     if nsteps is None:
         steps_ref, bins_hbm, w_hbm, ch_hbm, out_ref = refs
         nsteps = steps_ref[0]
     else:
         bins_hbm, w_hbm, ch_hbm, out_ref = refs
+
+    def blk(j):
+        """Row block ``j`` of this call in the operands: a call that
+        accumulates one segment of a pass (``segment``) starts at the
+        block its second prefetched scalar names."""
+        return j + steps_ref[1] if segment else j
+
     out_ref[...] = jnp.zeros_like(out_ref)
     ft = num_features
     b = num_bins
@@ -739,25 +751,25 @@ def _leaves_dma_common(*refs, num_features, contracted, num_bins, group,
     def body(bbuf, wbuf, cbuf, bsem, wsem, csem):
         def bins_dma(slot, j):
             return pltpu.make_async_copy(
-                bins_hbm.at[pl.ds(f0, ft), pl.ds(j * kb, kb)],
+                bins_hbm.at[pl.ds(f0, ft), pl.ds(blk(j) * kb, kb)],
                 bbuf.at[slot], bsem.at[slot])
 
         def w_dma(slot, j):
             if packed:
                 return pltpu.make_async_copy(
-                    w_hbm.at[:, :, pl.ds(j * kb, kb)], wbuf.at[slot],
+                    w_hbm.at[:, :, pl.ds(blk(j) * kb, kb)], wbuf.at[slot],
                     wsem.at[slot])
             return pltpu.make_async_copy(
-                w_hbm.at[:, pl.ds(j * kr, kr)], wbuf.at[slot],
+                w_hbm.at[:, pl.ds(blk(j) * kr, kr)], wbuf.at[slot],
                 wsem.at[slot])
 
         def ch_dma(slot, j):
             if packed:
                 return pltpu.make_async_copy(
-                    ch_hbm.at[:, :, pl.ds(j * kb, kb)], cbuf.at[slot],
+                    ch_hbm.at[:, :, pl.ds(blk(j) * kb, kb)], cbuf.at[slot],
                     csem.at[slot])
             return pltpu.make_async_copy(
-                ch_hbm.at[:, pl.ds(j * kr, kr)], cbuf.at[slot],
+                ch_hbm.at[:, pl.ds(blk(j) * kr, kr)], cbuf.at[slot],
                 csem.at[slot])
 
         def start(slot, j):
@@ -765,7 +777,12 @@ def _leaves_dma_common(*refs, num_features, contracted, num_bins, group,
             w_dma(slot, j).start()
             ch_dma(slot, j).start()
 
-        start(0, 0)
+        if segment:
+            # a segment past the pass's last block runs no step: a copy
+            # started here would never be waited for
+            pl.when(nsteps > 0)(lambda: start(0, 0))
+        else:
+            start(0, 0)
 
         def step(j, carry):
             slot = j % 2
@@ -1162,10 +1179,12 @@ def _leaves_dma_tiling(f: int, num_bins: int, m_cap: int):
 
 def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
                      m_cap, kr0, make_w128, onehot_dtype, acc_dtype,
-                     out_dtype, row_block, compact=False):
+                     out_dtype, row_block, compact=False, acc_rows=0):
     """Shared wrapper plumbing of the two DMA leaf-kernel builders.
     Returns ``(out, f_pad, rows)``: ``rows`` is how many rows the kernel
-    looped over, the static N or (``compact``) a device scalar."""
+    looped over, the static N or (``compact``) a device scalar.  With
+    ``acc_rows`` the pass is accumulated ``acc_rows`` rows a call and
+    ``out`` is the list of the calls' outputs (ops/quantize.py)."""
     f = bins_t.shape[0]
     n = bins_t.shape[1] * (2 if packed else 1)
     b, group, fstep, ft, f_pad, fc = _leaves_dma_tiling(f, num_bins, m_cap)
@@ -1186,31 +1205,51 @@ def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
         bins_t, w, ch2, steps = _compact_rows_dma(
             bins_t, w, ch2, fc=fc, kr=kr, interpret=interpret)
         n = bins_t.shape[1]
-        operands = (steps, bins_t, w, ch2)
-        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, **specs))
         rows = steps[0] * kr
     else:
-        operands = (bins_t, w, ch2)
         rows = n
-    out = pl.pallas_call(
-        functools.partial(_leaves_dma_common, num_features=ft,
-                          contracted=fc, num_bins=b, group=group,
-                          fstep=fstep, kr=kr,
-                          nsteps=None if compact else n // kr,
-                          packed=packed, make_w128=make_w128,
-                          onehot_dtype=onehot_dtype, acc_dtype=acc_dtype),
-        **specs,
-        out_shape=jax.ShapeDtypeStruct((f_pad * b, 128), out_dtype),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * fc * b * n * 128,
-            bytes_accessed=f_pad * (n // 2 if packed else n) +
-            n * (_C * 2 + 4) + f_pad * b * 512,
-            transcendentals=0),
-        interpret=interpret,
-        name=_kname(kind + "_dma" + ("_packed4" if packed else ""),
-                    f=f_pad, fc=fc, b=b, g=group, kr=kr, n=n),
-    )(*operands)
+
+    def call(prefetch, **static):
+        if prefetch is None:
+            operands, sp = (bins_t, w, ch2), specs
+        else:
+            operands = (prefetch, bins_t, w, ch2)
+            sp = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, **specs))
+        return pl.pallas_call(
+            functools.partial(_leaves_dma_common, num_features=ft,
+                              contracted=fc, num_bins=b, group=group,
+                              fstep=fstep, kr=kr,
+                              packed=packed, make_w128=make_w128,
+                              onehot_dtype=onehot_dtype, acc_dtype=acc_dtype,
+                              **static),
+            **sp,
+            out_shape=jax.ShapeDtypeStruct((f_pad * b, 128), out_dtype),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * fc * b * n * 128,
+                bytes_accessed=f_pad * (n // 2 if packed else n) +
+                n * (_C * 2 + 4) + f_pad * b * 512,
+                transcendentals=0),
+            interpret=interpret,
+            name=_kname(kind + "_dma" + ("_packed4" if packed else "")
+                        + ("_seg" if static.get("segment") else ""),
+                        f=f_pad, fc=fc, b=b, g=group, kr=kr, n=n),
+        )(*operands)
+
+    if acc_rows:
+        # one call a segment of ``acc_rows`` rows, each told its first
+        # block and its trip count: all the blocks of a dense pass, the
+        # blocks that hold active lanes of a compacted one
+        seg, blocks = max(1, acc_rows // kr), n // kr
+        have = steps[0] if compact else jnp.int32(blocks)
+        out = [call(jnp.stack([jnp.clip(have - first, 0, seg),
+                               jnp.int32(first)]),
+                    nsteps=None, segment=True)
+               for first in range(0, blocks, seg)]
+    elif compact:
+        out = call(steps, nsteps=None)
+    else:
+        out = call(None, nsteps=n // kr)
     return out, f_pad, rows
 
 
@@ -1310,9 +1349,11 @@ def build_histogram_pallas_leaves(bins_t: jnp.ndarray, w8: jnp.ndarray,
 # better conditioned than the reference's f64 CPU path.  Exactness bounds
 # per int32 accumulator bin: the count channel (weight 1) is exact to 2^31
 # rows/shard; the g_q/h_q channels (weights up to gq_max/hq_max) are exact
-# to 2^31/gq_max rows landing in ONE bin per shard (~16.9M rows at 127
-# levels — gbdt.py warns past the bound).  The bf16 kernel's f32 counts
-# cap at 2^24 (ops/histogram.py).
+# to 2^31/gq_max rows of ONE leaf landing in ONE bin per shard (~16.9M
+# rows at 127 levels); where a data set's fullest bin can pass that, the
+# pass is summed in segments and the sums kept as two limbs (``acc_rows``;
+# ops/quantize.py has the bound and who decides).  The bf16 kernel's f32
+# counts cap at 2^24 (ops/histogram.py).
 #
 # Mosaic constraints probed on v5e (scripts/proto_q8_*.py): 8-bit compares
 # and 8-bit elementwise multiplies are NOT supported — the one-hot and the
@@ -1416,10 +1457,10 @@ def _build_histogram_pallas_leaves_q8_bs(bins_t: jnp.ndarray,
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "row_block", "interpret",
-                                    "packed", "compact"))
+                                    "packed", "compact", "acc_rows"))
 def _build_histogram_pallas_leaves_q8_dma(bins_t, wch, ch, *, num_bins,
                                           row_block, interpret, packed,
-                                          compact=False):
+                                          compact=False, acc_rows=0):
     n = wch.shape[1]
     # int32 channel row: Mosaic cannot slice a (1, kr) slab out of a
     # one-row int8 array (its tiles are 4 sublanes deep)
@@ -1430,12 +1471,19 @@ def _build_histogram_pallas_leaves_q8_dma(bins_t, wch, ch, *, num_bins,
         packed=packed, m_cap=_LEAVES_Q8_M_CAP, kr0=4096,
         make_w128=_make_w128_q8,
         onehot_dtype=jnp.int8, acc_dtype=jnp.int32,
-        out_dtype=jnp.int32, row_block=row_block, compact=compact)
+        out_dtype=jnp.int32, row_block=row_block, compact=compact,
+        acc_rows=acc_rows)
     f = bins_t.shape[0]
-    b = out.shape[0] // f_pad
-    out = out[:, :Q_LEAF_CHANNELS * _QCB].reshape(f_pad, b,
-                                                  Q_LEAF_CHANNELS, _QCB)
-    return jnp.transpose(out, (2, 0, 1, 3))[:, :f, :num_bins, :], rows
+
+    def leaves(out):
+        b = out.shape[0] // f_pad
+        out = out[:, :Q_LEAF_CHANNELS * _QCB].reshape(f_pad, b,
+                                                      Q_LEAF_CHANNELS, _QCB)
+        return jnp.transpose(out, (2, 0, 1, 3))[:, :f, :num_bins, :]
+
+    if acc_rows:
+        return hist_limbs([leaves(o) for o in out]), rows
+    return leaves(out), rows
 
 
 def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
@@ -1444,7 +1492,8 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
                                      interpret: bool = None,
                                      pipeline: str = None,
                                      bins_packed: bool = False,
-                                     compact: bool = False):
+                                     compact: bool = False,
+                                     acc_rows: int = 0):
     """(Q_LEAF_CHANNELS, F, B, 3) int32 histograms of 42 leaf channels.
 
     Args:
@@ -1460,6 +1509,12 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
       interpret / pipeline / bins_packed: as :func:`build_histogram_pallas`.
       compact: as :func:`build_histogram_pallas_leaves`; the result is
         then ``(hist, rows)``.
+      acc_rows: 0, or the most rows that may be added into one int32
+        (ops/quantize.py ``hist_acc_rows``, a multiple of ``row_block``):
+        the pass is then summed in segments of that many rows (``dma``:
+        the same operands, each call told its first block; ``blockspec``:
+        row slices) and the result is the segments' exact sum as
+        (42, F, B, 5) int32 limbs (ops/quantize.py ``hist_limbs``).
     Returns:
       (42, F, B, 3) int32: channel sums (sum g_q, sum h_q, count) —
       exact integer sums, so every pipeline/packing variant, compacted
@@ -1487,7 +1542,15 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
         hist, rows = _build_histogram_pallas_leaves_q8_dma(
             bins_t, wch, ch, num_bins=num_bins, row_block=row_block,
             interpret=interpret, packed=bins_packed,
-            compact=compact and not bins_packed)
+            compact=compact and not bins_packed, acc_rows=acc_rows)
+    elif acc_rows:
+        acc_rows = max(row_block, acc_rows // row_block * row_block)
+        hist, rows = hist_limbs([
+            _build_histogram_pallas_leaves_q8_bs(
+                bins_t[:, lo:lo + acc_rows], wch[:, lo:lo + acc_rows],
+                ch[lo:lo + acc_rows], num_bins=num_bins,
+                row_block=row_block, interpret=interpret)
+            for lo in range(0, n, acc_rows)]), n
     else:
         hist, rows = _build_histogram_pallas_leaves_q8_bs(
             bins_t, wch, ch, num_bins=num_bins, row_block=row_block,
@@ -1509,12 +1572,15 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
 # caller that already holds the W columns passes them as the matrix, with
 # feature ids 0..W-1: the same kernel.  The ``blockspec`` pipeline still
 # gathers the columns in front of its kernel (:func:`gather_bin_rows`).
-# Numeric splits only — the categorical membership lookup is a per-row
-# gather Mosaic cannot express; wave.py keeps the XLA path when
-# categorical features or EFB bundles are present.
+# A categorical split's left set rides with the table as a 256-bit set
+# (eight SMEM words a slot, the word picked by seven selects on ``bin >>
+# 5``): no per-row gather.  wave.py keeps the XLA path for EFB bundles
+# and more than 255 bins.
 # ---------------------------------------------------------------------------
 
 _RU_SUB = 8      # the row update lays rows out as (_RU_SUB, N // _RU_SUB)
+_RU_TAB = 8      # scalar rows of the split table; a categorical table adds
+_RU_WORDS = 8    # is_cat and this many 32-bit words of left-set bins
 _RU_LANES = 512  # lanes swept at a time: rl, ch and a column in 12 vregs
 # Rows per fetched block of the dma pipeline, at most: a block costs W
 # column copies, and at 4096 rows (4 KB a copy) issuing them shows — at
@@ -1552,8 +1618,12 @@ def gather_bin_rows(bins_t: jnp.ndarray, feats: jnp.ndarray) -> jnp.ndarray:
          for j in range(feats.shape[0])], axis=0)
 
 
-def _row_update_sweep(col_of, rl, tab_ref, w: int):
-    """The W splits applied one after the other to a block of rows."""
+def _row_update_sweep(col_of, rl, tab_ref, w: int, cat: bool = False):
+    """The W splits applied one after the other to a block of rows.
+    ``cat``: the table carries, below its eight rows, a slot's ``is_cat``
+    flag (row 8) and the 256-bit set of the bins that go left as eight
+    words (rows 9..16, bit ``b & 31`` of word ``b >> 5``); a categorical
+    slot tests membership where a numeric one compares."""
     ch = jnp.full_like(rl, -1)
     for j in range(w):
         col = col_of(j).astype(jnp.int32)
@@ -1569,6 +1639,14 @@ def _row_update_sweep(col_of, rl, tab_ref, w: int):
         # int32 land and the flags compare as integers
         go_left = jnp.where(col == nanb, dlft,
                             (col <= thr).astype(jnp.int32))
+        if cat:
+            hi = col >> 5
+            word = tab_ref[_RU_TAB + 8, j]
+            for k in range(6, -1, -1):      # the word picked by 7 selects
+                word = jnp.where(hi == k, tab_ref[_RU_TAB + 1 + k, j], word)
+            member = (word >> (col & 31)) & 1
+            go_left = jnp.where(
+                jnp.full_like(col, tab_ref[_RU_TAB, j]) > 0, member, go_left)
         upd = (rl == selj) & (act > 0)
         ch = jnp.where(upd & (go_left == small), j, ch)
         rl = jnp.where(upd & (go_left == 0), newid, rl)
@@ -1576,15 +1654,17 @@ def _row_update_sweep(col_of, rl, tab_ref, w: int):
 
 
 def _row_update_kernel(cols_ref, rl_ref, tab_ref, rl_out, ch_out, *,
-                       w: int):
+                       w: int, cat: bool = False):
     rl, ch = _row_update_sweep(lambda j: cols_ref[j],
-                               rl_ref[...].astype(jnp.int32), tab_ref, w)
+                               rl_ref[...].astype(jnp.int32), tab_ref, w,
+                               cat)
     rl_out[...] = rl
     ch_out[...] = ch.astype(jnp.int8)
 
 
 def _row_update_kernel_dma(bins_hbm, rl_hbm, tab_ref, rl_out, ch_out, *,
-                           w: int, krd: int, nsteps: int):
+                           w: int, krd: int, nsteps: int,
+                           cat: bool = False):
     """Fully manual DMA pipeline of the wave row update: per row block,
     W copies bring the winning features' strips ``bins_hbm[tab[7, jj], :,
     block]`` (the feature ids ride in the split table's eighth row) and
@@ -1650,7 +1730,8 @@ def _row_update_kernel_dma(bins_hbm, rl_hbm, tab_ref, rl_out, ch_out, *,
                          pl.ds(pl.multiple_of(c * lw, lw), lw))
                 rl, ch = _row_update_sweep(
                     lambda jj: cbuf[slot, jj, :, lanes],
-                    ibuf[slot, :, lanes].astype(jnp.int32), tab_ref, w)
+                    ibuf[slot, :, lanes].astype(jnp.int32), tab_ref, w,
+                    cat)
                 robuf[slot, :, lanes] = rl
                 cobuf[slot, :, lanes] = ch.astype(jnp.int8)
                 return carry
@@ -1682,11 +1763,12 @@ def _row_update_kernel_dma(bins_hbm, rl_hbm, tab_ref, rl_out, ch_out, *,
                   pltpu.SemaphoreType.DMA((2,)))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "cat"))
 def _wave_row_update_dma(bins3: jnp.ndarray, rl: jnp.ndarray,
-                         tab: jnp.ndarray, *, interpret: bool = False):
+                         tab: jnp.ndarray, *, interpret: bool = False,
+                         cat: bool = False):
     """``bins3``: a :func:`bin_rows_view`; ``tab[7]``: W ids into its
-    leading axis."""
+    leading axis; ``cat``: ``tab`` is a :func:`_cat_table`."""
     f, _, nd = bins3.shape
     w = tab.shape[1]
     n = nd * _RU_SUB
@@ -1695,7 +1777,7 @@ def _wave_row_update_dma(bins3: jnp.ndarray, rl: jnp.ndarray,
     rl2 = rl.astype(jnp.int32).reshape(_RU_SUB, nd)
     rl_new, ch = pl.pallas_call(
         functools.partial(_row_update_kernel_dma, w=w, krd=krd,
-                          nsteps=n // kr),
+                          nsteps=n // kr, cat=cat),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -1710,16 +1792,18 @@ def _wave_row_update_dma(bins3: jnp.ndarray, rl: jnp.ndarray,
             jax.ShapeDtypeStruct((_RU_SUB, nd), jnp.int8),
         ],
         interpret=interpret,
-        name=_kname("wave_row_update_dma", w=w, f=f, kr=kr, n=n),
+        name=_kname("wave_row_update_dma" + ("_cat" if cat else ""),
+                    w=w, f=f, kr=kr, n=n),
     )(bins3, rl2, tab)
     return rl_new.reshape(n), ch.reshape(n)
 
 
-@functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("row_block", "interpret", "cat"))
 def _wave_row_update_bs(cols_w: jnp.ndarray, rl: jnp.ndarray,
                         tab: jnp.ndarray, *,
                         row_block: int = DEFAULT_ROW_BLOCK,
-                        interpret: bool = False):
+                        interpret: bool = False, cat: bool = False):
     """Implicit-pipeline (BlockSpec-fetched) row update (v1 layout)."""
     w, n = cols_w.shape
     kr = math.gcd(row_block, 4096)
@@ -1730,7 +1814,7 @@ def _wave_row_update_bs(cols_w: jnp.ndarray, rl: jnp.ndarray,
 
     grid = (n // kr,)
     rl_new, ch = pl.pallas_call(
-        functools.partial(_row_update_kernel, w=w),
+        functools.partial(_row_update_kernel, w=w, cat=cat),
         grid=grid,
         in_specs=[
             pl.BlockSpec((w, 8, krd), lambda i: (0, 0, i),
@@ -1750,16 +1834,33 @@ def _wave_row_update_bs(cols_w: jnp.ndarray, rl: jnp.ndarray,
             jax.ShapeDtypeStruct((8, nd), jnp.int8),
         ],
         interpret=interpret,
-        name=_kname("wave_row_update_blockspec", w=w, kr=kr, n=n),
+        name=_kname("wave_row_update_blockspec" + ("_cat" if cat else ""),
+                    w=w, kr=kr, n=n),
     )(cols3, rl2, tab)
     return rl_new.reshape(n), ch.reshape(n)
 
 
+def _cat_table(tab: jnp.ndarray, is_cat: jnp.ndarray,
+               member: jnp.ndarray) -> jnp.ndarray:
+    """The (8, W) split table with the categorical slots' tests below it:
+    row 8 ``is_cat``, rows 9..16 the (W, B <= 256) left-set membership
+    ``member`` as eight 32-bit words a slot."""
+    w, b = member.shape
+    bits = jnp.pad(member, ((0, 0), (0, 32 * _RU_WORDS - b))).reshape(
+        w, _RU_WORDS, 32).astype(jnp.uint32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                    dtype=jnp.uint32)
+    return jnp.concatenate([
+        tab, is_cat.astype(jnp.int32)[None, :],
+        jax.lax.bitcast_convert_type(words, jnp.int32).T])
+
+
 def wave_row_update_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
                            tab: jnp.ndarray, *, feats: jnp.ndarray = None,
+                           cat: tuple = None,
                            row_block: int = DEFAULT_ROW_BLOCK,
                            interpret: bool = None, pipeline: str = None):
-    """Apply a wave's W numeric splits to every row in one fused pass.
+    """Apply a wave's W splits to every row in one fused pass.
 
     Args:
       bins: where the splits' bin columns come from, N a multiple of
@@ -1775,6 +1876,11 @@ def wave_row_update_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
         new_right_id, active, (overwritten: the feature ids)].
       feats: (W,) integer feature id of each split (clipped to [0, F)),
         or None when ``bins`` holds the columns.
+      cat: None where every split is numeric (``bin <= threshold``, one
+        NaN bin with its direction), else ``(is_cat (W,) bool, member
+        (W, B <= 256) bool)``: a categorical slot sends a row left iff its
+        bin is in the slot's set, whatever the slot's threshold and NaN
+        bin say; the kernel is then named ``..._cat_...``.
       interpret / pipeline: as :func:`build_histogram_pallas` ("dma"
         streams the column blocks AND the rl/ch write-backs through
         double-buffered async copies).
@@ -1794,20 +1900,25 @@ def wave_row_update_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
     pipeline = resolve_pipeline(pipeline)
     interpret = resolve_interpret(interpret)
     _note_kernel(f"ops/hist_kernel/row_update/{pipeline}"
-                 + ("/fetch" if fetch and pipeline == "dma" else ""),
+                 + ("/fetch" if fetch and pipeline == "dma" else "")
+                 + ("/cat" if cat is not None else ""),
                  w * n * bins.dtype.itemsize + n * 4 + n * 5)
     if fetch:
         feats = jnp.clip(feats.astype(jnp.int32), 0, f - 1)
+    is_cat = cat is not None
     if pipeline == "dma":
         if bins.ndim == 2:
             bins = bin_rows_view(bins, pipeline)
         ids = feats if fetch else jnp.arange(w, dtype=jnp.int32)
-        return _wave_row_update_dma(bins, rl, tab.at[7].set(ids),
-                                    interpret=interpret)
+        tab = tab.at[7].set(ids)
+        return _wave_row_update_dma(
+            bins, rl, _cat_table(tab, *cat) if is_cat else tab,
+            interpret=interpret, cat=is_cat)
     if fetch:
         bins = gather_bin_rows(bins.reshape(f, n), feats)
-    return _wave_row_update_bs(bins, rl, tab, row_block=row_block,
-                               interpret=interpret)
+    return _wave_row_update_bs(
+        bins, rl, _cat_table(tab, *cat) if is_cat else tab,
+        row_block=row_block, interpret=interpret, cat=is_cat)
 
 
 def wave_trial_channels_pallas(bins: jnp.ndarray, rl: jnp.ndarray,

@@ -252,6 +252,11 @@ def _best_split_impl(hist: jnp.ndarray, parent_sum: jnp.ndarray,
     # per-(f,b) validity of a threshold: real-value bins only, and at least
     # one bin must remain on the right
     real_bin = jnp.where(has_nan[:, None], bins_r < nan_bin, bins_r < num_bins[:, None])
+    if params.any_cat:
+        # bin 0 of a categorical feature holds what is no binned category
+        # (binning.py: missing, folded away, never seen); it is never a
+        # candidate for a left set (feature_histogram.hpp bin_start = 1)
+        real_bin = real_bin & jnp.logical_not(is_cat[:, None] & (bins_r == 0))
     thr_valid = jnp.where(has_nan[:, None],
                           bins_r < nan_bin,             # b in [0, nan_bin-1]
                           bins_r < num_bins[:, None] - 1)
@@ -328,20 +333,24 @@ def _best_split_impl(hist: jnp.ndarray, parent_sum: jnp.ndarray,
     gain_l = dir_gain(cum_g + nan_g, cum_h + nan_h, cum_c + nan_c)
     gain_l = jnp.where(hn_f, gain_l, NEG_INF)
 
-    if params.any_cat:
+    def _cat_search():
+        """One-vs-rest and sorted-subset search over the categorical
+        features: ``(gain (F,), left-set membership (F, B), left sums
+        (F, 3))``."""
         # ---- categorical one-vs-rest: category bin b goes left, rest right
         # (feature_histogram.hpp FindBestThresholdCategoricalInner
-        # one-hot branch; cat_l2 regularizes)
+        # one-hot branch, which keeps the plain lambda_l2: cat_l2 is added
+        # in the sorted-subset branch only)
         cat_l2 = l2 + params.cat_l2
         crg, crh, crc = tot_g - hg_m, tot_h - hh_m, tot_c - hc_m
         if use_out:  # clamp/smooth outputs (no direction check for cats)
-            c_out_l = clamped_out(hg_m, hh_m, hc_m, cat_l2)
-            c_out_r = clamped_out(crg, crh, crc, cat_l2)
-            cgl = _gain_given_output(hg_m, hh_m, c_out_l, l1, cat_l2)
-            cgr = _gain_given_output(crg, crh, c_out_r, l1, cat_l2)
+            c_out_l = clamped_out(hg_m, hh_m, hc_m, l2)
+            c_out_r = clamped_out(crg, crh, crc, l2)
+            cgl = _gain_given_output(hg_m, hh_m, c_out_l, l1, l2)
+            cgr = _gain_given_output(crg, crh, c_out_r, l1, l2)
         else:
-            cgl = _leaf_gain(hg_m, hh_m, l1, cat_l2)
-            cgr = _leaf_gain(crg, crh, l1, cat_l2)
+            cgl = _leaf_gain(hg_m, hh_m, l1, l2)
+            cgr = _leaf_gain(crg, crh, l1, l2)
         cat_ok = ((hc_m >= min_cnt) & (crc >= min_cnt) &
                   (hh_m >= min_h) & (crh >= min_h) & real_bin)
         if use_et:  # one random category per node (USE_RAND one-hot branch)
@@ -472,6 +481,15 @@ def _best_split_impl(hist: jnp.ndarray, parent_sum: jnp.ndarray,
             cat_best_gain = oh_gain
             cat_member = oh_member
             cat_left_sum = oh_left
+        return cat_best_gain, cat_member, cat_left_sum
+
+    if params.any_cat:
+        # its own scope inside the caller's (the wave grower's
+        # ``lgbm.wave.scan``): a trace tells the categorical search's
+        # time from the numeric scan's; traced only where any_cat
+        with jax.named_scope("lgbm.wave.cat_scan"):
+            (cat_best_gain, cat_member,
+             cat_left_sum) = _cat_search()
     else:
         # no categorical features in the dataset: the scan skips the
         # one-vs-rest/subset machinery entirely (is_cat is all-False, so

@@ -20,7 +20,7 @@ from .mesh import get_mesh
 def create_parallel_learner(config, num_features, max_bins, num_bins, is_cat,
                             has_nan, monotone=None, interaction_groups=(),
                             cegb_lazy=(), forced_splits=(),
-                            feature_contri=()):
+                            feature_contri=(), acc_rows=0):
     """Factory (reference tree_learner.h:104 TreeLearner::CreateTreeLearner
     dispatching on tree_learner type)."""
     kind = config.tree_learner
@@ -46,13 +46,14 @@ def create_parallel_learner(config, num_features, max_bins, num_bins, is_cat,
             config, num_features, max_bins, num_bins, is_cat, has_nan,
             monotone, forced_splits,
             interaction_groups=interaction_groups, cegb_lazy=cegb_lazy,
-            feature_contri=feature_contri)
+            feature_contri=feature_contri, acc_rows=acc_rows)
     # feature_contri stops here for every mesh learner: inherited, not
     # chosen (ROADMAP D2: honour or raise)
     if kind == "data":
         return cls(config, num_features, max_bins, num_bins, is_cat,
                    has_nan, monotone, interaction_groups=interaction_groups,
-                   cegb_lazy=cegb_lazy, forced_splits=forced_splits)
+                   cegb_lazy=cegb_lazy, forced_splits=forced_splits,
+                   acc_rows=acc_rows)
     if interaction_groups or cegb_lazy or forced_splits:
         from ..utils.log import log_warning
         log_warning("interaction_constraints / cegb_penalty_feature_lazy / "

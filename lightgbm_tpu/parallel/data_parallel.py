@@ -302,7 +302,8 @@ class DataParallelTreeLearner(WaveTreeLearner):
                  num_bins: np.ndarray, is_cat: np.ndarray, has_nan: np.ndarray,
                  monotone: Optional[np.ndarray] = None,
                  interaction_groups: tuple = (),
-                 cegb_lazy: tuple = (), forced_splits: tuple = ()):
+                 cegb_lazy: tuple = (), forced_splits: tuple = (),
+                 acc_rows: int = 0):
         mesh = get_mesh(int(config.num_devices))
         mode = str(config.tree_grow_mode)
         impl_wave = resolve_hist_impl(config, parallel=True, wave=True,
@@ -318,7 +319,7 @@ class DataParallelTreeLearner(WaveTreeLearner):
                 config, num_features, max_bins, num_bins, is_cat, has_nan,
                 monotone, hist_impl=impl_wave,
                 interaction_groups=interaction_groups, cegb_lazy=cegb_lazy,
-                forced_splits=forced_splits, mesh=mesh,
+                forced_splits=forced_splits, mesh=mesh, acc_rows=acc_rows,
                 strategy=WaveDPStrategy(
                     mesh.axis_names[0], nshards=mesh.devices.size,
                     hist_scatter=bool(config.tpu_dp_hist_scatter)))

@@ -16,8 +16,9 @@ boosting loop runs:
     the wave and endgame passes compacted theirs,
     ops/histogram_pallas.py; summed over the row shards this process
     holds), the rows in the tree's bag (``sampled_rows``: N where
-    nothing samples, else the bagging mask's or the GOSS draw's count)
-    and leaf counts —
+    nothing samples, else the bagging mask's or the GOSS draw's count),
+    how many of the tree's splits are categorical (``cat_splits``, read
+    off the node records the grower returns) and leaf counts —
     kept as device scalars and pulled in batched, lazy fetches so the
     async dispatch pipeline never stalls;
   * collective count and reduced bytes, tallied at the
@@ -334,10 +335,12 @@ class TrainRecord:
 
     def __init__(self, meta: Optional[Dict[str, Any]] = None,
                  compile_since: Optional[float] = None,
-                 mesh: Optional[Dict[str, Any]] = None) -> None:
+                 mesh: Optional[Dict[str, Any]] = None,
+                 grower: Optional[Dict[str, Any]] = None) -> None:
         self._lock = threading.Lock()
         self.meta = dict(meta or {})
         self.mesh = dict(mesh or {})
+        self.grower = dict(grower or {})
         self._t_created = time.perf_counter()
         # JAX's trace/lower/compile events count from here (perf_counter):
         # the start of the set-up the record belongs to, if it began earlier
@@ -348,7 +351,7 @@ class TrainRecord:
         self._phase_n: Dict[str, int] = {}
         # per-tree device scalars pending a batched host pull
         # (iteration, class_id, (hp, nl, wave, endgame, ramp_committed,
-        #  hist_rows_contracted, sampled_rows))
+        #  hist_rows_contracted, sampled_rows, decision_type))
         self._pending: List[tuple] = []
         self._trees: List[Dict[str, int]] = []
         self._setup_s: Dict[str, float] = {}
@@ -394,7 +397,7 @@ class TrainRecord:
     def add_tree(self, iteration: int, class_id: int, hist_passes,
                  num_leaves, wave_passes=0, endgame_passes=0,
                  ramp_committed=0, hist_rows_contracted=((0, 0),),
-                 sampled_rows=0) -> None:
+                 sampled_rows=0, decision_type=()) -> None:
         """Record one grown tree.  The counts may be device scalars; they
         are NOT synced here — batches are pulled lazily so the async
         dispatch pipeline keeps flowing."""
@@ -407,11 +410,14 @@ class TrainRecord:
         if not getattr(sampled_rows, "is_fully_addressable", True):
             # a count over rows that span processes: this process's copy
             sampled_rows = sampled_rows.addressable_shards[0].data
+        if not getattr(decision_type, "is_fully_addressable", True):
+            decision_type = decision_type.addressable_shards[0].data
         with self._lock:
             self._pending.append((int(iteration), int(class_id),
                                   (hist_passes, num_leaves, wave_passes,
                                    endgame_passes, ramp_committed,
-                                   hist_rows_contracted, sampled_rows)))
+                                   hist_rows_contracted, sampled_rows,
+                                   decision_type)))
             flush = len(self._pending) >= _FLUSH_EVERY
         if flush:
             self._flush()
@@ -442,8 +448,13 @@ class TrainRecord:
                  # (shards, 2) [count, unit] -> rows, over the shards
                  "hist_rows_contracted": sum(
                      int(c) * int(u) for c, u in np.reshape(rows, (-1, 2))),
-                 "sampled_rows": int(sr)}
-                for (it, cid, _), (hp, nl, wp, ep, rc, rows, sr)
+                 "sampled_rows": int(sr),
+                 # the grower's node records (models/tree.py: bit 0 of a
+                 # node's decision_type says categorical), come with the
+                 # same fetch
+                 "cat_splits": int(np.count_nonzero(
+                     np.asarray(dt, np.int64)[:max(int(nl) - 1, 0)] & 1))}
+                for (it, cid, _), (hp, nl, wp, ep, rc, rows, sr, dt)
                 in zip(pending, vals)]
         with self._lock:
             self._trees.extend(rows)
@@ -484,7 +495,11 @@ class TrainRecord:
         again, and a record that saw no trace of a site leaves it out.
         ``mesh``: ``{"chips", "axis", "rows_per_chip"}``
         as the learner built it (one chip, no axis: the serial
-        learner)."""
+        learner).  ``grower``: the wave grower's own statement of the
+        static paths it was built with (learner/wave.py ``static_paths``:
+        ``ramp``, ``endgame``, ``scatter``, ``voting``, ``efb``,
+        ``any_cat``, ``row_update`` "kernel" | "xla", ``hist_acc_rows``),
+        {} under a learner that grows some other way."""
         self._flush()
         self.note_memory()  # final watermark: periodic samples miss the tail
         with self._lock:
@@ -542,6 +557,7 @@ class TrainRecord:
             "setup_seconds": {k: round(v, 6) for k, v in setup_s.items()},
             "collectives": coll,
             "mesh": dict(self.mesh),
+            "grower": dict(self.grower),
             "hist_kernel": hist_kernels,
             "compile_events": events,
             "compile_seconds": secs,

@@ -1,6 +1,7 @@
 """BinMapper tests (reference src/io/bin.cpp FindBin semantics)."""
 
 import numpy as np
+import pytest
 
 from lightgbm_tpu.binning import MissingType, bin_matrix, find_bin
 
@@ -64,12 +65,35 @@ def test_categorical():
     m = find_bin(vals, max_bin=32, min_data_in_bin=1, is_categorical=True)
     assert m.is_categorical
     b = m.value_to_bin(np.array([5.0, 2.0, 1.0, 0.0]))
-    # bins ordered by descending frequency: 5 -> 0, 2 -> 1, 1 -> 2, 0 -> 3
-    assert b.tolist() == [0, 1, 2, 3]
-    # unseen category -> bin 0 (most frequent)
-    assert m.value_to_bin(np.array([99.0]))[0] == 0
-    # NaN -> most frequent bin
-    assert m.value_to_bin(np.array([np.nan]))[0] == 0
+    # bin 0 is no category's; then by descending frequency: 5 -> 1, 2 -> 2,
+    # 1 -> 3, 0 -> 4
+    assert b.tolist() == [1, 2, 3, 4]
+    assert m.num_bin == 5 and m.bin_to_cat.tolist() == [-1, 5, 2, 1, 0]
+    assert m.max_bin_share == pytest.approx(0.4)
+
+
+@pytest.mark.parametrize("value", [99.0, 6.0, 3.0, -1.0, -7.0, 2.5, np.nan,
+                                   np.inf, -np.inf, 1e12])
+def test_categorical_bin0_takes_what_is_no_binned_category(value):
+    """Unseen (above, just above and between the binned ids), negative,
+    non-integer, missing and infinite values all land in bin 0, which the
+    split search never sends left (PR 34)."""
+    vals = np.array([0, 1, 1, 2, 2, 2, 5, 5, 5, 5] * 10, dtype=np.float64)
+    m = find_bin(vals, max_bin=32, min_data_in_bin=1, is_categorical=True)
+    assert m.value_to_bin(np.array([value, 5.0]))[0] == 0
+
+
+def test_categorical_folded_categories_share_bin0():
+    """Categories past the bin budget are folded into bin 0 and counted
+    in its share; a column with one category and nothing else is trivial."""
+    vals = np.repeat(np.arange(10.0), np.arange(10, 0, -1) * 10)
+    m = find_bin(vals, max_bin=4, min_data_in_bin=1, is_categorical=True)
+    assert m.num_bin == 4 and m.bin_to_cat.tolist() == [-1, 0, 1, 2]
+    assert m.value_to_bin(np.array([0.0, 2.0, 3.0, 9.0])).tolist() == [1, 3, 0, 0]
+    assert m.max_bin_share == pytest.approx(280 / 550)
+    assert find_bin(np.full(50, 3.0), max_bin=8, is_categorical=True).is_trivial
+    assert not find_bin(np.r_[np.full(50, 3.0), np.nan], max_bin=8,
+                        is_categorical=True).is_trivial
 
 
 def test_trivial_feature():

@@ -122,6 +122,28 @@ def test_row_update_fetch_kernel_compiles_at_cell_shape(S, w):
     assert f"u8[{F},8,{N // 8}]" in text
 
 
+def test_cat_row_update_kernel_compiles_at_cell_shape(S):
+    """``criteo-cat-q8.train`` (PR 34): 45,840,617 rows padded to the row
+    block x 39 columns; the split table carries ``is_cat`` and eight words
+    of left-set bins a slot below its eight scalar rows."""
+    f, n, w, b = 39, 45_842_432, 42, 255
+
+    def route(bins, feats, rl, tab, is_cat, member):
+        return wave_row_update_pallas(
+            bin_rows_view(bins, "dma"), rl, tab, feats=feats,
+            cat=(is_cat, member), pipeline="dma", interpret=False)
+
+    compiled = jax.jit(route).lower(
+        S((f, n), jnp.uint8), S((w,), jnp.int32), S((n,), jnp.uint8),
+        S((8, w), jnp.int32), S((w,), jnp.bool_),
+        S((w, b), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"lgbm_wave_row_update_dma_cat_w{w}_f{f}_kr16384_n{n}" in \
+        traced_kernels()
+    assert f"u8[{f},8,{n // 8}]" in text and f"s32[17,{w}]" in text
+
+
 def test_goss_sampler_compiles_at_cell_rows(S):
     """The GOSS draw of ``criteo-q8-goss.train`` (PR 32): one program over
     the cell's 21,250,000 rows whose exact threshold is a loop of counting
