@@ -180,17 +180,62 @@ _update_score_by_leaf_donated = jax.jit(
     _score_update_impl, donate_argnums=SCORE_DONATE_ARGNUMS)
 
 
-def _score_update_entry():
-    """The donated entry on TPU, the plain one elsewhere."""
-    from ..utils.backend import default_backend
-    if default_backend() == "tpu":
-        return _update_score_by_leaf_donated
-    return _update_score_by_leaf
+def _score_select_impl(score, row_leaf, leaf_value, shrinkage, *,
+                       interpret=None):
+    """:func:`_score_update_impl` with the lookup as a streaming select
+    over the rows (ops/histogram_pallas.py ``score_update_pallas``): the
+    same bits, since a select hands a leaf's f32 value on untouched."""
+    from ..ops.histogram_pallas import score_update_pallas
+    with jax.named_scope("lgbm.score_update"):
+        return score_update_pallas(score, row_leaf, shrinkage * leaf_value,
+                                   interpret=interpret)
+
+
+_update_score_by_select_donated = jax.jit(
+    _score_select_impl, donate_argnums=SCORE_DONATE_ARGNUMS)
+
+
+@functools.lru_cache(maxsize=None)
+def _update_score_by_select_sharded(mesh, axis: str, interpret=None):
+    """The select over row shards of ``mesh``: a ``pallas_call`` is not
+    partitioned by GSPMD, so each chip runs it on its own rows (score and
+    ``row_leaf`` sharded alike, the leaf values replicated; no
+    collective)."""
+    from jax.sharding import PartitionSpec as P
+    return jax.jit(jax.shard_map(
+        functools.partial(_score_select_impl, interpret=interpret),
+        mesh=mesh,
+        in_specs=(P(axis), P(axis), P(), P()), out_specs=P(axis),
+        check_vma=False), donate_argnums=SCORE_DONATE_ARGNUMS)
+
+
+# The select costs rows x leaves, the gather rows whatever the leaves.  On
+# a TPU v5e at 21.25M rows (scripts/bench_score_update.py; PERF.md section
+# 6, PR 35) the select took 2.17 ms at 255 leaves, 8.30 at 1023 and 32.88
+# at 4095 (8.0 us a leaf), the gather 175.1 / 113.7 / 152.1 ms: the lines
+# cross near 14,000 leaves, and the table (SMEM) is kept to half of that.
+SCORE_SELECT_MAX_LEAVES = 8192
+
+
+def score_update_lowering(backend: str, num_leaves: int) -> str:
+    """``"select"`` or ``"gather"``: which lowering the training-set score
+    update takes, from what the program can observe — the backend (off
+    the TPU a ``pallas_call`` is interpreted, and the gather is what
+    XLA:CPU does well) and the static length of ``leaf_value``."""
+    if backend == "tpu" and num_leaves <= SCORE_SELECT_MAX_LEAVES:
+        return "select"
+    return "gather"
+
 
 _contracts.donation_contract(
     "gbdt/score_update", lambda: _update_score_by_leaf_donated,
     SCORE_DONATE_ARGNUMS,
     lambda: (jnp.zeros((64,), jnp.float32), jnp.zeros((64,), jnp.int32),
+             jnp.zeros((8,), jnp.float32), np.float32(0.1)))
+_contracts.donation_contract(
+    "gbdt/score_update_select", lambda: _update_score_by_select_donated,
+    SCORE_DONATE_ARGNUMS,
+    lambda: (jnp.zeros((64,), jnp.float32), jnp.zeros((4096,), jnp.int32),
              jnp.zeros((8,), jnp.float32), np.float32(0.1)))
 
 
@@ -667,7 +712,8 @@ class GBDT:
             "num_data": int(self.num_data),
             "num_features": int(self.num_features),
         }, compile_since=t_init, mesh=self._mesh_record(),
-            grower=getattr(self.learner, "grower_paths", None))
+            grower=getattr(self.learner, "grower_paths", None),
+            score_update=self._choose_score_update())
         self.train_record.add_setup_seconds(
             getattr(train_set, "setup_seconds", {}))
         self.train_record.add_setup_seconds(setup_s)
@@ -828,23 +874,55 @@ class GBDT:
         return {"chips": int(mesh.size), "axis": str(mesh.axis_names[0]),
                 "rows_per_chip": -(-int(self.num_data) // int(mesh.size))}
 
+    def _row_mesh(self, rows: int):
+        """The learner's mesh where per-row arrays of ``rows`` rows live
+        on it as row shards, else None: rows that do not divide over the
+        mesh stay on one device, and so does everything in a
+        multi-process world (each process holds the full host data there
+        and reads labels and scores back, which a cross-process array
+        does not allow)."""
+        mesh = self.learner.mesh
+        if mesh is not None and self.learner.rows_sharded \
+                and jax.process_count() == 1 and rows % mesh.size == 0:
+            return mesh
+        return None
+
     def _put_rows(self, arr):
         """Host per-row array -> device.  Under a row-sharded learner
         (tree_learner=data/voting) the array is created on the learner's
         mesh, sharded by rows, so the bin matrix, scores, labels and
         masks never sit whole on device 0 to be re-scattered by every
-        grower call.  Rows that do not divide over the mesh stay on one
-        device, and so does everything in a multi-process world (each
-        process holds the full host data there and reads labels and
-        scores back, which a cross-process array does not allow); the
-        learner scatters those per call."""
-        mesh = self.learner.mesh
-        if mesh is not None and self.learner.rows_sharded \
-                and jax.process_count() == 1 \
-                and arr.shape[0] % mesh.size == 0:
+        grower call; where :meth:`_row_mesh` has none for it, the
+        learner scatters per call."""
+        mesh = self._row_mesh(arr.shape[0])
+        if mesh is not None:
             from ..parallel.mesh import shard_rows
             return shard_rows(mesh, arr, mesh.axis_names[0])
         return jnp.asarray(arr)
+
+    def _choose_score_update(self) -> str:
+        """Bind ``self._score_upd``, the jitted entry every tree's
+        training-set score update goes through (donated on the TPU), and
+        say which lowering it is
+        (``TrainRecord.snapshot()["score_update"]``)."""
+        from ..utils.backend import default_backend
+        backend = default_backend()
+        lowering = score_update_lowering(backend,
+                                         int(self.config.num_leaves))
+        mesh = self._row_mesh(self.num_data)
+        if mesh is None and self.learner.mesh is not None \
+                and self.learner.rows_sharded:
+            # score and row_leaf are not shards of one mesh: the gather,
+            # which GSPMD partitions however they lie
+            lowering = "gather"
+        if lowering == "select":
+            self._score_upd = _update_score_by_select_donated \
+                if mesh is None else \
+                _update_score_by_select_sharded(mesh, mesh.axis_names[0])
+        else:
+            self._score_upd = _update_score_by_leaf_donated \
+                if backend == "tpu" else _update_score_by_leaf
+        return lowering
 
     def _walk(self, bins, *tree_args):
         """Binned tree walk; routes through the bundle-space decode
@@ -1238,7 +1316,7 @@ class GBDT:
         # update train scores from the grower's leaf assignment
         lv = (grown.leaf_value if renewed is None
               else jnp.asarray(renewed, jnp.float32)) * shrinkage
-        upd = _score_update_entry()
+        upd = self._score_upd
         if self.num_tree_per_iteration == 1:
             self.score = upd(self.score, grown.row_leaf, lv, 1.0)
         else:

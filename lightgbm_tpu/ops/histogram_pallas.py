@@ -1950,3 +1950,101 @@ def wave_trial_channels_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
                                    row_block=row_block,
                                    interpret=interpret, pipeline=pipeline)
     return ch
+
+
+# ---------------------------------------------------------------------------
+# Score update: ``score + leaf_value[row_leaf]`` for the training rows,
+# once a tree.  As an XLA gather the lookup costs 8.2 ns a row whatever
+# the table's size (174 ms at 21.25M rows: PERF.md, PR 35); here it is a
+# streaming pass over the rows that picks each row's value out of the
+# table by compares — ``acc = where(rl == l, lv[l], acc)`` for every leaf
+# l, the table in SMEM, ``row_leaf`` and the selected value in registers
+# across the chain.  A select hands one f32 value on untouched, so the
+# result has the gather's bits.  The arrays keep their own 1-D layout (no
+# relayout in front of the kernel), the score is written where it was
+# read, and a last block that the rows do not fill is masked by the
+# pipeline: the score keeps the data set's own length, ``row_leaf`` may
+# carry the grower's padded rows behind it.
+# ---------------------------------------------------------------------------
+
+# At 21.25M rows x 255 leaves on a v5e (PERF.md, PR 35): 2.03-2.22 ms at
+# 32768-262144 rows a step, 8192-16384 rows a sweep and 32-254 leaves a
+# trip; 2.39 / 2.86 ms at 4096 / 2048 rows a sweep.
+_SU_KR = 65536    # rows per grid step
+_SU_LANES = 8192  # rows swept at a time: row_leaf and the value in 16 vregs
+_SU_GROUP = 128   # leaves per trip of the chain's loop, unrolled
+
+
+def _score_update_kernel(lv_ref, rl_ref, score_ref, out_ref, *, leaves: int,
+                         kr: int, lanes: int, group: int):
+    trips = (leaves - 1) // group
+
+    def sweep(c, carry):
+        at = pl.ds(pl.multiple_of(c * lanes, lanes), lanes)
+        rl = rl_ref[at]
+
+        def pick(first, count, acc):
+            for l in range(count):
+                acc = jnp.where(rl == first + l, lv_ref[first + l], acc)
+            return acc
+
+        # leaf 0 is the chain's start, so an id outside the table reads
+        # leaf 0 (the grower hands none out)
+        value = jnp.full(rl.shape, lv_ref[0], jnp.float32)
+        done = 1
+        if trips > 1:   # a single trip is unrolled with the remainder
+            value = jax.lax.fori_loop(
+                0, trips, lambda t, acc: pick(1 + t * group, group, acc),
+                value)
+            done += trips * group
+        value = pick(done, leaves - done, value)
+        out_ref[at] = score_ref[at] + value
+        return carry
+
+    jax.lax.fori_loop(0, kr // lanes, sweep, 0)
+
+
+def _tile_1d(n: int) -> int:
+    """XLA's tile of a 1-D 32-bit array on a TPU: 1024 elements, for a
+    shorter array the next power of two from 128 up."""
+    return min(1024, max(128, 1 << (n - 1).bit_length()))
+
+
+def score_update_pallas(score: jnp.ndarray, row_leaf: jnp.ndarray,
+                        leaf_value: jnp.ndarray, *,
+                        interpret: bool = None) -> jnp.ndarray:
+    """``score + leaf_value[row_leaf[:N]]`` with the lookup as a select
+    chain over the leaves, in place where the caller donates ``score``.
+
+    Args:
+      score: (N,) f32, any N.
+      row_leaf: (>= N,) integer leaf id of every row, in [0, L); what lies
+        behind the score's N rows is never read.
+      leaf_value: (L,) f32.
+    The cost is N x L compare-and-selects: the caller picks the gather
+    where L is large (models/gbdt.py).
+    """
+    n, = score.shape
+    leaves, = leaf_value.shape
+    if row_leaf.shape[0] < n:
+        raise ValueError(f"score_update_pallas: {row_leaf.shape[0]} leaf ids "
+                         f"for {n} scores")
+    # a block is whole tiles of every array it reads
+    tile = _tile_1d(n)
+    if _tile_1d(row_leaf.shape[0]) != tile:
+        row_leaf = row_leaf[:n]
+    lanes = min(_SU_LANES, _round_up(n, tile))
+    kr = min(_SU_KR, _round_up(n, lanes))
+    rows = pl.BlockSpec((kr,), lambda i: (i,))
+    return pl.pallas_call(
+        functools.partial(_score_update_kernel, leaves=leaves, kr=kr,
+                          lanes=lanes, group=_SU_GROUP),
+        grid=(pl.cdiv(n, kr),),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), rows, rows],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        input_output_aliases={2: 0},
+        interpret=resolve_interpret(interpret),
+        name=_kname("score_update", l=leaves, kr=kr, n=n),
+    )(leaf_value.astype(jnp.float32), row_leaf.astype(jnp.int32),
+      score.astype(jnp.float32))

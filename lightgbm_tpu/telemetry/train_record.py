@@ -336,11 +336,13 @@ class TrainRecord:
     def __init__(self, meta: Optional[Dict[str, Any]] = None,
                  compile_since: Optional[float] = None,
                  mesh: Optional[Dict[str, Any]] = None,
-                 grower: Optional[Dict[str, Any]] = None) -> None:
+                 grower: Optional[Dict[str, Any]] = None,
+                 score_update: Optional[str] = None) -> None:
         self._lock = threading.Lock()
         self.meta = dict(meta or {})
         self.mesh = dict(mesh or {})
         self.grower = dict(grower or {})
+        self.score_update = score_update
         self._t_created = time.perf_counter()
         # JAX's trace/lower/compile events count from here (perf_counter):
         # the start of the set-up the record belongs to, if it began earlier
@@ -499,7 +501,10 @@ class TrainRecord:
         static paths it was built with (learner/wave.py ``static_paths``:
         ``ramp``, ``endgame``, ``scatter``, ``voting``, ``efb``,
         ``any_cat``, ``row_update`` "kernel" | "xla", ``hist_acc_rows``),
-        {} under a learner that grows some other way."""
+        {} under a learner that grows some other way.  ``score_update``:
+        the lowering the booster's training-set score update took,
+        ``"select"`` | ``"gather"`` (models/gbdt.py
+        ``score_update_lowering``), None for a record no booster made."""
         self._flush()
         self.note_memory()  # final watermark: periodic samples miss the tail
         with self._lock:
@@ -558,6 +563,7 @@ class TrainRecord:
             "collectives": coll,
             "mesh": dict(self.mesh),
             "grower": dict(self.grower),
+            "score_update": self.score_update,
             "hist_kernel": hist_kernels,
             "compile_events": events,
             "compile_seconds": secs,

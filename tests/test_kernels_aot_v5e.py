@@ -10,6 +10,7 @@ never at import (one process at a time may load the TPU's library, and
 every xdist worker imports every test file), and these tests live in
 this ONE file so a single worker loads it."""
 
+import functools
 import os
 
 import pytest
@@ -27,10 +28,9 @@ F, N, MAX_BIN = 67, 21_250_048, 255
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     for k, v in (("TPU_LOG_DIR", "disabled"),
                  ("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
                  ("TPU_WORKER_HOSTNAMES", "localhost"),
@@ -46,9 +46,15 @@ def one_chip():
     # warn and compile again): keep these compiles out of it
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -159,3 +165,56 @@ def test_goss_sampler_compiles_at_cell_rows(S):
     # classes (1 B a row), mask and the two scaled vectors (4 B a row each)
     assert mem.output_size_in_bytes < 13.5 * rows
     assert mem.temp_size_in_bytes < 4 * rows
+
+
+# score rows, row_leaf rows (padded to the row block), small-shape probes
+@pytest.mark.parametrize("rows,leaf_rows,leaves", [
+    (21_250_000, N, 255), (45_840_617, 45_842_432, 255),
+    (21_250_000, N, 8192), (64, 4096, 255), (600, 600, 7)])
+def test_score_update_kernel_compiles_in_place(S, rows, leaf_rows, leaves):
+    """The select lowering, donated as its entry is (PR 35), at the cells'
+    own lengths, which no block divides, at the largest table it takes and
+    at the 64-row probes the drivers build: Mosaic takes the 1-D blocks,
+    the score is aliased and nothing is copied, padded or sliced around
+    the kernel."""
+    from lightgbm_tpu.models.gbdt import (SCORE_DONATE_ARGNUMS,
+                                          _score_select_impl)
+    compiled = jax.jit(
+        functools.partial(_score_select_impl, interpret=False),
+        donate_argnums=SCORE_DONATE_ARGNUMS).lower(
+        S((rows,), jnp.float32), S((leaf_rows,), jnp.int32),
+        S((leaves,), jnp.float32), S((), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert any(k.startswith(f"lgbm_score_update_l{leaves}_")
+               and k.endswith(f"_n{rows}") for k in traced_kernels())
+    assert "output_to_operand_aliasing={{}: (2, {})}" in text
+    assert " pad(" not in text and "gather" not in text
+    mem = compiled.memory_analysis()
+    # the score is an alias (sizes are whole tiles), temporaries: none
+    assert mem.temp_size_in_bytes < 4096
+    assert 4 * rows <= mem.alias_size_in_bytes < 4 * rows + 4096
+
+
+def test_score_update_over_four_chips_has_no_collective(topo):
+    """``criteo-q8-dp4.train``: 85M scores and leaf ids as row shards of
+    the 2x2 mesh; each chip runs the kernel on its 21.25M rows in place."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.models.gbdt import _update_score_by_select_sharded
+    mesh = Mesh(np.array(topo.devices), ("workers",))
+    rows, by_rows = 85_000_000, NamedSharding(mesh, P("workers"))
+    whole = NamedSharding(mesh, P())
+    compiled = _update_score_by_select_sharded(
+        mesh, "workers", interpret=False).lower(
+        jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=by_rows),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=by_rows),
+        jax.ShapeDtypeStruct((255,), jnp.float32, sharding=whole),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=whole)).compile()
+    text = compiled.as_text()
+    assert f"lgbm_score_update_l255_kr65536_n{rows // 4}" in traced_kernels()
+    assert "tpu_custom_call" in text and f"f32[{rows // 4}]" in text
+    for collective in ("all-gather", "all-reduce", "collective-permute",
+                       "all-to-all"):
+        assert collective not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4096

@@ -75,6 +75,20 @@ def test_score_update_carries_its_scope():
     assert scopes_of(text) == {"lgbm.score_update"}
 
 
+def test_score_update_kernel_carries_its_scope():
+    """The select lowering (PR 35): the kernel's operations sit in the
+    gather's scope, so a trace books them under ``score_renew_``; its name
+    is no ``lgbm_hist_`` one, which a trace would count as histogram
+    work."""
+    from lightgbm_tpu.models.gbdt import _score_select_impl
+    text = jax.jit(_score_select_impl).lower(
+        jnp.zeros((64,), jnp.float32), jnp.zeros((4096,), jnp.int32),
+        jnp.zeros((7,), jnp.float32), 0.1).compile().as_text()
+    assert scopes_of(text) == {"lgbm.score_update"}
+    assert kernel_scopes(text) == {
+        "lgbm_score_update_l7_kr128_n64": {"lgbm.score_update"}}
+
+
 def test_a_scope_outside_any_jit_names_nothing():
     """Why the eager per-tree ops (objective gradients, the learner's row
     padding, the one-time layout) carry no ``lgbm.`` name: each eager
@@ -91,7 +105,7 @@ def kernel_scopes(hlo_text: str) -> dict:
     (an interpreted kernel's operations keep the ``pallas_call``'s name)."""
     found = {}
     for op_name in re.findall(r'op_name="([^"]*)"', hlo_text):
-        kernel = re.search(r"lgbm_hist_[a-z0-9_]+", op_name)
+        kernel = re.search(r"lgbm_(hist|score_update)_[a-z0-9_]+", op_name)
         if kernel:
             named = [c for c in op_name.split("/") if c.startswith("lgbm.")]
             found.setdefault(kernel.group(0), set()).add(
