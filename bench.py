@@ -99,12 +99,6 @@ def _run(device: dict) -> None:
     extra = {}
     if int(os.environ.get("BENCH_SELFCHECK", 1)):
         extra = {f"kernel_{k}": v for k, v in kernel_selfcheck().items()}
-    # full-data histogram passes of the last tree (wave grower counter;
-    # the exact-endgame + spec-ramp target is <=7 at 255 leaves)
-    passes = getattr(booster._gbdt, "last_hist_passes", None)
-    if passes is not None and int(passes) > 0:  # 0 = non-wave grower
-        extra["hist_passes_per_tree"] = int(passes)
-
     print(json.dumps({
         "metric": f"boosting_iters_per_sec (binary, {rows}x{f}, "
                   f"{leaves} leaves, {bins} bins"

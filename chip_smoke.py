@@ -259,8 +259,7 @@ def predict_held_out(bst, X) -> np.ndarray:
 
 
 def _xla_compile_seconds(rec: dict) -> float:
-    return sum(v for k, v in rec["compile_seconds"].items()
-               if "backend_compile" in k)
+    return rec["setup_seconds"].get("compile_or_load", 0.0)
 
 
 def train_phase(name: str, train_set, holdout, params: dict, steps: int, *,

@@ -542,5 +542,7 @@ class ChunkedWaveGrower:
             # has neither a ramp nor an endgame
             wave_passes=host["hist_passes"] - 1,
             endgame_passes=np.int32(0), ramp_committed=np.int32(0),
-            hist_rows_contracted=np.zeros((1, 2), np.int32))
+            hist_rows_contracted=np.zeros((1, 2), np.int32),
+            pass_log=np.zeros((1, 0, 6), np.int32),
+            ramp_sample=np.zeros((1, 2), np.int32))
         return grown, rl_chunks

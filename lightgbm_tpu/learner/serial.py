@@ -87,6 +87,16 @@ class GrownTree(NamedTuple):
     #                                looped over by the histogram kernels of
     #                                those passes (hist_passes * N unless a
     #                                pass compacted its rows; 0 = untracked)
+    pass_log: jnp.ndarray          # (shards, P, 6) int32 — per row shard,
+    #                                one entry a counted pass in its order:
+    #                                [kind (learner/wave.py PASS_*), leaves
+    #                                built, rows looped (in the unit above),
+    #                                lanes with a channel, compaction blocks,
+    #                                blocks holding such a lane]; P = 0:
+    #                                a grower that keeps no log
+    ramp_sample: jnp.ndarray       # (shards, 2) int32 — [in-bag lanes,
+    #                                lanes] of the speculative ramp's
+    #                                subsample (0, 0 with the ramp off)
 
 
 def untracked_passes() -> dict:
@@ -94,7 +104,9 @@ def untracked_passes() -> dict:
     z = jnp.asarray(0, jnp.int32)
     return dict(hist_passes=z, wave_passes=z, endgame_passes=z,
                 ramp_committed=z,
-                hist_rows_contracted=jnp.zeros((1, 2), jnp.int32))
+                hist_rows_contracted=jnp.zeros((1, 2), jnp.int32),
+                pass_log=jnp.zeros((1, 0, 6), jnp.int32),
+                ramp_sample=jnp.zeros((1, 2), jnp.int32))
 
 
 def local_best_candidate(hist, leaf_sum, num_bins, is_cat, has_nan,

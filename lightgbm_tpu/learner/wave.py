@@ -37,7 +37,12 @@ passes where the former wave-halving taper spent 3-4, and exact where
 the taper was approximate.  Configurations outside the endgame gate keep
 the taper; quality parity is asserted by tests on held-out loss.  The
 ``hist_passes`` field of the returned GrownTree counts full-data
-histogram passes (root/mega + one per wave + one per endgame pass).
+histogram passes (root/mega + one per wave + one per endgame pass), and
+``pass_log`` says what each of them was: its kind, the leaves it built,
+the rows its kernels looped over, the lanes that carried a channel and the
+compaction's blocks, written where the pass is counted from counts the
+pass makes anyway (``log_pass``; ``TrainRecord`` rows carry it as
+``passes``).
 
 Forced splits (serial_tree_learner.cpp:450 ForceSplits) are applied as
 pre-committed waves before gain-driven growth.  EFB, monotone
@@ -58,7 +63,7 @@ import jax.numpy as jnp
 from ..analysis.contracts import collective_contract, memory_budget
 from ..models.tree import CAT_MASK, DEFAULT_LEFT_MASK, MISSING_NAN
 from ..ops.histogram import build_histogram_leaves, histogram_subtract
-from ..ops.histogram_pallas import DEFAULT_ROW_BLOCK
+from ..ops.histogram_pallas import DEFAULT_ROW_BLOCK, dense_pass_counts
 from ..ops.quantize import (dequant_limbs, dequant_scales, hist_limbs,
                             quantize_wch)
 from ..ops.split import (BIG, NEG_INF, _leaf_gain, best_split_per_feature,
@@ -68,7 +73,18 @@ from .endgame import patch_child_pointers, write_split_records
 from .serial import CommStrategy, GrownTree, local_best_candidate
 
 __all__ = ["make_wave_grow_fn", "WAVE_SIZE", "Q_WAVE_SIZE",
-           "lazy_bitmap_init", "LAZY_PACK", "wave_taper_k"]
+           "lazy_bitmap_init", "LAZY_PACK", "wave_taper_k",
+           "PASS_LOG_CAP", "PASS_FIRST", "PASS_WAVE", "PASS_ENDGAME"]
+
+# The pass log (GrownTree.pass_log): one entry a counted pass, its ``kind``
+# one of these, then ``leaves, rows, active_rows, blocks, blocks_active``.
+PASS_FIRST, PASS_WAVE, PASS_ENDGAME = 0, 1, 2
+# Entries a tree's log holds at most.  Every pass after the first commits
+# a split, but for one barren wave that ends the tree and the forced waves:
+# ``num_leaves + 1 + forced waves`` passes is the bound, and the log is
+# that long where that is under this cap.  Beyond it the later passes'
+# counts are summed into the last entry (``hist_passes`` says how many).
+PASS_LOG_CAP = 64
 
 
 def wave_taper_k(budget, W: int):
@@ -413,6 +429,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             nl_sim += 1
         if cur:
             forced_waves.append(cur)
+    PL = min(L + 1 + len(forced_waves), PASS_LOG_CAP)   # the pass log's length
     # Feature-sliced reduce-scatter histogram merge (all static): under a
     # row-sharded WaveDPStrategy with ``hist_scatter``, each wave's
     # (W, G, Bb, 3) batch is psum_scatter'd over a padded feature-block
@@ -499,8 +516,27 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
         # and the W feature ids: the kernel fetches the columns it needs
         # itself, and no (W, N) array is built between X_T and it.
         n = X_T.shape[1] * 2 if pack4 else X_T.shape[1]
-        # what ``hist_rows`` counts in (exact in int32 where rows are not)
+        # what the pass log counts looped rows in (exact in int32 where a
+        # tree's rows are not)
         row_unit = math.gcd(n, DEFAULT_ROW_BLOCK)
+
+        def log_pass(s, kind, leaves, counts):
+            """``s["pass_log"]`` with the pass that ``s["hist_passes"]``
+            is about to count written at that index: its ``kind``, the
+            ``leaves`` whose histograms it built and ``hist_waves``'
+            ``counts``.  Past the log's length the counts are summed
+            into the last entry, which keeps the newest kind."""
+            i = jnp.minimum(s["hist_passes"], PL - 1)
+            entry = jnp.concatenate(
+                [jnp.reshape(leaves, (1,)).astype(jnp.int32), counts])
+            return s["pass_log"].at[i, 1:].add(entry).at[i, 0].set(kind)
+
+        def first_pass(leaves, counts):
+            """The two counters of a state whose first pass is done."""
+            zero = {"hist_passes": jnp.asarray(0, jnp.int32),
+                    "pass_log": jnp.zeros((PL, 6), jnp.int32)}
+            return {"hist_passes": jnp.asarray(1, jnp.int32),
+                    "pass_log": log_pass(zero, PASS_FIRST, leaves, counts)}
 
         def router_bins(mat):
             """What the fused row-update kernel reads ``mat``'s columns
@@ -691,8 +727,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             return hmg, _dqh(hmg[:, 0].sum(axis=1))
 
         def leaf_hists(bins, w, ch, bag, sparse):
-            """``(histograms, rows looped over)`` of one call of the
-            Pallas leaf kernel on ``bins`` / ``w`` (the tree's or the
+            """``(histograms, counts)`` of one call of the Pallas leaf
+            kernel on ``bins`` / ``w`` (``counts``: ``hist_waves``; the tree's or the
             ramp's subsample's).  THE place where a grower built for a
             booster that samples rows leaves the out-of-bag ones out:
             their weight levels are all 0, so with channel -1 and the
@@ -705,7 +741,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             h = build(bins, w, ch, num_bins=Bb, interpret=interpret,
                       pipeline=pipeline, bins_packed=pack4, compact=sparse,
                       **({"acc_rows": hist_acc_rows} if wide else {}))
-            return h if sparse else (h, w.shape[1])
+            return h if sparse else (h, dense_pass_counts(w.shape[1]))
 
         def hist_waves(ch, k=W, with_totals=False, sparse=False):
             """(k, G_loc, Bb, 3) histograms of the wave's leaf channels,
@@ -725,13 +761,16 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             ramp and the root pass put every row in a channel: they keep
             the direct call, unless the grower is built for a booster
             that samples rows: there every pass is sparse
-            (``leaf_hists``).  Returns ``(histograms, rows)``, ``rows``
-            the rows this shard's kernel looped over, in ``row_unit``s."""
-            rows = n // row_unit
+            (``leaf_hists``).  Returns ``(histograms, counts)``, ``counts``
+            this shard's ``[rows, active_rows, blocks, blocks_active]``
+            (ops/histogram_pallas.py ``dense_pass_counts``): the rows the
+            kernel looped over, in ``row_unit``s, the lanes that carry a
+            channel, the compaction blocks and those that hold such a
+            lane.  A pass that puts every row in a channel says so
+            itself; no pass over ``ch`` is added for the log."""
             if pallas:
-                h, rows = leaf_hists(X_T, wch0 if quantized else w8, ch,
-                                     in_bag, sparse)
-                rows = rows // row_unit
+                h, counts = leaf_hists(X_T, wch0 if quantized else w8, ch,
+                                       in_bag, sparse)
             elif quantized:
                 # off-TPU emulation: f32 sums of integer levels are
                 # exact while |sum| < 2^24 per bin — ample for the
@@ -749,7 +788,10 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 h = build_histogram_leaves(
                     bins_rows, gm, hm, cnt_mask, ch,
                     num_channels=W, num_bins=Bb, impl=hist_impl)
-            return _reduce_waves(h, k, with_totals), rows
+            if not pallas:
+                counts = dense_pass_counts(n, ch if sparse else None)
+            return (_reduce_waves(h, k, with_totals),
+                    counts.at[0].set(counts[0] // row_unit))
 
         def feature_col(feat):
             """FEATURE-space bin codes (N,) of one feature (decoded from
@@ -1069,7 +1111,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 rl_full = rlf.astype(jnp.uint8)
 
             # -- ONE full-data pass: exact per-prov-leaf channel sums --
-            (h_ch, leaf_tot), rows_v = hist_waves(
+            (h_ch, leaf_tot), counts_v = hist_waves(
                 rl_full.astype(jnp.int8), k=Kc, with_totals=True)  # (Kc, 3)
             # voting: keep the batch RAW and shard-local — the node-sum
             # einsum is exact in int32 and _voting_candidates merges
@@ -1194,16 +1236,18 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 "num_leaves": nl_run,
                 "done": jnp.asarray(False),
                 # full-data histogram passes so far: the one verification
-                # mega-pass (the ~log2(W) provisional passes run at
-                # subsample scale and are not counted)
-                "hist_passes": jnp.asarray(1, jnp.int32),
-                "hist_rows": jnp.asarray(rows_v, jnp.int32),
-            }
+                # mega-pass over the provisional leaves (the ~log2(W)
+                # provisional passes run at subsample scale and are not
+                # counted)
+                **first_pass(nlp, counts_v),
+            }, jnp.stack([jnp.sum(in_bag_ss, dtype=jnp.int32) if sampled
+                          else jnp.int32(n_ss), jnp.int32(n_ss)])
 
         if use_spec:
             with jax.named_scope("lgbm.ramp"):
-                state = _spec_state()
+                state, ramp_sample = _spec_state()
         else:
+            ramp_sample = jnp.zeros((2,), jnp.int32)
             # ---- root ----
             with jax.named_scope("lgbm.root"):
                 if quantized:
@@ -1213,12 +1257,12 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     # for every feature, shard and merge mode) so candidate
                     # left+right sums stay consistent with the totals
                     # downstream
-                    (rh, rtot), rows_r = hist_waves(
+                    (rh, rtot), counts_r = hist_waves(
                         jnp.zeros((n,), jnp.int8), k=1, with_totals=True)
                     root_hist = rh[0]
                     root_sum = rtot[0]
                 else:
-                    rh, rows_r = hist_waves(jnp.zeros((n,), jnp.int8), k=1)
+                    rh, counts_r = hist_waves(jnp.zeros((n,), jnp.int8), k=1)
                     root_hist = rh[0]
                     root_sum = strat.reduce_sum(jnp.stack([
                         jnp.sum(gm), jnp.sum(hm), jnp.sum(cnt_mask)]))
@@ -1304,8 +1348,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                     "leaf_count": jnp.zeros((L,), jnp.float32).at[0].set(root_sum[2]),
                     "num_leaves": jnp.asarray(1, jnp.int32),
                     "done": jnp.asarray(False),
-                    "hist_passes": jnp.asarray(1, jnp.int32),  # the root pass
-                    "hist_rows": jnp.asarray(rows_r, jnp.int32),
+                    **first_pass(1, counts_r),                 # the root pass
                 }
                 if use_mc:
                     state["leaf_mn"] = jnp.full((L,), -BIG, jnp.float32)
@@ -1508,7 +1551,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
 
             # ---- one kernel pass: all W smaller-child histograms ----
             with jax.named_scope("lgbm.wave.hist"):
-                hist_small, rows = hist_waves(ch, sparse=True)  # (W, G, Bb, 3)
+                hist_small, counts = hist_waves(ch, sparse=True)  # (W, G, Bb, 3)
                 parents = s["hists"][sel_leaves]
                 hist_big = parents - hist_small
                 ls4 = left_smaller[:, None, None, None]
@@ -1781,8 +1824,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
 
                 out["num_leaves"] = nl0 + total_new
                 out["done"] = total_new == 0
+                out["pass_log"] = log_pass(s, PASS_WAVE, total_new, counts)
                 out["hist_passes"] = s["hist_passes"] + 1
-                out["hist_rows"] = s["hist_rows"] + rows
             return out
 
         if use_endgame:
@@ -1983,7 +2026,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                                          fnanb, dleft, small)
                 with jax.named_scope("lgbm.endgame.hist"):
                     # (W, G, Bb, 3); DP: one psum
-                    bank, rows = hist_waves(ch, sparse=True)
+                    bank, counts = hist_waves(ch, sparse=True)
                 slot = jnp.full((L,), -1, jnp.int32).at[
                     jnp.where(sel, sel_leaves, L)].set(
                         jnp.arange(W, dtype=jnp.int32), mode="drop")
@@ -1992,8 +2035,9 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                         _commit_cond, _make_commit(bank),
                         (s, slot, pend, pcnt))
                 s = dict(s)
+                s["pass_log"] = log_pass(
+                    s, PASS_ENDGAME, jnp.sum(sel, dtype=jnp.int32), counts)
                 s["hist_passes"] = s["hist_passes"] + 1
-                s["hist_rows"] = s["hist_rows"] + rows
                 return (s, pend, pcnt)
 
         def cond(s):
@@ -2086,8 +2130,11 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             hist_passes=s["hist_passes"], wave_passes=wave_passes,
             endgame_passes=s["hist_passes"] - 1 - wave_passes,
             ramp_committed=ramp_committed,
+            # the rows the kernels looped over are the log's, summed
             hist_rows_contracted=jnp.stack(
-                [s["hist_rows"], jnp.asarray(row_unit, jnp.int32)])[None])
+                [s["pass_log"][:, 2].sum(),
+                 jnp.asarray(row_unit, jnp.int32)])[None],
+            pass_log=s["pass_log"][None], ramp_sample=ramp_sample[None])
         if use_lazy:
             return tree_out, s["used"]
         return tree_out
@@ -2100,6 +2147,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
         "ramp": bool(use_spec), "endgame": bool(use_endgame),
         "scatter": bool(use_scatter), "voting": bool(use_voting),
         "efb": bool(use_efb), "any_cat": bool(any_cat),
+        "sampled": bool(sampled and pallas),
         "row_update": "kernel" if pallas and small_bins else "xla",
         "hist_acc_rows": int(hist_acc_rows) if wide else 0}
     return fn

@@ -1093,22 +1093,29 @@ class GBDT:
             grad = _coerce(grad)
             hess = _coerce(hess)
 
-        # Lagged no-split stop for the deferred-tree path: the previous
-        # iteration's tree sizes are device-computed by now, so this host
-        # pull is a bare RTT and doesn't stall the dispatch pipeline.
+        # Lagged no-split stop for the deferred-tree path: the one place
+        # a boosting iteration waits for the device.  The pull returns
+        # when the PREVIOUS iteration's tree sizes are computed, i.e. when
+        # its grower is done (its score update may still run), so the host
+        # stays a tree ahead; the clock read behind it is that tree's
+        # completion stamp, ``wait_prev`` the host's wait for the device.
         # When the previous iteration grew only stumps, pop them (the
         # reference pops non-splitting trees, gbdt.cpp:430-450) and stop.
         prev = getattr(self, "_prev_iter_leaves", None)
-        if prev is not None and \
-                all(int(x) <= 1 for x in jax.device_get(prev)):
-            self._prev_iter_leaves = None
-            self._pop_stump_iteration()
-            log_warning("Stopped training because there are no more "
-                        "leaves that meet the split requirements")
-            return True
+        if prev is not None:
+            with rec.phase("wait_prev"):
+                prev = jax.device_get(prev)
+            rec.tree_done(self.iter_ - 1)
+            if all(int(x) <= 1 for x in prev):
+                self._prev_iter_leaves = None
+                self._pop_stump_iteration()
+                log_warning("Stopped training because there are no more "
+                            "leaves that meet the split requirements")
+                return True
 
         finished = True
-        fl_leaves = fl_gain = None  # flight-event fields (last class)
+        # flight-event fields (last class)
+        fl_passes = fl_leaves = fl_gain = None
         fmask = self._feature_mask()
         with rec.phase("sample"):
             # sampled_rows is fetched with the tree's other counters
@@ -1154,20 +1161,18 @@ class GBDT:
                 except Exception as exc:
                     _name_refused_kernels(exc)
                     raise
-            # full-data histogram passes of the last grown tree (wave
-            # grower; 0 = untracked) — a device scalar, pulled lazily
-            # by bench/diagnostic readers only
-            self.last_hist_passes = grown.hist_passes
             rec.add_tree(self.iter_, cid, grown.hist_passes,
                          grown.num_leaves, grown.wave_passes,
                          grown.endgame_passes, grown.ramp_committed,
                          grown.hist_rows_contracted, sampled_rows,
-                         grown.decision_type)
+                         grown.decision_type, grown.pass_log,
+                         grown.ramp_sample)
             if self.flight.enabled:
                 # last grown tree's fields for this iteration's
                 # flight event (device scalars, pulled lazily on
                 # dump; the max over split gains is one tiny
                 # device reduce)
+                fl_passes = grown.hist_passes
                 fl_leaves = grown.num_leaves
                 fl_gain = jnp.max(grown.split_gain)
             with rec.phase("record"):
@@ -1190,10 +1195,11 @@ class GBDT:
             # in their kernels)
             if hasattr(x, "copy_to_host_async"):
                 x.copy_to_host_async()
+        rec.end_of_iter(self.iter_)
         self.iter_ += 1
         if self.flight.enabled:
             self.flight.note_iter(
-                self.iter_, hist_passes=self.last_hist_passes,
+                self.iter_, hist_passes=fl_passes,
                 num_leaves=fl_leaves, best_gain=fl_gain)
         if self.iter_ % 16 == 1:
             # periodic device-memory watermark sample (cheap local
@@ -1295,12 +1301,15 @@ class GBDT:
             # keep only what _grown_to_tree reads: dropping row_leaf
             # releases the (N,) per-tree assignment (42 MB/tree at Higgs
             # scale) instead of holding it in HBM until flush and hauling
-            # it through the device->host pull (the two row-sharded
-            # fields: in a multi-process world no process can pull them)
+            # it through the device->host pull (the row-sharded fields:
+            # in a multi-process world no process can pull them; the
+            # record has taken the counters)
             self._pending.append(
                 (grown._replace(
                     row_leaf=jnp.zeros((0,), jnp.int32),
-                    hist_rows_contracted=np.zeros((0, 2), np.int32)),
+                    hist_rows_contracted=np.zeros((0, 2), np.int32),
+                    pass_log=np.zeros((0, 0, 6), np.int32),
+                    ramp_sample=np.zeros((0, 2), np.int32)),
                  shrinkage, bias))
             tree = None
         else:

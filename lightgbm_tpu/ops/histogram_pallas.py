@@ -68,7 +68,7 @@ from .quantize import hist_limbs
 __all__ = ["build_histogram_pallas", "build_histogram_pallas_leaves",
            "build_histogram_pallas_leaves_q8", "pack_weights8",
            "wave_trial_channels_pallas", "wave_row_update_pallas",
-           "bin_rows_view", "gather_bin_rows",
+           "bin_rows_view", "gather_bin_rows", "dense_pass_counts",
            "DEFAULT_ROW_BLOCK", "pad_rows", "LEAF_CHANNELS",
            "Q_LEAF_CHANNELS", "resolve_pipeline",
            "resolve_interpret", "pack_bins4", "unpack_bins4",
@@ -931,6 +931,9 @@ def _compact_plan(ch, *, kb: int, kr: int, interpret: bool):
       arrays, in lanes (a multiple of 128); the last entry is the total.
     ``steps`` (1,) int32: the ``kr``-lane blocks that hold the total (at
       least one: the leaf kernel's pipeline always fetches block 0).
+    ``counts`` (3,) int32: the active lanes, the ``kb``-lane blocks and
+      how many of those hold an active lane (the pass log's
+      ``active_rows``, ``blocks``, ``blocks_active``: learner/wave.py).
 
     The counts and offsets are XLA work on N / sub integers; ``code``,
     which needs each lane's place among its sub-block's active lanes, is
@@ -955,11 +958,19 @@ def _compact_plan(ch, *, kb: int, kr: int, interpret: bool):
     )(ch2, (start % 128).reshape(n // sub, 1))
     wt = jnp.concatenate([(start // 128).reshape(-1),
                           jnp.zeros((1,), jnp.int32)])
-    padded = _round_up(jnp.sum(cnt, axis=1), 128)
+    held = jnp.sum(cnt, axis=1)                    # active lanes a block
+    padded = _round_up(held, 128)
     off = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                            jnp.cumsum(padded)])
     steps = jnp.maximum(1, -(-off[-1:] // kr))
-    return code.reshape(1, n), wt, off, steps
+    # The log's counts read the blocks' totals as a value of their own:
+    # without the barrier XLA folds each of the two sums back into a
+    # reduction over all of ``ch``, 0.25 ms each a pass at 45.8M rows
+    # (PERF.md section 6, PR 36).
+    held = jax.lax.optimization_barrier(held)
+    counts = jnp.stack([jnp.sum(held), jnp.int32(n // kb),
+                        jnp.sum(held > 0, dtype=jnp.int32)])
+    return code.reshape(1, n), wt, off, steps, counts
 
 
 def _compact_kernel(wt_ref, off_ref, bins_hbm, w_hbm, code_hbm, bins_out,
@@ -1131,15 +1142,15 @@ def _compact_rows_dma(bins_t, w, ch2, *, fc: int, kr: int, interpret: bool):
     """Move the lanes with ``ch2 >= 0`` of ``bins_t`` (f_pad, N) uint8
     (its first ``fc`` rows: the contracted ones), ``w`` (8, N) and ``ch2``
     (1, N) int32 to the front, block by block and in their order.
-    Returns ``(bins, w, ch, steps)``: the arrays with some lanes more
-    than N, of which the first ``steps[0] * kr`` hold every active lane
-    and else padding (ch -1, zero weights), and ``steps`` (1,) int32 for
-    the leaf kernel's scalar prefetch."""
+    Returns ``(bins, w, ch, steps, counts)``: the arrays with some lanes
+    more than N, of which the first ``steps[0] * kr`` hold every active
+    lane and else padding (ch -1, zero weights), ``steps`` (1,) int32 for
+    the leaf kernel's scalar prefetch, and the plan's ``counts``."""
     f_pad, n = bins_t.shape
     kb = _compact_block(n, f_pad)
     npad = -(-kr // kb)
-    code, wt, off, steps = _compact_plan(ch2[0], kb=kb, kr=kr,
-                                         interpret=interpret)
+    code, wt, off, steps, counts = _compact_plan(ch2[0], kb=kb, kr=kr,
+                                                 interpret=interpret)
     any_ = pl.BlockSpec(memory_space=pl.ANY)
     lanes = n + npad * kb
     outs = pl.pallas_call(
@@ -1155,7 +1166,20 @@ def _compact_rows_dma(bins_t, w, ch2, *, fc: int, kr: int, interpret: bool):
         name=_kname("hist_compact_dma", f=f_pad, fc=fc, s=_CP_SUB, kb=kb,
                     n=n),
     )(wt, off, bins_t, w, code)
-    return (*outs, steps)
+    return (*outs, steps, counts)
+
+
+def dense_pass_counts(n: int, ch=None):
+    """``[rows, active_rows, blocks, blocks_active]`` (4,) int32 of a pass
+    that loops over all ``n`` lanes: every block counted as one that
+    holds work (blocks of the lanes :func:`_compact_block` gives narrow
+    bins), the active lanes those with ``ch >= 0`` (all of them where the
+    caller knows so and hands no ``ch``).  What a compacted pass gives
+    in :func:`_leaves_dma_call`'s ``rows``."""
+    blocks = -(-n // (_CP_KB if n % _CP_SUB else math.gcd(n, _CP_KB)))
+    active = n if ch is None else jnp.sum(ch >= 0)
+    return jnp.stack([jnp.asarray(v, jnp.int32)
+                      for v in (n, active, blocks, blocks)])
 
 
 # stacked one-hot M dim (group * b) cap of the two DMA leaf kernels
@@ -1182,7 +1206,8 @@ def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
                      out_dtype, row_block, compact=False, acc_rows=0):
     """Shared wrapper plumbing of the two DMA leaf-kernel builders.
     Returns ``(out, f_pad, rows)``: ``rows`` is how many rows the kernel
-    looped over, the static N or (``compact``) a device scalar.  With
+    looped over, the static N, or (``compact``) the device vector
+    ``[rows, active_rows, blocks, blocks_active]`` (4,) int32.  With
     ``acc_rows`` the pass is accumulated ``acc_rows`` rows a call and
     ``out`` is the list of the calls' outputs (ops/quantize.py)."""
     f = bins_t.shape[0]
@@ -1202,10 +1227,10 @@ def _leaves_dma_call(bins_t, w, ch2, *, kind, num_bins, interpret, packed,
         out_specs=pl.BlockSpec((ft * b, 128), lambda i, *_: (i, 0),
                                memory_space=pltpu.VMEM))
     if compact:
-        bins_t, w, ch2, steps = _compact_rows_dma(
+        bins_t, w, ch2, steps, counts = _compact_rows_dma(
             bins_t, w, ch2, fc=fc, kr=kr, interpret=interpret)
         n = bins_t.shape[1]
-        rows = steps[0] * kr
+        rows = jnp.concatenate([steps * kr, counts])
     else:
         rows = n
 
@@ -1299,11 +1324,13 @@ def build_histogram_pallas_leaves(bins_t: jnp.ndarray, w8: jnp.ndarray,
         endgame's smaller children; any pass of a sampled tree, whose
         out-of-bag rows carry -1).  The ``dma`` pipeline then moves the
         active rows to the front (:func:`_compact_rows_dma`) and contracts
-        the row blocks that hold them; the result is ``(hist, rows)``
-        with ``rows`` the rows the kernel looped over, a device scalar
-        (N itself under ``blockspec`` and nibble-packed bins, which keep
-        the dense form).  f32 sums may differ from the dense pass's in
-        the last bit: the same products meet in other row blocks.
+        the row blocks that hold them; the result is ``(hist, counts)``
+        with ``counts`` (4,) int32: the rows the kernel looped over, the
+        active lanes, the compaction blocks and those of them that hold
+        an active lane (under ``blockspec`` and nibble-packed bins, which
+        keep the dense form: :func:`dense_pass_counts`).  f32 sums may
+        differ from the dense pass's in the last bit: the same products
+        meet in other row blocks.
     """
     f, np_ = bins_t.shape
     n = np_ * 2 if bins_packed else np_
@@ -1323,16 +1350,18 @@ def build_histogram_pallas_leaves(bins_t: jnp.ndarray, w8: jnp.ndarray,
                  + ("/packed4" if bins_packed else ""),
                  f * np_ * bins_t.dtype.itemsize + n * (_C * 2 + 4) +
                  LEAF_CHANNELS * f * num_bins * 3 * 4, *rows)
+    compacts = compact and pipeline == "dma" and not bins_packed
     if pipeline == "dma":
         hist, rows = _build_histogram_pallas_leaves_dma(
             bins_t, w8, ch, num_bins=num_bins, row_block=row_block,
-            interpret=interpret, packed=bins_packed,
-            compact=compact and not bins_packed)
+            interpret=interpret, packed=bins_packed, compact=compacts)
     else:
-        hist, rows = _build_histogram_pallas_leaves_bs(
+        hist = _build_histogram_pallas_leaves_bs(
             bins_t, w8, ch, num_bins=num_bins, row_block=row_block,
-            interpret=interpret), n
-    return (hist, rows) if compact else hist
+            interpret=interpret)
+    if not compact:
+        return hist
+    return hist, (rows if compacts else dense_pass_counts(n, ch))
 
 
 # ---------------------------------------------------------------------------
@@ -1508,7 +1537,7 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
       num_bins: static global bin count B (<= 256).
       interpret / pipeline / bins_packed: as :func:`build_histogram_pallas`.
       compact: as :func:`build_histogram_pallas_leaves`; the result is
-        then ``(hist, rows)``.
+        then ``(hist, counts)``.
       acc_rows: 0, or the most rows that may be added into one int32
         (ops/quantize.py ``hist_acc_rows``, a multiple of ``row_block``):
         the pass is then summed in segments of that many rows (``dma``:
@@ -1538,24 +1567,27 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
                  + ("/packed4" if bins_packed else ""),
                  f * np_ * bins_t.dtype.itemsize + n * 9 +
                  Q_LEAF_CHANNELS * f * num_bins * 3 * 4, *rows)
+    compacts = compact and pipeline == "dma" and not bins_packed
     if pipeline == "dma":
         hist, rows = _build_histogram_pallas_leaves_q8_dma(
             bins_t, wch, ch, num_bins=num_bins, row_block=row_block,
             interpret=interpret, packed=bins_packed,
-            compact=compact and not bins_packed, acc_rows=acc_rows)
+            compact=compacts, acc_rows=acc_rows)
     elif acc_rows:
         acc_rows = max(row_block, acc_rows // row_block * row_block)
-        hist, rows = hist_limbs([
+        hist = hist_limbs([
             _build_histogram_pallas_leaves_q8_bs(
                 bins_t[:, lo:lo + acc_rows], wch[:, lo:lo + acc_rows],
                 ch[lo:lo + acc_rows], num_bins=num_bins,
                 row_block=row_block, interpret=interpret)
-            for lo in range(0, n, acc_rows)]), n
+            for lo in range(0, n, acc_rows)])
     else:
-        hist, rows = _build_histogram_pallas_leaves_q8_bs(
+        hist = _build_histogram_pallas_leaves_q8_bs(
             bins_t, wch, ch, num_bins=num_bins, row_block=row_block,
-            interpret=interpret), n
-    return (hist, rows) if compact else hist
+            interpret=interpret)
+    if not compact:
+        return hist
+    return hist, (rows if compacts else dense_pass_counts(n, ch))
 
 
 # ---------------------------------------------------------------------------

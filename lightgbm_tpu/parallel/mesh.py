@@ -41,8 +41,9 @@ def replicate(mesh: Mesh, arr):
 def tree_specs(axis) -> GrownTree:
     """``shard_map`` out_specs of a grown tree: every field replicated
     but ``row_leaf``, which stays with its rows on ``axis`` (``None``
-    where the rows are not sharded), and ``hist_rows_contracted``, one
-    row a shard."""
+    where the rows are not sharded), and the three per-shard counters
+    (``hist_rows_contracted``, ``pass_log``, ``ramp_sample``), one row a
+    shard."""
     return GrownTree(
         split_feature=P(), threshold_bin=P(), nan_bin=P(),
         cat_member=P(), decision_type=P(), left_child=P(),
@@ -51,7 +52,8 @@ def tree_specs(axis) -> GrownTree:
         leaf_weight=P(), leaf_count=P(), num_leaves=P(),
         row_leaf=P(axis), hist_passes=P(), wave_passes=P(),
         endgame_passes=P(), ramp_committed=P(),
-        hist_rows_contracted=P(axis))
+        hist_rows_contracted=P(axis), pass_log=P(axis),
+        ramp_sample=P(axis))
 
 
 def shard_wave_grower(grow, mesh, axis: str, *, n_keys: int = 0,

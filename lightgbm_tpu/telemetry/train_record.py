@@ -18,9 +18,17 @@ boosting loop runs:
     holds), the rows in the tree's bag (``sampled_rows``: N where
     nothing samples, else the bagging mask's or the GOSS draw's count),
     how many of the tree's splits are categorical (``cat_splits``, read
-    off the node records the grower returns) and leaf counts —
+    off the node records the grower returns), leaf counts, and the
+    wave grower's own log of those passes (``passes``: each one's kind,
+    leaves built, rows looped, active rows, compaction blocks and blocks
+    that held an active row; ``ramp_sample_rows`` / ``ramp_sample_lanes``:
+    the in-bag lanes of the speculative ramp's subsample) —
     kept as device scalars and pulled in batched, lazy fetches so the
     async dispatch pipeline never stalls;
+  * the tree clock: when each tree's ``num_leaves`` reached the host
+    (``done_s``, stamped after the one wait a boosting iteration has, the
+    lagged stump check ``wait_prev``), what the iteration spent in that
+    wait (``wait_s``) and outside it (``dispatch_s``); nothing is forced;
   * collective count and reduced bytes, tallied at the
     ``parallel/*.py`` collective call sites.  Those sites execute at
     TRACE time (the growers are jit/shard_map programs), so the tally
@@ -172,7 +180,6 @@ def hist_kernel_reset() -> None:
 
 _mon_lock = threading.Lock()
 _mon_counts: Dict[str, int] = {}
-_mon_secs: Dict[str, float] = {}
 _mon_registered = False
 
 
@@ -205,7 +212,6 @@ def _on_event_duration(event: str, duration: float, **kwargs) -> None:
     end = time.perf_counter()
     with _mon_lock:
         _mon_counts[event] = _mon_counts.get(event, 0) + 1
-        _mon_secs[event] = _mon_secs.get(event, 0.0) + float(duration)
         kind = _SETUP_KIND_OF_EVENT.get(event)
         if kind is not None:
             _mon_intervals.append((kind, end - float(duration), end))
@@ -257,7 +263,7 @@ def _ensure_monitoring() -> None:
 
 def _monitoring_snapshot():
     with _mon_lock:
-        return dict(_mon_counts), dict(_mon_secs)
+        return dict(_mon_counts)
 
 
 _COMPILE_MARKERS = ("compil", "trace", "jit")
@@ -323,6 +329,34 @@ class _NoopPhase:
 
 _NOOP_PHASE = _NoopPhase()
 
+# the phases of a boosting iteration that enqueue its trees: a tree row's
+# ``dispatch_s`` is their host seconds (``wait_prev`` is its ``wait_s``)
+_DISPATCH_PHASES = ("gradients", "sample", "grow", "record")
+
+_PASS_KEYS = ("kind", "leaves", "rows", "active_rows", "blocks",
+              "blocks_active")
+
+
+def _passes(log, rows_units, hist_passes: int) -> List[Dict[str, int]]:
+    """A tree row's ``passes`` from the grower's log ``(shards, P, 6)``
+    (learner/serial.py ``GrownTree.pass_log``): one dict a counted pass,
+    ``kind`` and ``leaves`` as every shard states them, the four counts
+    summed over the row shards, ``rows`` in rows (the log counts them in
+    the unit ``hist_rows_contracted`` carries).  A tree with more passes
+    than its log holds has the later ones' counts in the last entry."""
+    import numpy as np
+    log = np.asarray(log, np.int64)
+    if not log.size:
+        return []
+    log = log.reshape((-1,) + log.shape[-2:])[:, :hist_passes]
+    unit = np.reshape(rows_units, (-1, 2))[:, 1].astype(np.int64)
+    counts = log[:, :, 2:].copy()
+    counts[:, :, 0] *= unit[:, None]
+    counts = counts.sum(axis=0)          # over the row shards
+    return [dict(zip(_PASS_KEYS, map(int, (*log[0, i, :2], *counts[i]))))
+            for i in range(log.shape[1])]
+
+
 _FLUSH_EVERY = 256  # pending device scalars pulled per batched fetch
 
 
@@ -353,15 +387,19 @@ class TrainRecord:
         self._phase_n: Dict[str, int] = {}
         # per-tree device scalars pending a batched host pull
         # (iteration, class_id, (hp, nl, wave, endgame, ramp_committed,
-        #  hist_rows_contracted, sampled_rows, decision_type))
+        #  hist_rows_contracted, sampled_rows, decision_type, pass_log,
+        #  ramp_sample))
         self._pending: List[tuple] = []
-        self._trees: List[Dict[str, int]] = []
+        self._trees: List[Dict[str, Any]] = []
+        # the tree clock: iteration -> {"done_s", "wait_s", "dispatch_s"}
+        self._iter_clock: Dict[int, Dict[str, Optional[float]]] = {}
+        self._clock_seen = (0.0, 0.0)   # (wait, dispatch) seconds booked
         self._setup_s: Dict[str, float] = {}
         self._mem_peak: Optional[int] = None
         self._coll_base = collectives_snapshot()
         self._hist_base = hist_kernel_snapshot()
         _ensure_monitoring()
-        self._mon_base, self._mon_secs_base = _monitoring_snapshot()
+        self._mon_base = _monitoring_snapshot()
 
     # -- accumulation (boosting loop) ------------------------------------
     def phase(self, name: str):
@@ -396,10 +434,36 @@ class TrainRecord:
             for k, v in seconds.items():
                 self._setup_s[k] = self._setup_s.get(k, 0.0) + float(v)
 
+    def tree_done(self, iteration: int) -> None:
+        """Iteration ``iteration``'s ``num_leaves`` has just reached the
+        host (the boosting loop's lagged stump check returned): its
+        ``done_s``, the one clock read a tree costs."""
+        if not _config.enabled():
+            return
+        done = time.perf_counter() - self._t_created
+        with self._lock:
+            self._iter_clock.setdefault(iteration, {})["done_s"] = done
+
+    def end_of_iter(self, iteration: int) -> None:
+        """Iteration ``iteration`` has enqueued its trees: its ``wait_s``
+        and ``dispatch_s`` are what ``phase_seconds`` gained since the
+        iteration before (``wait_prev``; gradients + sample + grow +
+        record).  No clock is read."""
+        if not _config.enabled():
+            return
+        with self._lock:
+            now = (self._phase_s.get("wait_prev", 0.0),
+                   sum(self._phase_s.get(k, 0.0) for k in _DISPATCH_PHASES))
+            self._iter_clock.setdefault(iteration, {}).update(
+                wait_s=now[0] - self._clock_seen[0],
+                dispatch_s=now[1] - self._clock_seen[1])
+            self._clock_seen = now
+
     def add_tree(self, iteration: int, class_id: int, hist_passes,
                  num_leaves, wave_passes=0, endgame_passes=0,
                  ramp_committed=0, hist_rows_contracted=((0, 0),),
-                 sampled_rows=0, decision_type=()) -> None:
+                 sampled_rows=0, decision_type=(),
+                 pass_log=((),), ramp_sample=((0, 0),)) -> None:
         """Record one grown tree.  The counts may be device scalars; they
         are NOT synced here — batches are pulled lazily so the async
         dispatch pipeline keeps flowing."""
@@ -407,8 +471,9 @@ class TrainRecord:
             return
         if not getattr(hist_rows_contracted, "is_fully_addressable", True):
             # a multi-process world: the row shards this process holds
-            hist_rows_contracted = [
-                s.data for s in hist_rows_contracted.addressable_shards]
+            hist_rows_contracted, pass_log, ramp_sample = (
+                [s.data for s in a.addressable_shards]
+                for a in (hist_rows_contracted, pass_log, ramp_sample))
         if not getattr(sampled_rows, "is_fully_addressable", True):
             # a count over rows that span processes: this process's copy
             sampled_rows = sampled_rows.addressable_shards[0].data
@@ -419,7 +484,7 @@ class TrainRecord:
                                   (hist_passes, num_leaves, wave_passes,
                                    endgame_passes, ramp_committed,
                                    hist_rows_contracted, sampled_rows,
-                                   decision_type)))
+                                   decision_type, pass_log, ramp_sample)))
             flush = len(self._pending) >= _FLUSH_EVERY
         if flush:
             self._flush()
@@ -455,8 +520,12 @@ class TrainRecord:
                  # node's decision_type says categorical), come with the
                  # same fetch
                  "cat_splits": int(np.count_nonzero(
-                     np.asarray(dt, np.int64)[:max(int(nl) - 1, 0)] & 1))}
-                for (it, cid, _), (hp, nl, wp, ep, rc, rows, sr, dt)
+                     np.asarray(dt, np.int64)[:max(int(nl) - 1, 0)] & 1)),
+                 "passes": _passes(log, rows, int(hp)),
+                 # (shards, 2) [in-bag lanes, lanes], over the shards
+                 "ramp_sample_rows": int(np.reshape(ramp, (-1, 2))[:, 0].sum()),
+                 "ramp_sample_lanes": int(np.reshape(ramp, (-1, 2))[:, 1].sum())}
+                for (it, cid, _), (hp, nl, wp, ep, rc, rows, sr, dt, log, ramp)
                 in zip(pending, vals)]
         with self._lock:
             self._trees.extend(rows)
@@ -466,6 +535,34 @@ class TrainRecord:
         """JSON-ready record; pulls any pending device scalars (one
         batched fetch) and diffs the process-wide compile/collective
         tallies against this record's baseline.
+
+        ``trees``: one row a tree.  Beside the counters the module's
+        docstring lists, ``passes`` is the wave grower's log of the tree's
+        counted passes in their order (``len == hist_passes``), each
+        ``{"kind": 0 the first pass (the root's, or the ramp's verifying
+        one) | 1 a wave | 2 an endgame pass, "leaves": leaves whose
+        histograms it built, "rows": rows the leaf kernels looped over
+        (they sum to ``hist_rows_contracted``), "active_rows": lanes that
+        carried a channel, "blocks" / "blocks_active": the row
+        compaction's blocks and those that held such a lane (a pass that
+        was not compacted: every 8,192-lane block, all counted active)}``,
+        the counts summed over the row shards; ``[]`` under a grower that
+        keeps no log.  ``ramp_sample_rows`` / ``ramp_sample_lanes``: the
+        in-bag lanes and all lanes of the speculative ramp's subsample (0
+        with the ramp off).  The tree clock, host seconds since the record
+        was made, the same three on every class row of an iteration:
+        ``done_s``: when the tree's ``num_leaves`` reached the host, i.e.
+        the END OF ITS GROWER on the device, not of its score update
+        (which runs into the next tree's period); stamped by the next
+        iteration after its one wait, so None for the newest tree and
+        for a path that does not defer its trees (all three are None
+        for a trainer that keeps no tree clock).  ``wait_s``: what the
+        tree's own iteration spent blocked in that wait
+        (``train/iter/wait_prev``: the host waiting for the device to
+        finish the tree BEFORE).  ``dispatch_s``: the iteration's host
+        seconds in gradients + sample + grow + record, what
+        ``phase_seconds`` sums over the run.  ``phase_seconds`` itself
+        stays host time of ENQUEUEING, whatever the device does.
 
         ``setup_seconds`` (host seconds; a phase that only enqueues device
         work reads its dispatch time): ``to_float64``, ``bin_find``,
@@ -514,6 +611,9 @@ class TrainRecord:
             setup_s = dict(self._setup_s)
             mem_peak = self._mem_peak
             elapsed = time.perf_counter() - self._t_created
+            clock = {it: dict(c) for it, c in self._iter_clock.items()}
+        trees = [{**r, "done_s": None, "wait_s": None, "dispatch_s": None,
+                  **clock.get(r["iteration"], {})} for r in trees]
         trees.sort(key=lambda r: (r["iteration"], r["class_id"]))
         coll_now = collectives_snapshot()
         coll = {}
@@ -536,17 +636,12 @@ class TrainRecord:
             db = rec["bytes"] - base["bytes"]
             if dc > 0:
                 hist_kernels[site] = {**rec, "count": dc, "bytes": db}
-        mon_counts, mon_secs = _monitoring_snapshot()
+        mon_counts = _monitoring_snapshot()
         events = {}
         for k, v in _compile_events(mon_counts).items():
             d = v - self._mon_base.get(k, 0)
             if d > 0:
                 events[k] = d
-        secs = {}
-        for k, v in mon_secs.items():
-            d = v - self._mon_secs_base.get(k, 0.0)
-            if d > 1e-9 and any(m in k.lower() for m in _COMPILE_MARKERS):
-                secs[k] = round(d, 6)
         hp = [r["hist_passes"] for r in trees]
         setup_s.update(_compile_seconds_by_kind(
             self._compile_since, self._compile_until or time.perf_counter()))
@@ -566,7 +661,6 @@ class TrainRecord:
             "score_update": self.score_update,
             "hist_kernel": hist_kernels,
             "compile_events": events,
-            "compile_seconds": secs,
             "device_memory_peak_bytes": mem_peak,
             "elapsed_seconds": round(elapsed, 6),
         }

@@ -101,7 +101,7 @@ def main():
                 emit(kind=kind, tiling=tiling, share=share, rows=n,
                      features=f, dense_ms=t_dense, plan_ms=t_plan,
                      compact_ms=t_comp, pass_ms=t_pass,
-                     rows_contracted=int(rows), gap=gap)
+                     rows_contracted=int(rows[0]), gap=gap)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/hist_compact.json", "w") as fh:
         json.dump(lines, fh, indent=1)

@@ -73,7 +73,7 @@ def test_segmented_kernels_sum_to_the_whole_pass(pipeline, compact):
                                            pipeline=pipeline, compact=compact, acc_rows=8192)
     if compact:
         out, rows = out
-        assert int(rows) == 12288            # 8,200 active rows: the third segment runs
+        assert int(rows[0]) == 12288            # 8,200 active rows: the third segment runs
     out = np.asarray(out).astype(np.int64)
     assert out.shape == (42, f, B, 5)
     np.testing.assert_array_equal(out[..., 3:] * 65536 + out[..., :2], ref[..., :2])

@@ -55,13 +55,16 @@ def test_compaction_kernel_against_numpy(share, wdt):
         w = np.array(jnp.asarray(rng.randn(8, n), jnp.bfloat16))
     w[5:] = 0                     # the rows the packers leave zero
     ch = _channels(rng, n, share)
-    b, ww, cc, steps = (np.asarray(o) for o in hp._compact_rows_dma(
+    b, ww, cc, steps, counts = (np.asarray(o) for o in hp._compact_rows_dma(
         jnp.asarray(bins), jnp.asarray(w), jnp.asarray(ch)[None], fc=fc,
         kr=KR, interpret=True))
     kb = hp._compact_block(n, f_pad)
     rb, rw, rc = _numpy_compact(bins, w, ch, kb)
     total = rc.shape[1]
     assert total % 128 == 0 and total == _numpy_total(ch, kb)
+    # the plan's counts: active lanes, blocks, blocks with an active lane
+    held = sum(bool((ch[lo:lo + kb] >= 0).any()) for lo in range(0, n, kb))
+    assert counts.tolist() == [int((ch >= 0).sum()), n // kb, held]
     np.testing.assert_array_equal(b[:fc, :total], rb[:fc])
     np.testing.assert_array_equal(ww[:, :total].astype(np.float32),
                                   rw.astype(np.float32))
@@ -85,7 +88,7 @@ def test_plan_kernel_with_a_ragged_last_step():
     sub, kb = hp._CP_SUB, 4096
     n = sub * (hp._CP_PLAN_ROWS + 8)
     ch = _channels(rng, n, 0.3)
-    code, wt, off, steps = (np.asarray(o) for o in hp._compact_plan(
+    code, wt, off, steps, _ = (np.asarray(o) for o in hp._compact_plan(
         jnp.asarray(ch), kb=kb, kr=KR, interpret=True))
     act = (ch >= 0).reshape(n // kb, kb)
     before = np.cumsum(act, axis=1) - act             # in the block
@@ -109,7 +112,7 @@ def test_compaction_kernel_moves_wide_bins_in_row_groups():
     ch = _channels(rng, n, 0.3)
     kb = hp._compact_block(n, f_pad)
     assert kb < hp._CP_KB
-    b, ww, cc, _ = (np.asarray(o) for o in hp._compact_rows_dma(
+    b, ww, cc, *_ = (np.asarray(o) for o in hp._compact_rows_dma(
         jnp.asarray(bins), jnp.asarray(w), jnp.asarray(ch)[None], fc=fc,
         kr=KR, interpret=True))
     rb, rw, rc = _numpy_compact(bins, w, ch, kb)
@@ -138,10 +141,14 @@ def test_compacted_leaf_pass_equals_dense(kind, share):
                               -1).astype(np.int8))
     kw = dict(num_bins=255, pipeline="dma", interpret=True)
     dense = build(bins, w, ch, **kw)
-    got, rows = build(bins, w, ch, compact=True, **kw)
-    assert int(rows) <= n and int(rows) % KR == 0
+    got, counts = build(bins, w, ch, compact=True, **kw)
+    rows, active, blocks, held = (int(c) for c in counts)
+    assert rows <= n and rows % KR == 0
     if share < 1.0:
-        assert int(rows) < n
+        assert rows < n
+    assert active == int((np.asarray(ch) >= 0).sum()) <= max(rows, KR)
+    assert blocks == n // hp._compact_block(n, 16)
+    assert held == (blocks if share > 0 else 0)
     if kind == "q8":
         assert got.dtype == jnp.int32
         np.testing.assert_array_equal(np.asarray(got), np.asarray(dense))
@@ -169,9 +176,9 @@ def test_dense_pipelines_report_every_row():
     for kw in (dict(pipeline="blockspec"),
                dict(pipeline="dma", bins_packed=True)):
         b = hp.pack_bins4(bins) if kw.get("bins_packed") else bins
-        got, rows = hp.build_histogram_pallas_leaves_q8(
+        got, counts = hp.build_histogram_pallas_leaves_q8(
             b, w, ch, num_bins=15, interpret=True, compact=True, **kw)
-        assert rows == n
+        assert counts.tolist() == [n, int((np.asarray(ch) >= 0).sum()), 1, 1]
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -205,7 +212,8 @@ def dense_call_sites(monkeypatch):
         def dense(bins_t, w, ch, *, compact=False, _build=getattr(hp, name),
                   **kw):
             hist = _build(bins_t, w, ch, **kw)
-            return (hist, w.shape[1]) if compact else hist
+            return (hist, hp.dense_pass_counts(w.shape[1], ch)) \
+                if compact else hist
         monkeypatch.setattr(hp, name, dense)
 
 

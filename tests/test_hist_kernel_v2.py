@@ -649,6 +649,12 @@ def test_grower_builds_no_columns_for_the_row_update(quantized):
             assert int(a[0, 0]) <= int(c[0, 0]) == \
                 int(want.hist_passes) * (n // int(c[0, 1]))
             continue
+        if name == "pass_log":
+            # the same passes (kind, leaves, active lanes); what they
+            # looped over and in which blocks is the pipeline's
+            np.testing.assert_array_equal(np.asarray(a)[..., (0, 1, 3)],
+                                          np.asarray(c)[..., (0, 1, 3)])
+            continue
         np.testing.assert_array_equal(np.asarray(a), np.asarray(c),
                                       err_msg=name)
 

@@ -240,8 +240,8 @@ def test_train_record_accumulates():
 
 def test_train_record_wave_hist_passes():
     """Through the full Booster path on the wave grower, the exported
-    per-tree hist_passes must equal the GrownTree counter the endgame
-    tests assert (gbdt.last_hist_passes is the last tree's)."""
+    per-tree hist_passes is the GrownTree counter the endgame tests
+    assert: every tree's pass log is that long."""
     bst, _ = _train_binary(n=600, trees=3,
                            extra={"tree_grow_mode": "wave",
                                   "num_leaves": 13})
@@ -249,9 +249,66 @@ def test_train_record_wave_hist_passes():
     hp = [r["hist_passes"] for r in snap["trees"]]
     assert len(hp) == 3
     assert all(p >= 1 for p in hp), hp  # wave grower tracks passes
-    assert hp[-1] == int(bst._gbdt.last_hist_passes)
+    assert hp == [len(r["passes"]) for r in snap["trees"]]
     assert snap["hist_passes_total"] == sum(hp)
     assert snap["hist_passes_last"] == hp[-1]
+
+
+class _TickClock:
+    """``time`` for the two telemetry modules: every read is a second on."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self):
+        self.now += 1.0
+        return self.now
+
+
+@pytest.mark.parametrize("classes", [1, 3], ids=["binary", "multiclass"])
+def test_tree_clock_with_a_patched_clock(monkeypatch, classes):
+    """``done_s`` is stamped by the NEXT iteration, after its one wait:
+    monotone, None for the newest tree; an iteration's wait and dispatch
+    fit in its tree's period; every class row of an iteration carries the
+    same three."""
+    from lightgbm_tpu.telemetry import trace as ttrace
+    from lightgbm_tpu.telemetry import train_record as tr
+    rng = np.random.RandomState(1)
+    X = rng.randn(600, 6)
+    y = ((X[:, 0] > 0).astype(int) + (X[:, 1] > 0.3)).astype(np.float64)
+    p = {**SMALL, "tree_grow_mode": "wave", **(
+        {"objective": "multiclass", "num_class": 3} if classes == 3
+        else {"objective": "binary"})}
+    if classes == 1:
+        y = (y > 0).astype(np.float64)
+    train_set = lgb.Dataset(X, y, params=p)
+    train_set.construct()
+    clock = _TickClock()
+    monkeypatch.setattr(ttrace, "time", clock)
+    monkeypatch.setattr(tr, "time", clock)
+    bst = lgb.Booster(params=p, train_set=train_set)
+    for _ in range(5):
+        bst.update()
+    snap = bst.train_record.snapshot()
+    rows = snap["trees"]
+    assert len(rows) == 5 * classes
+    by_iter = [rows[i * classes:(i + 1) * classes] for i in range(5)]
+    for group in by_iter:
+        assert len({(r["done_s"], r["wait_s"], r["dispatch_s"])
+                    for r in group}) == 1
+    it = [g[0] for g in by_iter]
+    assert it[-1]["done_s"] is None
+    done = [r["done_s"] for r in it[:-1]]
+    assert all(float(d).is_integer() for d in done)      # the patched clock
+    assert all(b > a for a, b in zip(done, done[1:]))
+    assert it[0]["wait_s"] == 0.0                        # nothing to wait for
+    for before, row in zip(it[:-2], it[1:-1]):
+        period = row["done_s"] - before["done_s"]
+        assert row["wait_s"] > 0 and row["dispatch_s"] > 0
+        assert row["wait_s"] + row["dispatch_s"] <= period
+    # the wait has a span and a phase of its own, once a later iteration
+    assert snap["phase_calls"]["wait_prev"] == 4
+    assert sum(r["wait_s"] for r in it) == snap["phase_seconds"]["wait_prev"]
 
 
 def test_setup_seconds_in_train_record():
@@ -346,9 +403,14 @@ def test_training_bit_identical_with_telemetry_disabled():
     assert bst_on.model_to_string() == txt_off
     np.testing.assert_array_equal(
         bst_on.predict(X2[:50], raw_score=True), pred_off)
-    # and the disabled run recorded nothing
-    assert bst_off.train_record.snapshot()["num_trees"] == 0
-    assert bst_on.train_record.snapshot()["num_trees"] == 4
+    # and the disabled run recorded nothing: no row, no clock, no phase
+    off = bst_off.train_record.snapshot()
+    assert off["num_trees"] == 0 and off["trees"] == []
+    assert off["phase_seconds"] == {}
+    on = bst_on.train_record.snapshot()
+    assert on["num_trees"] == 4
+    assert all({"done_s", "wait_s", "dispatch_s", "passes"} <= set(r)
+               for r in on["trees"])
 
 
 # -- collective tally vs the traced program ---------------------------------

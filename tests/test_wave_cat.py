@@ -140,7 +140,8 @@ def test_the_record_counts_categorical_splits_and_states_the_growers_paths(kerne
     snap = bst._gbdt.train_record.snapshot()
     assert snap["grower"] == {
         "ramp": False, "endgame": False, "scatter": False, "voting": False,
-        "efb": False, "any_cat": True, "row_update": "kernel", "hist_acc_rows": 0}
+        "efb": False, "any_cat": True, "sampled": False, "row_update": "kernel",
+        "hist_acc_rows": 0}
     for row, tree in zip(snap["trees"], bst._gbdt.models):
         k = tree.num_leaves - 1
         assert row["cat_splits"] == int(np.sum(tree.decision_type[:k] & 1)) > 0

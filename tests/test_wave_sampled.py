@@ -86,8 +86,8 @@ def _rows(tree):
 
 def _assert_same_tree(got, want, exact_bits):
     for name in type(want)._fields:
-        if name == "hist_rows_contracted":
-            continue
+        if name in ("hist_rows_contracted", "pass_log", "ramp_sample"):
+            continue      # a shard's own counts: of the rows it looped over
         a, b = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
         if exact_bits or not np.issubdtype(b.dtype, np.floating):
             np.testing.assert_array_equal(a, b, err_msg=name)
@@ -128,6 +128,13 @@ def test_sampled_grower_grows_the_unsampled_growers_tree(kind, how, first):
     # the dense first pass alone is a third of the plain tree's rows
     assert _rows(want) >= hp.pad_rows(N_TREE)
     assert _rows(got) <= 0.4 * _rows(want)
+    # the same passes, pass by pass: kinds and leaves built; the sampled
+    # grower's channels hold the bag's rows alone, in every pass
+    log_w, log_g = np.asarray(want.pass_log)[0], np.asarray(got.pass_log)[0]
+    passes = int(want.hist_passes)
+    np.testing.assert_array_equal(log_g[:, :2], log_w[:, :2])
+    assert (log_g[:passes, 3] < log_w[:passes, 3]).all()
+    assert log_g[0, 3] == int(np.asarray(sample[2]).sum())
 
 
 @pytest.mark.parametrize("first", list(FIRST_PASS))
