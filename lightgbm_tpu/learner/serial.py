@@ -716,7 +716,7 @@ def full_space_split_params(config: Config, num_bins, is_cat) -> SplitParams:
     """``split_params_from_config`` for a grower whose scans and row
     updates run in the FULL feature space (serial; the wave grower under
     any strategy): the static cat-column positions ride along (they
-    bound the subset search's argsort and enable the embedding-style
+    bound the subset search's sorts and enable the embedding-style
     membership lookup)."""
     sp = split_params_from_config(config, num_bins, is_cat)
     cat = np.where(np.asarray(is_cat))[0]

@@ -61,7 +61,7 @@ class SplitParams(NamedTuple):
                                    # sorted-subset search (num_bin > onehot)
     cat_idx: tuple = ()            # STATIC positions of categorical
                                    # features — the sorted-subset search
-                                   # (argsort per candidate) runs on this
+                                   # (two sorts per candidate) runs on this
                                    # slice only, not all F features
     # cost-effective gradient boosting (cost_effective_gradient_boosting
     # .hpp DeltaGain — upstream spells the method ``DetlaGain``):
@@ -158,6 +158,51 @@ def monotone_penalty_factor(depth, penalty: float):
                      jnp.where(penalty <= 1.0,
                                1.0 - penalty / jnp.exp2(d) + eps,
                                1.0 - jnp.exp2(penalty - 1.0 - d) + eps))
+
+
+def _shift_left(a: jnp.ndarray, k: int) -> jnp.ndarray:
+    """``a`` moved ``k`` places toward index 0 along its last axis, zeros
+    entering from the far end (``k`` static)."""
+    n = a.shape[-1]
+    if k >= n:
+        return jnp.zeros_like(a)
+    return jnp.concatenate(
+        [a[..., k:], jnp.zeros(a.shape[:-1] + (k,), a.dtype)], axis=-1)
+
+
+def _ratio_sorted_prefixes(ratio: jnp.ndarray, used: jnp.ndarray, planes):
+    """The data movement of the sorted-subset search, by the sort network
+    and static shifts (no gather, no scatter: both move one element at a
+    time on a TPU).
+
+    ``ratio`` (nc, B) is the sort key, ``used`` (nc,) how many of a row's
+    keys are candidates (they sort first), ``planes`` the (nc, B) channel
+    planes.  Returns ``rank`` (nc, B) int32, each bin's place in the stable
+    ascending order of its row, and per plane ``(cumf, cumb)``: the prefix
+    sums over the ``i + 1`` smallest and over the ``i + 1`` largest used
+    ratios, the latter as ``total_used - cumf[used - 2 - i]``."""
+    nc, b = ratio.shape
+    iota = jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[None, :], (nc, b))
+    # one stable sort keyed on the ratio alone carries the bin ids and the
+    # planes into ratio order; sorting the ids back carries the places
+    _, order, *in_order = jax.lax.sort((ratio, iota, *planes), dimension=1,
+                                       is_stable=True, num_keys=1)
+    _, rank = jax.lax.sort((order, iota), dimension=1, num_keys=1)
+    pos_used = iota < used[:, None]
+    # cumf[used - 2 - i] is flip(cumf)[i + shift], shift = b + 1 - used in
+    # [1, b + 1]: one select per bit of the shift between the plane and
+    # its static shift (what runs off the far end is masked below anyway)
+    shift = (b + 1 - used)[:, None]
+    from_end = used[:, None] - 2 - iota >= 0
+    out = []
+    for sh in in_order:
+        cumf = jnp.cumsum(jnp.where(pos_used, sh, 0.0), axis=1)
+        tb = jnp.flip(cumf, axis=1)
+        for bit in range((b + 1).bit_length()):
+            tb = jnp.where(((shift >> bit) & 1) == 1,
+                           _shift_left(tb, 1 << bit), tb)
+        out.append((cumf, cumf[:, -1:] - jnp.where(from_end, tb, 0.0)))
+    return rank, out
 
 
 def best_split_per_feature(hist: jnp.ndarray, parent_sum: jnp.ndarray,
@@ -367,10 +412,12 @@ def _best_split_impl(hist: jnp.ndarray, parent_sum: jnp.ndarray,
         # non-onehot branch): categories ordered by sum_grad/(sum_hess +
         # cat_smooth); prefix subsets scanned from BOTH ends, up to
         # max_cat_threshold categories; the LEFT child takes the subset.
-        # The argsort/rank machinery is the single most expensive part of
-        # a categorical scan, so it runs ONLY on the static cat columns
-        # (params.cat_idx) and scatters back — numeric features never pay
-        # for it.
+        # It runs ONLY on the static cat columns (params.cat_idx) and its
+        # results go back into F-space at the end: numeric features do not
+        # ride through the two sorts.  No (nc, B) plane is moved by index
+        # (_ratio_sorted_prefixes, at_pos): a gather of one costs a TPU
+        # some 10 ns an element, 6-9 ms at 84 children x 26 columns x 256
+        # bins against 0.3 ms for the five-operand sort (PERF.md, PR 37).
         if params.use_cat_subset:
             ci = jnp.asarray(params.cat_idx, jnp.int32) \
                 if params.cat_idx else jnp.arange(f, dtype=jnp.int32)
@@ -384,32 +431,11 @@ def _best_split_impl(hist: jnp.ndarray, parent_sum: jnp.ndarray,
             cat_valid = real_bin_c & (hcc >= params.cat_smooth)
             ratio = jnp.where(cat_valid,
                               hgc / (hhc + params.cat_smooth), BIG)
-            order = jnp.argsort(ratio, axis=1, stable=True)      # (nc, B)
-            rank = jnp.zeros((nc, b), jnp.int32).at[
-                jnp.arange(nc)[:, None], order].set(
-                jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[None, :],
-                                 (nc, b)))
             used = jnp.sum(cat_valid, axis=1).astype(jnp.int32)  # (nc,)
             pos = jnp.arange(b, dtype=jnp.int32)[None, :]        # (1, B)
-            pos_used = pos < used[:, None]
-
-            def fwd_bwd(plane):
-                """Forward/backward ratio-ordered prefix cumsums of one
-                channel plane."""
-                sh = jnp.take_along_axis(plane, order, axis=1)
-                sh = jnp.where(pos_used, sh, 0.0)
-                cumf = jnp.cumsum(sh, axis=1)                    # (F, B)
-                total_used = cumf[:, -1:]
-                # prefix of the (i+1) LARGEST ratios =
-                #   total_used - cumf[used-2-i]
-                bidx = used[:, None] - 2 - pos                   # (F, B)
-                tb = jnp.take_along_axis(cumf, jnp.clip(bidx, 0, b - 1), 1)
-                cumb = total_used - jnp.where(bidx >= 0, tb, 0.0)
-                return cumf, cumb
-
-            cumf_g, cumb_g = fwd_bwd(hgc)
-            cumf_h, cumb_h = fwd_bwd(hhc)
-            cumf_c, cumb_c = fwd_bwd(hcc)
+            rank, ((cumf_g, cumb_g), (cumf_h, cumb_h),
+                   (cumf_c, cumb_c)) = _ratio_sorted_prefixes(
+                       ratio, used, (hgc, hhc, hcc))
 
             max_pos = jnp.minimum(jnp.minimum(params.max_cat_threshold,
                                               (used[:, None] + 1) // 2),
@@ -444,19 +470,23 @@ def _best_split_impl(hist: jnp.ndarray, parent_sum: jnp.ndarray,
 
             gain_f = subset_gain(cumf_g, cumf_h, cumf_c)
             gain_bk = subset_gain(cumb_g, cumb_h, cumb_c)
+
+            def at_pos(a, idx):
+                """One position a row of an (nc, B) plane, as a masked sum
+                (exact: every other term is +0.0) and not as a gather."""
+                return jnp.sum(jnp.where(pos == idx[:, None], a, 0.0), axis=1)
+
             f_pos = jnp.argmax(gain_f, axis=1)
-            f_best = at_bin(gain_f, f_pos)
+            f_best = jnp.max(gain_f, axis=1)
             b_pos = jnp.argmax(gain_bk, axis=1)
-            b_best = at_bin(gain_bk, b_pos)
+            b_best = jnp.max(gain_bk, axis=1)
             use_bk = b_best > f_best
             sub_gain = jnp.where(use_bk, b_best, f_best)
             sub_pos = jnp.where(use_bk, b_pos, f_pos)
-            sub_left = jnp.where(
-                use_bk[:, None],
-                jnp.stack([at_bin(cumb_g, b_pos), at_bin(cumb_h, b_pos),
-                           at_bin(cumb_c, b_pos)], axis=-1),
-                jnp.stack([at_bin(cumf_g, f_pos), at_bin(cumf_h, f_pos),
-                           at_bin(cumf_c, f_pos)], axis=-1))
+            sub_left = jnp.stack(
+                [at_pos(jnp.where(use_bk[:, None], cb, cf), sub_pos)
+                 for cf, cb in ((cumf_g, cumb_g), (cumf_h, cumb_h),
+                                (cumf_c, cumb_c))], axis=-1)
             # membership: forward -> ranks [0, pos]; backward -> the top
             # (pos+1) ranks of the used range
             sub_member = jnp.where(
@@ -465,7 +495,8 @@ def _best_split_impl(hist: jnp.ndarray, parent_sum: jnp.ndarray,
                 (rank < used[:, None]),
                 rank <= sub_pos[:, None])
 
-            # scatter the nc-sliced results back into F-space
+            # the nc-sliced results back into F-space (whole rows at
+            # static places)
             sub_gain = jnp.full((f,), NEG_INF, hist.dtype).at[ci].set(
                 sub_gain, mode="drop")
             sub_left = jnp.zeros((f, 3), hist.dtype).at[ci].set(
