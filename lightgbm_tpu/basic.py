@@ -20,6 +20,10 @@ from .utils.log import log_warning
 __all__ = ["Booster"]
 
 
+# cells Booster.predict densifies of a sparse input at a time (0.5 GB of float32)
+_SPARSE_PREDICT_CELLS = 1 << 27
+
+
 class Booster:
     """Trained-model handle (reference basic.py Booster; C-side
     src/c_api.cpp:108 Booster wrapper)."""
@@ -186,17 +190,23 @@ class Booster:
             num_iteration = self.best_iteration if self.best_iteration > 0 else None
         if hasattr(data, "to_numpy"):
             data = data.to_numpy(dtype=np.float64, na_value=np.nan)
-        if hasattr(data, "todense"):
-            data = np.asarray(data.todense())
-        return self._gbdt.predict(np.asarray(data, dtype=np.float64),
-                                  raw_score=raw_score,
-                                  start_iteration=start_iteration,
-                                  num_iteration=num_iteration,
-                                  pred_leaf=pred_leaf,
-                                  pred_contrib=pred_contrib,
-                                  pred_early_stop=pred_early_stop,
-                                  pred_early_stop_freq=pred_early_stop_freq,
-                                  pred_early_stop_margin=pred_early_stop_margin)
+        how = dict(raw_score=raw_score, start_iteration=start_iteration,
+                   num_iteration=num_iteration, pred_leaf=pred_leaf,
+                   pred_contrib=pred_contrib,
+                   pred_early_stop=pred_early_stop,
+                   pred_early_stop_freq=pred_early_stop_freq,
+                   pred_early_stop_margin=pred_early_stop_margin)
+        if hasattr(data, "tocsr"):
+            # sparse rows are densified a slice at a time: never more than
+            # _SPARSE_PREDICT_CELLS values at once (12M x 4,228 whole would
+            # be 412 GB of float64), and in their own dtype: the predictor
+            # walks float32, a float64 copy of float32 values adds nothing
+            data = data.tocsr()
+            step = max(1, _SPARSE_PREDICT_CELLS // max(1, data.shape[1]))
+            return np.concatenate([
+                self._gbdt.predict(data[lo:lo + step].toarray(), **how)
+                for lo in range(0, max(data.shape[0], 1), step)], axis=0)
+        return self._gbdt.predict(np.asarray(data, dtype=np.float64), **how)
 
     def to_predictor(self, num_iteration: Optional[int] = None,
                      warmup: bool = False, **kwargs):

@@ -369,16 +369,28 @@ class Dataset:
         mappers = [self.bin_mappers[j] for j in used]
 
         if self.efb is None:
-            self.efb = self._maybe_bundle(cfg, raw, sparse, used, mappers,
-                                          sample_idx, n, sample)
+            with timed_span(secs, "find_bundles",
+                            "dataset/construct/find_bundles"):
+                self.efb = self._maybe_bundle(cfg, raw, sparse, used,
+                                              mappers, sample_idx, n, sample)
         if self.efb is not None:
             from .efb import bundle_binned_matrix, bundle_sparse_csc
-            if sparse:
-                self.X_binned = bundle_sparse_csc(raw[:, used].tocsc(),
-                                                  mappers, self.efb)
-            else:
-                self.X_binned = bundle_binned_matrix(
-                    self._bin_dense(raw, used, mappers), self.efb)
+            if self.reference is not None:
+                # the conflict count is the training set's own
+                import copy
+                self.efb = copy.copy(self.efb)
+            codes = None if sparse else self._bin_dense(raw, used, mappers)
+            with timed_span(secs, "bundle_matrix",
+                            "dataset/construct/bundle_matrix"):
+                if sparse:
+                    self.X_binned = bundle_sparse_csc(
+                        raw if len(used) == f else raw[:, used].tocsc(),
+                        mappers, self.efb)
+                else:
+                    self.X_binned = bundle_binned_matrix(codes, self.efb)
+            # the bundled matrix IS this data set's bin matrix
+            secs["bin_matrix"] = secs.get("bin_matrix", 0.0) + \
+                secs["bundle_matrix"]
             log_info(f"EFB: bundled {len(used)} features into "
                      f"{self.efb.n_bundles} device columns "
                      f"({self.efb.bundle_bins} bundle bins)")
@@ -530,6 +542,12 @@ class Dataset:
         nondefault = []
         cand = []
         from .efb import MAX_BUNDLE_BINS
+        if sparse:
+            # a row's place in the sample, -1 outside it: one lookup a
+            # stored value (a set intersection a column sorted the sample
+            # 4,228 times over)
+            place = np.full(n, -1, np.int32)
+            place[sample_idx] = np.arange(len(sample_idx), dtype=np.int32)
         for jj, m in enumerate(mappers):
             if m.is_categorical:
                 continue
@@ -541,9 +559,8 @@ class Dataset:
             if sparse:
                 lo, hi = raw.indptr[j], raw.indptr[j + 1]
                 mask = np.zeros(len(sample_idx), bool)
-                mask[np.searchsorted(sample_idx,
-                                     np.intersect1d(raw.indices[lo:hi],
-                                                    sample_idx))] = True
+                at = place[raw.indices[lo:hi]]
+                mask[at[at >= 0]] = True
             else:
                 col = mappers[jj].value_to_bin(
                     raw[sample_idx, j] if sample is None else sample[:, j])
@@ -689,6 +706,21 @@ class Dataset:
         # inner FEATURE count — under EFB the device matrix is narrower
         # (bundle columns), but the feature surface stays per-feature
         return int(len(self.used_feature_map))
+
+    def efb_conflicts(self) -> tuple:
+        """``(rows int64, columns int32)``: the entries of the raw data
+        that a bundle's conflict overwrote when this data set was built
+        (efb.py): in row ``rows[i]``, column ``columns[i]`` (an index into
+        the raw columns) held a non-default value and was trained on as
+        its default, because another column of its bundle was set there
+        too.  Empty without bundling.  Every other value was trained on
+        as it is, so only these rows can reach another leaf at prediction
+        than they reached in training."""
+        self._check_constructed()
+        if self.efb is None:
+            return np.zeros(0, np.int64), np.zeros(0, np.int32)
+        rows, feats = self.efb.conflict_entries
+        return rows, np.asarray(self.used_feature_map, np.int32)[feats]
 
     def fingerprint(self) -> Dict[str, Any]:
         """Identity of the BINNED training matrix for checkpoint/resume

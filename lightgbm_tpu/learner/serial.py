@@ -726,7 +726,7 @@ def full_space_split_params(config: Config, num_bins, is_cat) -> SplitParams:
 
 def wave_grow_kwargs(config: Config, num_features: int, max_bins: int,
                      num_bins, is_cat, hist_impl: str, *, efb_dims=None,
-                     forced_splits: tuple = (),
+                     efb_layout: tuple = (), forced_splits: tuple = (),
                      interaction_groups: tuple = (),
                      feature_contri: tuple = (), cegb_lazy: tuple = (),
                      strategy=None, sampled: bool = False,
@@ -766,7 +766,7 @@ def wave_grow_kwargs(config: Config, num_features: int, max_bins: int,
         max_bins=int(max_bins), max_depth=int(config.max_depth),
         split_params=sp, hist_impl=hist_impl, any_cat=any_cat,
         wave_size=int(config.tpu_wave_size), pack4=pack4, pipeline=pipeline,
-        efb_dims=efb_dims,
+        efb_dims=efb_dims, efb_layout=efb_layout,
         feature_contri=tuple(float(v) for v in feature_contri),
         strategy=strategy, quantized=bool(config.use_quantized_grad),
         gq_max=gq_max, hq_max=hq_max,
@@ -842,7 +842,9 @@ class WaveTreeLearner:
         self._grow_factory = make_wave_grow_fn
         kw = self._grow_kwargs = wave_grow_kwargs(
             config, num_features, self.max_bins, num_bins, is_cat, hist_impl,
-            efb_dims=self._efb_dims, forced_splits=forced_splits,
+            efb_dims=self._efb_dims,
+            efb_layout=efb.layout() if efb is not None else (),
+            forced_splits=forced_splits,
             interaction_groups=interaction_groups,
             feature_contri=feature_contri, cegb_lazy=cegb_lazy,
             strategy=strategy, sampled=sampled, acc_rows=acc_rows)

@@ -67,6 +67,7 @@ from ..ops.histogram_pallas import DEFAULT_ROW_BLOCK, dense_pass_counts
 from ..ops.quantize import (dequant_limbs, dequant_scales, hist_limbs,
                             quantize_wch)
 from ..ops.split import (BIG, NEG_INF, _leaf_gain, best_split_per_feature,
+                         best_split_two_bin,
                          leaf_output,
                          leaf_output_smoothed)
 from .endgame import patch_child_pointers, write_split_records
@@ -263,7 +264,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                       any_cat: bool = True, interpret: bool = None,
                       pack4: bool = False, pipeline: str = None,
                       jit: bool = True, wave_size: int = 0,
-                      efb_dims=None, feature_contri: tuple = (),
+                      efb_dims=None, efb_layout: tuple = (),
+                      feature_contri: tuple = (),
                       strategy=None, quantized: bool = False,
                       gq_max: int = 127, hq_max: int = 127,
                       renew_leaf: bool = False, stochastic: bool = True,
@@ -284,6 +286,13 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
     cegb_penalty, efb_arrays, feature_mask) -> GrownTree`` with X_T the
     FEATURE-MAJOR (G, N) bin matrix (bundle-space under EFB), N a multiple
     of the Pallas row block when hist_impl == 'pallas'.
+
+    ``efb_dims`` / ``efb_layout``: (G, Bb) of an EFB-bundled data set and
+    its static layout (efb.py ``BundleInfo.layout``): the histograms are
+    built and banked in bundle space, the scans read member features out
+    of them by static slices (``make_scan_expand``, scope
+    ``lgbm.wave.efb_expand``), and the fused row update takes a bundled
+    slot's left set in bundle codes.
 
     ``strategy`` hooks the data-parallel mesh in: under shard_map with
     row-sharded X_T/grad/hess, each wave's (W, G, Bb, 3) histogram batch
@@ -390,6 +399,9 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
     # bin codes stay uint8 (255 reserved as the no-NaN sentinel) and leaf
     # ids uint8 when the tree fits — 4x less HBM traffic than int32.
     small_bins = (not use_efb) and max_bins <= 255
+    # The fused row-update kernel reads uint8 codes with 255 free: feature
+    # bins, or the codes of bundle columns (a bundle holds at most 255)
+    fused_update = pallas and max(max_bins, Bb) <= 255
     # Exact device-side endgame eligibility (all static).  Once the
     # remaining budget drops below 2W the halving taper is replaced by
     # ONE batched kernel pass over the frontier candidates' smaller
@@ -541,7 +553,7 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
         def router_bins(mat):
             """What the fused row-update kernel reads ``mat``'s columns
             from: made once per tree (a relayout of ``mat`` on a TPU)."""
-            if pack4 or not (pallas and small_bins):
+            if pack4 or not fused_update:
                 return mat
             return bin_rows_view(mat, pipeline)
 
@@ -555,8 +567,8 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
         def route_rows(bins, feats, rl, tab, cat=None):
             bins, feats = router_args(bins, feats)
             return wave_row_update_pallas(
-                bins, rl, tab, feats=feats, cat=cat, interpret=interpret,
-                pipeline=pipeline)
+                bins, rl, tab, feats=feats, cat=cat, bundled=use_efb,
+                interpret=interpret, pipeline=pipeline)
 
         with jax.named_scope("lgbm.wave.row_update"):
             X_R = router_bins(X_T)
@@ -629,11 +641,16 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 return (gmax, f_win, pk[:, 0].astype(jnp.int32),
                         pk[:, 1] > 0, pk[:, 2:5], pk[:, 5:8], member)
 
-        from ..efb import make_bundle_decode, make_expand_hist
+        from ..efb import (bundle_left_sets, make_bundle_decode,
+                           make_expand_hist, make_scan_expand)
+        # the per-leaf (F, B) gather: forced splits only (and the ramp's
+        # node scan, which EFB switches off); the scans take scan_expand
         expand_hist = make_expand_hist(efb_arrays if use_efb else (),
                                        F, G, Bb)
         bundle_decode = make_bundle_decode(efb_arrays if use_efb else ())
         f_bundle = efb_arrays[1] if use_efb else None
+        if use_efb:
+            scan_expand = make_scan_expand(efb_layout, G, Bb, max_bins)
 
         with jax.named_scope("lgbm.quantize"):
             gm = (grad * bag_mask).astype(jnp.float32)
@@ -689,6 +706,11 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             exact integer psum of the selected slices."""
             if use_voting:
                 return h
+            if use_efb:
+                # (wide, narrow): member features where they lie in bundle
+                # space (efb.make_scan_expand), for _efb_candidates
+                with jax.named_scope("lgbm.wave.efb_expand"):
+                    return jax.vmap(scan_expand.expand)(_dqh(h), totals)
             return jax.vmap(expand_hist)(_dqh(h), totals)
 
         def _reduce_waves(h, k, with_totals=False):
@@ -805,6 +827,84 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 return v                                     # uint8
             return bundle_decode(v.astype(jnp.int32), feat)
 
+        def _efb_candidates(hists, sums, bounds, depths, pouts, fms, rbs,
+                            cegb2, cegb, contri):
+            """Best-split candidates for k leaves of a bundled data set.
+            ``hists`` is ``_scan_hists``' (wide (k, Fw, B, 3), narrow
+            (k, 2, 3, Fn)) pair: the wide class takes the scan every
+            unbundled feature takes, the two-bin members of bundles the
+            one-split scan with the features on the lanes (ops/split.py
+            ``best_split_two_bin``).  A class's operands are cut out of
+            the (F,) ones by its static feature ids; the winner is the
+            best gain, the LOWEST feature id among equal gains, as the
+            argmax over an (F,) gain vector picks it."""
+            hw, hn_ = hists
+            wid, nid = scan_expand.wide_ids, scan_expand.narrow_ids
+            per_node = use_ic or use_bynode    # else fms is feature_mask's
+            pen = cegb2 if cegb2 is not None else cegb
+            pen_k = cegb2 is not None
+
+            def cut(a, ids):
+                return None if a is None else jnp.take(a, ids, axis=-1)
+
+            def pick(gain, ids):
+                """(best gain, its feature id, its place) of one class."""
+                gmax = jnp.max(gain)
+                fid = jnp.min(jnp.where(gain >= gmax, ids, jnp.int32(2 ** 30)))
+                return gmax, fid, jnp.argmax(ids == fid)
+
+            def wide_one(h, s, bd, d, po, fm, pn, rb):
+                fs = best_split_per_feature(
+                    h, s, nb_w, ic_w, hn_w, sp_w, mono_w,
+                    bd if use_mc else None, d, pn, contri_w, po, rb)
+                g, fid, at = pick(jnp.where(fm, fs.gain, NEG_INF), wid_j)
+                return (g, fid, fs.threshold_bin[at], fs.default_left[at],
+                        fs.left_sum[at], fs.right_sum[at], fs.cat_member[at])
+
+            def narrow_one(h2, s, bd, d, po, fm, pn):
+                gain = best_split_two_bin(
+                    h2[0], s, sp, mono_n, bd if use_mc else None, d, pn,
+                    contri_n, po)
+                g, fid, at = pick(jnp.where(fm, gain, NEG_INF), nid_j)
+                ls = h2[0][:, at]
+                return (g, fid, jnp.int32(0), jnp.asarray(False), ls, s - ls,
+                        jnp.zeros((max_bins,), jnp.bool_))
+
+            def operands(ids):
+                fm = cut(fms if per_node else feature_mask, ids)
+                return (fm, 0 if per_node else None,
+                        cut(pen, ids), 0 if pen_k else None)
+
+            outs = []
+            if len(wid):
+                wid_j = jnp.asarray(wid)
+                nb_w, ic_w, hn_w = nb_full[wid_j], ic_full[wid_j], hn_full[wid_j]
+                mono_w = monotone[wid_j]
+                contri_w = cut(contri, wid_j)
+                place = {int(f): i for i, f in enumerate(wid)}
+                sp_w = sp._replace(cat_idx=tuple(place[c] for c in sp.cat_idx))
+                fm, fm_ax, pn, pn_ax = operands(wid_j)
+                rb = cut(rbs, wid_j)
+                outs.append(jax.vmap(
+                    wide_one, in_axes=(0, 0, 0, 0, 0, fm_ax, pn_ax,
+                                       None if rb is None else 0))(
+                    hw, sums, bounds, depths, pouts, fm, pn, rb))
+            if len(nid):
+                nid_j = jnp.asarray(nid)
+                mono_n = monotone[nid_j]
+                contri_n = cut(contri, nid_j)
+                fm, fm_ax, pn, pn_ax = operands(nid_j)
+                outs.append(jax.vmap(
+                    narrow_one, in_axes=(0, 0, 0, 0, 0, fm_ax, pn_ax))(
+                    hn_, sums, bounds, depths, pouts, fm, pn))
+            if len(outs) == 1:
+                return outs[0]
+            a, b = outs
+            take_b = (b[0] > a[0]) | ((b[0] == a[0]) & (b[1] < a[1]))
+            return tuple(
+                jnp.where(take_b.reshape((-1,) + (1,) * (x.ndim - 1)), y, x)
+                for x, y in zip(a, b))
+
         def _voting_candidates(hists, sums, bounds, depths, pouts, fms,
                                rbs, cegb2, cegb, contri):
             """PV-Tree voted merge + scan for k leaves (the voting
@@ -906,6 +1006,9 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                 return _voting_candidates(hists, sums, bounds, depths,
                                           pouts, fms, rbs, cegb2, cegb,
                                           contri)
+            if use_efb:
+                return _efb_candidates(hists, sums, bounds, depths, pouts,
+                                       fms, rbs, cegb2, cegb, contri)
             if use_scatter:
                 nb_s, ic_s, hn_s, mono_s = nb_sc, ic_sc, hn_sc, mono_sc
                 fms = _slf2(fms, False)
@@ -1298,11 +1401,12 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
                         preferred_element_type=jnp.float32)[:, 0])       # (F,)
                     strat.cegb_full = base + lazy_pen * jnp.maximum(
                         root_sum[2] - used_root, 0.0)
-                if use_scatter or use_voting:
+                if use_scatter or use_voting or use_efb:
                     # the root scan rides the sliced/voted many_candidates
                     # path (a 1-channel batch) so it too scans only this
-                    # shard's block (scatter) or merges only the voted
-                    # feature slices (voting)
+                    # shard's block (scatter), merges only the voted
+                    # feature slices (voting) or reads the members where
+                    # they lie in bundle space (EFB)
                     c1 = many_candidates(
                         _scan_hists(root_hist[None], root_sum[None]),
                         root_sum[None], root_bound[None],
@@ -1453,24 +1557,38 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
             with jax.named_scope("lgbm.wave.row_update"):
                 rl = s["row_leaf"]
                 rl_old = rl
-                if pallas and small_bins:
+                if fused_update:
                     # one fused kernel pass instead of W masked XLA sweeps
                     # (each sweep's fused-loop launch overhead alone costs
                     # ~0.7 ms at 10.5M rows); a data set with categorical
-                    # columns hands it the slots' left sets as bit sets
+                    # columns hands it the slots' left sets as bit sets,
+                    # and so does a bundled one: a split on a member of a
+                    # bundle is the set of the bundle column's codes that
+                    # go left, and the kernel fetches the BUNDLE's column
                     tab = jnp.stack([
                         thr, f_nan_bin, dleft.astype(jnp.int32),
                         left_smaller.astype(jnp.int32), sel_leaves, new_ids,
                         sel.astype(jnp.int32), jnp.zeros_like(thr)])
-                    rl_new, ch = route_rows(
-                        X_R, feat, rl, tab,
-                        cat=(fcat, member) if any_cat else None)
+                    if use_efb:
+                        bundled, go = bundle_left_sets(
+                            efb_arrays, feat, thr, f_nan_bin, dleft)
+                        sets = jnp.pad(member, ((0, 0), (0, 256 - max_bins)))
+                        slots = (fcat | bundled,
+                                 jnp.where(bundled[:, None], go, sets))
+                        rl_new, ch = route_rows(X_R, f_bundle[feat], rl, tab,
+                                                cat=slots)
+                    else:
+                        rl_new, ch = route_rows(
+                            X_R, feat, rl, tab,
+                            cat=(fcat, member) if any_cat else None)
                     rl = rl_new.astype(rl.dtype)
                 else:
                     # Vectorized XLA form, for what the fused kernel does
-                    # not take (EFB bundles, more than 255 bins, a
-                    # histogram implementation other than Pallas) and, off
-                    # the TPU, the kernel's test oracle: every row belongs
+                    # not take (more than 255 bins in a feature or a
+                    # bundle column, a histogram implementation other
+                    # than Pallas; EFB bundles take the kernel since PR
+                    # 38) and, off the TPU, the kernel's test oracle:
+                    # every row belongs
                     # to at most one split leaf, so an argmax over the
                     # (W, N) match matrix picks its slot and a single
                     # take_along_axis resolves the decision.  (The [old]
@@ -2148,6 +2266,6 @@ def make_wave_grow_fn(*, num_leaves: int, num_features: int, max_bins: int,
         "scatter": bool(use_scatter), "voting": bool(use_voting),
         "efb": bool(use_efb), "any_cat": bool(any_cat),
         "sampled": bool(sampled and pallas),
-        "row_update": "kernel" if pallas and small_bins else "xla",
+        "row_update": "kernel" if fused_update else "xla",
         "hist_acc_rows": int(hist_acc_rows) if wide else 0}
     return fn

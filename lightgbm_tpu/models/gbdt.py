@@ -713,7 +713,9 @@ class GBDT:
             "num_features": int(self.num_features),
         }, compile_since=t_init, mesh=self._mesh_record(),
             grower=getattr(self.learner, "grower_paths", None),
-            score_update=self._choose_score_update())
+            score_update=self._choose_score_update(),
+            efb=train_set.efb.record() if train_set.efb is not None
+            else None)
         self.train_record.add_setup_seconds(
             getattr(train_set, "setup_seconds", {}))
         self.train_record.add_setup_seconds(setup_s)
@@ -1588,7 +1590,8 @@ class GBDT:
         # map raw columns to inner (used) features
         used = self.train_set.used_feature_map if self.train_set is not None \
             else np.arange(X.shape[1])
-        Xi = X[:, used]
+        # every column used (the map is strictly ascending): no copy
+        Xi = X if len(used) == X.shape[1] else X[:, used]
         if pred_leaf:
             return self._predict_leaf(Xi, start_iteration, num_iteration)
         if pred_contrib:

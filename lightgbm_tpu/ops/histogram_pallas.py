@@ -1606,8 +1606,10 @@ def build_histogram_pallas_leaves_q8(bins_t: jnp.ndarray, wch: jnp.ndarray,
 # gathers the columns in front of its kernel (:func:`gather_bin_rows`).
 # A categorical split's left set rides with the table as a 256-bit set
 # (eight SMEM words a slot, the word picked by seven selects on ``bin >>
-# 5``): no per-row gather.  wave.py keeps the XLA path for EFB bundles
-# and more than 255 bins.
+# 5``): no per-row gather.  A slot whose split feature lives in an EFB
+# bundle is such a slot too: its set holds the bundle column's codes that go
+# left (efb.py ``bundle_left_sets``), and the kernel's name says ``_efb``.
+# wave.py keeps the XLA path for more than 255 bins.
 # ---------------------------------------------------------------------------
 
 _RU_SUB = 8      # the row update lays rows out as (_RU_SUB, N // _RU_SUB)
@@ -1795,12 +1797,19 @@ def _row_update_kernel_dma(bins_hbm, rl_hbm, tab_ref, rl_out, ch_out, *,
                   pltpu.SemaphoreType.DMA((2,)))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "cat"))
+def _set_suffix(cat: bool, tag: str) -> str:
+    """The part of a row-update kernel's name that says which slots carry
+    left sets: ``_cat`` categorical ones, ``_efb`` bundled ones."""
+    return tag if tag else ("_cat" if cat else "")
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "cat", "tag"))
 def _wave_row_update_dma(bins3: jnp.ndarray, rl: jnp.ndarray,
                          tab: jnp.ndarray, *, interpret: bool = False,
-                         cat: bool = False):
+                         cat: bool = False, tag: str = ""):
     """``bins3``: a :func:`bin_rows_view`; ``tab[7]``: W ids into its
-    leading axis; ``cat``: ``tab`` is a :func:`_cat_table`."""
+    leading axis; ``cat``: ``tab`` is a :func:`_cat_table`; ``tag``: the
+    sets' kind in the kernel's name, where not ``_cat``."""
     f, _, nd = bins3.shape
     w = tab.shape[1]
     n = nd * _RU_SUB
@@ -1824,18 +1833,19 @@ def _wave_row_update_dma(bins3: jnp.ndarray, rl: jnp.ndarray,
             jax.ShapeDtypeStruct((_RU_SUB, nd), jnp.int8),
         ],
         interpret=interpret,
-        name=_kname("wave_row_update_dma" + ("_cat" if cat else ""),
+        name=_kname("wave_row_update_dma" + _set_suffix(cat, tag),
                     w=w, f=f, kr=kr, n=n),
     )(bins3, rl2, tab)
     return rl_new.reshape(n), ch.reshape(n)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("row_block", "interpret", "cat"))
+                   static_argnames=("row_block", "interpret", "cat", "tag"))
 def _wave_row_update_bs(cols_w: jnp.ndarray, rl: jnp.ndarray,
                         tab: jnp.ndarray, *,
                         row_block: int = DEFAULT_ROW_BLOCK,
-                        interpret: bool = False, cat: bool = False):
+                        interpret: bool = False, cat: bool = False,
+                        tag: str = ""):
     """Implicit-pipeline (BlockSpec-fetched) row update (v1 layout)."""
     w, n = cols_w.shape
     kr = math.gcd(row_block, 4096)
@@ -1866,7 +1876,7 @@ def _wave_row_update_bs(cols_w: jnp.ndarray, rl: jnp.ndarray,
             jax.ShapeDtypeStruct((8, nd), jnp.int8),
         ],
         interpret=interpret,
-        name=_kname("wave_row_update_blockspec" + ("_cat" if cat else ""),
+        name=_kname("wave_row_update_blockspec" + _set_suffix(cat, tag),
                     w=w, kr=kr, n=n),
     )(cols3, rl2, tab)
     return rl_new.reshape(n), ch.reshape(n)
@@ -1889,7 +1899,7 @@ def _cat_table(tab: jnp.ndarray, is_cat: jnp.ndarray,
 
 def wave_row_update_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
                            tab: jnp.ndarray, *, feats: jnp.ndarray = None,
-                           cat: tuple = None,
+                           cat: tuple = None, bundled: bool = False,
                            row_block: int = DEFAULT_ROW_BLOCK,
                            interpret: bool = None, pipeline: str = None):
     """Apply a wave's W splits to every row in one fused pass.
@@ -1913,6 +1923,11 @@ def wave_row_update_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
         (W, B <= 256) bool)``: a categorical slot sends a row left iff its
         bin is in the slot's set, whatever the slot's threshold and NaN
         bin say; the kernel is then named ``..._cat_...``.
+      bundled: the data set is EFB-bundled: ``bins`` holds bundle columns,
+        ``feats`` bundle ids, and ``cat`` marks the slots whose split
+        feature is a bundle member too, their sets in bundle codes
+        (efb.py ``bundle_left_sets``); the kernel is named ``..._efb_...``
+        (``..._cat_efb_...`` never: one name for a bundled data set).
       interpret / pipeline: as :func:`build_histogram_pallas` ("dma"
         streams the column blocks AND the rl/ch write-backs through
         double-buffered async copies).
@@ -1933,11 +1948,12 @@ def wave_row_update_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
     interpret = resolve_interpret(interpret)
     _note_kernel(f"ops/hist_kernel/row_update/{pipeline}"
                  + ("/fetch" if fetch and pipeline == "dma" else "")
-                 + ("/cat" if cat is not None else ""),
+                 + ("/efb" if bundled else "/cat" if cat is not None else ""),
                  w * n * bins.dtype.itemsize + n * 4 + n * 5)
     if fetch:
         feats = jnp.clip(feats.astype(jnp.int32), 0, f - 1)
     is_cat = cat is not None
+    tag = "_efb" if bundled else ""
     if pipeline == "dma":
         if bins.ndim == 2:
             bins = bin_rows_view(bins, pipeline)
@@ -1945,12 +1961,12 @@ def wave_row_update_pallas(bins: jnp.ndarray, rl: jnp.ndarray,
         tab = tab.at[7].set(ids)
         return _wave_row_update_dma(
             bins, rl, _cat_table(tab, *cat) if is_cat else tab,
-            interpret=interpret, cat=is_cat)
+            interpret=interpret, cat=is_cat, tag=tag)
     if fetch:
         bins = gather_bin_rows(bins.reshape(f, n), feats)
     return _wave_row_update_bs(
         bins, rl, _cat_table(tab, *cat) if is_cat else tab,
-        row_block=row_block, interpret=interpret, cat=is_cat)
+        row_block=row_block, interpret=interpret, cat=is_cat, tag=tag)
 
 
 def wave_trial_channels_pallas(bins: jnp.ndarray, rl: jnp.ndarray,

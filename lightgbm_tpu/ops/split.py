@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["SplitParams", "FeatureSplits", "best_split_per_feature",
-           "leaf_output", "leaf_output_smoothed",
+           "best_split_two_bin", "leaf_output", "leaf_output_smoothed",
            "monotone_penalty_factor", "BIG"]
 
 NEG_INF = -1e30
@@ -589,3 +589,77 @@ def _best_split_impl(hist: jnp.ndarray, parent_sum: jnp.ndarray,
 
 _best_split_jit = functools.partial(jax.jit, static_argnames=("params",))(
     _best_split_impl)
+
+
+def best_split_two_bin(left: jnp.ndarray, parent_sum: jnp.ndarray,
+                       params: SplitParams,
+                       monotone: Optional[jnp.ndarray] = None,
+                       bound: Optional[jnp.ndarray] = None,
+                       depth: Optional[jnp.ndarray] = None,
+                       cegb_penalty: Optional[jnp.ndarray] = None,
+                       gain_scale: Optional[jnp.ndarray] = None,
+                       parent_out: Optional[jnp.ndarray] = None
+                       ) -> jnp.ndarray:
+    """Gain (F,) of the ONE split a numeric two-bin feature has, bin 0
+    left | bin 1 right, with the FEATURES ON THE LANE AXIS: ``left``
+    (3, F) holds bin 0's (sum_grad, sum_hess, count) of every feature.
+
+    The arithmetic is :func:`best_split_per_feature`'s at threshold 0 of
+    a (F, 2, 3) histogram, operation for operation (an (F, 2) plane would
+    put 2 bins on 128 lanes); a trailing NaN bin changes nothing, since a
+    split that leaves one side empty is never valid.  The wave grower's
+    EFB scan calls it for the indicator columns of a bundle (efb.py
+    ``make_scan_expand``); the other per-feature operands as there.
+    The winner's left sums are ``left[:, f]``, its right sums the parent's
+    less them, its threshold bin 0 and its default direction right."""
+    l1, l2 = params.lambda_l1, params.lambda_l2
+    min_h = params.min_sum_hessian_in_leaf
+    mdl = params.min_data_in_leaf
+    min_cnt = (mdl.astype(jnp.float32)
+               if isinstance(mdl, (jax.Array, jax.core.Tracer))
+               else float(mdl))
+    use_mc = params.use_monotone
+    use_sm = params.path_smooth > 0.0
+    if use_sm:
+        parent_gain = _gain_given_output(parent_sum[0], parent_sum[1],
+                                         parent_out, l1, l2)
+    else:
+        parent_gain = _leaf_gain(parent_sum[0], parent_sum[1], l1, l2)
+    min_gain_shift = parent_gain + params.min_gain_to_split
+    lg, lh, lc = left[0], left[1], left[2]
+    rg, rh, rc = parent_sum[0] - lg, parent_sum[1] - lh, parent_sum[2] - lc
+    ok = (lc >= min_cnt) & (rc >= min_cnt) & (lh >= min_h) & (rh >= min_h)
+    if use_mc or use_sm:
+        def out_of(sg, sh, sc):
+            t = _threshold_l1(sg, l1)
+            h_ = sh + l2
+            out = jnp.where(h_ > 0, -t / h_, 0.0)
+            if params.max_delta_step > 0.0:
+                out = jnp.clip(out, -params.max_delta_step,
+                               params.max_delta_step)
+            if use_sm:
+                fac = sc / (sc + params.path_smooth)
+                out = out * fac + parent_out * (1.0 - fac)
+            return jnp.clip(out, bound[0], bound[1]) if use_mc else out
+        out_l, out_r = out_of(lg, lh, lc), out_of(rg, rh, rc)
+        gl = _gain_given_output(lg, lh, out_l, l1, l2)
+        gr = _gain_given_output(rg, rh, out_r, l1, l2)
+        if use_mc:
+            ok = ok & jnp.logical_not(((monotone > 0) & (out_l > out_r)) |
+                                      ((monotone < 0) & (out_l < out_r)))
+    else:
+        gl = _leaf_gain(lg, lh, l1, l2)
+        gr = _leaf_gain(rg, rh, l1, l2)
+    g = gl + gr - min_gain_shift
+    if use_mc and params.monotone_penalty > 0.0:
+        pen = monotone_penalty_factor(depth, params.monotone_penalty)
+        g = jnp.where(monotone != 0, g * pen, g)
+    gain = jnp.where(ok & (g > 0), g, NEG_INF)
+    if params.use_cegb:
+        delta = (params.cegb_tradeoff * params.cegb_penalty_split *
+                 parent_sum[2] +
+                 (cegb_penalty if cegb_penalty is not None else 0.0))
+        gain = jnp.where(gain > NEG_INF / 2, gain - delta, gain)
+    if gain_scale is not None:
+        gain = jnp.where(gain > NEG_INF / 2, gain * gain_scale, gain)
+    return gain

@@ -371,11 +371,13 @@ class TrainRecord:
                  compile_since: Optional[float] = None,
                  mesh: Optional[Dict[str, Any]] = None,
                  grower: Optional[Dict[str, Any]] = None,
-                 score_update: Optional[str] = None) -> None:
+                 score_update: Optional[str] = None,
+                 efb: Optional[Dict[str, Any]] = None) -> None:
         self._lock = threading.Lock()
         self.meta = dict(meta or {})
         self.mesh = dict(mesh or {})
         self.grower = dict(grower or {})
+        self.efb = dict(efb or {})
         self.score_update = score_update
         self._t_created = time.perf_counter()
         # JAX's trace/lower/compile events count from here (perf_counter):
@@ -598,7 +600,12 @@ class TrainRecord:
         static paths it was built with (learner/wave.py ``static_paths``:
         ``ramp``, ``endgame``, ``scatter``, ``voting``, ``efb``,
         ``any_cat``, ``row_update`` "kernel" | "xla", ``hist_acc_rows``),
-        {} under a learner that grows some other way.  ``score_update``:
+        {} under a learner that grows some other way.  ``efb``: the
+        bundling as the data set was built (efb.py ``BundleInfo.record``:
+        ``features``, ``bundles``, ``bundle_bins``, ``bundled_features``,
+        ``conflict_rows``, the rows in which a bundle's conflict overwrote
+        a member's value, counted over all rows), {} where nothing was
+        bundled.  ``score_update``:
         the lowering the booster's training-set score update took,
         ``"select"`` | ``"gather"`` (models/gbdt.py
         ``score_update_lowering``), None for a record no booster made."""
@@ -658,6 +665,7 @@ class TrainRecord:
             "collectives": coll,
             "mesh": dict(self.mesh),
             "grower": dict(self.grower),
+            "efb": dict(self.efb),
             "score_update": self.score_update,
             "hist_kernel": hist_kernels,
             "compile_events": events,

@@ -44,7 +44,8 @@ from chipbench.tests.test_run_cat import (  # noqa: F401
 def test_the_manifest_with_five_cells_passes(tmp_path):
     assert validate.validate(helpers.REPO) == []
     m = mf.load_manifest(helpers.REPO)
-    assert len(m["configs"]) == 5 and len(m["workloads"]) == 5
+    # five when this cell came (PR 34); tests/test_chipbench_efb.py holds the count now
+    assert len(m["configs"]) >= 5 and len(m["workloads"]) >= 5
     assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == ["criteo-q8-dp4.train"]
     cell = mf.find_named(m["workloads"], "criteo-cat-q8.train", "workload")
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
@@ -55,7 +56,8 @@ def test_the_manifest_with_five_cells_passes(tmp_path):
     no_ramp_no_endgame = {"marginal_pass_ms", "endgame_rows_share", "ramp_sample_row_share"}
     assert mine == (q8 - no_ramp_no_endgame) | set(helpers_cat.CAT_METRICS)
     for name in helpers_cat.CAT_METRICS:
-        assert mf.find_named(m["per_layer"], name, "metric")["workloads"] == [cell["name"]]
+        # row_update_kernel_roofline holds for any fused routing: PR 38's cell reports it too
+        assert mf.find_named(m["per_layer"], name, "metric")["workloads"][0] == cell["name"]
     cfg = mf.load_json(f"{helpers.REPO}/chipbench/configs/criteo-kaggle-cat-q8.json")
     assert cfg["reduced"] == ["num_trees"] and cfg["data"]["rows"] == 45_840_617
     assert cfg["data"]["rows"] == cfg["upstream"]["rows"]

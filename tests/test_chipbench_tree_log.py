@@ -139,11 +139,14 @@ def test_class_rows_of_one_iteration_count_once():
 def test_the_manifest_lists_the_nine_where_they_find_something():
     assert validate.validate(helpers.REPO) == []
     m = mf.load_manifest(helpers.REPO)
-    assert [p["name"] for p in m["per_layer"][-9:]] == CLOCK + COUNTS
+    names = [p["name"] for p in m["per_layer"]]
+    first = names.index(CLOCK[0])       # later PRs append their metrics behind the nine
+    assert names[first:first + 9] == CLOCK + COUNTS
     for name in CLOCK + COUNTS:
         entry = mf.find_named(m["per_layer"], name, "metric")
         assert entry["moves"] == "train_iters_per_s"
-        assert entry["workloads"] == (NUMERIC if name in NOT_IN_CAT else CELLS)
+        listed = NUMERIC if name in NOT_IN_CAT else CELLS
+        assert entry["workloads"][:len(listed)] == listed     # and their cells behind these
         assert entry["source"] == ("program_span" if name in CLOCK
                                    else "program_counter")
     cat = {p["name"] for p in mf.metrics_for(m, "criteo-cat-q8.train", "per_layer")}

@@ -150,6 +150,28 @@ def test_cat_row_update_kernel_compiles_at_cell_shape(S):
     assert f"u8[{f},8,{n // 8}]" in text and f"s32[17,{w}]" in text
 
 
+def test_efb_row_update_kernel_compiles_at_cell_shape(S):
+    """``allstate-efb-q8.train`` (PR 38): 12,184,290 rows padded to the row
+    block x 48 bundle columns; a slot whose split feature lives in a bundle
+    carries the set of bundle codes that go left where a categorical slot
+    carries its categories, and the kernel's name says ``_efb``."""
+    g, n, w = 48, 12_185_600, 42
+
+    def route(bins, feats, rl, tab, bundled, go):
+        return wave_row_update_pallas(
+            bin_rows_view(bins, "dma"), rl, tab, feats=feats,
+            cat=(bundled, go), bundled=True, pipeline="dma", interpret=False)
+
+    compiled = jax.jit(route).lower(
+        S((g, n), jnp.uint8), S((w,), jnp.int32), S((n,), jnp.uint8),
+        S((8, w), jnp.int32), S((w,), jnp.bool_),
+        S((w, 256), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"lgbm_wave_row_update_dma_efb_w{w}_f{g}_kr4096_n{n}" in traced_kernels()
+    assert f"u8[{g},8,{n // 8}]" in text and f"s32[17,{w}]" in text
+
+
 def test_goss_sampler_compiles_at_cell_rows(S):
     """The GOSS draw of ``criteo-q8-goss.train`` (PR 32): one program over
     the cell's 21,250,000 rows whose exact threshold is a loop of counting
