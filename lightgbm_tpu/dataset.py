@@ -646,6 +646,12 @@ class Dataset:
                 raise ValueError(f"label length {len(self.metadata.label)} != rows {n}")
         self.metadata.set_weight(self._weight_arg)
         self.metadata.set_group(self._group_arg)
+        qb = self.metadata.query_boundaries
+        if qb is not None and qb[-1] != n:
+            # the boundaries are global row offsets: a block input's rows
+            # arrive in order, so its queries may straddle its blocks
+            raise ValueError(f"group sizes sum to {int(qb[-1])}; the data has "
+                             f"{n} rows (reference metadata.cpp)")
         self.metadata.set_init_score(self._init_score_arg)
 
     # -- reference-API surface ----------------------------------------------

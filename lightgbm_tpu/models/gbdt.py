@@ -715,6 +715,8 @@ class GBDT:
             grower=getattr(self.learner, "grower_paths", None),
             score_update=self._choose_score_update(),
             efb=train_set.efb.record() if train_set.efb is not None
+            else None,
+            objective=self.objective.record() if self.objective is not None
             else None)
         self.train_record.add_setup_seconds(
             getattr(train_set, "setup_seconds", {}))

@@ -49,6 +49,12 @@ class ObjectiveFunction:
     def check_label(self, label: np.ndarray) -> None:
         pass
 
+    def record(self) -> dict:
+        """What the objective laid out at ``init``, as the run's
+        ``TrainRecord`` states it (``snapshot()["objective"]``); {} where
+        it lays out nothing beyond the rows."""
+        return {}
+
     @property
     def num_model_per_iteration(self) -> int:
         return 1

@@ -2,15 +2,30 @@
 ``RankingObjective`` base parallelizing per query at :25-67, LambdarankNDCG
 pairwise lambdas at :140-227, RankXENDCG at :284-352).
 
-The reference loops documents per query with OpenMP; here queries are padded
-to a common length M and the per-query pairwise computation is a vmapped
-(M, M) tensor expression, chunked over queries with ``lax.map`` to bound the
-pairwise memory.  The sigmoid lookup table (rank_objective.hpp:249) is
-unnecessary — the VPU computes exact sigmoids faster than a gather."""
+The reference loops documents per query with OpenMP.  Here lambdarank lays
+its queries out in LENGTH BUCKETS (:class:`QueryLayout`): a query of L
+documents takes a column of the bucket whose width is the least power of two
+>= max(L, 8), the widest bucket no wider than the longest query.  A bucket
+holds its documents along the first axis and its queries along the second,
+so on the chip the queries fill the lanes.  The reference's pair loop pairs
+only the top ``lambdarank_truncation_level`` sorted positions with the rest
+(``i < truncation_level``), so a bucket of width M evaluates a (T, M) pair
+block a query, T = min(truncation, M), not (M, M): its sums over the
+positions give the lambdas of the top T, its sums over the top T those of
+every position.  Each row of the training set has exactly one slot in the
+layout; the rows reach their slots, and the lambdas return to row order, by
+sorts on static keys: a TPU gather moves one element at a time (on a v5e
+the three gathers, 10.3 M slots in and 7.3 M lambdas and hessians out, took
+200 ms an iteration, the two sorts that replace them about 80 ms).  The sigmoid lookup table
+(rank_objective.hpp:249) is unnecessary — the VPU computes exact sigmoids
+faster than a gather.
+
+``rank_xendcg`` keeps one (Q, M) padding of every query to the longest
+(:func:`pad_queries`): its per-query work is a softmax, linear in M."""
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -19,22 +34,114 @@ import numpy as np
 from .base import EPS, ObjectiveFunction
 
 KMIN_SCORE = -1e30
+MIN_BUCKET_WIDTH = 8
+# elements of one chunk's (T, M, chunk) pair tensor (~64 MB a float32 plane)
+PAIR_CHUNK_ELEMENTS = 16 << 20
+LANES = 128
 
 
 def pad_queries(query_boundaries: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Build (Q, M) flat-index + validity tensors from query boundaries."""
+    """Build (Q, M) flat-index + validity tensors from query boundaries,
+    M the longest query rounded up to a multiple of 8."""
     sizes = np.diff(query_boundaries)
-    q = len(sizes)
-    m = int(sizes.max()) if q else 1
-    # round M up to a lane-friendly multiple
+    m = max(1, int(sizes.max()) if len(sizes) else 1)
     m = int(np.ceil(m / 8) * 8)
-    idx = np.zeros((q, m), dtype=np.int32)
-    valid = np.zeros((q, m), dtype=bool)
-    for i in range(q):
-        s, e = query_boundaries[i], query_boundaries[i + 1]
-        idx[i, : e - s] = np.arange(s, e)
-        valid[i, : e - s] = True
-    return idx, valid
+    pos = np.arange(m)
+    valid = pos[None, :] < sizes[:, None]
+    idx = np.where(valid, query_boundaries[:-1, None] + pos[None, :], 0)
+    return idx.astype(np.int32), valid
+
+
+def bucket_width(sizes: np.ndarray) -> np.ndarray:
+    """Per query, the width of its bucket: the least power of two >= max(L,
+    8), capped at the longest query rounded up to a multiple of 8."""
+    sizes = np.asarray(sizes, np.int64)
+    top = int(np.ceil(max(int(sizes.max()), 1) / 8) * 8)
+    pow2 = np.left_shift(1, np.ceil(np.log2(np.maximum(sizes, 1))).astype(np.int64))
+    return np.minimum(np.maximum(pow2, MIN_BUCKET_WIDTH), top)
+
+
+def pairs_counted(sizes: np.ndarray, truncation: int) -> int:
+    """The pairs (i, j), i < j, i < truncation in sorted order, over all
+    queries: what the reference's loop visits, labels aside."""
+    L = np.asarray(sizes, np.int64)
+    t = np.minimum(truncation, L)
+    return int((t * (L - 1) - t * (t - 1) // 2).sum())
+
+
+class QueryLayout:
+    """Queries in length buckets, documents along the first axis and queries
+    along the second (host arrays, built without a loop over queries): on
+    the chip the queries fill the lanes.
+
+    Per bucket, in ascending width: ``widths[b]``, ``query_ids[b]`` (Q_b,),
+    ``idx[b]`` (M_b, Q_b) int32 rows of the training set (0 where padded),
+    ``valid[b]`` (M_b, Q_b) bool.  Over the buckets' (M_b, Q_b) slots laid
+    end to end (``row_slots`` of them): ``slot`` (N,) int32, each row's
+    slot; ``pad_slots``, the slots no row takes; ``slot_row`` (row_slots,)
+    int32, each slot's row, the padding slots numbered on from N."""
+
+    def __init__(self, query_boundaries: np.ndarray, num_data: int):
+        qb = np.asarray(query_boundaries, np.int64)
+        sizes = np.diff(qb)
+        if len(sizes) == 0 or sizes.min() < 0:
+            raise ValueError("query sizes must be non-negative, and one query at least")
+        if qb[0] != 0 or qb[-1] != num_data:
+            raise ValueError(f"queries cover rows {qb[0]}..{qb[-1]}; the data has "
+                             f"{num_data} rows")
+        self.sizes = sizes
+        width = bucket_width(sizes)
+        self.widths: List[int] = [int(w) for w in np.unique(width)]
+        self.query_ids: List[np.ndarray] = []
+        self.idx: List[np.ndarray] = []
+        self.valid: List[np.ndarray] = []
+        self.slot = np.empty(num_data, np.int32)
+        base = 0
+        for w in self.widths:
+            q = np.flatnonzero(width == w)
+            pos = np.arange(w)[:, None]
+            valid = pos < sizes[None, q]
+            rows = qb[None, q] + pos
+            self.query_ids.append(q)
+            self.idx.append(np.where(valid, rows, 0).astype(np.int32))
+            self.valid.append(valid)
+            self.slot[rows[valid]] = base + np.flatnonzero(valid.ravel())
+            base += valid.size
+        self.row_slots = base
+        # sorted by slot_row, the slots come back in row order, padding last
+        self.slot_row = np.empty(base, np.int32)
+        self.slot_row[self.slot] = np.arange(num_data, dtype=np.int32)
+        pad = np.ones(base, bool)
+        pad[self.slot] = False
+        self.pad_slots = np.flatnonzero(pad).astype(np.int32)
+        self.slot_row[self.pad_slots] = num_data + np.arange(len(self.pad_slots))
+
+    def pair_width(self, b: int, truncation: int) -> int:
+        return min(int(truncation), self.widths[b])
+
+    def record(self, truncation: int) -> Dict[str, object]:
+        """The layout as a ``TrainRecord`` states it."""
+        pair_slots = sum(len(q) * self.pair_width(b, truncation) * w
+                         for b, (w, q) in enumerate(zip(self.widths, self.query_ids)))
+        return {"queries": int(len(self.sizes)),
+                "longest_query": int(self.sizes.max()),
+                "truncation_level": int(truncation),
+                "buckets": [[int(w), int(len(q))]
+                            for w, q in zip(self.widths, self.query_ids)],
+                "row_slots": int(self.row_slots),
+                "pair_slots": int(pair_slots),
+                "pairs_counted": pairs_counted(self.sizes, truncation)}
+
+
+def max_dcg_at_k(labels: np.ndarray, valid: np.ndarray, k: int,
+                 label_gain: np.ndarray) -> np.ndarray:
+    """Per column of an (M, Q) bucket, the DCG of its labels in descending
+    order at the top ``k`` (dcg_calculator.cpp CalMaxDCGAtK), float64."""
+    lab = np.where(valid, labels, -1.0)
+    top = -np.sort(-lab, axis=0)[:k]
+    disc = 1.0 / np.log2(np.arange(top.shape[0]) + 2.0)
+    gains = np.where(top >= 0, label_gain[np.maximum(top, 0).astype(np.int64)], 0.0)
+    return (gains * disc[:, None]).sum(axis=0)
 
 
 class RankingObjective(ObjectiveFunction):
@@ -45,13 +152,7 @@ class RankingObjective(ObjectiveFunction):
         if metadata.query_boundaries is None:
             raise ValueError(f"objective {self.name} requires query/group data")
         self.query_boundaries = metadata.query_boundaries
-        idx, valid = pad_queries(self.query_boundaries)
-        self.q_idx = jnp.asarray(idx)
-        self.q_valid = jnp.asarray(valid)
-        self.num_queries = idx.shape[0]
-        # chunk queries so the (chunk, M, M) pairwise tensor stays ~64MB
-        m = idx.shape[1]
-        self.q_chunk = max(1, min(self.num_queries, int((16 << 20) / max(1, m * m))))
+        self.num_queries = len(self.query_boundaries) - 1
 
 
 class LambdarankNDCG(RankingObjective):
@@ -72,115 +173,130 @@ class LambdarankNDCG(RankingObjective):
 
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
-        # inverse max DCG per query at the truncation level
-        # (rank_objective.hpp:121-135 via dcg_calculator.cpp CalMaxDCGAtK)
-        lab = np.asarray(metadata.label)
-        qb = self.query_boundaries
-        inv = np.zeros(self.num_queries)
-        for i in range(self.num_queries):
-            ql = np.sort(lab[qb[i]:qb[i + 1]])[::-1][:self.truncation_level]
-            gains = self.label_gain[ql.astype(np.int32)]
-            disc = 1.0 / np.log2(np.arange(len(ql)) + 2.0)
-            mdcg = float((gains * disc).sum())
-            inv[i] = 1.0 / mdcg if mdcg > 0 else 0.0
-        self.inverse_max_dcg = jnp.asarray(inv, jnp.float32)
-        self.label_gain_dev = jnp.asarray(self.label_gain, jnp.float32)
+        layout = QueryLayout(self.query_boundaries, num_data)
+        self.layout = layout
+        lab = np.asarray(metadata.label, np.float32)
+        buckets, base = [], 0
+        for idx, valid in zip(layout.idx, layout.valid):
+            lb = np.where(valid, lab[idx], 0.0).astype(np.float32)
+            # inverse max DCG per query at the truncation level
+            # (rank_objective.hpp:121-135 via dcg_calculator.cpp CalMaxDCGAtK)
+            mdcg = max_dcg_at_k(lb, valid, self.truncation_level, self.label_gain)
+            inv = np.where(mdcg > 0, 1.0 / np.where(mdcg > 0, mdcg, 1.0), 0.0)
+            gain = self.label_gain[lb.astype(np.int64)]
+            rows = layout.slot_row[base:base + valid.size].reshape(valid.shape)
+            base += valid.size
+            buckets.append(tuple(jnp.asarray(a) for a in (
+                rows, valid.sum(axis=0).astype(np.int32), lb, gain.astype(np.float32),
+                inv.astype(np.float32))))
+        # the discount of every sorted position, rounded once from float64
+        # (DCGCalculator's table): the chip's log2 is a few ulp off, and the
+        # paired discount of neighbouring positions magnifies that
+        disc = 1.0 / np.log2(np.arange(max(layout.widths)) + 2.0)
+        # sorted by it, the rows followed by padding land in their slots
+        fwd = np.concatenate([layout.slot, layout.pad_slots]).astype(np.int32)
+        self._bucket_args = (jnp.asarray(fwd), tuple(buckets), jnp.asarray(disc, jnp.float32))
         self._grad_fn = self._build_grad_fn()
+
+    def record(self) -> Dict[str, object]:
+        return {"name": self.name, **self.layout.record(self.truncation_level)}
 
     def _build_grad_fn(self):
         sig = self.sigmoid
-        trunc = self.truncation_level
         norm = self.norm
-        m = int(self.q_idx.shape[1])
+        layout = self.layout
+        tops = [layout.pair_width(b, self.truncation_level)
+                for b in range(len(layout.widths))]
 
-        def one_query(s, lab, valid, inv_max_dcg):
-            # sort docs by score desc (stable); padding scores are KMIN_SCORE
-            s_in = jnp.where(valid, s, KMIN_SCORE)
-            order = jnp.argsort(-s_in, stable=True)
-            ss = s_in[order]
-            sl = lab[order]
-            sv = valid[order]
-            gains = self.label_gain_dev[jnp.clip(sl.astype(jnp.int32), 0, None)]
-            ranks = jnp.arange(m)
-            disc = 1.0 / jnp.log2(ranks + 2.0)
-            n_valid = jnp.sum(sv.astype(jnp.int32))
+        def pair_block(ss, sl, sg, n_valid, inv_max_dcg, disc, t):
+            """Lambdas and hessians of a chunk of queries sorted along axis 0,
+            (M, c): the pairs (i < t, j > i) of the reference's loop, in a
+            (t, M, c) block whose two sums give every position's share."""
+            m = ss.shape[0]
+            pos = jnp.arange(m)
+            sv = pos[:, None] < n_valid[None, :]
             best = ss[0]
-            worst = ss[jnp.maximum(n_valid - 1, 0)]
-
-            iu = ranks[:, None]
-            ju = ranks[None, :]
-            pair = ((iu < ju) & sv[:, None] & sv[None, :] &
-                    (sl[:, None] != sl[None, :]) & (iu < trunc))
-            hi_is_i = sl[:, None] > sl[None, :]
-            s_hi = jnp.where(hi_is_i, ss[:, None], ss[None, :])
-            s_lo = jnp.where(hi_is_i, ss[None, :], ss[:, None])
-            delta_score = s_hi - s_lo
-            dcg_gap = jnp.abs(gains[:, None] - gains[None, :])
-            paired_disc = jnp.abs(disc[:, None] - disc[None, :])
-            delta_ndcg = dcg_gap * paired_disc * inv_max_dcg
+            worst = jnp.take_along_axis(ss, jnp.maximum(n_valid - 1, 0)[None, :], 0)[0]
+            d = disc[:m, None]
+            # i: the top t sorted positions (axis 0); j: every position (axis 1)
+            s_i, l_i, g_i, v_i, d_i = (a[:t, None, :] for a in (ss, sl, sg, sv, d))
+            s_j, l_j, g_j, v_j, d_j = (a[None, :, :] for a in (ss, sl, sg, sv, d))
+            pair = (pos[:t, None] < pos[None, :])[:, :, None] & v_i & v_j & (l_i != l_j)
+            hi_is_i = l_i > l_j
+            delta_score = jnp.where(hi_is_i, s_i - s_j, s_j - s_i)
+            delta_ndcg = jnp.abs(g_i - g_j) * jnp.abs(d_i - d_j) * inv_max_dcg[None, None, :]
             if norm:
-                delta_ndcg = jnp.where(best != worst,
+                delta_ndcg = jnp.where((best != worst)[None, None, :],
                                        delta_ndcg / (0.01 + jnp.abs(delta_score)),
                                        delta_ndcg)
             p = 1.0 / (1.0 + jnp.exp(sig * delta_score))
-            p_lambda = -sig * delta_ndcg * p
-            p_hess = sig * sig * delta_ndcg * p * (1.0 - p)
-            p_lambda = jnp.where(pair, p_lambda, 0.0)
-            p_hess = jnp.where(pair, p_hess, 0.0)
-
-            sign = jnp.where(hi_is_i, 1.0, -1.0)
-            contrib = sign * p_lambda
-            lam_sorted = jnp.sum(contrib, axis=1) - jnp.sum(contrib, axis=0)
-            hess_sorted = jnp.sum(p_hess, axis=1) + jnp.sum(p_hess, axis=0)
-            sum_lambdas = -2.0 * jnp.sum(p_lambda)
+            p_lambda = jnp.where(pair, -sig * delta_ndcg * p, 0.0)
+            p_hess = jnp.where(pair, sig * sig * delta_ndcg * p * (1.0 - p), 0.0)
+            # lambdas[high] += p_lambda, lambdas[low] -= p_lambda
+            contrib = jnp.where(hi_is_i, p_lambda, -p_lambda)
+            lam = -jnp.sum(contrib, axis=0)
+            hes = jnp.sum(p_hess, axis=0)
+            lam = lam.at[:t].add(jnp.sum(contrib, axis=1))
+            hes = hes.at[:t].add(jnp.sum(p_hess, axis=1))
             if norm:
-                factor = jnp.where(sum_lambdas > 0,
-                                   jnp.log2(1.0 + sum_lambdas) / jnp.maximum(
-                                       sum_lambdas, EPS), 1.0)
-                lam_sorted = lam_sorted * factor
-                hess_sorted = hess_sorted * factor
-            # unsort back to query-document order
-            inv_order = jnp.argsort(order, stable=True)
-            return lam_sorted[inv_order], hess_sorted[inv_order]
+                sum_lambdas = -2.0 * jnp.sum(p_lambda, axis=(0, 1))
+                factor = jnp.where(sum_lambdas > 0, jnp.log2(1.0 + sum_lambdas) /
+                                   jnp.maximum(sum_lambdas, EPS), 1.0)
+                lam = lam * factor[None, :]
+                hes = hes * factor[None, :]
+            return lam, hes
 
-        vq = jax.vmap(one_query)
+        def bucket(s, disc, rows, n_valid, lab, gain, inv_max_dcg, t):
+            m, q = s.shape
+            s = jnp.where(s == 0, 0.0, s)            # -0.0 ties with 0.0, as in C++
+            # descending, stable: padding (KMIN_SCORE) sorts last
+            neg, rows, sl, sg = jax.lax.sort((-s, rows, lab, gain), dimension=0, num_keys=1,
+                                             is_stable=True)
+            ss = -neg
+            chunk = max(1, min(q, PAIR_CHUNK_ELEMENTS // (t * m)))
+            if chunk >= q:
+                lam, hes = pair_block(ss, sl, sg, n_valid, inv_max_dcg, disc, t)
+            else:
+                chunk = max(LANES, chunk // LANES * LANES)
+                nch = -(-q // chunk)
+                pad = nch * chunk - q
+                cols = lambda a: jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),))
+                ss, sl, sg, n_valid, inv = map(cols, (ss, sl, sg, n_valid, inv_max_dcg))
+
+                def step(k, acc):
+                    at = k * chunk
+                    part = lambda a: jax.lax.dynamic_slice_in_dim(a, at, chunk, a.ndim - 1)
+                    lk, hk = pair_block(part(ss), part(sl), part(sg), part(n_valid),
+                                        part(inv), disc, t)
+                    return (jax.lax.dynamic_update_slice_in_dim(acc[0], lk, at, 1),
+                            jax.lax.dynamic_update_slice_in_dim(acc[1], hk, at, 1))
+
+                zero = jnp.zeros((m, nch * chunk), jnp.float32)
+                lam, hes = jax.lax.fori_loop(0, nch, step, (zero, zero))
+                lam, hes = lam[:, :q], hes[:, :q]
+            return rows.reshape(-1), lam.reshape(-1), hes.reshape(-1)
+
+        shapes = [v.shape for v in layout.valid]
 
         @jax.jit
-        def grad_fn(score, label, q_idx, q_valid, inv_max_dcg):
-            n = score.shape[0]
-            q, mm = q_idx.shape
-            chunk = self.q_chunk
-            nchunks = -(-q // chunk)
-            padq = nchunks * chunk - q
-            qi = jnp.pad(q_idx, ((0, padq), (0, 0)))
-            qv = jnp.pad(q_valid, ((0, padq), (0, 0)))
-            qd = jnp.pad(inv_max_dcg, (0, padq))
-            s_g = score[qi]
-            l_g = label[qi]
-
-            def do_chunk(args):
-                s, l, v, d = args
-                return vq(s, l, v, d)
-
-            lam, hes = jax.lax.map(do_chunk, (
-                s_g.reshape(nchunks, chunk, mm),
-                l_g.reshape(nchunks, chunk, mm),
-                qv.reshape(nchunks, chunk, mm),
-                qd.reshape(nchunks, chunk)))
-            lam = lam.reshape(-1, mm)[:q]
-            hes = hes.reshape(-1, mm)[:q]
-            v = q_valid
-            grad = jnp.zeros((n,), jnp.float32).at[q_idx.reshape(-1)].add(
-                jnp.where(v, lam, 0.0).reshape(-1), mode="drop")
-            hess = jnp.zeros((n,), jnp.float32).at[q_idx.reshape(-1)].add(
-                jnp.where(v, hes, 0.0).reshape(-1), mode="drop")
-            return grad, hess
+        def grad_fn(score, fwd, buckets, disc):
+            with jax.named_scope("lgbm.rank.lambdas"):
+                n = score.shape[0]
+                ext = jnp.concatenate([score, jnp.full((fwd.shape[0] - n,), KMIN_SCORE,
+                                                       score.dtype)])
+                _, flat = jax.lax.sort((fwd, ext), num_keys=1)
+                parts, at = [], 0
+                for (m, q), args, t in zip(shapes, buckets, tops):
+                    parts.append(bucket(flat[at:at + m * q].reshape(m, q), disc, *args, t))
+                    at += m * q
+                rows, lam, hes = (jnp.concatenate(a) for a in zip(*parts))
+                _, lam, hes = jax.lax.sort((rows, lam, hes), num_keys=1)
+                return lam[:n], hes[:n]
 
         return grad_fn
 
     def get_gradients(self, score):
-        return self._grad_fn(score, self.label, self.q_idx, self.q_valid,
-                             self.inverse_max_dcg)
+        return self._grad_fn(score, *self._bucket_args)
 
 
 class RankXENDCG(RankingObjective):
@@ -197,6 +313,9 @@ class RankXENDCG(RankingObjective):
 
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
+        idx, valid = pad_queries(self.query_boundaries)
+        self.q_idx = jnp.asarray(idx)
+        self.q_valid = jnp.asarray(valid)
         self._grad_fn = self._build_grad_fn()
 
     def _build_grad_fn(self):

@@ -377,12 +377,14 @@ class TrainRecord:
                  mesh: Optional[Dict[str, Any]] = None,
                  grower: Optional[Dict[str, Any]] = None,
                  score_update: Optional[str] = None,
-                 efb: Optional[Dict[str, Any]] = None) -> None:
+                 efb: Optional[Dict[str, Any]] = None,
+                 objective: Optional[Dict[str, Any]] = None) -> None:
         self._lock = threading.Lock()
         self.meta = dict(meta or {})
         self.mesh = dict(mesh or {})
         self.grower = dict(grower or {})
         self.efb = dict(efb or {})
+        self.objective = dict(objective or {})
         self.score_update = score_update
         self._t_created = time.perf_counter()
         # JAX's trace/lower/compile events count from here (perf_counter):
@@ -610,7 +612,13 @@ class TrainRecord:
         ``features``, ``bundles``, ``bundle_bins``, ``bundled_features``,
         ``conflict_rows``, the rows in which a bundle's conflict overwrote
         a member's value, counted over all rows), {} where nothing was
-        bundled.  ``score_update``:
+        bundled.  ``objective``: what the objective laid out at ``init``
+        (``ObjectiveFunction.record``); lambdarank states its query
+        layout (objective/rank.py ``QueryLayout.record``: ``queries``,
+        ``longest_query``, ``truncation_level``, ``buckets`` [[width,
+        queries], ...], ``row_slots``, ``pair_slots`` evaluated an
+        iteration, ``pairs_counted`` by the reference's loop), {} for an
+        objective that lays out nothing.  ``score_update``:
         the lowering the booster's training-set score update took,
         ``"select"`` | ``"gather"`` (models/gbdt.py
         ``score_update_lowering``), None for a record no booster made."""
@@ -671,6 +679,7 @@ class TrainRecord:
             "mesh": dict(self.mesh),
             "grower": dict(self.grower),
             "efb": dict(self.efb),
+            "objective": dict(self.objective),
             "score_update": self.score_update,
             "hist_kernel": hist_kernels,
             "compile_events": events,

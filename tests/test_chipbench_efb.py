@@ -110,7 +110,7 @@ def failed(line) -> set:
 def test_the_manifest_with_six_cells_passes(tmp_path):
     assert validate.validate(helpers.REPO) == []
     m = mf.load_manifest(helpers.REPO)
-    assert len(m["configs"]) == 6 and len(m["workloads"]) == 6
+    assert len(m["configs"]) >= 6 and len(m["workloads"]) >= 6
     assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == ["criteo-q8-dp4.train"]
     cell = mf.find_named(m["workloads"], CELL, "workload")
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
